@@ -132,10 +132,8 @@ def main() -> int:
     if args.cpu:
         import jax
 
-        from pio_tpu.utils.jaxcompat import set_cpu_device_count
-
         jax.config.update("jax_platforms", "cpu")
-        set_cpu_device_count(1)
+        jax.config.update("jax_num_cpu_devices", 1)
     import statistics
 
     import jax

@@ -66,10 +66,8 @@ def _best(fn, reps: int = REPS) -> float:
 def run_one(n_dev: int) -> dict:
     import jax
 
-    from pio_tpu.utils.jaxcompat import set_cpu_device_count
-
     jax.config.update("jax_platforms", "cpu")
-    set_cpu_device_count(n_dev)
+    jax.config.update("jax_num_cpu_devices", n_dev)
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P

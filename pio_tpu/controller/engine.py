@@ -10,6 +10,8 @@ Engine.scala:727-817), and engine-variant JSON -> EngineParams extraction
 from __future__ import annotations
 
 import json
+import logging
+import time
 from dataclasses import dataclass, field
 from typing import Any, Sequence
 
@@ -20,6 +22,8 @@ from pio_tpu.controller.base import (
     params_to_dict,
     sanity_check,
 )
+
+log = logging.getLogger("pio_tpu.workflow")
 
 
 @dataclass
@@ -105,17 +109,27 @@ class Engine:
         stop_after_prepare: bool = False,
     ) -> list[Any]:
         ds, prep, algos, _ = self._doers(engine_params)
+        t0 = time.monotonic()
         td = ds.read_training(ctx)
         sanity_check(td)
         if stop_after_read:
             raise TrainingInterruption("read")
+        t1 = time.monotonic()
         pd = prep.prepare(ctx, td)
         sanity_check(pd)
         if stop_after_prepare:
             raise TrainingInterruption("prepare")
+        t2 = time.monotonic()
         models = [algo.train(ctx, pd) for algo in algos]
         for m in models:
             sanity_check(m)
+        import jax
+
+        # dispatch is asynchronous: wait here so the stage time below is
+        # the training's, not the enqueue's (persist would wait anyway)
+        jax.block_until_ready(models)
+        log.info("train stages: read %.2fs, prepare %.2fs, algorithms "
+                 "%.2fs", t1 - t0, t2 - t1, time.monotonic() - t2)
         return models
 
     # -- eval (reference Engine.object.eval, Engine.scala:727-817) ----------

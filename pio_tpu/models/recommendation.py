@@ -10,6 +10,7 @@ mesh); the model keeps factors as jax arrays resident in HBM for serving.
 
 from __future__ import annotations
 
+import logging
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -27,6 +28,8 @@ from pio_tpu.controller.engine import Engine, EngineFactory
 from pio_tpu.data.bimap import EntityIdIndex
 from pio_tpu.data.eventstore import Interactions
 from pio_tpu.ops import als
+
+log = logging.getLogger("pio_tpu.workflow")
 
 
 @dataclass(frozen=True)
@@ -205,7 +208,16 @@ class ALSAlgorithm(PAlgorithm):
         data.sanity_check()
         ap = self._als_params()
         vf = self.params.validation_fraction
-        if ctx.mesh is not None and ctx.mesh.devices.size > 1:
+        sharded = ctx.mesh is not None and ctx.mesh.devices.size > 1
+        log.info(
+            "ALS train: %d users x %d items, %d ratings, rank %d, %d "
+            "sweeps, %s; accum=%s gather=%s packed_a=%s",
+            data.n_users, data.n_items, len(data.values), ap.rank,
+            ap.iterations,
+            f"sharded over {ctx.mesh.devices.size} devices" if sharded
+            else "one device",
+            ap.resolved_accum(), ap.resolved_gather(), ap.resolved_packed())
+        if sharded:
             # sharded path: best-sweep selection not yet threaded through
             # shard_map (the curve would need a psum'd heldout metric);
             # last-sweep factors, as the reference always does

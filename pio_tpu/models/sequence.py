@@ -34,10 +34,6 @@ import optax
 from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pio_tpu.utils.jaxcompat import ensure_jax_compat
-
-ensure_jax_compat()  # jax<0.5: install the jax.shard_map forwarding wrapper
-
 from pio_tpu.controller.base import (
     DataSource,
     FirstServing,

@@ -180,8 +180,7 @@ def train_two_tower(
 
     def run_span(params, opt_state, uu, ii):
         """lax.scan over a span of steps — the whole span is ONE device
-        program: no per-step host round trip (dispatch-bound on a
-        remote/tunneled device) and no per-step transfer."""
+        program: no per-step host round trip and no per-step transfer."""
         def body(carry, xs):
             params, opt_state = carry
             u, i = xs

@@ -34,10 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from pio_tpu.utils.jaxcompat import ensure_jax_compat
-
-ensure_jax_compat()  # jax<0.5: install the jax.shard_map forwarding wrapper
-
 NEG_INF = -1e30
 
 

@@ -496,10 +496,9 @@ def build_device_index(index: RetrievalIndex) -> DeviceRetrievalIndex:
 # ---------------------------------------------------------------------------
 
 def resolved_impl(impl: str) -> str:
-    """"auto" stays pinned to the XLA scan until the on-hardware A/B
-    (bench retrieval cell on a TPU window) shows the Pallas kernel
-    winning — the als_pallas.py discipline: interpret-validated kernels
-    do not serve by default."""
+    """"auto" stays pinned to the XLA scan until an on-chip A/B shows
+    the Pallas kernel winning (ROADMAP S4): a kernel that merely
+    compiles does not serve by default."""
     return "xla" if impl == "auto" else impl
 
 
@@ -513,7 +512,7 @@ def quantized_scores_xla(table2d, scales, u) -> jax.Array:
 
 
 def quantized_scores_pallas(table2d, scales, u, *,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool | None = None) -> jax.Array:
     """Pallas TPU scan over one quantized block: the table block stays
     in its storage dtype until the in-register astype feeding the MXU
     dot (the whole point — HBM->VMEM moves 1-2 bytes/element, not 4).
@@ -522,14 +521,16 @@ def quantized_scores_pallas(table2d, scales, u, *,
     sublane tile (32) and k to the 128 lane; the user row is broadcast
     to a (k_pad, LANE) operand so the product is one lane-aligned MXU
     dot whose output columns are identical — column 0 is the answer.
-    Status: interpret-mode CPU parity vs quantized_scores_xla is pinned
-    in tests/test_retrieval.py; ``interpret=False`` compiles via Mosaic
-    but has not had a hardware A/B yet, so resolved_impl never selects
-    this path from "auto"."""
+    ``interpret`` resolves from the backend (compiled on a TPU, the
+    interpreter on CPU). Status: interpret-mode CPU parity vs
+    quantized_scores_xla is pinned in tests/test_retrieval.py; compiled
+    on a v5e it matches to 2e-3 at the MXU's default precision
+    (eval/kernel_parity.py, PR 21). No timing A/B yet, so resolved_impl
+    never selects this path from "auto"."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    del pltpu  # memory spaces default correctly for whole-array blocks
+    if interpret is None:
+        interpret = jax.devices()[0].platform == "cpu"
     m, k = table2d.shape
     lane = 128
     m_pad = m + (-m % 32)
@@ -571,8 +572,9 @@ def _clustered_topk_jit(u, centroids, table, scales, gidx, item_factors,
     sub_s = scales[top_c]                              # (B, P, Lmax)
     sub_g = gidx[top_c]                                # (B, P, Lmax)
     if impl == "pallas":
-        # interpret-mode kernel over each query's survivor block; the
-        # XLA path below is what "auto" serves (see resolved_impl)
+        # Pallas kernel over each query's survivor block (compiled on
+        # TPU, interpreted on CPU); the XLA path below is what "auto"
+        # serves (see resolved_impl)
         def one(args):
             q2d, s2d, urow = args
             return quantized_scores_pallas(

@@ -92,16 +92,6 @@ def initialize_distributed(
             "PIO_TPU_PROCESS_ID or pass them as arguments); all three are "
             "required for a multi-host job"
         )
-    platforms = (jax.config.jax_platforms
-                 or os.environ.get("JAX_PLATFORMS", "")).lower()
-    if int(kwargs["num_processes"]) > 1 and "cpu" in platforms.split(","):
-        # CPU backend: multiprocess computations need a cross-process
-        # collectives implementation selected BEFORE backend init (jax
-        # defaults to 'none' and fails at dispatch); no-op on TPU/GPU
-        # (platforms empty/auto) and on jaxlib builds without gloo
-        from pio_tpu.utils.jaxcompat import enable_cpu_collectives
-
-        enable_cpu_collectives()
     jax.distributed.initialize(**kwargs)
     _initialized = True
     log.info(

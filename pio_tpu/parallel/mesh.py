@@ -21,10 +21,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pio_tpu.utils.jaxcompat import ensure_jax_compat
-
-ensure_jax_compat()  # jax<0.5: install the jax.shard_map forwarding wrapper
-
 DATA_AXIS = "data"
 SEQ_AXIS = "seq"
 MODEL_AXIS = "model"
@@ -62,6 +58,28 @@ def create_mesh(
         data, seq, model
     )
     return Mesh(dev_array, (DATA_AXIS, SEQ_AXIS, MODEL_AXIS))
+
+
+def describe_devices() -> str:
+    """Count, platform, kind and jax version in one line. Every process
+    that takes the device logs it, so a run can show which backend it
+    ran on."""
+    devices = jax.devices()
+    return (f"{len(devices)} x {devices[0].platform} "
+            f"({devices[0].device_kind}), jax {jax.__version__}")
+
+
+def describe_device_memory() -> str:
+    """Peak and current bytes in use per local device, as the backend
+    reports them (the CPU backend reports none)."""
+    parts = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        parts.append(
+            f"{d.id}: peak {stats.get('peak_bytes_in_use', 'n/a')} "
+            f"in_use {stats.get('bytes_in_use', 'n/a')} "
+            f"limit {stats.get('bytes_limit', 'n/a')}")
+    return "; ".join(parts)
 
 
 def data_sharding(mesh: Mesh) -> NamedSharding:

@@ -24,10 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pio_tpu.utils.jaxcompat import ensure_jax_compat
-
-ensure_jax_compat()  # jax<0.5: install the jax.shard_map forwarding wrapper
-
 from pio_tpu.parallel.mesh import MODEL_AXIS
 
 

@@ -149,13 +149,11 @@ def cmd_version(args) -> int:
 
 def cmd_status(args) -> int:
     """Environment doctor (reference Console.status:1035-1107)."""
-    import jax
+    from pio_tpu.parallel.mesh import describe_devices
 
     print(f"pio-tpu {__version__}")
-    print(f"Python {sys.version.split()[0]}, jax {jax.__version__}")
-    devices = jax.devices()
-    print(f"devices: {len(devices)} x {devices[0].platform}"
-          f" ({devices[0].device_kind})")
+    print(f"Python {sys.version.split()[0]}")
+    print(f"devices: {describe_devices()}")
     storage = get_storage()
     print("storage sources:")
     for name, spec in storage.sources.items():
@@ -2855,8 +2853,9 @@ def build_parser() -> argparse.ArgumentParser:
         help="persistent XLA compile cache: show size/location, prune, "
              "or clear (docs/performance.md)")
     x.add_argument("--dir", default=None,
-                   help="cache directory (default $PIO_TPU_COMPILE_CACHE "
-                        "or $PIO_TPU_HOME/compile_cache)")
+                   help="cache directory (default "
+                        "$JAX_COMPILATION_CACHE_DIR, else .jax_cache in "
+                        "the checkout)")
     x.add_argument("--clear", action="store_true",
                    help="delete every cached executable and bucket "
                         "registry (next train/deploy recompiles)")
@@ -2915,10 +2914,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: list[str] | None = None) -> int:
-    # Platform override for CPU-only hosts / CI. Must use the config API:
-    # some deployments (including this project's own test image) pin
-    # JAX_PLATFORMS at interpreter startup, so the plain env var is
-    # snapshotted before user code runs.
+    # Platform override for CPU-only hosts / CI (JAX_PLATFORMS works too;
+    # this one also holds when jax was imported before the CLI ran).
     platform = os.environ.get("PIO_TPU_PLATFORM")
     n_cpu = os.environ.get("PIO_TPU_CPU_DEVICES")
     if platform or n_cpu:
@@ -2927,10 +2924,8 @@ def main(argv: list[str] | None = None) -> int:
         if platform:
             jax.config.update("jax_platforms", platform)
         if n_cpu:
-            from pio_tpu.utils.jaxcompat import set_cpu_device_count
-
             try:
-                set_cpu_device_count(int(n_cpu))
+                jax.config.update("jax_num_cpu_devices", int(n_cpu))
             except ValueError:
                 return _fail(f"PIO_TPU_CPU_DEVICES={n_cpu!r} is not an int")
     # engine dirs put engine.py on the path (factory "engine.MyEngine")
@@ -2946,4 +2941,13 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    # a pio process says on stderr what it runs on and what it did
+    # (devices, compile cache, stage timings, instance ids)
+    import logging
+
+    _handler = logging.StreamHandler()
+    _handler.setFormatter(logging.Formatter(
+        "%(asctime)s %(levelname)s %(name)s: %(message)s"))
+    logging.getLogger("pio_tpu").addHandler(_handler)
+    logging.getLogger("pio_tpu").setLevel(logging.INFO)
     sys.exit(main())

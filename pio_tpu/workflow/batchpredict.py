@@ -5,7 +5,7 @@ version this framework re-implements) because deploy-server round trips
 are the wrong shape for backfills; users migrating from the reference
 expect it, and it is the MOST TPU-congenial serving mode — large
 batched predicts amortize the device dispatch that dominates
-single-query latency (eval/SERVING_DECOMP.md).
+single-query latency (eval/serving_decomposition.py measures it).
 
 Runs each input line through the engine's full serving composition
 (supplement -> [algo.batch_predict ...] -> serve) via

@@ -3,7 +3,7 @@
 Both neural trainers (models/twotower.py, models/sequence.py) replace
 their per-step host loops with `lax.scan` over SPANS of steps: one
 compiled program per span instead of one dispatch + batch transfer per
-step (the per-step loop is dispatch-bound on remote/tunneled devices).
+step (the per-step loop is dispatch-bound).
 The span boundaries have to respect two constraints:
 
  * bounded staging — a span's batch tensors are materialized host-side

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import logging
 import os
+import time
 import traceback
 from contextlib import nullcontext
 from dataclasses import replace
@@ -168,10 +169,12 @@ def run_train(
     # consecutive train of the same engine deserializes its executables
     # instead of re-running XLA (utils/compilecache.py; PIO_TPU_COMPILE_
     # CACHE=off disables)
-    from pio_tpu.utils.compilecache import enable_compile_cache
+    from pio_tpu.parallel.mesh import describe_device_memory, describe_devices
+    from pio_tpu.utils.compilecache import CompileMeter, enable_compile_cache
 
     enable_compile_cache()
     ctx = ctx or create_workflow_context(storage)
+    log.info("train devices: %s", describe_devices())
     instances = storage.get_metadata_engine_instances()
     from pio_tpu.parallel.distributed import barrier, is_primary
 
@@ -242,14 +245,17 @@ def run_train(
     lifecycle.start()  # wall-clock liveness beat (see TrainLifecycle)
 
     ctx.lifecycle = lifecycle
+    compile_meter = CompileMeter()
     try:
         with handler if handler is not None else nullcontext():
+            t_train = time.monotonic()
             models = engine.train(
                 ctx,
                 engine_params,
                 stop_after_read=stop_after_read,
                 stop_after_prepare=stop_after_prepare,
             )
+            t_persist = time.monotonic()
             # chaos point: a `train.persist` spec simulates a storage
             # fault during the final model write — the run must land
             # FAILED (resumable from its last checkpoint), never
@@ -274,6 +280,10 @@ def run_train(
             record("COMPLETED")
             log.info("training %s COMPLETED (%d bytes of models)",
                      instance_id, len(blob))
+            log.info("train timing: engine.train %.2fs, of which compile "
+                     "%s; persist %.2fs", t_persist - t_train,
+                     compile_meter, time.monotonic() - t_persist)
+            log.info("train device memory: %s", describe_device_memory())
             return instance_id
     except TrainingPreempted as preempted:
         try:
@@ -306,6 +316,7 @@ def run_train(
             raise train_error from update_error
         raise
     finally:
+        compile_meter.close()
         lifecycle.stop()
         ctx.lifecycle = None
 

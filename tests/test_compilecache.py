@@ -17,23 +17,28 @@ def _reset_jax_cache():
     # jax binds its cache instance to the FIRST directory used in the
     # process; tests that switch directories must reset it (real
     # deployments use one directory per process, so only tests care)
-    try:
-        from jax._src import compilation_cache as jcc
+    from jax._src import compilation_cache as jcc
 
-        jcc.reset_cache()
-    except Exception:  # noqa: BLE001 - jax-version-dependent internals
-        pass
+    jcc.reset_cache()
 
 
 @pytest.fixture()
 def cache_dir(tmp_path, monkeypatch):
-    d = tmp_path / "cc"
-    monkeypatch.setenv("PIO_TPU_COMPILE_CACHE", str(d))
+    """A private cache directory, placed the way a deployment places it:
+    JAX_COMPILATION_CACHE_DIR. jax reads that variable into its config
+    at import; it is long imported here, so the fixture mirrors the read
+    by hand (the program itself must set no directory when it is set)."""
+    import jax
+
+    d = str(tmp_path / "cc")
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    jax.config.update("jax_compilation_cache_dir", d)
     # reset the module's enable-once state so each test sees a fresh dir
-    monkeypatch.setattr(cc, "_enabled_dir", None)
+    monkeypatch.setattr(cc, "_enabled", False)
     _reset_jax_cache()
-    yield str(d)
-    monkeypatch.setattr(cc, "_enabled_dir", None)
+    yield d
+    jax.config.update("jax_compilation_cache_dir", prev)
     _reset_jax_cache()
 
 
@@ -55,9 +60,83 @@ def test_enable_and_stats_and_clear(cache_dir):
     assert cc.cache_stats(d)["entries"] == 0
 
 
+def _spy_config_updates(monkeypatch) -> list[str]:
+    """-> the (growing) list of jax config options updated from now on."""
+    import jax
+
+    names: list[str] = []
+    real = jax.config.update
+
+    def update(name, value):
+        names.append(name)
+        return real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", update)
+    return names
+
+
+def test_env_places_the_cache_and_code_sets_no_directory(
+        cache_dir, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: jax has read it itself, so the
+    program uses that directory — for the stats and the bucket registry
+    too — and sets none of its own."""
+    updated = _spy_config_updates(monkeypatch)
+    assert cc.enable_compile_cache() == cache_dir
+    assert "jax_compilation_cache_dir" not in updated
+    assert updated         # the persistence floors are still dropped
+    assert cc.default_cache_dir() == cache_dir
+    import jax
+    import jax.numpy as jnp
+
+    float(jax.jit(lambda x: jnp.cos(x) - 7)(jnp.ones(())))
+    stats = cc.cache_stats()
+    assert stats["dir"] == cache_dir and stats["entries"] >= 1
+    reg = cc.BucketRegistry("rec", "1", "default")
+    assert reg.path.startswith(cache_dir)
+    # registries ride in the directory without counting as executables
+    reg.record(4)
+    reg.flush()
+    assert cc.cache_stats()["entries"] == stats["entries"]
+
+
+def test_without_env_the_cache_sits_at_one_fixed_path_in_the_checkout(
+        monkeypatch):
+    import os
+    import subprocess
+    import sys
+
+    import jax
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    code = ("from pio_tpu.utils import compilecache as cc; "
+            "print(cc.default_cache_dir())")
+    seen = {
+        subprocess.run([sys.executable, "-c", code], cwd=cwd, env=dict(
+            env, PYTHONPATH=repo), capture_output=True, text=True,
+            check=True).stdout.strip()
+        for cwd in (repo, "/")     # two processes, two working dirs
+    }
+    assert seen == {want}           # no pid, time or temp name in it
+
+    # in this process: with the variable unset the code points jax there
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(cc, "_enabled", False)
+    updated = _spy_config_updates(monkeypatch)
+    try:
+        assert cc.enable_compile_cache() == want
+        assert "jax_compilation_cache_dir" in updated
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        _reset_jax_cache()
+
+
 def test_kill_switch(monkeypatch):
     monkeypatch.setenv("PIO_TPU_COMPILE_CACHE", "off")
-    monkeypatch.setattr(cc, "_enabled_dir", None)
     assert cc.cache_disabled()
     assert cc.enable_compile_cache() is None
     probe = cc.CacheProbe()

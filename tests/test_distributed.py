@@ -16,17 +16,9 @@ from pio_tpu.parallel.distributed import (
     is_primary,
     runtime_info,
 )
-from pio_tpu.utils.jaxcompat import multiprocess_cpu_supported
 
 # the 2-process tests dispatch real cross-process collectives on the CPU
-# backend, which needs gloo TCP collectives in jaxlib (selected by
-# initialize_distributed); without it XLA fails with "Multiprocess
-# computations aren't implemented on the CPU backend"
-needs_multiprocess_cpu = pytest.mark.skipif(
-    not multiprocess_cpu_supported(),
-    reason="this jaxlib lacks gloo CPU collectives (multiprocess CPU "
-           "computations unsupported)",
-)
+# backend over gloo (jax's default jax_cpu_collectives_implementation)
 
 
 def test_single_host_is_noop(monkeypatch):
@@ -82,9 +74,8 @@ os.environ["PIO_TPU_PROCESS_ID"] = str(pid)
 import jax
 sys.path.insert(0, "{repo}")
 sys.path.insert(0, "{repo}/tests")
-from pio_tpu.utils.jaxcompat import set_cpu_device_count
 jax.config.update("jax_platforms", "cpu")
-set_cpu_device_count(2)
+jax.config.update("jax_num_cpu_devices", 2)
 from pio_tpu.parallel.distributed import initialize_distributed, runtime_info
 assert initialize_distributed() is True
 info = runtime_info()
@@ -185,7 +176,6 @@ def _assert_children_ok(code, expected, extra=()):
         assert f"CHILD_OK {pid}" in out, f"process {pid} failed:\n{err}"
 
 
-@needs_multiprocess_cpu
 def test_two_process_collectives_match_single_process(tmp_path):
     """Two real OS processes join one distributed runtime (2 procs x 2 local
     CPU devices = 4 global) and run sharded ALS + dp x tp two-tower steps
@@ -210,7 +200,6 @@ def test_two_process_collectives_match_single_process(tmp_path):
     _assert_children_ok(code, expected)
 
 
-@needs_multiprocess_cpu
 def test_two_process_training_from_shared_storage_server(tmp_path):
     """The full multi-host data plane, ours end to end: a storage server
     owns the events; TWO OS processes join one jax.distributed runtime,
@@ -264,9 +253,8 @@ os.environ["PIO_TPU_COORDINATOR"] = "127.0.0.1:{port}"
 os.environ["PIO_TPU_NUM_PROCESSES"] = "1"
 os.environ["PIO_TPU_PROCESS_ID"] = "0"
 import jax
-from pio_tpu.utils.jaxcompat import set_cpu_device_count
 jax.config.update("jax_platforms", "cpu")
-set_cpu_device_count(4)
+jax.config.update("jax_num_cpu_devices", 4)
 from pio_tpu.parallel.distributed import initialize_distributed, runtime_info
 assert initialize_distributed() is True
 info = runtime_info()
