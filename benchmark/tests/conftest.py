@@ -1,0 +1,10 @@
+"""The benchmark's own tests: `python -m pytest benchmark/tests -q`.
+They run on the CPU at tiny sizes and are not part of tests/."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
