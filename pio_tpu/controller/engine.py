@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import json
 import logging
-import time
 from dataclasses import dataclass, field
 from typing import Any, Sequence
 
@@ -22,6 +21,7 @@ from pio_tpu.controller.base import (
     params_to_dict,
     sanity_check,
 )
+from pio_tpu.utils import tracing
 
 log = logging.getLogger("pio_tpu.workflow")
 
@@ -50,6 +50,13 @@ class EngineParams:
             },
             sort_keys=True,
         )
+
+
+def _label_ratings(sp: dict, data) -> None:
+    """`ratings` on a stage's span, where its output is interactions."""
+    values = getattr(data, "values", None)
+    if values is not None and hasattr(values, "__len__"):
+        sp["ratings"] = len(values)
 
 
 def _single_class_map(x) -> dict[str, type]:
@@ -108,28 +115,38 @@ class Engine:
         stop_after_read: bool = False,
         stop_after_prepare: bool = False,
     ) -> list[Any]:
+        from pio_tpu.utils.compilecache import CompileMeter
+
         ds, prep, algos, _ = self._doers(engine_params)
-        t0 = time.monotonic()
-        td = ds.read_training(ctx)
-        sanity_check(td)
+        tracer = tracing.current_tracer()
+        with tracer.span("train.read") as sp:
+            td = ds.read_training(ctx)
+            sanity_check(td)
+            _label_ratings(sp, td)
         if stop_after_read:
             raise TrainingInterruption("read")
-        t1 = time.monotonic()
-        pd = prep.prepare(ctx, td)
-        sanity_check(pd)
+        with tracer.span("train.prepare") as sp:
+            pd = prep.prepare(ctx, td)
+            sanity_check(pd)
+            _label_ratings(sp, pd)
         if stop_after_prepare:
             raise TrainingInterruption("prepare")
-        t2 = time.monotonic()
-        models = [algo.train(ctx, pd) for algo in algos]
-        for m in models:
-            sanity_check(m)
-        import jax
+        with tracer.span("train.algorithms") as sp, CompileMeter() as meter:
+            models = [algo.train(ctx, pd) for algo in algos]
+            for m in models:
+                sanity_check(m)
+            import jax
 
-        # dispatch is asynchronous: wait here so the stage time below is
-        # the training's, not the enqueue's (persist would wait anyway)
-        jax.block_until_ready(models)
-        log.info("train stages: read %.2fs, prepare %.2fs, algorithms "
-                 "%.2fs", t1 - t0, t2 - t1, time.monotonic() - t2)
+            # dispatch is asynchronous: wait here so the stage time below
+            # is the training's, not the enqueue's (persist would wait
+            # anyway)
+            with tracer.span("als.wait"):
+                jax.block_until_ready(models)
+            sp.update(programs=meter.programs, cache_hits=meter.cache_hits,
+                      compile_s=round(meter.seconds, 3))
+        log.info("train stages: read %.3fs, prepare %.3fs, algorithms "
+                 "%.3fs", *(tracer.histogram(name).last for name in (
+                     "train.read", "train.prepare", "train.algorithms")))
         return models
 
     # -- eval (reference Engine.object.eval, Engine.scala:727-817) ----------

@@ -66,9 +66,11 @@ def error_fields(exc: BaseException,
 class SpanRecord:
     """One finished span. ``start_s`` is wall-clock epoch seconds (for
     cross-process ordering in the merged tree); ``duration_s`` comes
-    from the monotonic clock (immune to NTP steps). Slotted: recorders
-    hold thousands of these and the hot path builds several per
-    request."""
+    from the monotonic clock (immune to NTP steps); ``start_mono_s`` is
+    the same start on this process's monotonic clock, for offsets
+    inside one process (the `train spans:` record) and never sent.
+    Slotted: recorders hold thousands of these and the hot path builds
+    several per request."""
 
     trace_id: str
     span_id: str
@@ -80,6 +82,7 @@ class SpanRecord:
     status: str = "ok"            # "ok" | "error"
     error: str | None = None
     labels: dict = field(default_factory=dict)
+    start_mono_s: float = 0.0
 
     def to_dict(self) -> dict:
         return {
@@ -248,10 +251,13 @@ class TraceRecorder:
 
     # -- convenience: a non-HTTP root trace (the fold-in folder's cycle) -----
     @contextmanager
-    def trace(self, name: str, **labels):
+    def trace(self, name: str, labels: dict | None = None, **more):
         """Open a NEW root trace around a unit of background work, bind
         this recorder, and retain per the usual tail policy on exit.
-        Outbound HTTP inside the block joins the trace automatically."""
+        Outbound HTTP inside the block joins the trace automatically.
+        Labels are read when the root closes, so a caller that passes
+        its own ``labels`` dict may add to it inside the block."""
+        labels = more if labels is None else labels
         ctx = tracectx.new_trace()
         t0 = time.monotonic()
         # pio: lint-ok[bench-clock] span START is wall-clock on purpose —
@@ -259,7 +265,6 @@ class TraceRecorder:
         # clocks don't compare across hosts); the duration uses monotonic
         t0_wall = time.time()
         status, errmsg = "ok", None
-        labels = {str(k): str(v) for k, v in labels.items()}
         with tracectx.use(ctx, self):
             try:
                 yield ctx
@@ -273,7 +278,9 @@ class TraceRecorder:
                     parent_id=None, name=name, surface=self.surface,
                     start_s=t0_wall,
                     duration_s=time.monotonic() - t0,
-                    status=status, error=errmsg, labels=labels))
+                    status=status, error=errmsg,
+                    labels={str(k): str(v) for k, v in labels.items()},
+                    start_mono_s=t0))
                 self.finish_trace(ctx.trace_id)
 
     # -- read side -----------------------------------------------------------
@@ -295,6 +302,33 @@ class TraceRecorder:
                     else max(s.duration_s for s in spans), 6),
                 "spans": [s.to_dict() for s in spans],
             }
+
+    def span_rows(self, trace_id: str) -> list[dict]:
+        """One process's trace, compact and in start order: each span's
+        name, its parent's NAME (None for the root), its start as an
+        offset from the root's on the monotonic clock, its duration and
+        labels, and `status`/`error` where it failed. What a batch
+        process logs before it exits (`train spans:`), since nobody can
+        ask it for /debug/traces.json afterwards."""
+        with self._lock:
+            entry = self._traces.get(trace_id)
+            spans = list(entry["spans"]) if entry is not None else []
+            spans.extend(self._active.get(trace_id, ()))
+        if not spans:
+            return []
+        names = {s.span_id: s.name for s in spans}
+        t0 = min((s.start_mono_s for s in spans if s.parent_id is None),
+                 default=min(s.start_mono_s for s in spans))
+        rows = []
+        for s in sorted(spans, key=lambda s: s.start_mono_s):
+            row = {"name": s.name, "parent": names.get(s.parent_id),
+                   "start_s": round(s.start_mono_s - t0, 6),
+                   "duration_s": round(s.duration_s, 6),
+                   "labels": s.labels}
+            if s.status != "ok":
+                row.update(status=s.status, error=s.error)
+            rows.append(row)
+        return rows
 
     def traces(self, limit: int = 50) -> list[dict]:
         """Retained-trace summaries, most recent first."""

@@ -52,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pio_tpu.ops.bucketing import pow2_bucket
 from pio_tpu.parallel.mesh import DATA_AXIS
+from pio_tpu.utils import tracing
 
 log = logging.getLogger("pio_tpu.ops")
 
@@ -315,6 +316,7 @@ def _slots_for(nnz: int, n_self: int, width: int, chunk_slots: int) -> int:
     return math.ceil(s / chunk_slots) * chunk_slots
 
 
+@jax.named_scope("als.layout")
 def _device_slot_layout(u, o, v, n_self: int, width: int, slots_max: int):
     """Build the slot layout on device from (possibly sentinel-padded) COO.
 
@@ -370,14 +372,9 @@ def _gather_pow2_rows(m: int, cap: int = 1024) -> int:
     return r
 
 
-def _chunk_blocks(src, i_c, v_c, l_c, implicit: bool, alpha: float,
-                  gather: str = "xla"):
-    """One slot chunk -> per-slot normal-equation blocks
-    a_blk (C,k,k), b_blk (C,k) via batched MXU matmuls."""
-    W = i_c.shape[1]
-    mask = (
-        jnp.arange(W, dtype=jnp.int32)[None, :] < l_c[:, None]
-    ).astype(jnp.float32)
+def _gather_rows(src, i_c, gather: str):
+    """Rows of the opposing factor table for one slot chunk -> (C, W, k)
+    float32, by the XLA gather or one of the Pallas gather kernels."""
     if gather == "stream":
         from pio_tpu.ops.als_pallas import gather_rows_stream
 
@@ -387,13 +384,13 @@ def _chunk_blocks(src, i_c, v_c, l_c, implicit: bool, alpha: float,
         # copy between the gather and the blocks einsum (the 38 ms
         # y-copy in the round-5 profile)
         n, k = src.shape
-        C = i_c.shape[0]
+        C, W = i_c.shape
         flat = i_c.reshape(-1)
-        y = gather_rows_stream(
+        return gather_rows_stream(
             src, flat,
             rows_per_step=_gather_pow2_rows(flat.shape[0], cap=512),
         ).reshape(C, W, k).astype(jnp.float32)
-    elif gather.startswith("pallas"):
+    if gather.startswith("pallas"):
         from pio_tpu.ops.als_pallas import (
             GATHER_VMEM_TABLE_BUDGET, gather_rows_pallas, gather_table_bytes,
         )
@@ -402,36 +399,48 @@ def _chunk_blocks(src, i_c, v_c, l_c, implicit: bool, alpha: float,
         fits = gather_table_bytes(
             n, k, src.dtype == jnp.bfloat16) <= GATHER_VMEM_TABLE_BUDGET
         if fits:
-            C = i_c.shape[0]
+            C, W = i_c.shape
             flat = i_c.reshape(-1)
-            y = gather_rows_pallas(
+            return gather_rows_pallas(
                 src, flat,
                 rows_per_step=_gather_pow2_rows(flat.shape[0]),
                 variant=gather.split("-", 1)[1],
             ).reshape(C, W, k).astype(jnp.float32)
+        # big table: the XLA gather's fast emitter
+    return src[i_c].astype(jnp.float32)  # (C, W, k) gather
+
+
+def _chunk_blocks(src, i_c, v_c, l_c, implicit: bool, alpha: float,
+                  gather: str = "xla"):
+    """One slot chunk -> per-slot normal-equation blocks
+    a_blk (C,k,k), b_blk (C,k) via batched MXU matmuls."""
+    W = i_c.shape[1]
+    with jax.named_scope("als.blocks"):
+        mask = (
+            jnp.arange(W, dtype=jnp.int32)[None, :] < l_c[:, None]
+        ).astype(jnp.float32)
+    with jax.named_scope("als.gather"):
+        y = _gather_rows(src, i_c, gather)
+    with jax.named_scope("als.blocks"):
+        if implicit:
+            # c = 1 + alpha*v; A += (c-1) y y^T ; b += c * y   (p == 1)
+            w_outer = alpha * v_c * mask
+            w_rhs = (1.0 + alpha * v_c) * mask
         else:
-            y = src[i_c].astype(jnp.float32)  # big table: fast emitter
-    else:
-        y = src[i_c].astype(jnp.float32)  # (C, W, k) gather
-    if implicit:
-        # c = 1 + alpha*v; A += (c-1) y y^T ; b += c * y   (p == 1)
-        w_outer = alpha * v_c * mask
-        w_rhs = (1.0 + alpha * v_c) * mask
-    else:
-        w_outer = mask
-        w_rhs = v_c * mask
-    # Precision.HIGH (3-pass bf16): the MXU's default 1-pass contraction
-    # loses ~3e-3 relative on A, which the CG solve then cannot recover;
-    # HIGH restores ~1e-5 at ~3x the matmul passes
-    a_blk = jnp.einsum(
-        "bwi,bwj->bij", y * w_outer[:, :, None], y,
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGH,
-    )
-    b_blk = jnp.einsum(
-        "bwk,bw->bk", y, w_rhs, preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGH,
-    )
+            w_outer = mask
+            w_rhs = v_c * mask
+        # Precision.HIGH (3-pass bf16): the MXU's default 1-pass
+        # contraction loses ~3e-3 relative on A, which the CG solve then
+        # cannot recover; HIGH restores ~1e-5 at ~3x the matmul passes
+        a_blk = jnp.einsum(
+            "bwi,bwj->bij", y * w_outer[:, :, None], y,
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGH,
+        )
+        b_blk = jnp.einsum(
+            "bwk,bw->bk", y, w_rhs, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGH,
+        )
     return a_blk, b_blk
 
 
@@ -466,9 +475,11 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
     # bf16 source halves the gather's HBM traffic — the build's bottleneck;
     # the f32 upcast happens in-register before the (still f32-accumulated)
     # matmuls. RMSE impact measured at 5e-5 relative (ALSParams.bf16_gather)
-    src = (
-        other_factors.astype(jnp.bfloat16) if bf16_gather else other_factors
-    )
+    with jax.named_scope("als.gather"):
+        src = (
+            other_factors.astype(jnp.bfloat16) if bf16_gather
+            else other_factors
+        )
     if accum == "auto":
         # keep in sync with ALSParams.resolved_accum (per-backend choice)
         accum = "hybrid" if _accelerator_backend() else "carry"
@@ -520,12 +531,13 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
             a_blk, b_blk = _chunk_blocks(
                 src, i_c, v_c, l_c, implicit, alpha, gather=gather
             )
-            A = A.at[r_c].add(
-                a_blk, mode="drop", indices_are_sorted=True
-            )
-            b = b.at[r_c].add(
-                b_blk, mode="drop", indices_are_sorted=True
-            )
+            with jax.named_scope("als.blocks"):
+                A = A.at[r_c].add(
+                    a_blk, mode="drop", indices_are_sorted=True
+                )
+                b = b.at[r_c].add(
+                    b_blk, mode="drop", indices_are_sorted=True
+                )
             return (A, b), None
 
         xs = (
@@ -534,8 +546,9 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
             val.reshape(n_ch, chunk_slots, W),
             lens.reshape(n_ch, chunk_slots),
         )
-        A0 = jnp.zeros((n_self, k, k), dtype=jnp.float32)
-        b0 = jnp.zeros((n_self, k), dtype=jnp.float32)
+        with jax.named_scope("als.blocks"):
+            A0 = jnp.zeros((n_self, k, k), dtype=jnp.float32)
+            b0 = jnp.zeros((n_self, k), dtype=jnp.float32)
         (A, b), _ = jax.lax.scan(body, (A0, b0), xs)
         return A, b
 
@@ -547,8 +560,9 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
         1, min(group_slots, blocks_group_budget_slots(k)) // chunk_slots)
     g_slots = ch_per_group * chunk_slots
     n_groups = math.ceil(S / g_slots)
-    A = jnp.zeros((n_self, k, k), dtype=jnp.float32)
-    b = jnp.zeros((n_self, k), dtype=jnp.float32)
+    with jax.named_scope("als.blocks"):
+        A = jnp.zeros((n_self, k, k), dtype=jnp.float32)
+        b = jnp.zeros((n_self, k), dtype=jnp.float32)
     for g in range(n_groups):
         lo = g * g_slots
         hi = min(S, lo + g_slots)
@@ -567,15 +581,16 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
             )
 
         _, (a_blks, b_blks) = jax.lax.scan(body, None, xs)
-        r_g = rows[lo:hi]
-        A = A.at[r_g].add(
-            a_blks.reshape(hi - lo, k, k), mode="drop",
-            indices_are_sorted=True,
-        )
-        b = b.at[r_g].add(
-            b_blks.reshape(hi - lo, k), mode="drop",
-            indices_are_sorted=True,
-        )
+        with jax.named_scope("als.blocks"):
+            r_g = rows[lo:hi]
+            A = A.at[r_g].add(
+                a_blks.reshape(hi - lo, k, k), mode="drop",
+                indices_are_sorted=True,
+            )
+            b = b.at[r_g].add(
+                b_blks.reshape(hi - lo, k), mode="drop",
+                indices_are_sorted=True,
+            )
     return A, b
 
 
@@ -656,6 +671,12 @@ def _shared_yty(other_factors, yty):
     )
 
 
+@jax.named_scope("als.chol")
+def _chol_solve(A, b):
+    chol = jax.scipy.linalg.cho_factor(A)
+    return jax.scipy.linalg.cho_solve(chol, b)
+
+
 def _solve_packed(A, b, reg, implicit, alpha, other_factors, yty, x0,
                   cg_iters: int):
     """The solve on LANE-PACKED A (n, k²) from the streaming flush
@@ -672,26 +693,27 @@ def _solve_packed(A, b, reg, implicit, alpha, other_factors, yty, x0,
 
     n_self, k2 = A.shape
     k = b.shape[1]
-    eye_flat = jnp.eye(k, dtype=jnp.float32).reshape(k2)
-    if implicit:
-        A = A + _shared_yty(other_factors, yty).reshape(k2)[None, :]
-    A = A + reg * eye_flat[None, :]
+    with jax.named_scope("als.gram"):
+        eye_flat = jnp.eye(k, dtype=jnp.float32).reshape(k2)
+        if implicit:
+            A = A + _shared_yty(other_factors, yty).reshape(k2)[None, :]
+        A = A + reg * eye_flat[None, :]
     if cg_iters <= 0:
-        A3 = A.reshape(n_self, k, k)
-        chol = jax.scipy.linalg.cho_factor(A3)
-        return jax.scipy.linalg.cho_solve(chol, b)
-    block = _matvec_block_rows(k)
-    pad = -n_self % block
-    if pad:
-        A = jnp.concatenate(
-            [A, jnp.broadcast_to(eye_flat, (pad, k2))])
-        b = jnp.concatenate([b, jnp.zeros((pad, k), b.dtype)])
-        if x0 is not None:
-            x0 = jnp.concatenate([x0, jnp.zeros((pad, k), jnp.float32)])
-    if x0 is None:
-        x0 = jnp.zeros_like(b)
-    x = _cg_solve_packed(A, b, x0, cg_iters, block)
-    return x[:n_self]
+        return _chol_solve(A.reshape(n_self, k, k), b)
+    with jax.named_scope("als.cg"):
+        block = _matvec_block_rows(k)
+        pad = -n_self % block
+        if pad:
+            A = jnp.concatenate(
+                [A, jnp.broadcast_to(eye_flat, (pad, k2))])
+            b = jnp.concatenate([b, jnp.zeros((pad, k), b.dtype)])
+            if x0 is not None:
+                x0 = jnp.concatenate(
+                    [x0, jnp.zeros((pad, k), jnp.float32)])
+        if x0 is None:
+            x0 = jnp.zeros_like(b)
+        x = _cg_solve_packed(A, b, x0, cg_iters, block)
+        return x[:n_self]
 
 
 def _solve_factors(layout, other_factors, n_self, reg, implicit, alpha,
@@ -709,16 +731,17 @@ def _solve_factors(layout, other_factors, n_self, reg, implicit, alpha,
         return _solve_packed(A, b, reg, implicit, alpha, other_factors,
                              yty, x0, cg_iters)
     k = other_factors.shape[1]
-    eye = jnp.eye(k, dtype=jnp.float32)
-    if implicit:
-        A = A + _shared_yty(other_factors, yty)[None, :, :]
-    A = A + reg * eye[None, :, :]
+    with jax.named_scope("als.gram"):
+        eye = jnp.eye(k, dtype=jnp.float32)
+        if implicit:
+            A = A + _shared_yty(other_factors, yty)[None, :, :]
+        A = A + reg * eye[None, :, :]
     if cg_iters > 0:
-        if x0 is None:
-            x0 = jnp.zeros_like(b)
-        return _cg_solve(A, b, x0, cg_iters)
-    chol = jax.scipy.linalg.cho_factor(A)
-    return jax.scipy.linalg.cho_solve(chol, b)
+        with jax.named_scope("als.cg"):
+            if x0 is None:
+                x0 = jnp.zeros_like(b)
+            return _cg_solve(A, b, x0, cg_iters)
+    return _chol_solve(A, b)
 
 
 def init_factors(n: int, rank: int, key) -> jax.Array:
@@ -750,14 +773,45 @@ def _cg_schedule(params: ALSParams, cg_u: int, cg_i: int):
     return n_full, n_warm, w_u, w_i
 
 
+def _slot_counts(nnz_u: int, nnz_i: int, n_users: int, n_items: int,
+                 params: ALSParams) -> tuple[int, int, int]:
+    """-> (cs, su, si): the chunk size actually used and each side's
+    static slot count, from the (padded) rating counts a side holds."""
+    cs = min(params.chunk_slots,
+             _slots_for(max(nnz_u, nnz_i), 0, params.width, 1))
+    return (cs, _slots_for(nnz_u, n_users, params.width, cs),
+            _slots_for(nnz_i, n_items, params.width, cs))
+
+
+def _cg_matvecs(params: ALSParams, cg_u: int, cg_i: int) -> int:
+    """Batched A@x products one job schedules: each CG solve takes its
+    iteration count plus one for the first residual; an exact side none."""
+    n_full, n_warm, w_u, w_i = _cg_schedule(params, cg_u, cg_i)
+    return sum(n * (it + 1) for n, its in ((n_full, (cg_u, cg_i)),
+                                          (n_warm, (w_u, w_i)))
+               for it in its if it > 0)
+
+
+def _dispatch_labels(sp: dict, nnz_u: int, nnz_i: int, slots, params,
+                     cg_u: int, cg_i: int) -> None:
+    """The counts at the `als.dispatch` boundary: what the program about
+    to run was sized for (`slots` from `_slot_counts`), and how full the
+    ratings of one side (of its fullest block, when sharded) leave it."""
+    cs, su, si = slots
+    sp.update(cs=cs, su=su, si=si,
+              fill_u=round(nnz_u / (su * params.width), 4),
+              fill_i=round(nnz_i / (si * params.width), 4),
+              cg_matvecs=_cg_matvecs(params, cg_u, cg_i))
+
+
 def _build_layouts(u, i, v, n_users: int, n_items: int, params: ALSParams):
     """Slot layouts for both halves + the chunk size actually used."""
     nnz = u.shape[0]
-    cs = min(params.chunk_slots, _slots_for(nnz, 0, params.width, 1))
-    su = _slots_for(nnz, n_users, params.width, cs)
-    si = _slots_for(nnz, n_items, params.width, cs)
-    by_user = _device_slot_layout(u, i, v, n_users, params.width, su)
-    by_item = _device_slot_layout(i, u, v, n_items, params.width, si)
+    cs, su, si = _slot_counts(nnz, nnz, n_users, n_items, params)
+    with jax.named_scope("als.user"):
+        by_user = _device_slot_layout(u, i, v, n_users, params.width, su)
+    with jax.named_scope("als.item"):
+        by_item = _device_slot_layout(i, u, v, n_items, params.width, si)
     return by_user, by_item, cs
 
 
@@ -779,20 +833,24 @@ def _sweep_factory(by_user, by_item, n_users: int, n_items: int, cs: int,
     def sweep_with(cg_u_n: int, cg_i_n: int):
         def sweep(carry, _):
             users, items = carry
-            users = _solve_factors(
-                by_user, items, n_users,
-                reg, params.implicit, alpha, cs,
-                x0=users, cg_iters=cg_u_n, bf16_gather=params.bf16_gather,
-                accum=params.accum, group_slots=params.group_slots,
-                gather=params.gather, packed=params.packed_a,
-            )
-            items = _solve_factors(
-                by_item, users, n_items,
-                reg, params.implicit, alpha, cs,
-                x0=items, cg_iters=cg_i_n, bf16_gather=params.bf16_gather,
-                accum=params.accum, group_slots=params.group_slots,
-                gather=params.gather, packed=params.packed_a,
-            )
+            with jax.named_scope("als.user"):
+                users = _solve_factors(
+                    by_user, items, n_users,
+                    reg, params.implicit, alpha, cs,
+                    x0=users, cg_iters=cg_u_n,
+                    bf16_gather=params.bf16_gather,
+                    accum=params.accum, group_slots=params.group_slots,
+                    gather=params.gather, packed=params.packed_a,
+                )
+            with jax.named_scope("als.item"):
+                items = _solve_factors(
+                    by_item, users, n_items,
+                    reg, params.implicit, alpha, cs,
+                    x0=items, cg_iters=cg_i_n,
+                    bf16_gather=params.bf16_gather,
+                    accum=params.accum, group_slots=params.group_slots,
+                    gather=params.gather, packed=params.packed_a,
+                )
             return (users, items), None
         return sweep
     return sweep_with
@@ -917,8 +975,7 @@ def als_build_layouts(
     ``als_train(..., layouts=...)``. Inputs may be host numpy or
     device-resident jax arrays (same contract as als_train)."""
     u, i, v = _prep_coo(user_idx, item_idx, values, n_users, n_items, params)
-    nnz = u.shape[0]
-    cs = min(params.chunk_slots, _slots_for(nnz, 0, params.width, 1))
+    cs, _, _ = _slot_counts(u.shape[0], u.shape[0], n_users, n_items, params)
     by_user, by_item = _layouts_jit(u, i, v, n_users, n_items, params)
     return ALSLayouts(by_user, by_item, cs, n_users, n_items, params.width)
 
@@ -967,7 +1024,7 @@ def als_warm_compile(
             a, b, c, n_users=n_users, n_items=n_items, params=params),
         u, u, v,
     )
-    cs = min(params.chunk_slots, _slots_for(nnz_pad, 0, params.width, 1))
+    cs, _, _ = _slot_counts(nnz_pad, nnz_pad, n_users, n_items, params)
     user0, item0 = jax.eval_shape(
         lambda: _init_or(None, n_users, n_items, params))
     for length in sweep_lengths:
@@ -1005,7 +1062,8 @@ def als_train(
     per-call slot-layout rebuild entirely — the retrain/trajectory fast
     path; the COO args are ignored then (pass the same arrays for
     clarity)."""
-    user0, item0 = _init_or(init, n_users, n_items, params)
+    with tracing.span("als.init"):
+        user0, item0 = _init_or(init, n_users, n_items, params)
     if layouts is not None:
         if (layouts.n_users, layouts.n_items, layouts.width) != \
                 (n_users, n_items, params.width):
@@ -1018,10 +1076,25 @@ def als_train(
             n_users, n_items, layouts.cs, params, user0, item0,
         )
         return ALSModel(users, items)
-    u, i, v = _prep_coo(user_idx, item_idx, values, n_users, n_items, params)
-    users, items = _train_jit(
-        u, i, v, n_users, n_items, params, user0, item0
-    )
+    with tracing.span("als.prep") as sp:
+        u, i, v = _prep_coo(
+            user_idx, item_idx, values, n_users, n_items, params)
+        sp["padded"] = u.shape[0] - len(values)
+    with tracing.span("als.transfer") as sp:
+        # host arrays would cross inside the jitted call; putting them
+        # first (and waiting) keeps the crossing apart from the dispatch
+        sp["bytes"] = sum(
+            a.nbytes for a in (u, i, v) if not isinstance(a, jax.Array))
+        u, i, v = jax.block_until_ready(jax.device_put((u, i, v)))
+    with tracing.span("als.dispatch") as sp:
+        _dispatch_labels(
+            sp, len(values), len(values),
+            _slot_counts(u.shape[0], u.shape[0], n_users, n_items, params),
+            params, params.resolved_cg_iters(n_users),
+            params.resolved_cg_iters(n_items))
+        users, items = _train_jit(
+            u, i, v, n_users, n_items, params, user0, item0
+        )
     return ALSModel(users, items)
 
 
@@ -1271,13 +1344,16 @@ def _sharded_train_fn(mesh: Mesh, ub: int, ib: int, su: int, si: int,
         check_vma=False,
     )
     def run(u_r, u_c, u_v, i_r, i_c, i_v, u0, i0):
-        by_user = _device_slot_layout(
-            u_r[0], u_c[0], u_v[0], ub, params.width, su
-        )
-        by_item = _device_slot_layout(
-            i_r[0], i_c[0], i_v[0], ib, params.width, si
-        )
+        with jax.named_scope("als.user"):
+            by_user = _device_slot_layout(
+                u_r[0], u_c[0], u_v[0], ub, params.width, su
+            )
+        with jax.named_scope("als.item"):
+            by_item = _device_slot_layout(
+                i_r[0], i_c[0], i_v[0], ib, params.width, si
+            )
 
+        @jax.named_scope("als.gram")
         def gram_psum(block):
             """Y^T Y of the full factor matrix from the LOCAL block:
             per-device (b,k)x(k,b) matmul + one (k,k) psum over ICI —
@@ -1290,32 +1366,36 @@ def _sharded_train_fn(mesh: Mesh, ub: int, ib: int, su: int, si: int,
         def sweep_with(cg_u_n: int, cg_i_n: int):
             def sweep(carry, _):
                 users, items = carry  # local blocks (ub, k) / (ib, k)
-                yty_i = gram_psum(items) if params.implicit else None
-                all_items = jax.lax.all_gather(
-                    items, DATA_AXIS, tiled=True
-                )  # (ib*n_dev, k)
-                users = _solve_factors(
-                    by_user, all_items, ub,
-                    params.reg, params.implicit, params.alpha, cs,
-                    x0=users, cg_iters=cg_u_n,
-                    bf16_gather=params.bf16_gather,
-                    accum=params.accum, group_slots=params.group_slots,
-                    yty=yty_i, gather=params.gather,
-                    packed=params.packed_a,
-                )
-                yty_u = gram_psum(users) if params.implicit else None
-                all_users = jax.lax.all_gather(
-                    users, DATA_AXIS, tiled=True
-                )
-                items = _solve_factors(
-                    by_item, all_users, ib,
-                    params.reg, params.implicit, params.alpha, cs,
-                    x0=items, cg_iters=cg_i_n,
-                    bf16_gather=params.bf16_gather,
-                    accum=params.accum, group_slots=params.group_slots,
-                    yty=yty_u, gather=params.gather,
-                    packed=params.packed_a,
-                )
+                with jax.named_scope("als.user"):
+                    yty_i = gram_psum(items) if params.implicit else None
+                    with jax.named_scope("als.all_gather"):
+                        all_items = jax.lax.all_gather(
+                            items, DATA_AXIS, tiled=True
+                        )  # (ib*n_dev, k)
+                    users = _solve_factors(
+                        by_user, all_items, ub,
+                        params.reg, params.implicit, params.alpha, cs,
+                        x0=users, cg_iters=cg_u_n,
+                        bf16_gather=params.bf16_gather,
+                        accum=params.accum, group_slots=params.group_slots,
+                        yty=yty_i, gather=params.gather,
+                        packed=params.packed_a,
+                    )
+                with jax.named_scope("als.item"):
+                    yty_u = gram_psum(users) if params.implicit else None
+                    with jax.named_scope("als.all_gather"):
+                        all_users = jax.lax.all_gather(
+                            users, DATA_AXIS, tiled=True
+                        )
+                    items = _solve_factors(
+                        by_item, all_users, ib,
+                        params.reg, params.implicit, params.alpha, cs,
+                        x0=items, cg_iters=cg_i_n,
+                        bf16_gather=params.bf16_gather,
+                        accum=params.accum, group_slots=params.group_slots,
+                        yty=yty_u, gather=params.gather,
+                        packed=params.packed_a,
+                    )
                 return (users, items), None
             return sweep
 
@@ -1361,11 +1441,13 @@ def als_train_sharded(
 
     def partition(rows, cols, vals, block):
         """-> (n_dev, nnz_max) stacked COO with LOCAL row ids; padding
-        entries carry row id = block (the sentinel >= any local id)."""
+        entries carry row id = block (the sentinel >= any local id);
+        and each device's rating count."""
         dev_of = rows // block
         per_dev = [np.flatnonzero(dev_of == dv) for dv in range(n_dev)]
+        counts = [len(ix) for ix in per_dev]
         # bucket to a chunk multiple for compile reuse across retrains
-        nnz_max = max(len(ix) for ix in per_dev)
+        nnz_max = max(counts)
         nnz_max += -nnz_max % max(1, params.chunk)
         r_st = np.full((n_dev, nnz_max), block, np.int32)
         c_st = np.zeros((n_dev, nnz_max), np.int32)
@@ -1374,30 +1456,37 @@ def als_train_sharded(
             r_st[dv, :len(ix)] = rows[ix] - dv * block
             c_st[dv, :len(ix)] = cols[ix]
             v_st[dv, :len(ix)] = vals[ix]
-        return r_st, c_st, v_st, nnz_max
+        return r_st, c_st, v_st, nnz_max, counts
 
-    rows = np.asarray(user_idx, dtype=np.int64)
-    cols = np.asarray(item_idx, dtype=np.int64)
-    vals = np.asarray(values, dtype=np.float32)
-    u_r, u_c, u_v, u_nnz = partition(rows, cols, vals, ub)
-    i_r, i_c, i_v, i_nnz = partition(cols, rows, vals, ib)
+    with tracing.span("als.partition") as sp:
+        rows = np.asarray(user_idx, dtype=np.int64)
+        cols = np.asarray(item_idx, dtype=np.int64)
+        vals = np.asarray(values, dtype=np.float32)
+        u_r, u_c, u_v, u_nnz, u_counts = partition(rows, cols, vals, ub)
+        i_r, i_c, i_v, i_nnz, i_counts = partition(cols, rows, vals, ib)
+        nnz = len(vals)
+        sp.update(
+            rows_u=ub, rows_i=ib,
+            nnz_max_u=max(u_counts), nnz_min_u=min(u_counts),
+            nnz_max_i=max(i_counts), nnz_min_i=min(i_counts),
+            padded_u=round(1 - nnz / (n_dev * u_nnz), 4),
+            padded_i=round(1 - nnz / (n_dev * i_nnz), 4))
 
-    key = jax.random.PRNGKey(params.seed)
-    ku, ki = jax.random.split(key)
-    # draw the init at the UNPADDED shape — the exact same draw
-    # als_train makes — then zero-pad the phantom rows: a non-zero init
-    # there would contaminate the shared Y^T Y term of the implicit-ALS
-    # first sweep.
-    user0 = np.zeros((ub * n_dev, params.rank), np.float32)
-    item0 = np.zeros((ib * n_dev, params.rank), np.float32)
-    user0[:n_users] = np.array(init_factors(n_users, params.rank, ku))
-    item0[:n_items] = np.array(init_factors(n_items, params.rank, ki))
-    user0 = user0.reshape(n_dev, ub, params.rank)
-    item0 = item0.reshape(n_dev, ib, params.rank)
+    with tracing.span("als.init"):
+        key = jax.random.PRNGKey(params.seed)
+        ku, ki = jax.random.split(key)
+        # draw the init at the UNPADDED shape — the exact same draw
+        # als_train makes — then zero-pad the phantom rows: a non-zero
+        # init there would contaminate the shared Y^T Y term of the
+        # implicit-ALS first sweep.
+        user0 = np.zeros((ub * n_dev, params.rank), np.float32)
+        item0 = np.zeros((ib * n_dev, params.rank), np.float32)
+        user0[:n_users] = np.array(init_factors(n_users, params.rank, ku))
+        item0[:n_items] = np.array(init_factors(n_items, params.rank, ki))
+        user0 = user0.reshape(n_dev, ub, params.rank)
+        item0 = item0.reshape(n_dev, ib, params.rank)
 
-    cs = min(params.chunk_slots, _slots_for(max(u_nnz, i_nnz), 0, params.width, 1))
-    su = _slots_for(u_nnz, ub, params.width, cs)
-    si = _slots_for(i_nnz, ib, params.width, cs)
+    cs, su, si = _slot_counts(u_nnz, i_nnz, ub, ib, params)
 
     # cache key: only program-relevant fields — seed and chunk are
     # host-side (init RNG / padding quantum) and chunk_slots is already
@@ -1405,18 +1494,26 @@ def als_train_sharded(
     key_params = dataclasses.replace(params, seed=0, chunk=0,
                                      chunk_slots=cs)
     run = _sharded_train_fn(mesh, ub, ib, su, si, cs, key_params)
-    dev_spec = P(DATA_AXIS)
-    sharding = NamedSharding(mesh, dev_spec)
-    put = lambda a: jax.device_put(a, sharding)  # noqa: E731
-    coo = [put(a) for a in (u_r, u_c, u_v, i_r, i_c, i_v)]
-    users, items = run(*coo, put(user0), put(item0))
+    sharding = NamedSharding(mesh, P(DATA_AXIS))
+    with tracing.span("als.transfer") as sp:
+        host = (u_r, u_c, u_v, i_r, i_c, i_v, user0, item0)
+        sp["bytes"] = sum(a.nbytes for a in host)
+        # waited for, so that the crossing is not charged to the dispatch
+        placed = jax.block_until_ready(
+            [jax.device_put(a, sharding) for a in host])
+    with tracing.span("als.dispatch") as sp:
+        _dispatch_labels(sp, max(u_counts), max(i_counts), (cs, su, si),
+                         params, _sharded_cg_iters(params, ub),
+                         _sharded_cg_iters(params, ib))
+        users, items = run(*placed)
+        blocks_on = sorted(s.device.id for s in users.addressable_shards)
+        users = users.reshape(-1, params.rank)[:n_users]
+        items = items.reshape(-1, params.rank)[:n_items]
     log.info(
         "sharded ALS over %d devices: rating blocks on devices %s, "
         "factor blocks on devices %s", n_dev,
-        sorted(s.device.id for s in coo[0].addressable_shards),
-        sorted(s.device.id for s in users.addressable_shards))
-    users = users.reshape(-1, params.rank)[:n_users]
-    items = items.reshape(-1, params.rank)[:n_items]
+        sorted(s.device.id for s in placed[0].addressable_shards),
+        blocks_on)
     return ALSModel(users, items)
 
 

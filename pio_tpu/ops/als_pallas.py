@@ -316,6 +316,7 @@ def _segment_kernel_stream(*refs, chunk: int, slot_fn, packed: bool):
         trail_row[0, 0] = cur_row[0]
 
 
+@jax.named_scope("als.flush")
 def _run_segment_group(rows_g, data, data_specs, a_buf, b_buf, *,
                        chunk: int, k: int, lane: int, slot_fn,
                        interpret: bool, overlap: bool = False,
@@ -395,6 +396,7 @@ def _run_segment_group(rows_g, data, data_specs, a_buf, b_buf, *,
     )(rows_g.reshape(n_steps, 1, chunk), *data, a_buf, b_buf)
 
 
+@jax.named_scope("als.layout")
 def _pad_slots(layout, pad: int, n_self: int):
     """Append `pad` sentinel slots (row id n_self — keeps the sorted-rows
     invariant; zero lens/idx/val contribute nothing) to a slot layout."""
@@ -429,26 +431,29 @@ def _chain_groups(n_self: int, k: int, groups, packed: bool = False):
     k=64)."""
     lane = _lane_for(k)
     n_pad = n_self + 1
-    if packed:
-        a_buf = jnp.zeros((n_pad, k * k), jnp.float32)
-    else:
-        a_buf = jnp.zeros((n_pad, k, lane), jnp.float32)
-    b_buf = jnp.zeros((n_pad, lane), jnp.float32)
+    with jax.named_scope("als.flush"):
+        if packed:
+            a_buf = jnp.zeros((n_pad, k * k), jnp.float32)
+        else:
+            a_buf = jnp.zeros((n_pad, k, lane), jnp.float32)
+        b_buf = jnp.zeros((n_pad, lane), jnp.float32)
     t_rows, t_as, t_bs = [], [], []
     for run in groups:
         a_buf, b_buf, tr_a, tr_b, tr_row = run(a_buf, b_buf, lane)
-        t_rows.append(tr_row.reshape(1))
+        with jax.named_scope("als.flush"):
+            t_rows.append(tr_row.reshape(1))
         t_as.append(tr_a)
         t_bs.append(tr_b)
-    t_a = jnp.stack(t_as)                       # (n_groups, k, lane)
-    if packed:
-        t_a = t_a[:, :, :k].reshape(len(t_as), k * k)
-    A = a_buf.at[jnp.concatenate(t_rows)].add(t_a, mode="drop")
-    b = b_buf.at[jnp.concatenate(t_rows)].add(
-        jnp.concatenate(t_bs), mode="drop")
-    if packed:
-        return A[:n_self], b[:n_self, :k]
-    return A[:n_self, :, :k], b[:n_self, :k]
+    with jax.named_scope("als.flush"):
+        t_a = jnp.stack(t_as)                       # (n_groups, k, lane)
+        if packed:
+            t_a = t_a[:, :, :k].reshape(len(t_as), k * k)
+        A = a_buf.at[jnp.concatenate(t_rows)].add(t_a, mode="drop")
+        b = b_buf.at[jnp.concatenate(t_rows)].add(
+            jnp.concatenate(t_bs), mode="drop")
+        if packed:
+            return A[:n_self], b[:n_self, :k]
+        return A[:n_self, :, :k], b[:n_self, :k]
 
 
 def normal_equations_pallas(layout, other_factors, n_self: int,
@@ -580,9 +585,11 @@ def normal_equations_hybrid(layout, other_factors, n_self: int,
     pad = -S % quantum
     rows, idx, val, lens = _pad_slots((rows, idx, val, lens), pad, n_self)
     S += pad
-    src = (
-        other_factors.astype(jnp.bfloat16) if bf16_gather else other_factors
-    )
+    with jax.named_scope("als.gather"):
+        src = (
+            other_factors.astype(jnp.bfloat16) if bf16_gather
+            else other_factors
+        )
     from pio_tpu.ops.als import blocks_group_budget_slots
 
     g_eff = min(group_slots, blocks_group_budget_slots(k))

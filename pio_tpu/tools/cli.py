@@ -1132,6 +1132,10 @@ def cmd_train(args) -> int:
         batch = f"{batch} {marker}".strip()
         print(f"Training with best params from evaluation {eval_id}")
     ctx = create_workflow_context(storage, use_mesh=not args.no_mesh)
+    if args.device_profile:
+        from pio_tpu.utils.tracing import start_device_profile
+
+        start_device_profile(args.device_profile)
     try:
         instance_id = run_train(
             engine, ep, storage,
@@ -1157,6 +1161,14 @@ def cmd_train(args) -> int:
         # controlled debug stop (reference --stop-after-read/-prepare)
         print(f"Training interrupted: {e}")
         return 0
+    finally:
+        if args.device_profile:
+            from pio_tpu.utils.tracing import stop_device_profile
+
+            stop_device_profile()
+            print(f"Device profile in {args.device_profile}; read it "
+                  "with: python -m pio_tpu.obs.profile "
+                  f"{args.device_profile}")
     print(f"Training completed. Engine instance: {instance_id}")
     return 0
 
@@ -2460,6 +2472,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "<eval-iid>:best_params record); the instance "
                         "is batch-tagged from-eval:<id> so doctor can "
                         "tell production runs the best-known params")
+    x.add_argument("--device-profile", default="", metavar="DIR",
+                   help="take a jax device profile of the whole train "
+                        "into DIR; `python -m pio_tpu.obs.profile DIR` "
+                        "reads it as device seconds by scope and idle "
+                        "seconds by span (docs/observability.md)")
     x.set_defaults(fn=cmd_train)
 
     x = sub.add_parser("eval")

@@ -12,7 +12,9 @@ This module provides:
    serve hot path (a monotonic clock read + a ring-buffer store);
  * device profiling — start/stop wrappers around `jax.profiler` so a
    running deploy server can capture an XLA trace on demand (the TPU
-   answer to the Spark UI), plus `annotate` for op-level trace labels.
+   answer to the Spark UI); a `Tracer(device=True)` puts its spans on
+   that trace's host timeline (`jax.profiler.TraceAnnotation`), where
+   `pio_tpu.obs.profile` reads them beside the device's operations.
 """
 
 from __future__ import annotations
@@ -20,6 +22,7 @@ from __future__ import annotations
 import threading
 import time
 from contextlib import contextmanager
+from contextvars import ContextVar
 from typing import Sequence
 
 # stdlib-only modules, hot-path imported once (a per-span `from ... import`
@@ -102,12 +105,28 @@ class Tracer:
     one-liner that feeds the histograms feeds the distributed span
     tree. Without a recorder (or outside any trace) the span is exactly
     the pre-existing histogram-only fast path.
+
+    ``device=True`` is for a surface that runs device programs (`pio
+    train`): every span also opens a ``jax.profiler.TraceAnnotation`` of
+    the same name, so under a running device profile the span is an
+    event on the host plane, on the device operations' clock, and with
+    none running it costs one flag read. This is the one place the tree
+    constructs annotations; a surface that must not import jax (event
+    server, storage server) leaves ``device`` off.
+
+    A span yields its label dict: counts known only once the work is
+    done (`sp["bytes"] = n`) are added to it inside the block.
     """
 
-    def __init__(self, recorder=None):
+    def __init__(self, recorder=None, device: bool = False):
         self._spans: dict[str, LatencyHistogram] = {}
         self._lock = threading.Lock()
         self.recorder = recorder          # obs.recorder.TraceRecorder | None
+        self._annotation = None
+        if device:
+            import jax
+
+            self._annotation = jax.profiler.TraceAnnotation
 
     def histogram(self, name: str) -> LatencyHistogram:
         with self._lock:
@@ -116,16 +135,28 @@ class Tracer:
                 h = self._spans[name] = LatencyHistogram()
             return h
 
+    def _note(self, name: str):
+        """The span as an entered TraceAnnotation (a device tracer's),
+        for the caller to exit; else None."""
+        if self._annotation is None:
+            return None
+        note = self._annotation(name)
+        note.__enter__()
+        return note
+
     @contextmanager
     def span(self, name: str, **labels):
         recorder = self.recorder
         ctx = _tracectx.current() if recorder is not None else None
+        note = self._note(name)
         if ctx is None:
             t0 = time.monotonic()
             try:
-                yield
+                yield labels
             finally:
                 self.histogram(name).record(time.monotonic() - t0)
+                if note is not None:
+                    note.__exit__(None, None, None)
             return
         child = ctx.child()
         token = _tracectx.push(child)  # nested spans/outbound RPCs parent here
@@ -136,7 +167,7 @@ class Tracer:
         t0_wall = time.time()
         status, errmsg = "ok", None
         try:
-            yield
+            yield labels
         except BaseException as e:
             status = "error"
             errmsg, labels = _error_fields(e, labels)
@@ -144,13 +175,39 @@ class Tracer:
         finally:
             _tracectx.pop(token)
             dt = time.monotonic() - t0
+            if note is not None:
+                note.__exit__(None, None, None)
             self.histogram(name).record(dt)
             recorder.record(_SpanRecord(
                 trace_id=ctx.trace_id, span_id=child.span_id,
                 parent_id=ctx.span_id, name=name,
                 surface=recorder.surface, start_s=t0_wall, duration_s=dt,
                 status=status, error=errmsg,
-                labels={str(k): str(v) for k, v in labels.items()}))
+                labels={str(k): str(v) for k, v in labels.items()},
+                start_mono_s=t0))
+
+    @contextmanager
+    def trace(self, name: str, **labels):
+        """One unit of batch work (a `pio train`) as a NEW root trace on
+        this tracer's recorder, with this tracer ambient for the block:
+        code below reaches it through `current_tracer()` / `span()`
+        without a tracer threaded through every signature. Yields
+        ``(trace id, label dict)``; with no recorder (PIO_TPU_TRACE=off)
+        the id is None and the root is a histogram like any span."""
+        token = _ambient.set(self)
+        note = self._note(name)
+        t0 = time.monotonic()
+        try:
+            if self.recorder is None:
+                yield None, labels
+            else:
+                with self.recorder.trace(name, labels) as ctx:
+                    yield ctx.trace_id, labels
+        finally:
+            if note is not None:
+                note.__exit__(None, None, None)
+            self.histogram(name).record(time.monotonic() - t0)
+            _ambient.reset(token)
 
     def record(self, name: str, seconds: float) -> None:
         self.histogram(name).record(seconds)
@@ -159,6 +216,25 @@ class Tracer:
         with self._lock:
             names = list(self._spans)
         return {n: self._spans[n].snapshot() for n in names}
+
+
+# the tracer of the unit of work in flight (`Tracer.trace` sets it)
+_ambient: ContextVar["Tracer | None"] = ContextVar(
+    "pio_tpu_tracer", default=None)
+
+
+def current_tracer() -> Tracer:
+    """The ambient tracer; outside any `Tracer.trace`, a fresh
+    recorderless one (its spans are histogram-only and die with it:
+    nothing shared, nothing that grows)."""
+    return _ambient.get() or Tracer()
+
+
+def span(name: str, **labels):
+    """`current_tracer().span(...)` — the call a function deep under
+    `run_train` (the ALS trainer, model persistence) makes to put its
+    own work in the job's span tree."""
+    return current_tracer().span(name, **labels)
 
 
 # ---------------------------------------------------------------------------
@@ -194,22 +270,6 @@ def stop_device_profile() -> str | None:
         logdir, _profile_dir = _profile_dir, None
         jax.profiler.stop_trace()
         return logdir
-
-
-@contextmanager
-def device_profile(logdir: str):
-    start_device_profile(logdir)
-    try:
-        yield
-    finally:
-        stop_device_profile()
-
-
-def annotate(name: str):
-    """Label a region in the device trace (jax.profiler.TraceAnnotation)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 PROMETHEUS_CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"

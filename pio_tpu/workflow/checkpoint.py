@@ -21,6 +21,8 @@ from typing import Any
 import jax
 import numpy as np
 
+from pio_tpu.data.bimap import EntityIdIndex
+from pio_tpu.utils import tracing
 from pio_tpu.utils.durable import ModelIntegrityError, frame, unframe
 
 __all__ = [
@@ -45,9 +47,23 @@ def models_to_bytes(models: list[Any]) -> bytes:
     INSIDE the blob, so every backend — file, SQL BLOB, wire — hands
     `models_from_bytes` enough to detect truncation and bit-rot, not
     just the localfs path with its own file-level durability."""
-    buf = io.BytesIO()
-    pickle.dump([host_copy(m) for m in models], buf, protocol=5)
-    return frame(buf.getvalue())
+    with tracing.span("persist.d2h") as sp:
+        on_host = [host_copy(m) for m in models]
+        leaves = jax.tree_util.tree_leaves(on_host)
+        sp["bytes"] = sum(
+            x.nbytes for x in leaves if isinstance(x, np.ndarray))
+    with tracing.span("persist.pickle") as sp:
+        buf = io.BytesIO()
+        pickle.dump(on_host, buf, protocol=5)
+        sp["bytes"] = buf.tell()
+        sp["ids"] = sum(
+            len(x) for m in on_host
+            for x in getattr(m, "__dict__", {}).values()
+            if isinstance(x, EntityIdIndex))
+    with tracing.span("persist.frame") as sp:
+        blob = frame(buf.getvalue())
+        sp["bytes"] = len(blob)
+    return blob
 
 
 def models_from_bytes(data: bytes) -> list[Any]:

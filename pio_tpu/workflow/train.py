@@ -26,9 +26,9 @@ Beyond the reference, the run is *supervised* (workflow/lifecycle.py):
 
 from __future__ import annotations
 
+import json
 import logging
 import os
-import time
 import traceback
 from contextlib import nullcontext
 from dataclasses import replace
@@ -38,7 +38,9 @@ from pio_tpu.controller.base import TrainingInterruption
 from pio_tpu.controller.engine import Engine, EngineParams
 from pio_tpu.data.dao import EngineInstance, Model
 from pio_tpu.data.storage import Storage
+from pio_tpu.obs import make_recorder
 from pio_tpu.resilience import chaos
+from pio_tpu.utils.tracing import Tracer
 from pio_tpu.utils.time import format_time, utcnow
 from pio_tpu.workflow.checkpoint import models_from_bytes, models_to_bytes
 from pio_tpu.workflow.context import WorkflowContext, create_workflow_context
@@ -137,6 +139,12 @@ def _resolve_instance(
     return instances.get(instance_id)
 
 
+def _seconds(tracer: Tracer, *spans: str) -> float:
+    """What this job's spans of those names took, together (each ran
+    once in it: a tracer serves one `run_train`)."""
+    return sum(tracer.histogram(name).last for name in spans)
+
+
 def run_train(
     engine: Engine,
     engine_params: EngineParams,
@@ -164,7 +172,35 @@ def run_train(
     startup zombie sweep. ``resume_instance_id`` re-enters a resumable
     (INTERRUPTED/FAILED) instance; ``auto_resume`` picks the most recent
     one with checkpoints on disk.
+
+    The run is one `train` trace on a recorder of its own (surface
+    `train`; docs/observability.md "Training"): every stage below is a
+    span in it, emitted where the work happens, and the finished tree
+    is logged as one `train spans:` record.
     """
+    tracer = Tracer(recorder=make_recorder("train"), device=True)
+    trace_id = None
+    try:
+        with tracer.trace("train", engine=engine_id) as (trace_id, root):
+            return _run_train(
+                tracer, root, engine, engine_params, storage, engine_id,
+                engine_version, engine_variant, engine_factory, batch, ctx,
+                stop_after_read, stop_after_prepare, resume_instance_id,
+                auto_resume, checkpoint_root, supervise,
+                heartbeat_every_steps, sweep_stale_s)
+    finally:
+        if trace_id is not None:
+            log.info("train spans: %s", json.dumps(
+                tracer.recorder.span_rows(trace_id),
+                separators=(",", ":")))
+
+
+def _run_train(
+    tracer: Tracer, root: dict, engine, engine_params, storage, engine_id,
+    engine_version, engine_variant, engine_factory, batch, ctx,
+    stop_after_read, stop_after_prepare, resume_instance_id, auto_resume,
+    checkpoint_root, supervise, heartbeat_every_steps, sweep_stale_s,
+) -> str:
     # persistent XLA compile cache BEFORE any engine compile: the second
     # consecutive train of the same engine deserializes its executables
     # instead of re-running XLA (utils/compilecache.py; PIO_TPU_COMPILE_
@@ -172,90 +208,94 @@ def run_train(
     from pio_tpu.parallel.mesh import describe_device_memory, describe_devices
     from pio_tpu.utils.compilecache import CompileMeter, enable_compile_cache
 
-    enable_compile_cache()
-    ctx = ctx or create_workflow_context(storage)
-    log.info("train devices: %s", describe_devices())
-    instances = storage.get_metadata_engine_instances()
-    from pio_tpu.parallel.distributed import barrier, is_primary
+    with tracer.span("train.setup"):
+        enable_compile_cache()
+        ctx = ctx or create_workflow_context(storage)
+        log.info("train devices: %s", describe_devices())
+        instances = storage.get_metadata_engine_instances()
+        from pio_tpu.parallel.distributed import barrier, is_primary
 
-    primary = is_primary()
-    if supervise and primary:
-        try:
-            swept = sweep_zombies(
-                storage,
-                **({"stale_after_s": sweep_stale_s}
-                   if sweep_stale_s is not None else {}),
-            )
-            if swept:
-                log.warning("startup sweep transitioned %d zombie "
-                            "instance(s) to FAILED: %s",
-                            len(swept), [i.id for i in swept])
-        except Exception:  # noqa: BLE001 - the sweep is advisory
-            log.warning("startup zombie sweep failed", exc_info=True)
+        primary = is_primary()
+        if supervise and primary:
+            try:
+                swept = sweep_zombies(
+                    storage,
+                    **({"stale_after_s": sweep_stale_s}
+                       if sweep_stale_s is not None else {}),
+                )
+                if swept:
+                    log.warning("startup sweep transitioned %d zombie "
+                                "instance(s) to FAILED: %s",
+                                len(swept), [i.id for i in swept])
+            except Exception:  # noqa: BLE001 - the sweep is advisory
+                log.warning("startup zombie sweep failed", exc_info=True)
 
-    instance = _resolve_instance(
-        instances, primary, resume_instance_id, auto_resume,
-        engine_id, engine_version, engine_variant, engine_factory, batch,
-        engine_params, checkpoint_root,
-    )
-    resumed = instance.status in RESUMABLE_STATUSES
-    instance_id = instance.id
-
-    # a resumed run MUST read the directory the original run recorded —
-    # recomputing from the current --checkpoint-root/env could point at
-    # an empty dir and silently restart from step 0 (and --auto-resume's
-    # has_checkpoint validation reads the recorded dir)
-    ckpt_dir = (
-        (instance.progress or {}).get("checkpoint_dir") if resumed else None
-    ) or checkpoint_dir_for(instance_id, checkpoint_root)
-    handler = PreemptionHandler() if supervise else None
-    lifecycle = TrainLifecycle(
-        instances,
-        instance,
-        checkpoint_dir=ckpt_dir,
-        heartbeat_every_steps=heartbeat_every_steps,
-        preemption=handler,
-        readonly=not primary,
-    )
-
-    def record(status: str, **progress_extra) -> None:
-        """Terminal status transition, keeping accumulated progress."""
-        lifecycle.stop()  # the liveness beat must not race terminal writes
-        if not primary:
-            return
-        progress = dict(lifecycle.instance.progress)
-        progress.update(progress_extra)
-        lifecycle.instance = replace(
-            lifecycle.instance, status=status, end_time=utcnow(),
-            progress=progress,
+        instance = _resolve_instance(
+            instances, primary, resume_instance_id, auto_resume,
+            engine_id, engine_version, engine_variant, engine_factory, batch,
+            engine_params, checkpoint_root,
         )
-        instances.update(lifecycle.instance)
+        resumed = instance.status in RESUMABLE_STATUSES
+        instance_id = instance.id
+        root.update(
+            instance=instance_id,
+            chips=ctx.mesh.devices.size if ctx.mesh is not None else 1)
 
-    # mark the run live before training: TRAINING + an initial heartbeat
-    # so a kill -9 from now on is detectable as a stale zombie
-    progress = dict(instance.progress)
-    if resumed:
-        progress["resumed_at"] = format_time(utcnow())
-    lifecycle.instance = replace(
-        instance, status="TRAINING", progress=progress
-    )
-    if primary:
-        instances.update(lifecycle.instance)
-    lifecycle.heartbeat(progress.get("step", 0), force=True)
-    lifecycle.start()  # wall-clock liveness beat (see TrainLifecycle)
+        # a resumed run MUST read the directory the original run recorded
+        # — recomputing from the current --checkpoint-root/env could point
+        # at an empty dir and silently restart from step 0 (and
+        # --auto-resume's has_checkpoint validation reads the recorded dir)
+        ckpt_dir = (
+            (instance.progress or {}).get("checkpoint_dir")
+            if resumed else None
+        ) or checkpoint_dir_for(instance_id, checkpoint_root)
+        handler = PreemptionHandler() if supervise else None
+        lifecycle = TrainLifecycle(
+            instances,
+            instance,
+            checkpoint_dir=ckpt_dir,
+            heartbeat_every_steps=heartbeat_every_steps,
+            preemption=handler,
+            readonly=not primary,
+        )
+
+        def record(status: str, **progress_extra) -> None:
+            """Terminal status transition, keeping accumulated progress."""
+            # the liveness beat must not race terminal writes
+            lifecycle.stop()
+            if not primary:
+                return
+            progress = dict(lifecycle.instance.progress)
+            progress.update(progress_extra)
+            lifecycle.instance = replace(
+                lifecycle.instance, status=status, end_time=utcnow(),
+                progress=progress,
+            )
+            instances.update(lifecycle.instance)
+
+        # mark the run live before training: TRAINING + an initial
+        # heartbeat so a kill -9 from now on is detectable as a stale zombie
+        progress = dict(instance.progress)
+        if resumed:
+            progress["resumed_at"] = format_time(utcnow())
+        lifecycle.instance = replace(
+            instance, status="TRAINING", progress=progress
+        )
+        if primary:
+            instances.update(lifecycle.instance)
+        lifecycle.heartbeat(progress.get("step", 0), force=True)
+        lifecycle.start()  # wall-clock liveness beat (see TrainLifecycle)
 
     ctx.lifecycle = lifecycle
     compile_meter = CompileMeter()
     try:
         with handler if handler is not None else nullcontext():
-            t_train = time.monotonic()
             models = engine.train(
                 ctx,
                 engine_params,
                 stop_after_read=stop_after_read,
                 stop_after_prepare=stop_after_prepare,
             )
-            t_persist = time.monotonic()
             # chaos point: a `train.persist` spec simulates a storage
             # fault during the final model write — the run must land
             # FAILED (resumable from its last checkpoint), never
@@ -264,25 +304,35 @@ def run_train(
             # its peers blocked in sync_global_devices forever.
             persist_error: Exception | None = None
             try:
-                chaos.maybe_inject("train.persist")
                 blob = models_to_bytes(models)
-                if primary:
-                    storage.get_model_data_models().insert(
-                        Model(instance_id, blob)
-                    )
+                with tracer.span("persist.insert", bytes=len(blob)):
+                    chaos.maybe_inject("train.persist")
+                    if primary:
+                        storage.get_model_data_models().insert(
+                            Model(instance_id, blob)
+                        )
             except Exception as e:  # noqa: BLE001 - re-raised after barrier
                 persist_error = e
             # the COMPLETED transition must not outrun any host's part of
             # the persist epoch
-            barrier("train-persist")
+            with tracer.span("train.barrier"):
+                barrier("train-persist")
             if persist_error is not None:
                 raise persist_error
-            record("COMPLETED")
+            with tracer.span("train.complete"):
+                record("COMPLETED")
             log.info("training %s COMPLETED (%d bytes of models)",
                      instance_id, len(blob))
-            log.info("train timing: engine.train %.2fs, of which compile "
-                     "%s; persist %.2fs", t_persist - t_train,
-                     compile_meter, time.monotonic() - t_persist)
+            # from the spans: engine.train is its three stages, persist
+            # everything from the models in hand to the instance COMPLETED
+            log.info("train timing: engine.train %.3fs, of which compile "
+                     "%s; persist %.3fs",
+                     _seconds(tracer, "train.read", "train.prepare",
+                              "train.algorithms"),
+                     compile_meter,
+                     _seconds(tracer, "persist.d2h", "persist.pickle",
+                              "persist.frame", "persist.insert",
+                              "train.barrier", "train.complete"))
             log.info("train device memory: %s", describe_device_memory())
             return instance_id
     except TrainingPreempted as preempted:

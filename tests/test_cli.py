@@ -122,6 +122,18 @@ def test_build_train_deploy_roundtrip(cli, memory_storage, tmp_path):
                     "--stop-after-read")
     assert code == 0 and "interrupted" in out.out.lower()
 
+    # a device profile of the whole train: the job's spans lie on the
+    # trace's host plane (no device plane on the CPU backend)
+    from pio_tpu.obs import profile
+
+    prof = tmp_path / "prof"
+    code, out = cli("train", "--engine-dir", str(engine_dir), "--no-mesh",
+                    "--device-profile", str(prof))
+    assert code == 0 and "python -m pio_tpu.obs.profile" in out.out
+    spans = {name for name, _, _ in profile.read_profile(str(prof))["spans"]}
+    assert {"train", "train.algorithms", "als.dispatch",
+            "persist.insert"} <= spans
+
 
 def test_build_missing_engine_json(cli, tmp_path):
     code, out = cli("build", "--engine-dir", str(tmp_path))

@@ -1,0 +1,138 @@
+"""`run_train` as a span tree (workflow/train.py, controller/engine.py,
+workflow/checkpoint.py, ops/als.py through utils/tracing.py): one
+`train` trace a job on the `train` surface, every stage a span emitted
+where the work happens, the finished tree logged as `train spans:`, and
+the two older INFO records printed from the spans' durations in the
+words the benchmark's regular expressions read."""
+
+import json
+import logging
+
+import pytest
+
+from benchmark.drivers.train_child import _STAGES, _TIMING
+from pio_tpu.obs import set_tracing
+from pio_tpu.resilience import chaos
+from pio_tpu.workflow.context import create_workflow_context
+from pio_tpu.workflow.train import run_train
+from tests._tiny_train import memory_storage, tiny_engine, tiny_params
+
+UNDER_ROOT = ["train.setup", "train.read", "train.prepare",
+              "train.algorithms", "persist.d2h", "persist.pickle",
+              "persist.frame", "persist.insert", "train.barrier",
+              "train.complete"]
+# under train.algorithms, in order, by path; then the labels a span
+# must carry (the counts at its boundary)
+PATHS = {
+    "one-device": ["als.init", "als.prep", "als.transfer", "als.dispatch",
+                   "als.wait"],
+    "eight-devices": ["als.partition", "als.init", "als.transfer",
+                      "als.dispatch", "als.wait"],
+}
+LABELS = {
+    "train": {"engine", "instance", "chips"},
+    "train.read": {"ratings"},
+    "train.algorithms": {"programs", "cache_hits", "compile_s"},
+    "als.partition": {"rows_u", "rows_i", "nnz_max_u", "nnz_min_u",
+                      "nnz_max_i", "nnz_min_i", "padded_u", "padded_i"},
+    "als.prep": {"padded"},
+    "als.transfer": {"bytes"},
+    "als.dispatch": {"cs", "su", "si", "fill_u", "fill_i", "cg_matvecs"},
+    "persist.d2h": {"bytes"},
+    "persist.pickle": {"bytes", "ids"},
+    "persist.frame": {"bytes"},
+    "persist.insert": {"bytes"},
+}
+
+
+def _train(path: str, caplog, jobs: int = 1, **run) -> list[str]:
+    """-> the messages `run_train` logged, last job's last."""
+    storage = memory_storage()
+    engine = tiny_engine()
+    ctx = create_workflow_context(
+        storage, use_mesh=path == "eight-devices")
+    with caplog.at_level(logging.INFO, logger="pio_tpu.workflow"):
+        for _ in range(jobs):
+            caplog.clear()
+            run_train(engine, tiny_params(cg_iters=3), storage,
+                      engine_id="tiny", ctx=ctx, **run)
+    return [r.getMessage() for r in caplog.records]
+
+
+def _spans(messages: list[str]) -> list[dict]:
+    [line] = [m for m in messages if m.startswith("train spans: ")]
+    return json.loads(line[len("train spans: "):])
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_one_tree_with_every_span_of_the_path(path, caplog):
+    # the second job: the first one compiles inside `als.dispatch`
+    rows = _spans(_train(path, caplog, jobs=2))
+    names = [r["name"] for r in rows]
+    assert names.count("train") == 1 and rows[0]["name"] == "train"
+    root = rows[0]
+    assert root["parent"] is None and root["start_s"] == 0.0
+    assert root["labels"]["chips"] == ("8" if path == "eight-devices"
+                                       else "1")
+    assert [r["name"] for r in rows if r["parent"] == "train"] == UNDER_ROOT
+    assert [r["name"] for r in rows
+            if r["parent"] == "train.algorithms"] == PATHS[path]
+    assert len(rows) == 1 + len(UNDER_ROOT) + len(PATHS[path])
+    for row in rows:
+        assert LABELS.get(row["name"], set()) <= set(row["labels"]), row
+        assert "status" not in row
+        assert 0.0 <= row["start_s"] <= root["duration_s"]
+    by_name = {r["name"]: r for r in rows}
+    assert by_name["train.read"]["labels"]["ratings"] == "5000"
+    assert by_name["persist.pickle"]["labels"]["ids"] == "500"
+    assert int(by_name["als.transfer"]["labels"]["bytes"]) > 0
+    assert int(by_name["als.dispatch"]["labels"]["cg_matvecs"]) > 0
+    # the children cover the root: what is left is its self time
+    self_s = root["duration_s"] - sum(
+        r["duration_s"] for r in rows if r["parent"] == "train")
+    assert 0.0 <= self_s < max(0.02 * root["duration_s"], 0.005)
+    kids = sum(r["duration_s"] for r in rows
+               if r["parent"] == "train.algorithms")
+    assert kids <= by_name["train.algorithms"]["duration_s"]
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_failing_persist_leaves_the_span_in_error(path, caplog):
+    with pytest.raises(chaos.ChaosError):
+        with chaos.inject("train.persist", error=1.0):
+            _train(path, caplog)
+    rows = {r["name"]: r for r in _spans(
+        [r.getMessage() for r in caplog.records])}
+    failed = rows["persist.insert"]
+    assert failed["status"] == "error" and "ChaosError" in failed["error"]
+    assert failed["labels"]["chaos"] == "train.persist"
+    assert rows["train"]["status"] == "error"
+    assert "status" not in rows["persist.frame"]
+    # the barrier is reached on both outcomes; COMPLETED is not
+    assert "train.barrier" in rows and "train.complete" not in rows
+
+
+@pytest.mark.parametrize("tracing", ["on", "off"])
+def test_the_benchmarks_expressions_still_read_the_records(tracing, caplog):
+    set_tracing(tracing == "on")
+    try:
+        messages = _train("one-device", caplog)
+    finally:
+        set_tracing(None)
+    [stages] = [m for m in map(_STAGES.search, messages) if m]
+    [timing] = [m for m in map(_TIMING.search, messages) if m]
+    read, prepare, algorithms = map(float, stages.groups())
+    assert timing[1].count(".") == 1 and len(timing[1].split(".")[1]) == 3
+    assert float(timing[1]) == pytest.approx(
+        read + prepare + algorithms, abs=2e-3)
+    assert int(timing[3]) >= 0 and int(timing[4]) >= 0
+    assert float(timing[5]) >= 0.0
+    if tracing == "on":
+        rows = {r["name"]: r for r in _spans(messages)}
+        assert float(algorithms) == pytest.approx(
+            rows["train.algorithms"]["duration_s"], abs=1e-3)
+        persist = sum(rows[n]["duration_s"] for n in UNDER_ROOT[4:])
+        assert float(timing[5]) == pytest.approx(persist, abs=1e-3)
+    else:
+        # PIO_TPU_TRACE=off: no recorder, no tree; the records stay
+        assert not [m for m in messages if m.startswith("train spans:")]
