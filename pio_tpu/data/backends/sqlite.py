@@ -7,19 +7,41 @@ model blobs, in one database file. Uses a single `events` table keyed by
 table-per-app DDL (JDBCLEvents.scala:106) — same namespace semantics via an
 explicit namespaces table. The DAO bodies live in sqlcommon.py, shared
 with the PostgreSQL backend; this module provides the sqlite dialect
-(INSERT OR REPLACE upserts, `IS ?` null-safe equality, lastrowid) and
-the schema/migration.
+(INSERT OR REPLACE upserts, `IS ?` null-safe equality, lastrowid), the
+schema/migration, and its own Models DAO.
+
+Model blobs of `EXTERNAL_BLOB_BYTES` (1 MiB) or more live OUTSIDE the
+database, one file each under `PATH.models/`, written by
+`utils.durable.durable_write`; their `models` row holds NULL. Inline, a
+212 MB model was 52,000 overflow pages that went to the WAL and fsync,
+and the same commit's auto-checkpoint then copied every one of them
+into PATH and fsynced again: each byte written twice. A file is one
+sequential write. Smaller blobs (rollout state, shard plans, sweep
+records: JSON of a few KB) stay inline, and so does every inline row a
+database already holds: no migration, they keep reading. A backup of a
+sqlite store is therefore PATH **and** PATH.models/.
 """
 
 from __future__ import annotations
 
+import hashlib
 import logging
 import os
 import sqlite3
 import threading
+from urllib.parse import quote
 
+from pio_tpu.data import dao as d
 from pio_tpu.data.backends import sqlcommon as sc
 from pio_tpu.data.storage import Backend
+from pio_tpu.utils import tracing
+from pio_tpu.utils.durable import (
+    ModelIntegrityError, durable_read, durable_write, fsync_dir,
+)
+
+# a model blob at least this long is a file beside the database, not
+# pages inside it (module docstring); chosen by the blob's own length
+EXTERNAL_BLOB_BYTES = 1 << 20
 
 _SCHEMA = """
 CREATE TABLE IF NOT EXISTS apps (
@@ -122,6 +144,86 @@ class _SqliteDb:
         pass  # sqlite rowid allocation is MAX(rowid)+1: always aligned
 
 
+class _SqliteModels(sc.SqlModels):
+    """Models DAO of the sqlite dialect: large blobs as files under
+    `blob_dir`, their row `(id, NULL)`; `blob_dir` None (an in-memory
+    database) keeps everything inline.
+
+    Order is the crash contract. `insert`: the file and its directory
+    entry are synced before the row commits, so a committed NULL row
+    always has its file; a crash between the two leaves a file without
+    a row, which `get` never sees and the next insert of that id
+    overwrites. `delete`: the row first, for the same reason.
+    """
+
+    def __init__(self, db, blob_dir: str | None, blob_lock: threading.Lock):
+        super().__init__(db)
+        self._dir = blob_dir
+        # one writer at a time: durable_write's tmp name is per process
+        self._blob_lock = blob_lock
+
+    def _file(self, model_id: str) -> str:
+        # percent-encoding is one-to-one and leaves no separator; "+"
+        # is never in its output, so a hashed name (an id too long for
+        # a file name) cannot meet an encoded one
+        name = quote(model_id, safe="")
+        if len(name) > 200:
+            name = name[:100] + "+" + hashlib.sha256(
+                model_id.encode("utf-8")).hexdigest()
+        return os.path.join(self._dir, name + ".bin")
+
+    def _remove_file(self, model_id: str) -> None:
+        if self._dir is None:
+            return
+        try:
+            os.unlink(self._file(model_id))
+        except FileNotFoundError:
+            pass
+
+    def insert(self, m: d.Model):
+        blob = m.models
+        external = (self._dir is not None
+                    and len(blob) >= EXTERNAL_BLOB_BYTES)
+        if external:
+            with tracing.span("models.file", bytes=len(blob)), \
+                    self._blob_lock:
+                if not os.path.isdir(self._dir):
+                    os.makedirs(self._dir, exist_ok=True)
+                    # pio: lint-ok[blocking-under-lock] as below; once
+                    fsync_dir(os.path.dirname(self._dir))
+                # pio: lint-ok[blocking-under-lock] the lock exists to
+                # keep two writers off one tmp file; only writers of
+                # large model files ever wait for it (seconds, where
+                # the inline insert held the database's own lock)
+                durable_write(self._file(m.id), blob)
+        with tracing.span("models.row",
+                          inline_bytes=0 if external else len(blob)):
+            super().insert(d.Model(m.id, None) if external else m)
+        if not external:
+            # the id may have been stored the other way: one copy, not two
+            self._remove_file(m.id)
+
+    def get(self, model_id):
+        record = super().get(model_id)
+        if record is None or record.models is not None or self._dir is None:
+            return record
+        path = self._file(model_id)
+        try:
+            # every reader of a model unframes it (models_from_bytes):
+            # the content frame is checked there, once
+            blob = durable_read(path, verify_content=False)
+        except FileNotFoundError:
+            raise ModelIntegrityError(
+                f"model {model_id!r} has its row but not its file {path}: "
+                f"a copy of a sqlite store needs {self._dir}/ as well"
+            ) from None
+        return d.Model(record.id, blob)
+
+    def delete(self, model_id):
+        super().delete(model_id)
+        self._remove_file(model_id)
+
+
 class SqliteBackend(Backend):
     def __init__(self, config):
         super().__init__(config)
@@ -135,6 +237,9 @@ class SqliteBackend(Backend):
         self._conn.execute("PRAGMA journal_mode=WAL")
         self._lock = threading.RLock()
         self._db = _SqliteDb(self._conn, self._lock)
+        self._blob_dir = (
+            None if path == ":memory:" else os.path.abspath(path) + ".models")
+        self._blob_lock = threading.Lock()
         with self._lock:
             self._migrate_events_pk()
             self._conn.executescript(_SCHEMA)
@@ -188,8 +293,9 @@ class SqliteBackend(Backend):
     def close(self):
         with self._lock:
             # fold the WAL back into the main db file so a plain file copy of
-            # PATH is a complete backup (operators expect that); sqlite
-            # reports BUSY via the result row, not an exception
+            # PATH, with the model files under PATH.models/, is a complete
+            # backup (operators expect that); sqlite reports BUSY via the
+            # result row, not an exception
             try:
                 row = self._conn.execute(
                     "PRAGMA wal_checkpoint(TRUNCATE)"
@@ -223,7 +329,7 @@ class SqliteBackend(Backend):
         return sc.SqlEvaluationInstances(self._db)
 
     def models(self):
-        return sc.SqlModels(self._db)
+        return _SqliteModels(self._db, self._blob_dir, self._blob_lock)
 
     def events(self):
         # sqlite's OR REPLACE resolves against the expression index

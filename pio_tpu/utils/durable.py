@@ -155,21 +155,26 @@ def durable_write(path: str, payload: bytes) -> None:
         except OSError:
             pass
         raise
-    _fsync_dir(directory)
+    fsync_dir(directory)
 
 
-def durable_read(path: str) -> bytes:
+def durable_read(path: str, verify_content: bool = True) -> bytes:
     """Read + verify a ``durable_write`` artifact, returning exactly the
     bytes that were passed to ``durable_write``: the ``WRAP_MAGIC``
     wrapper is verified and stripped; a content-framed (``MAGIC``) file
     is verified and returned WITH its frame (the caller's deserializer
     owns stripping it). Legacy unframed files pass through unverified
-    (back-compat with pre-durability stores)."""
+    (back-compat with pre-durability stores).
+
+    ``verify_content=False`` is for a reader whose every caller unframes
+    what it gets (a Models DAO: ``models_from_bytes``): the content
+    frame is then checked once, by its owner, not once here and again
+    there — two CRC passes over a model of hundreds of MB."""
     with open(path, "rb") as f:
         data = f.read()
     if is_framed(data, WRAP_MAGIC):
         return unframe(data, source=path, magic=WRAP_MAGIC)
-    if is_framed(data):
+    if verify_content and is_framed(data):
         unframe(data, source=path)  # verify only; frame belongs to caller
     return data
 
@@ -346,7 +351,7 @@ class FrameLog:
                 raise
             # pio: lint-ok[blocking-under-lock] same span as above: the
             # rename is not durable until the directory entry is synced
-            _fsync_dir(directory)
+            fsync_dir(directory)
             tail_payloads, tail_corrupt, _ = self._scan_bytes(tail)
             self._depth = len(keep) + len(tail_payloads)
             self.corrupt_pending = tail_corrupt
@@ -356,7 +361,7 @@ class FrameLog:
             return self._depth
 
 
-def _fsync_dir(directory: str) -> None:
+def fsync_dir(directory: str) -> None:
     """fsync the directory so the rename itself is durable; best-effort
     on platforms/filesystems that refuse O_RDONLY directory fds."""
     try:

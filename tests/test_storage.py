@@ -1,15 +1,20 @@
 """Storage locator + metadata DAOs, run against memory and sqlite backends
 (the reference's parameterized LEventsSpec pattern)."""
 
+import os
 from datetime import datetime, timedelta, timezone
 
 import pytest
 
 from pio_tpu.data import DataMap, Event
+from pio_tpu.data.backends.sqlite import EXTERNAL_BLOB_BYTES, SqliteBackend
 from pio_tpu.data.dao import (
     AccessKey, App, Channel, EngineInstance, EvaluationInstance, Model,
 )
-from pio_tpu.data.storage import Storage, StorageError, parse_env
+from pio_tpu.data.storage import (
+    Storage, StorageClientConfig, StorageError, parse_env,
+)
+from pio_tpu.utils.durable import ModelIntegrityError, frame, unframe
 
 T0 = datetime(2020, 1, 1, tzinfo=timezone.utc)
 
@@ -131,6 +136,205 @@ def test_models_blob(any_storage):
     assert models.get("inst1").models == b"v2"
     models.delete("inst1")
     assert models.get("inst1") is None
+
+
+# ---------------------------------------------------------------------------
+# the sqlite backend's Models DAO: blobs of 1 MiB or more are files under
+# PATH.models/, their row holds NULL (pio_tpu/data/backends/sqlite.py)
+# ---------------------------------------------------------------------------
+
+def _sqlite_backend(path):
+    return SqliteBackend(StorageClientConfig(properties={"PATH": str(path)}))
+
+
+def _big_blob(extra=1, fill=b"\xa5"):
+    """A content-framed blob of 1 MiB + `extra` bytes, as
+    `models_to_bytes` frames a model."""
+    blob = frame(fill * (EXTERNAL_BLOB_BYTES + extra - 17))
+    assert len(blob) == EXTERNAL_BLOB_BYTES + extra
+    return blob
+
+
+def _inline_len(be, model_id):
+    """Bytes the row itself holds: None without a row, 0 for NULL."""
+    rows = be._db.query(
+        "SELECT IFNULL(LENGTH(models), 0) FROM models WHERE id=?",
+        (model_id,))
+    return rows[0][0] if rows else None
+
+
+def _blob_files(path):
+    d = str(path) + ".models"
+    return sorted(os.listdir(d)) if os.path.isdir(d) else []
+
+
+def _case_external_round_trip(be, path):
+    blob = _big_blob()
+    be.models().insert(Model("inst:1/a", blob))
+    assert _inline_len(be, "inst:1/a") == 0
+    assert len(_blob_files(path)) == 1
+    assert be.models().get("inst:1/a").models == blob
+    # an unframed payload over the line comes back byte for byte too
+    raw = bytes(range(256)) * 4097
+    be.models().insert(Model("raw", raw))
+    assert _inline_len(be, "raw") == 0
+    assert be.models().get("raw").models == raw
+    # a second connection (another process) finds the same model
+    other = _sqlite_backend(path)
+    assert unframe(other.models().get("inst:1/a").models) == unframe(blob)
+    other.close()
+
+
+def _case_under_the_line_inline(be, path):
+    blob = b"j" * (EXTERNAL_BLOB_BYTES - 1)
+    be.models().insert(Model("small", blob))
+    assert _inline_len(be, "small") == len(blob)
+    assert _blob_files(path) == []
+    assert be.models().get("small").models == blob
+
+
+def _case_old_inline_row_reads(be, path):
+    # a database written before PR 25: the large blob is in the row
+    blob = _big_blob()
+    be._db.exec("INSERT INTO models (id, models) VALUES (?,?)",
+                ("old", blob))
+    assert be.models().get("old").models == blob
+    assert _blob_files(path) == []
+
+
+def _case_external_then_inline_one_copy(be, path):
+    be.models().insert(Model("m", _big_blob()))
+    assert len(_blob_files(path)) == 1
+    be.models().insert(Model("m", b"v2"))
+    assert _inline_len(be, "m") == 2 and _blob_files(path) == []
+    assert be.models().get("m").models == b"v2"
+
+
+def _case_inline_then_external_one_copy(be, path):
+    be.models().insert(Model("m", b"v1"))
+    blob = _big_blob()
+    be.models().insert(Model("m", blob))
+    assert _inline_len(be, "m") == 0 and len(_blob_files(path)) == 1
+    assert be.models().get("m").models == blob
+
+
+def _case_delete_row_and_file(be, path):
+    be.models().insert(Model("m", _big_blob()))
+    be.models().insert(Model("keep", _big_blob(2)))
+    be.models().delete("m")
+    assert be.models().get("m") is None and _inline_len(be, "m") is None
+    assert len(_blob_files(path)) == 1
+    assert be.models().get("keep").models == _big_blob(2)
+    be.models().delete("never-stored")
+
+
+def _damaged(be, path, damage):
+    from pio_tpu.workflow.checkpoint import models_from_bytes
+
+    # a model's frame is checked by the model's reader, once; a raw
+    # payload's wrapper by the DAO
+    be.models().insert(Model("m", _big_blob()))
+    be.models().insert(Model("raw", b"r" * (1 << 20)))
+    for name in _blob_files(path):
+        file = os.path.join(str(path) + ".models", name)
+        with open(file, "rb") as f:
+            data = f.read()
+        with open(file, "wb") as f:
+            f.write(damage(data))
+    with pytest.raises(ModelIntegrityError):
+        models_from_bytes(be.models().get("m").models)
+    with pytest.raises(ModelIntegrityError):
+        be.models().get("raw")
+
+
+def _case_truncated_file_raises(be, path):
+    _damaged(be, path, lambda data: data[:len(data) // 2])
+
+
+def _case_bit_flip_raises(be, path):
+    _damaged(be, path,
+             lambda data: data[:5000] + bytes([data[5000] ^ 1]) + data[5001:])
+
+
+def _case_file_without_row_is_no_model(be, path):
+    # a crash between the file and the row: the file is never seen
+    be.models().insert(Model("m", _big_blob()))
+    be._db.exec("DELETE FROM models WHERE id=?", ("m",))
+    assert len(_blob_files(path)) == 1
+    assert be.models().get("m") is None
+    # and the next insert of the id overwrites it
+    be.models().insert(Model("m", _big_blob(3)))
+    assert len(_blob_files(path)) == 1
+    assert be.models().get("m").models == _big_blob(3)
+    # the other way round (a copy of PATH without PATH.models/) is loud
+    os.unlink(os.path.join(str(path) + ".models", _blob_files(path)[0]))
+    with pytest.raises(ModelIntegrityError, match="pio.db.models"):
+        be.models().get("m")
+
+
+def _case_memory_stays_inline(be, path):
+    for config in (StorageClientConfig(properties={"PATH": ":memory:"}),
+                   StorageClientConfig(properties={"PATH": str(path)},
+                                       test=True)):
+        mem = SqliteBackend(config)
+        blob = _big_blob()
+        mem.models().insert(Model("m", blob))
+        assert _inline_len(mem, "m") == len(blob)
+        assert mem.models().get("m").models == blob
+        mem.models().delete("m")
+        assert mem.models().get("m") is None
+        mem.close()
+    assert _blob_files(path) == []
+
+
+def _case_long_id(be, path):
+    long_id = "fleet:" + "n\u00e9/" * 90 + ":plan"
+    be.models().insert(Model(long_id, _big_blob()))
+    be.models().insert(Model(long_id + "x", _big_blob(2)))
+    assert len(_blob_files(path)) == 2
+    assert be.models().get(long_id).models == _big_blob()
+    be.models().insert(Model(long_id, b"{}"))       # inline, file removed
+    assert len(_blob_files(path)) == 1
+    be.models().delete(long_id + "x")
+    assert _blob_files(path) == []
+
+
+@pytest.mark.parametrize("case", [
+    _case_external_round_trip, _case_under_the_line_inline,
+    _case_old_inline_row_reads, _case_external_then_inline_one_copy,
+    _case_inline_then_external_one_copy, _case_delete_row_and_file,
+    _case_truncated_file_raises, _case_bit_flip_raises,
+    _case_file_without_row_is_no_model, _case_memory_stays_inline,
+    _case_long_id,
+], ids=lambda f: f.__name__[len("_case_"):])
+def test_sqlite_models_external_blobs(tmp_path, case):
+    path = tmp_path / "pio.db"
+    be = _sqlite_backend(path)
+    try:
+        case(be, path)
+    finally:
+        be.close()
+
+
+def test_sqlite_large_model_stays_out_of_the_page_store(tmp_path):
+    """The mechanism: a 4 MiB model is one file; neither the database
+    nor its WAL grows by it (inline it was in both: written twice)."""
+    path = tmp_path / "pio.db"
+    be = _sqlite_backend(path)
+    try:
+        blob = _big_blob(3 << 20)
+        be.models().insert(Model("inst", blob))
+        in_pages = sum(
+            os.path.getsize(str(path) + suffix) for suffix in ("", "-wal")
+            if os.path.exists(str(path) + suffix))
+        assert in_pages < (1 << 20), in_pages
+        files = _blob_files(path)
+        assert len(files) == 1
+        assert os.path.getsize(
+            os.path.join(str(path) + ".models", files[0])) == len(blob)
+        assert be.models().get("inst").models == blob
+    finally:
+        be.close()
 
 
 def test_localfs_models(tmp_path):

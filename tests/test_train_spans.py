@@ -11,10 +11,11 @@ import logging
 import pytest
 
 from benchmark.drivers.train_child import _STAGES, _TIMING
+from pio_tpu.data.storage import Storage
 from pio_tpu.obs import set_tracing
 from pio_tpu.resilience import chaos
 from pio_tpu.workflow.context import create_workflow_context
-from pio_tpu.workflow.train import run_train
+from pio_tpu.workflow.train import load_models, run_train
 from tests._tiny_train import memory_storage, tiny_engine, tiny_params
 
 UNDER_ROOT = ["train.setup", "train.read", "train.prepare",
@@ -110,6 +111,47 @@ def test_failing_persist_leaves_the_span_in_error(path, caplog):
     assert "status" not in rows["persist.frame"]
     # the barrier is reached on both outcomes; COMPLETED is not
     assert "train.barrier" in rows and "train.complete" not in rows
+
+
+@pytest.mark.parametrize("users,way", [(40_000, "file"), (300, "inline")])
+def test_a_sqlite_store_says_which_way_the_blob_went(users, way, tmp_path,
+                                                     caplog):
+    """The sqlite Models DAO under `persist.insert`: a model of 1 MiB or
+    more is `models.file` (its bytes) then `models.row` (nothing
+    inline); a smaller one is the row alone."""
+    storage = Storage({
+        "PIO_STORAGE_SOURCES_DB_TYPE": "sqlite",
+        "PIO_STORAGE_SOURCES_DB_PATH": str(tmp_path / "pio.db"),
+        "PIO_STORAGE_SOURCES_MEM_TYPE": "memory",
+        "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "DB",
+        "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "DB",
+    })
+    engine, params = tiny_engine(n_users=users), tiny_params(cg_iters=3)
+    ctx = create_workflow_context(storage, use_mesh=False)
+    with caplog.at_level(logging.INFO, logger="pio_tpu.workflow"):
+        instance = run_train(engine, params, storage, engine_id="tiny",
+                             ctx=ctx)
+    rows = _spans([r.getMessage() for r in caplog.records])
+    insert = next(r for r in rows if r["name"] == "persist.insert")
+    under = [r for r in rows if r["parent"] == "persist.insert"]
+    blob = insert["labels"]["bytes"]
+    assert (int(blob) >= 1 << 20) == (way == "file")
+    if way == "file":
+        assert [r["name"] for r in under] == ["models.file", "models.row"]
+        assert under[0]["labels"] == {"bytes": blob}
+        assert under[1]["labels"] == {"inline_bytes": "0"}
+        # the file is whole and synced before its row is written
+        assert (under[0]["start_s"] + under[0]["duration_s"]
+                <= under[1]["start_s"] + 1e-6)
+    else:
+        assert [r["name"] for r in under] == ["models.row"]
+        assert under[0]["labels"] == {"inline_bytes": blob}
+    assert all("status" not in r for r in under)
+    # the benchmark's "persist" still holds the whole of it
+    assert sum(r["duration_s"] for r in under) <= insert["duration_s"]
+    [model] = load_models(storage, engine, params, instance, ctx)
+    assert len(model.users.ids()) == users
 
 
 @pytest.mark.parametrize("tracing", ["on", "off"])
