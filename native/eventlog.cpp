@@ -1520,7 +1520,10 @@ int64_t el_columnarize(
 
   auto cell_find = [&](uint64_t key) -> Cell* {
     size_t mask = cells.size() - 1;
-    size_t i = (key * 0x9E3779B97F4A7C15ull) & mask;
+    // the product's high half: its low bits depend on the key's low bits
+    // alone, which are the item code, and every pair of one item then
+    // starts probing at one slot (700 steps an event at 1 M ML-20M events)
+    size_t i = ((key * 0x9E3779B97F4A7C15ull) >> 32) & mask;
     while (cells[i].used && cells[i].key != key) i = (i + 1) & mask;
     return &cells[i];
   };

@@ -53,10 +53,13 @@ class EngineParams:
 
 
 def _label_ratings(sp: dict, data) -> None:
-    """`ratings` on a stage's span, where its output is interactions."""
-    values = getattr(data, "values", None)
-    if values is not None and hasattr(values, "__len__"):
-        sp["ratings"] = len(values)
+    """`ratings`, `users` and `items` on a stage's span, where its
+    output is interactions."""
+    for label, column in (("ratings", "values"), ("users", "users"),
+                          ("items", "items")):
+        held = getattr(data, column, None)
+        if held is not None and hasattr(held, "__len__"):
+            sp[label] = len(held)
 
 
 def _single_class_map(x) -> dict[str, type]:

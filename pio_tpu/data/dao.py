@@ -397,17 +397,24 @@ class EventsDAO(abc.ABC):
         default is what extends the columnar path to every LOCAL backend
         (memory/SQL) and the storage server's generic case."""
         from pio_tpu.data.columnar import columnar_interactions
+        from pio_tpu.utils import tracing
 
-        cols = self.find_columnar(
-            app_id=app_id, channel_id=channel_id,
-            start_time=start_time, until_time=until_time,
-            entity_type=entity_type, event_names=event_names,
-            target_entity_type=target_entity_type,
-        )
-        return columnar_interactions(
-            cols, value_key=value_key, default_value=default_value,
-            dedup=dedup, value_event=value_event,
-        )
+        # the job's `events.scan` (docs/observability.md "Training"):
+        # here the bulk read and the fold, ids already Python strings
+        with tracing.span("events.scan") as sp:
+            cols = self.find_columnar(
+                app_id=app_id, channel_id=channel_id,
+                start_time=start_time, until_time=until_time,
+                entity_type=entity_type, event_names=event_names,
+                target_entity_type=target_entity_type,
+            )
+            out = columnar_interactions(
+                cols, value_key=value_key, default_value=default_value,
+                dedup=dedup, value_event=value_event,
+            )
+            sp.update(rows=len(out.values), users=len(out.users),
+                      items=len(out.items))
+        return out
 
     def aggregate_properties(
         self,

@@ -20,6 +20,7 @@ from pio_tpu.data.dao import EventsDAO
 from pio_tpu.data.datamap import PropertyMap
 from pio_tpu.data.event import Event
 from pio_tpu.data.storage import Storage, StorageError, get_storage
+from pio_tpu.utils import tracing
 
 
 class EventStore:
@@ -121,6 +122,11 @@ class EventStore:
         default_value); `value_event` restricts that read to one event
         name (others take default_value) — the reference recommendation
         template's rate-vs-buy rule.
+
+        Inside a `pio train` this is `train.read`, and its parts are
+        spans of the job (docs/observability.md "Training"):
+        `events.scan` and `events.tables` where the store columnarizes
+        (native/eventlog.py; the DAO's default), `events.index` here.
         """
         app_id, channel_id = self._resolve(app_name, channel_name)
         dao = self._dao()
@@ -138,13 +144,14 @@ class EventStore:
                 dedup=dedup,
                 value_event=value_event,
             )
-            return Interactions(
-                user_idx=cols.user_idx.astype(np.int32),
-                item_idx=cols.item_idx.astype(np.int32),
-                values=cols.values,
-                users=EntityIdIndex(cols.users),
-                items=EntityIdIndex(cols.items),
-            )
+            with tracing.span("events.index"):
+                return Interactions(
+                    user_idx=cols.user_idx.astype(np.int32),
+                    item_idx=cols.item_idx.astype(np.int32),
+                    values=cols.values,
+                    users=EntityIdIndex(cols.users),
+                    items=EntityIdIndex(cols.items),
+                )
 
         events = self.find(
             app_name=app_name,

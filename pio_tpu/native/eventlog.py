@@ -24,6 +24,7 @@ import numpy as np
 from pio_tpu.data.datamap import DataMap
 from pio_tpu.data.event import Event
 from pio_tpu.native import load_library
+from pio_tpu.utils import tracing
 
 _EPOCH = datetime(1970, 1, 1, tzinfo=timezone.utc)
 _US = timedelta(microseconds=1)
@@ -381,7 +382,10 @@ class EventLog:
         value_event: str | None = None,
     ) -> Columns:
         """One native sweep: filter + dict-encode + value extract + dedup.
-        value_event restricts value_key extraction to that event name."""
+        value_event restricts value_key extraction to that event name.
+        Two spans of the job in flight (`pio train`'s `train.read`):
+        `events.scan`, the sweep, and `events.tables`, its output copied
+        into NumPy columns and Python id strings."""
         (flags, start, until, h_etype, _h_eid, events_arr, n_events,
          h_tetype, _h_teid, _h_eventid) = f.to_c()
         u8p = C.POINTER(C.c_uint8)
@@ -392,33 +396,37 @@ class EventLog:
         utab, itab = u8p(), u8p()
         ulen, ilen = C.c_uint64(), C.c_uint64()
         nu, ni = C.c_uint32(), C.c_uint32()
-        n = self._lib.el_columnarize(
-            self._h, flags, start, until, h_etype, events_arr, n_events,
-            h_tetype,
-            value_key.encode() if value_key else None,
-            default_value,
-            el_hash(value_event) if value_event else 0,
-            tombstones, len(tombstones), dedup,
-            C.byref(uc), C.byref(ic), C.byref(vals), C.byref(ts),
-            C.byref(utab), C.byref(ulen), C.byref(nu),
-            C.byref(itab), C.byref(ilen), C.byref(ni),
-        )
-        if n < 0:
-            raise OSError(f"columnarize failed on {self.path}")
-        try:
-            cols = Columns(
-                user_idx=np.ctypeslib.as_array(uc, shape=(n,)).copy()
-                if n else np.zeros(0, np.uint32),
-                item_idx=np.ctypeslib.as_array(ic, shape=(n,)).copy()
-                if n else np.zeros(0, np.uint32),
-                values=np.ctypeslib.as_array(vals, shape=(n,)).copy()
-                if n else np.zeros(0, np.float32),
-                times_us=np.ctypeslib.as_array(ts, shape=(n,)).copy()
-                if n else np.zeros(0, np.int64),
-                users=_decode_table(utab, ulen.value, nu.value),
-                items=_decode_table(itab, ilen.value, ni.value),
+        with tracing.span("events.scan") as sp:
+            n = self._lib.el_columnarize(
+                self._h, flags, start, until, h_etype, events_arr, n_events,
+                h_tetype,
+                value_key.encode() if value_key else None,
+                default_value,
+                el_hash(value_event) if value_event else 0,
+                tombstones, len(tombstones), dedup,
+                C.byref(uc), C.byref(ic), C.byref(vals), C.byref(ts),
+                C.byref(utab), C.byref(ulen), C.byref(nu),
+                C.byref(itab), C.byref(ilen), C.byref(ni),
             )
-        finally:
-            for p in (uc, ic, vals, ts, utab, itab):
-                self._lib.el_free(p)
+            if n < 0:
+                raise OSError(f"columnarize failed on {self.path}")
+            sp.update(rows=n, users=nu.value, items=ni.value,
+                      log_bytes=self.stats()[0])
+        with tracing.span("events.tables"):
+            try:
+                cols = Columns(
+                    user_idx=np.ctypeslib.as_array(uc, shape=(n,)).copy()
+                    if n else np.zeros(0, np.uint32),
+                    item_idx=np.ctypeslib.as_array(ic, shape=(n,)).copy()
+                    if n else np.zeros(0, np.uint32),
+                    values=np.ctypeslib.as_array(vals, shape=(n,)).copy()
+                    if n else np.zeros(0, np.float32),
+                    times_us=np.ctypeslib.as_array(ts, shape=(n,)).copy()
+                    if n else np.zeros(0, np.int64),
+                    users=_decode_table(utab, ulen.value, nu.value),
+                    items=_decode_table(itab, ilen.value, ni.value),
+                )
+            finally:
+                for p in (uc, ic, vals, ts, utab, itab):
+                    self._lib.el_free(p)
         return cols

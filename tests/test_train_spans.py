@@ -8,6 +8,7 @@ words the benchmark's regular expressions read."""
 import json
 import logging
 
+import numpy as np
 import pytest
 
 from benchmark.drivers.train_child import _STAGES, _TIMING
@@ -32,7 +33,7 @@ PATHS = {
 }
 LABELS = {
     "train": {"engine", "instance", "chips"},
-    "train.read": {"ratings"},
+    "train.read": {"ratings", "users", "items"},
     "train.algorithms": {"programs", "cache_hits", "compile_s"},
     "als.partition": {"rows_u", "rows_i", "nnz_max_u", "nnz_min_u",
                       "nnz_max_i", "nnz_min_i", "padded_u", "padded_i"},
@@ -78,13 +79,16 @@ def test_one_tree_with_every_span_of_the_path(path, caplog):
     assert [r["name"] for r in rows if r["parent"] == "train"] == UNDER_ROOT
     assert [r["name"] for r in rows
             if r["parent"] == "train.algorithms"] == PATHS[path]
+    # a DataSource that reads no events: no `events.*` span
     assert len(rows) == 1 + len(UNDER_ROOT) + len(PATHS[path])
+    assert not [n for n in names if n.startswith("events.")]
     for row in rows:
         assert LABELS.get(row["name"], set()) <= set(row["labels"]), row
         assert "status" not in row
         assert 0.0 <= row["start_s"] <= root["duration_s"]
     by_name = {r["name"]: r for r in rows}
-    assert by_name["train.read"]["labels"]["ratings"] == "5000"
+    assert by_name["train.read"]["labels"] == {
+        "ratings": "5000", "users": "300", "items": "200"}
     assert by_name["persist.pickle"]["labels"]["ids"] == "500"
     assert int(by_name["als.transfer"]["labels"]["bytes"]) > 0
     assert int(by_name["als.dispatch"]["labels"]["cg_matvecs"]) > 0
@@ -152,6 +156,64 @@ def test_a_sqlite_store_says_which_way_the_blob_went(users, way, tmp_path,
     assert sum(r["duration_s"] for r in under) <= insert["duration_s"]
     [model] = load_models(storage, engine, params, instance, ctx)
     assert len(model.users.ids()) == users
+
+
+@pytest.mark.parametrize("backend,under_read", [
+    ("eventlog", ["events.scan", "events.tables", "events.index"]),
+    ("memory", ["events.scan", "events.index"]),
+])
+def test_a_job_that_reads_events_says_where_the_read_went(
+        backend, under_read, tmp_path, caplog):
+    """The template's own DataSource over an event store: under
+    `train.read`, `events.scan` (the store's columnarize: one native
+    sweep of the `eventlog` store, which knows its log's bytes),
+    `events.tables` (that sweep's output copied into NumPy columns and
+    Python strings; the other stores fold ids as strings already) and
+    `events.index` (the two id indexes)."""
+    from benchmark.drivers.train_child import insert_events
+    from pio_tpu.controller.engine import EngineParams
+    from pio_tpu.models.recommendation import RecommendationEngine
+
+    env = {
+        "PIO_STORAGE_SOURCES_MEM_TYPE": "memory",
+        "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "MEM",
+    }
+    if backend == "eventlog":
+        env.update({"PIO_STORAGE_SOURCES_EL_TYPE": "eventlog",
+                    "PIO_STORAGE_SOURCES_EL_PATH": str(tmp_path / "log"),
+                    "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "EL"})
+    storage = Storage(env)
+    rng = np.random.default_rng(26)
+    pairs = rng.choice(60 * 40, 900, replace=False)   # distinct pairs
+    insert_events(storage, "bench", pairs // 40, pairs % 40,
+                  rng.integers(1, 6, 900))
+    params = EngineParams(
+        datasource=("", {"app_name": "bench", "event_names": ["rate"]}),
+        algorithms=tiny_params(cg_iters=3).algorithms)
+    ctx = create_workflow_context(storage, use_mesh=False)
+    with caplog.at_level(logging.INFO, logger="pio_tpu.workflow"):
+        run_train(RecommendationEngine.apply(), params, storage,
+                  engine_id="bench", ctx=ctx)
+    rows = _spans([r.getMessage() for r in caplog.records])
+    by_name = {r["name"]: r for r in rows}
+    read = by_name["train.read"]
+    counts = {"users": str(len(set((pairs // 40).tolist()))),
+              "items": str(len(set((pairs % 40).tolist())))}
+    assert read["labels"] == {"ratings": "900", **counts}
+    under = [r for r in rows if r["parent"] == "train.read"]
+    assert [r["name"] for r in under] == under_read
+    scan = dict(by_name["events.scan"]["labels"])
+    if backend == "eventlog":
+        assert int(scan.pop("log_bytes")) > 900 * 100
+    assert scan == {"rows": "900", **counts}
+    assert all(not r["labels"] and "status" not in r for r in under[1:])
+    # in order, inside the read, and covering it
+    ends = [r["start_s"] + r["duration_s"] for r in under]
+    assert all(a <= b["start_s"] + 1e-6 for a, b in zip(ends, under[1:]))
+    self_s = read["duration_s"] - sum(r["duration_s"] for r in under)
+    assert 0.0 <= self_s < max(0.02 * read["duration_s"], 0.005)
 
 
 @pytest.mark.parametrize("tracing", ["on", "off"])
