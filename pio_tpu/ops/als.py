@@ -29,7 +29,8 @@ TPU formulation is built around three hardware facts measured on v5e:
 The multi-chip path (`als_train_sharded`) partitions users/items into
 per-device blocks with `shard_map`; each half-sweep all_gathers the
 opposing factor block over ICI — the analogue of MLlib's shuffle, but a
-single fused collective.
+single fused collective. Its host splits the ratings by block once a
+side (`_partition_coo`); the initial factors never leave the devices.
 
 Ratings slots are (width,)-wide segments of one row's ratings; rows with
 more ratings than `width` naturally occupy several slots, and their partial
@@ -38,10 +39,13 @@ normal-equation blocks scatter-add into the same row system.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import functools
 import logging
 import math
+import os
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from functools import partial
 
@@ -1417,6 +1421,60 @@ def _sharded_train_fn(mesh: Mesh, ub: int, ib: int, su: int, si: int,
     return jax.jit(run)
 
 
+def _partition_coo(rows, cols, vals, block: int, n_dev: int, chunk: int):
+    """Split COO ratings into `n_dev` contiguous blocks of `block` rows.
+
+    -> (r_st, c_st, v_st, nnz_max, counts): (n_dev, nnz_max) stacks,
+    int32 / int32 / float32, with LOCAL row ids, each block's ratings in
+    their arrival order (the on-device slot layout sorts stably, so the
+    summation order and the model's bits follow it); padding entries
+    carry row id = block (the sentinel >= any local id), column 0 and
+    value 0; and each device's rating count.
+
+    One narrow block code a rating; then a thread a device lists its
+    block's ratings and gathers them straight into the stacks. What
+    costs on the host is first-touch page faults of fresh arrays more
+    than arithmetic (PERF.md, PR 27), so nothing wider than the inputs
+    is allocated but the index lists, and the devices' shares run side
+    by side (NumPy releases the GIL in every call here).
+    """
+    rows = np.asarray(rows)
+    if rows.min(initial=0) < 0 or rows.max(initial=0) >= block * n_dev:
+        raise ValueError(
+            f"row ids outside [0, {block * n_dev}): "
+            f"{rows.min()} .. {rows.max()}")
+    # int32 from every DataSource: no copy; a wider dtype narrows here
+    rows = rows.astype(np.int32, copy=False)
+    cols = np.asarray(cols).astype(np.int32, copy=False)
+    vals = np.asarray(vals, dtype=np.float32)
+    code = np.empty(len(rows), np.uint8 if n_dev <= 256 else np.uint16)
+    np.floor_divide(rows, block, out=code, casting="unsafe")
+    # no more threads than cores: each holds a mask as long as the ratings
+    with ThreadPoolExecutor(min(n_dev, os.cpu_count() or 1)) as pool:
+        picks = list(pool.map(lambda dv: np.flatnonzero(code == dv),
+                              range(n_dev)))
+        counts = [len(ix) for ix in picks]
+        # bucket to a chunk multiple for compile reuse across retrains
+        nnz_max = max(counts)
+        nnz_max += -nnz_max % max(1, chunk)
+        r_st = np.empty((n_dev, nnz_max), np.int32)
+        c_st = np.zeros((n_dev, nnz_max), np.int32)
+        v_st = np.zeros((n_dev, nnz_max), np.float32)
+
+        def fill(dv):
+            ix, n = picks[dv], counts[dv]
+            # mode="clip": the indexes are in range, and the default
+            # mode gathers into a buffer first and copies
+            np.take(rows, ix, out=r_st[dv, :n], mode="clip")
+            r_st[dv, :n] -= dv * block
+            r_st[dv, n:] = block
+            np.take(cols, ix, out=c_st[dv, :n], mode="clip")
+            np.take(vals, ix, out=v_st[dv, :n], mode="clip")
+
+        list(pool.map(fill, range(n_dev)))
+    return r_st, c_st, v_st, nnz_max, counts
+
+
 def als_train_sharded(
     user_idx: np.ndarray,
     item_idx: np.ndarray,
@@ -1430,40 +1488,29 @@ def als_train_sharded(
 
     Host-side work is only a per-device split of the COO arrays (users and
     their ratings partitioned into contiguous blocks, one per device;
-    likewise items), sentinel-padded so every device carries the same
-    shapes. Each device builds its slot layouts locally; each half-sweep
-    every device solves its block's normal equations against the full
-    opposing factor matrix, obtained by `all_gather` over ICI (factors are
-    small: n x k; the ratings never move).
+    likewise items: `_partition_coo`, the two sides side by side),
+    sentinel-padded so every device carries the same shapes; only those
+    six stacks cross from the host. The initial factors are drawn, padded
+    and split into the devices' blocks on the devices. Each device builds
+    its slot layouts locally; each half-sweep every device solves its
+    block's normal equations against the full opposing factor matrix,
+    obtained by `all_gather` over ICI (factors are small: n x k; the
+    ratings never move).
     """
     n_dev = mesh.shape[DATA_AXIS]
     ub, ib = _block(n_users, n_dev), _block(n_items, n_dev)
 
-    def partition(rows, cols, vals, block):
-        """-> (n_dev, nnz_max) stacked COO with LOCAL row ids; padding
-        entries carry row id = block (the sentinel >= any local id);
-        and each device's rating count."""
-        dev_of = rows // block
-        per_dev = [np.flatnonzero(dev_of == dv) for dv in range(n_dev)]
-        counts = [len(ix) for ix in per_dev]
-        # bucket to a chunk multiple for compile reuse across retrains
-        nnz_max = max(counts)
-        nnz_max += -nnz_max % max(1, params.chunk)
-        r_st = np.full((n_dev, nnz_max), block, np.int32)
-        c_st = np.zeros((n_dev, nnz_max), np.int32)
-        v_st = np.zeros((n_dev, nnz_max), np.float32)
-        for dv, ix in enumerate(per_dev):
-            r_st[dv, :len(ix)] = rows[ix] - dv * block
-            c_st[dv, :len(ix)] = cols[ix]
-            v_st[dv, :len(ix)] = vals[ix]
-        return r_st, c_st, v_st, nnz_max, counts
-
     with tracing.span("als.partition") as sp:
-        rows = np.asarray(user_idx, dtype=np.int64)
-        cols = np.asarray(item_idx, dtype=np.int64)
         vals = np.asarray(values, dtype=np.float32)
-        u_r, u_c, u_v, u_nnz, u_counts = partition(rows, cols, vals, ub)
-        i_r, i_c, i_v, i_nnz, i_counts = partition(cols, rows, vals, ib)
+        with ThreadPoolExecutor(2) as sides:
+            by_user = sides.submit(
+                contextvars.copy_context().run, _partition_coo,
+                user_idx, item_idx, vals, ub, n_dev, params.chunk)
+            by_item = sides.submit(
+                contextvars.copy_context().run, _partition_coo,
+                item_idx, user_idx, vals, ib, n_dev, params.chunk)
+            u_r, u_c, u_v, u_nnz, u_counts = by_user.result()
+            i_r, i_c, i_v, i_nnz, i_counts = by_item.result()
         nnz = len(vals)
         sp.update(
             rows_u=ub, rows_i=ib,
@@ -1472,19 +1519,25 @@ def als_train_sharded(
             padded_u=round(1 - nnz / (n_dev * u_nnz), 4),
             padded_i=round(1 - nnz / (n_dev * i_nnz), 4))
 
+    sharding = NamedSharding(mesh, P(DATA_AXIS))
     with tracing.span("als.init"):
         key = jax.random.PRNGKey(params.seed)
         ku, ki = jax.random.split(key)
-        # draw the init at the UNPADDED shape — the exact same draw
-        # als_train makes — then zero-pad the phantom rows: a non-zero
-        # init there would contaminate the shared Y^T Y term of the
-        # implicit-ALS first sweep.
-        user0 = np.zeros((ub * n_dev, params.rank), np.float32)
-        item0 = np.zeros((ib * n_dev, params.rank), np.float32)
-        user0[:n_users] = np.array(init_factors(n_users, params.rank, ku))
-        item0[:n_items] = np.array(init_factors(n_items, params.rank, ki))
-        user0 = user0.reshape(n_dev, ub, params.rank)
-        item0 = item0.reshape(n_dev, ib, params.rank)
+
+        def init_blocks(n, block, k):
+            # draw the init at the UNPADDED shape — the exact same draw
+            # als_train makes — then zero-pad the phantom rows: a non-zero
+            # init there would contaminate the shared Y^T Y term of the
+            # implicit-ALS first sweep. Drawn, padded and split into the
+            # devices' blocks without leaving the devices.
+            table = jnp.pad(init_factors(n, params.rank, k),
+                            ((0, block * n_dev - n), (0, 0)))
+            return jax.device_put(
+                table.reshape(n_dev, block, params.rank), sharding)
+
+        # waited for, so that the draw is charged to this span
+        user0, item0 = jax.block_until_ready(
+            [init_blocks(n_users, ub, ku), init_blocks(n_items, ib, ki)])
 
     cs, su, si = _slot_counts(u_nnz, i_nnz, ub, ib, params)
 
@@ -1494,13 +1547,13 @@ def als_train_sharded(
     key_params = dataclasses.replace(params, seed=0, chunk=0,
                                      chunk_slots=cs)
     run = _sharded_train_fn(mesh, ub, ib, su, si, cs, key_params)
-    sharding = NamedSharding(mesh, P(DATA_AXIS))
     with tracing.span("als.transfer") as sp:
-        host = (u_r, u_c, u_v, i_r, i_c, i_v, user0, item0)
+        host = (u_r, u_c, u_v, i_r, i_c, i_v)
         sp["bytes"] = sum(a.nbytes for a in host)
         # waited for, so that the crossing is not charged to the dispatch
         placed = jax.block_until_ready(
             [jax.device_put(a, sharding) for a in host])
+        placed += [user0, item0]
     with tracing.span("als.dispatch") as sp:
         _dispatch_labels(sp, max(u_counts), max(i_counts), (cs, su, si),
                          params, _sharded_cg_iters(params, ub),

@@ -13,6 +13,7 @@ import pytest
 
 from benchmark.drivers.train_child import _STAGES, _TIMING
 from pio_tpu.data.storage import Storage
+from pio_tpu.models.recommendation import ALSAlgorithmParams
 from pio_tpu.obs import set_tracing
 from pio_tpu.resilience import chaos
 from pio_tpu.workflow.context import create_workflow_context
@@ -91,6 +92,15 @@ def test_one_tree_with_every_span_of_the_path(path, caplog):
         "ratings": "5000", "users": "300", "items": "200"}
     assert by_name["persist.pickle"]["labels"]["ids"] == "500"
     assert int(by_name["als.transfer"]["labels"]["bytes"]) > 0
+    if path == "eight-devices":
+        # what crosses from the host is the six COO stacks and nothing
+        # else: the initial factors are drawn and split on the devices
+        split = by_name["als.partition"]["labels"]
+        chunk = ALSAlgorithmParams().chunk
+        slots = sum(-(-int(split[k]) // chunk) * chunk
+                    for k in ("nnz_max_u", "nnz_max_i"))
+        assert int(by_name["als.transfer"]["labels"]["bytes"]) == (
+            8 * slots * 3 * 4)
     assert int(by_name["als.dispatch"]["labels"]["cg_matvecs"]) > 0
     # the children cover the root: what is left is its self time
     self_s = root["duration_s"] - sum(
