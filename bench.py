@@ -931,7 +931,6 @@ def phase_smoke() -> dict:
         "pooled_binary_p99_x_fresh_json"]
     out["tenant_victim_p99_x_solo"] = out["tenant"]["victim_p99_x_solo"]
     out["tracing_overhead_p50_x"] = out["tracing"]["p50_overhead_x"]
-    out["kernel_lab"] = _smoke_kernel_cell()
     out["sweep"] = _smoke_sweep_cell()
     out["sweep_8pt_x_2seq"] = out["sweep"]["x_2seq"]
     out["retrieval"] = _smoke_retrieval_cell()
@@ -1712,46 +1711,6 @@ def _smoke_replicated_ingest_cell(single_eps: float) -> dict:
         "write_quorum": 2,
         "shed_events": repl["shed_events"],
         "retried_batches": repl["retried_batches"],
-    }
-
-
-def _smoke_kernel_cell() -> dict:
-    """Kernel-lab microcell for the smoke gate: the interpret-mode
-    streaming gather (ops/als_pallas.py gather_rows_stream) vs the XLA
-    gather on a small shape, every CI run. The cell's job is NOT the
-    timing (interpret mode measures the interpreter) — it is that the
-    round-6 kernel path EXECUTES and stays bit-exact on every PR, so a
-    pallas/jax regression is caught by the perf gate instead of the
-    next chip run; parity failure raises and fails the phase. The
-    wall numbers ride along as canaries (not baseline-gated)."""
-    import numpy as np
-
-    import jax.numpy as jnp
-    from pio_tpu.ops.als_pallas import gather_rows_stream
-
-    rng = np.random.default_rng(0)
-    table = jnp.asarray(rng.normal(size=(96, 16)).astype(np.float32))
-    idx = jnp.asarray(rng.integers(0, 96, 333), jnp.int32)
-
-    def run_stream():
-        return np.asarray(gather_rows_stream(table, idx, rows_per_step=64,
-                                             group=16))
-
-    got = run_stream()            # first call: trace + interpret warmup
-    t0 = time.perf_counter()
-    got2 = run_stream()           # steady interpret cost, post-trace
-    stream_ms = (time.perf_counter() - t0) * 1e3
-    ref = np.asarray(table[idx])  # XLA gather on the CPU backend, synced
-    if not (np.array_equal(got, ref) and np.array_equal(got2, ref)):
-        raise AssertionError(
-            "streaming-gather parity failure vs XLA gather (interpret "
-            "mode): the round-6 kernel path regressed")
-    return {
-        "gather_stream_parity": "exact",
-        # interpreter wall time — a canary for pathological slowdowns in
-        # the interpret path, NOT a kernel-vs-XLA comparison (that A/B
-        # is eval/als_kernel_lab.py, on hardware)
-        "gather_stream_interpret_ms": round(stream_ms, 2),
     }
 
 

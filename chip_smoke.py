@@ -499,8 +499,8 @@ def train_on_chip(run: Run, obs: dict, pairs: int) -> str:
     train_dev = parse_devices(log, "pio train")
     require_tpu(train_dev, "pio train", run.args.cpu_dry_run)
     m = grep1(r"ALS train: (\d+) users x (\d+) items, (\d+) ratings, "
-              r"rank (\d+), (\d+) sweeps, (.*?); accum=(\S+) gather=(\S+) "
-              r"packed_a=(\S+)", log, "pio train")
+              r"rank (\d+), (\d+) sweeps, (.*?); accum=(\S+)",
+              log, "pio train")
     if int(m.group(3)) != pairs:
         raise SmokeFailure(
             f"the trainer read {m.group(3)} ratings; {pairs} distinct "
@@ -514,8 +514,7 @@ def train_on_chip(run: Run, obs: dict, pairs: int) -> str:
     obs["train"] = {
         "device": train_dev,
         "layout": m.group(6),
-        "accum": m.group(7), "gather": m.group(8),
-        "packed_a": m.group(9),
+        "accum": m.group(7),
         "wall_seconds_process": round(train_wall, 2),
         "read_seconds": float(stages.group(1)),
         "prepare_seconds": float(stages.group(2)),

@@ -49,32 +49,11 @@ CELLS = [
     {"accum": "carry", "chunk_slots": 32768},    # fewer carries
     {"accum": "stacked", "chunk_slots": 8192},
     {"accum": "stacked", "chunk_slots": 32768},
-    # fused segment-flush kernel (ops/als_pallas.py); its internal VMEM
-    # chunk is capped at 128 regardless of the layout chunk
-    {"accum": "pallas", "chunk_slots": 8192},
-    # XLA batched-MXU blocks + Pallas segment-flush scatter — auto's TPU
-    # pick since round 3 (beats the XLA scatter emitter by ~10%/sweep)
+    # XLA batched-MXU blocks + Pallas segment-flush scatter: what
+    # `auto` runs on a TPU
     {"accum": "hybrid", "chunk_slots": 32768},
-    # round-4 gather A/B: the slot gather is the second-largest sweep
-    # term (119 ms) and the small (items) table takes XLA's slow-emitter
-    # path (the 16 MB codegen cliff, eval/ALS_ROOFLINE.md); these cells
-    # time the VMEM-resident Pallas gather variants against it at the
-    # production accum. ALSParams.gather "auto" flips on a win here.
-    {"accum": "hybrid", "chunk_slots": 32768, "gather": "pallas-copy"},
-    {"accum": "hybrid", "chunk_slots": 32768, "gather": "pallas-take"},
-    # round-6 streaming A/B (eval/ALS_ROOFLINE.md round-6 plan; these
-    # cells convert it to measured numbers on the chip): overlapped
-    # segment flush
-    # alone (vs the hybrid cell above isolates the 65 ms in-kernel
-    # flush waits), + the double-buffered streaming gather (vs the
-    # gather emitter's 119 ms), + lane-packed A end-to-end (the 6.1x
-    # isolated packed-matvec win composing with no relayout). A win
-    # flips ALSParams "auto" accum/gather; packed_a stays opt-in until
-    # the composed cell wins.
+    # the overlapped segment flush against the cell above (ROADMAP S1d)
     {"accum": "stream", "chunk_slots": 32768},
-    {"accum": "stream", "chunk_slots": 32768, "gather": "stream"},
-    {"accum": "stream", "chunk_slots": 32768, "gather": "stream",
-     "packed_a": True},
 ]
 
 
@@ -92,7 +71,7 @@ def main() -> None:
     results = []
     cells = [
         c for c in CELLS
-        if not (c["accum"] in ("pallas", "hybrid", "stream")
+        if not (c["accum"] in ("hybrid", "stream")
                 and dev.platform == "cpu")
         # pallas on CPU runs in interpret mode — a correctness tool
         # (tests/test_als_pallas.py), meaningless to time

@@ -14,16 +14,9 @@ the full ML-20M shape so the production knobs are set by data:
   matvec.packed     A stored (n, k*k) f32 (lane-dim packed), reshaped
                     in-kernel — tests the minor-dim=64 half-lane-waste
                     hypothesis
-  matvec.pallas_packed  round-6: the Pallas packed batched matvec
-                    (ops/als_pallas.py packed_block_matvec) consuming
-                    (n, k*k) natively — the variant that composes with
-                    no XLA relayout at the scatter/solve boundary
-  gather.xla_items / gather.stream_items /
-  gather.xla_users / gather.stream_users
-                    round-6: the double-buffered streaming gather
-                    (gather_rows_stream) vs the XLA emitter, on the
-                    VMEM-sized items table (the 10x-off-peak slow-
-                    emitter regime) AND the 4x-over-budget users table
+                    (the Pallas packed matvec and streaming gather
+                    had arms here until PR 28: Mosaic refused the
+                    kernels they fed, ops/als_pallas.py's header)
   cg16 / cg8        full CG solves at both iteration counts
 
 Numerical error for each blocks variant is reported vs a float64 numpy
@@ -224,36 +217,9 @@ def main() -> None:
                           preferred_element_type=jnp.float32,
                           precision=jax.lax.Precision.HIGH)
 
-    # round-6: the Pallas packed matvec — the XLA "packed" cell above
-    # pays a real relayout when composed (eval/ALS_ROOFLINE.md); this
-    # kernel consumes the packed rows natively. Interpret-mode timing
-    # is the interpreter, so the pallas cells run on accelerators only
-    # (parity on CPU is tests/test_als_pallas.py's job).
-    on_accel = dev.platform != "cpu"
-    if on_accel:
-        from pio_tpu.ops.als_pallas import (
-            _matvec_block_rows, packed_block_matvec,
-        )
-
-        blk = _matvec_block_rows(RANK)
-        n_blk = (N_USERS // blk) * blk
-        A_pk = A_packed[:n_blk]
-        b_pk = b[:n_blk]
-
-        def mv_pallas_packed(Ap, x):
-            return packed_block_matvec(Ap, x, block_rows=blk)
-
-        # numerical parity probe before timing (vs the einsum oracle)
-        probe = mv_pallas_packed(A_pk[:blk], b_pk[:blk])
-        ref = mv_high(A_pk[:blk].reshape(blk, RANK, RANK), b_pk[:blk])
-        res["matvec_pallas_packed_relerr"] = float(
-            jnp.max(jnp.abs(probe - ref)) / jnp.max(jnp.abs(ref)))
-
     x0 = jnp.zeros_like(b)
     matvec_cells = [("high", mv_high, A, b), ("default", mv_default, A, b),
                     ("packed", mv_packed, A_packed, b)]
-    if on_accel:
-        matvec_cells.append(("pallas_packed", mv_pallas_packed, A_pk, b_pk))
     for name, mv, Aarg, xarg in matvec_cells:
         @partial(jax.jit, static_argnums=(0,))
         def mv_t(reps, Ax, x, mv=mv):
@@ -265,59 +231,6 @@ def main() -> None:
         res[f"matvec_{name}_sec"] = timed(mv_t, Aarg, xarg)
         print(json.dumps({f"matvec_{name}_sec":
                           round(res[f"matvec_{name}_sec"], 5)}), flush=True)
-
-    # ---- round-6 gather A/B: streaming kernel vs the XLA emitter ---------
-    # both tables at the production shape: items is the VMEM-sized
-    # slow-emitter regime (the 16 MB cliff), users is 4x over budget —
-    # the streaming kernel is the one variant that covers both. Each
-    # table gets the index stream PRODUCTION feeds it: the users-half
-    # layout's idx are ITEM ids (gathering fac_i), the items-half
-    # layout's idx are USER ids (gathering fac_u) — indexing the users
-    # table with item ids would touch only its first ~19% and measure
-    # the wrong working set.
-    if on_accel:
-        from pio_tpu.ops.als_pallas import gather_rows_stream
-
-        si = _slots_for(NNZ, N_ITEMS, WIDTH, CHUNK_SLOTS)
-        lay_i = jax.jit(_device_slot_layout, static_argnums=(3, 4, 5))(
-            d_i, d_u, d_v, N_ITEMS, WIDTH, si)
-        idx_by_item = jnp.asarray(lay_i[1])   # (S_i, W) of USER ids
-
-        g_idx_items = jnp.asarray(idx[:CHUNK_SLOTS].reshape(-1))
-        g_idx_users = idx_by_item[:CHUNK_SLOTS].reshape(-1)
-
-        for gname, table, g_idx in (("items", fac_i, g_idx_items),
-                                    ("users", fac_u, g_idx_users)):
-            tbl16 = table.astype(jnp.bfloat16)
-
-            @partial(jax.jit, static_argnums=(0,))
-            def gx_t(reps, tbl, ix):
-                def body(acc):
-                    y = tbl[ix]
-                    return acc + jnp.sum(y[:, 0].astype(jnp.float32)) * 1e-30
-
-                return chain(body, jnp.float32(0), reps)
-
-            @partial(jax.jit, static_argnums=(0,))
-            def gs_t(reps, tbl, ix):
-                def body(acc):
-                    # rows_per_step=512: the SAME step size production
-                    # uses (_chunk_blocks caps _gather_pow2_rows at
-                    # 512) — this cell decides the auto flip, so it
-                    # must time the configuration that would ship
-                    y = gather_rows_stream(tbl, ix, rows_per_step=512)
-                    return acc + jnp.sum(y[:, 0].astype(jnp.float32)) * 1e-30
-
-                return chain(body, jnp.float32(0), reps)
-
-            res[f"gather_xla_{gname}_sec"] = timed(gx_t, tbl16, g_idx)
-            res[f"gather_stream_{gname}_sec"] = timed(gs_t, tbl16, g_idx)
-            print(json.dumps({
-                f"gather_xla_{gname}_sec":
-                    round(res[f"gather_xla_{gname}_sec"], 5),
-                f"gather_stream_{gname}_sec":
-                    round(res[f"gather_stream_{gname}_sec"], 5)}),
-                flush=True)
 
     for iters in (8, 16):
         @partial(jax.jit, static_argnums=(0,))

@@ -1,8 +1,8 @@
 """Does every Pallas kernel compile on this backend and agree with its
 XLA oracle?
 
-One cell per kernel in ops/als_pallas.py, ops/attention.py and
-ops/retrieval.py, each run ONCE, compiled (on a TPU `interpret` resolves
+One cell per kernel in ops/als_pallas.py (each on both halves of a
+sweep), ops/attention.py and ops/retrieval.py, each run ONCE, compiled (on a TPU `interpret` resolves
 to False; this script refuses to call a result "compiled" anywhere else),
 at the MovieLens-20M factor shapes (138,493 x 64 and 26,744 x 64), and
 compared with the plain-XLA implementation of the same contract. The ALS
@@ -36,7 +36,6 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from pio_tpu.ops import als  # noqa: E402
-from pio_tpu.ops import als_pallas  # noqa: E402
 from pio_tpu.ops import attention  # noqa: E402
 from pio_tpu.ops import retrieval  # noqa: E402
 
@@ -99,68 +98,25 @@ def main() -> int:
         results.append(row)
         print(json.dumps(row), flush=True)
 
-    # -- ALS accumulation / gather kernels, in composition ------------------
+    # -- ALS accumulation kernels, in composition ---------------------------
+    # (the gather kernels, lane-packed A and the fused kernel had their
+    # cells here until PR 28: ops/als_pallas.py's header has the verdicts)
     @functools.cache
     def xla_users():
-        return half(by_user, fac_i, N_USERS, fac_u,
-                    accum="carry", gather="xla")
+        return half(by_user, fac_i, N_USERS, fac_u, accum="carry")
 
     @functools.cache
     def xla_items():
-        return half(by_item, fac_u, N_ITEMS, fac_i,
-                    accum="carry", gather="xla")
+        return half(by_item, fac_u, N_ITEMS, fac_i, accum="carry")
 
-    for name, mode in (
-        ("segment flush (accum=hybrid), users half",
-         dict(accum="hybrid", gather="xla")),
-        ("fused segment kernel (accum=pallas), users half",
-         dict(accum="pallas", gather="xla")),
-        ("overlapped flush (accum=stream), users half",
-         dict(accum="stream", gather="xla")),
-        ("overlapped flush + packed_a + packed_block_matvec, users half",
-         dict(accum="stream", gather="xla", packed=True)),
-        ("gather_rows_pallas copy (in hybrid), users half",
-         dict(accum="hybrid", gather="pallas-copy")),
-        ("gather_rows_pallas take (in hybrid), users half",
-         dict(accum="hybrid", gather="pallas-take")),
-        ("gather_rows_stream (in hybrid), users half",
-         dict(accum="hybrid", gather="stream")),
-    ):
-        cell(name, lambda mode=mode: half(by_user, fac_i, N_USERS, fac_u,
-                                          **mode), xla_users)
-    for name, mode in (
-        ("segment flush (accum=hybrid), items half",
-         dict(accum="hybrid", gather="xla")),
-        ("gather_rows_stream (in hybrid), items half (users table)",
-         dict(accum="hybrid", gather="stream")),
-    ):
-        cell(name, lambda mode=mode: half(by_item, fac_u, N_ITEMS, fac_i,
-                                          **mode), xla_items)
-
-    # -- the gathers and the packed matvec alone ----------------------------
-    flat = jnp.asarray(by_user[1][:512].reshape(-1))       # item ids
-    tbl = fac_i.astype(jnp.bfloat16)
-    for variant in ("copy", "take"):
-        cell(f"gather_rows_pallas {variant}, items table",
-             lambda variant=variant: als_pallas.gather_rows_pallas(
-                 tbl, flat, rows_per_step=1024, variant=variant
-             ).astype(jnp.float32),
-             lambda: tbl[flat].astype(jnp.float32))
-    flat_u = jnp.asarray(by_item[1][:512].reshape(-1))     # user ids
-    tbl_u = fac_u.astype(jnp.bfloat16)
-    cell("gather_rows_stream, users table",
-         lambda: als_pallas.gather_rows_stream(
-             tbl_u, flat_u, rows_per_step=512).astype(jnp.float32),
-         lambda: tbl_u[flat_u].astype(jnp.float32))
-    blk = als_pallas._matvec_block_rows(RANK)
-    n_mv = (N_USERS // blk) * blk
-    a_pk = jax.random.normal(ku, (n_mv, RANK * RANK), jnp.float32)
-    x_mv = jax.random.normal(ki, (n_mv, RANK), jnp.float32)
-    cell("packed_block_matvec",
-         lambda: als_pallas.packed_block_matvec(a_pk, x_mv, block_rows=blk),
-         lambda: jnp.einsum(
-             "bij,bj->bi", a_pk.reshape(n_mv, RANK, RANK), x_mv,
-             precision=jax.lax.Precision.HIGHEST))
+    for side, layout, other, n_self, x0, oracle in (
+            ("users", by_user, fac_i, N_USERS, fac_u, xla_users),
+            ("items", by_item, fac_u, N_ITEMS, fac_i, xla_items)):
+        for name, accum in (("segment flush", "hybrid"),
+                            ("overlapped flush", "stream")):
+            cell(f"{name} (accum={accum}), {side} half",
+                 functools.partial(half, layout, other, n_self, x0,
+                                   accum=accum), oracle)
 
     # -- flash attention (sequence serving on TPU) --------------------------
     b, s, h, d = (1, 256, 2, 64) if SMALL else (2, 2048, 8, 64)

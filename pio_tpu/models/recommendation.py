@@ -211,12 +211,12 @@ class ALSAlgorithm(PAlgorithm):
         sharded = ctx.mesh is not None and ctx.mesh.devices.size > 1
         log.info(
             "ALS train: %d users x %d items, %d ratings, rank %d, %d "
-            "sweeps, %s; accum=%s gather=%s packed_a=%s",
+            "sweeps, %s; accum=%s",
             data.n_users, data.n_items, len(data.values), ap.rank,
             ap.iterations,
             f"sharded over {ctx.mesh.devices.size} devices" if sharded
             else "one device",
-            ap.resolved_accum(), ap.resolved_gather(), ap.resolved_packed())
+            ap.resolved_accum())
         if sharded:
             # sharded path: best-sweep selection not yet threaded through
             # shard_map (the curve would need a psum'd heldout metric);

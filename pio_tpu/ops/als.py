@@ -9,17 +9,15 @@ TPU formulation is built around three hardware facts measured on v5e:
  * per-rating outer-product scatters are HBM-bound (O(nnz*k^2) traffic), so
    the per-row normal equations  (Y^T C Y + lambda I) x = Y^T C p  are
    accumulated as *batched matmuls* over fixed-width rating slots — MXU
-   work with O(nnz*k) traffic;
+   work with O(nnz*k) traffic; how the slots' blocks are summed into
+   rows is `ALSParams.accum` (one place decides: `resolved_accum`);
  * the solve is short warm-started Jacobi-CG by default: XLA's batched
-   Cholesky does not use the MXU (measured 10 GFLOP/s on (138k,64,64)
-   v5e — 1.16 s of a 1.75 s half-sweep), while CG is pure batched
-   matvecs. At the auto cap max(16, rank//4), per-sweep component timing
-   on the ML-20M shape shows the solve at 142 ms vs Cholesky's 1157 ms,
-   and quality is at parity or better: implicit objective within 1e-5
-   relative of the exact solve, explicit heldout RMSE *lower* (1.310 vs
-   1.352 at rank 64; 1.291 vs 1.322 at rank 100 — the inexact inner
-   solve early-stops the per-row overfit that exact ALS commits to).
-   cg_iters=0 selects the exact Cholesky when bit-exactness matters;
+   Cholesky does not use the MXU, while CG is pure batched matvecs (the
+   CG phase is 1.0 s of a 6.5 s job at the ML-20M shape: PERF.md section
+   5). Quality at the auto cap max(16, rank//4) is at parity or better
+   (eval/RMSE_PARITY.md: the inexact inner solve early-stops the per-row
+   overfit that exact ALS commits to). cg_iters=0 selects the exact
+   Cholesky when bit-exactness matters;
  * the host is slow relative to the chip (single-core sort of 20M ratings
    costs more than the whole train), so the slot layout itself is built
    ON DEVICE from the raw COO arrays: one stable `lax.sort` by row, then
@@ -54,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from pio_tpu.ops import als_pallas
 from pio_tpu.ops.bucketing import pow2_bucket
 from pio_tpu.parallel.mesh import DATA_AXIS
 from pio_tpu.utils import tracing
@@ -75,34 +74,27 @@ class ALSParams:
     width: int = 128          # ratings per slot (= MXU contraction width)
     chunk_slots: int = 8192   # slots per accumulation step (bounds gather temp)
     # gather the opposing factors in bf16 when building the normal
-    # equations: halves that gather's HBM traffic. With the short-CG solve
-    # (which removed the Cholesky wall that used to hide it) this measures
-    # +15% end-to-end at the ML-20M shape on v5e (29.7M vs 25.7M
-    # ratings/s warm); heldout-RMSE delta vs f32 is 1.7e-4 relative on 2M
-    # ratings (bf16+CG 1.33714 vs f32+Cholesky 1.33691), so it defaults
-    # on. Set False for bit-conservative factor builds.
+    # equations: halves that gather's HBM traffic (the gathers are the
+    # largest phase of a job on the chip, PERF.md section 5); heldout-RMSE
+    # delta vs f32 is 1.7e-4 relative on 2M ratings (bf16+CG 1.33714 vs
+    # f32+Cholesky 1.33691), so it defaults on. Set False for
+    # bit-conservative factor builds.
     bf16_gather: bool = True
     cg_iters: int = -1        # -1: auto (per-side: exact Cholesky for
                               # small row batches, short warm-started CG
                               # for large); 0: exact batched Cholesky;
                               # >0: explicit CG iteration count
     # auto mode switches a side to CG above this many rows: below it the
-    # batched Cholesky costs <~70ms (linear in batch; 1157ms at 138k on
-    # v5e) so exactness is free; above it CG's MXU matvecs win big
+    # batched Cholesky's cost (linear in the batch) is small and
+    # exactness is free
     auto_cg_rows: int = 8192
     # warm-sweep CG schedule: after `cg_warm_sweeps` full-strength sweeps,
     # drop to `cg_warm_iters` CG iterations (-1 keeps the full count).
-    # Rationale from the v5e per-op profile (eval/ALS_ROOFLINE.md): the CG
-    # matvecs are the sweep's single largest term (134 ms of ~520 ms at
-    # the ML-20M shape) and the only one already running at HBM peak, so
-    # fewer iterations is the one lever that cuts REAL traffic instead of
-    # emitter overhead. ALS warm-starts each solve from the previous
-    # sweep's factors; once the outer iteration is near its fixed point
-    # the inner Krylov correction is small and half the iterations hold
-    # the heldout RMSE (measured: see eval/RMSE_PARITY.md).
-    # Default 6 (vs the cold cap of 16): measured on v5e at the ML-20M
-    # shape the schedule is worth ~-75 ms/sweep; per the committed grid
-    # artifact (eval/CG_WARM_QUALITY.json) explicit heldout RMSE is
+    # ALS warm-starts each solve from the previous sweep's factors; once
+    # the outer iteration is near its fixed point the inner Krylov
+    # correction is small and fewer iterations hold the heldout RMSE.
+    # Default 6 (vs the cold cap of 16): per the committed grid artifact
+    # (eval/CG_WARM_QUALITY.json) explicit heldout RMSE is
     # flat-to-better at 8 and 6 (0.44459 / 0.44435 vs 0.44494 full) and
     # the implicit objective is BETTER than full-strength CG at both
     # (-2.5% at 8, -3.3% at 6 — the inexact inner solve mildly
@@ -110,76 +102,28 @@ class ALSParams:
     # disables the schedule.
     cg_warm_iters: int = 6
     cg_warm_sweeps: int = 2
-    # normal-equation accumulation strategy:
+    # how the per-slot blocks are summed into the rows' normal equations
+    # (`_normal_equations` has the loops, `resolved_accum` the choice):
     #   "carry":   scatter-add each chunk's blocks into the (n,k,k)
-    #              accumulator inside the scan (the accumulator is a loop
-    #              carry — if XLA materializes the carry per iteration the
-    #              full accumulator re-streams once per chunk);
-    #   "stacked": chunks emit their blocks as scan OUTPUTS (no big carry),
-    #              then one sorted scatter-add per slot group folds them
-    #              into A — bounded temp via group_slots;
-    #   "pallas":  fused Pallas segment-flush kernel (ops/als_pallas.py):
-    #              no scatter, no carry, each A row written once;
-    #   "hybrid":  XLA batched-MXU blocks + Pallas segment-flush scatter
-    #              (ops/als_pallas.py normal_equations_hybrid) — keeps
-    #              the fast einsum, replaces only the scatter emitter;
-    #   "stream":  hybrid with the OVERLAPPED flush kernel
-    #              (_segment_kernel_stream): each A-row DMA starts at
-    #              its flush point and is awaited at the next flush
-    #              that reuses the staging slot, hiding the
-    #              65 ms/sweep of exposed flush latency the round-5
-    #              profile charged the hybrid kernel's in-kernel waits;
-    #   "auto":    per-backend (see resolved_accum)
+    #              accumulator, a scan carry: what `auto` runs off the
+    #              chip, what fold-in pins, the tests' reference;
+    #   "stacked": chunks emit their blocks as scan OUTPUTS, one sorted
+    #              scatter-add per slot group folds them into A: pure XLA
+    #              on the chip (the stacked sweep; ranks the kernel cannot
+    #              hold);
+    #   "hybrid":  the same groups of blocks, summed by the Pallas
+    #              segment-flush kernel (ops/als_pallas.py) in place of
+    #              the scatter: what `auto` runs on a TPU;
+    #   "stream":  hybrid with the overlapped flush kernel: a candidate,
+    #              reached by tests only until ROADMAP S1d's paired runs;
+    #   "auto":    per backend and rank (see resolved_accum)
     accum: str = "auto"
-    # store A lane-packed (n, k²) end-to-end: the streaming flush
-    # kernel writes packed rows (k² is a 128-multiple — no lane
-    # padding, a 2x byte cut on A at rank 64) and the CG solve consumes
-    # them through the Pallas packed batched matvec, so the 6.1x
-    # isolated packed-matvec win (eval/als_kernel_lab.py) composes with
-    # no XLA relayout at the scatter/solve boundary
-    # (eval/ALS_ROOFLINE.md "Lane-packed A" verdict). Requires the
-    # streaming flush: accum="hybrid" is promoted to "stream", the XLA
-    # accumulation paths ignore the flag (resolved_packed() reports
-    # what actually ran). Exact-Cholesky sides unpack once per solve.
-    # Interpret-validated only: today's Mosaic refuses the in-kernel
-    # pack on a v5e (ops/als_pallas.py docstring) and selecting it
-    # there raises.
-    packed_a: bool = False
-    # stacked mode: max slots whose (k,k) blocks are materialized at once;
-    # temp bytes = group_slots * k * k * 4 (73k slots @ k=64 = 1.2 GB)
-    group_slots: int = 73728
-    # slot-gather implementation for the normal-equation build:
-    #   "xla":         the plain src[idx] gather (XLA emitter);
-    #   "pallas-copy" / "pallas-take": VMEM-resident Pallas gather
-    #       (ops/als_pallas.py gather_rows_pallas) — XLA's emitter runs
-    #       ~10x off HBM peak for VMEM-sized tables and the decision is
-    #       out of reach from JAX (eval/ALS_ROOFLINE.md); applied only
-    #       when the table fits GATHER_VMEM_TABLE_BUDGET, XLA otherwise;
-    #   "stream":      double-buffered HBM->VMEM streaming gather
-    #       (ops/als_pallas.py gather_rows_stream): per-row async
-    #       copies with mini-group prefetch, ANY table size — the
-    #       custom gather eval/ALS_ROOFLINE.md calls for on both sweep
-    #       halves (the users-half table is 4x over the VMEM budget);
-    #   "auto":        "xla" — the Pallas variants are interpret-mode-
-    #       validated only: today's Mosaic refuses all three on a v5e
-    #       (ops/als_pallas.py docstring), and selecting one there
-    #       raises the compiler's error
-    gather: str = "auto"
 
-    _GATHER_MODES = ("auto", "xla", "pallas-copy", "pallas-take", "stream")
-    _ACCUM_MODES = ("auto", "carry", "stacked", "pallas", "hybrid", "stream")
+    _ACCUM_MODES = ("auto", "carry", "stacked", "hybrid", "stream")
 
     def __post_init__(self):
-        # validate here, not in the kernel: "pallas" alone would pass a
-        # startswith check and then IndexError inside the jit trace, and
-        # any other typo would silently fall back to the XLA path
-        if self.gather not in self._GATHER_MODES:
-            raise ValueError(
-                f"ALSParams.gather={self.gather!r}; "
-                f"expected one of {self._GATHER_MODES}")
-        # same rationale for accum: the dispatch chain and the packed_a
-        # promotion key on exact strings, so a typo ("strem") would
-        # silently run the stacked path unpacked
+        # validate here, not in the kernel: a typo ("strem") would
+        # otherwise surface as an error inside the jit trace
         if self.accum not in self._ACCUM_MODES:
             raise ValueError(
                 f"ALSParams.accum={self.accum!r}; "
@@ -192,14 +136,9 @@ class ALSParams:
           batch the solve is not the bottleneck, and on noiseless/tiny
           data the exact solve measurably generalizes better;
         * large sides: short warm-started Jacobi-CG capped at
-          max(16, rank//4). Measured on v5e at the ML-20M shape (rank
-          64, implicit, warm): 28.2M ratings/s at cg=8, 25.7M at cg=16,
-          vs 10.5M with the exact Cholesky — XLA's batched Cholesky runs
-          at ~10 GFLOP/s on TPU while CG is batched matvecs on the MXU.
-          Quality at the cap is at parity or better at realistic scale
-          (implicit objective within 1e-5; explicit heldout RMSE lower:
-          1.310 vs 1.352 at rank 64, 1.291 vs 1.322 at rank 100 — the
-          inexact inner solve early-stops per-row overfit). CG
+          max(16, rank//4): XLA's batched Cholesky does not use the MXU,
+          CG is batched matvecs on it. Quality at the cap is at parity
+          or better at realistic scale (eval/RMSE_PARITY.md). CG
           convergence is governed by conditioning, not the Krylov
           dimension, so the cap grows only mildly with rank; the warm
           start carries convergence across sweeps.
@@ -212,45 +151,19 @@ class ALSParams:
         return max(16, self.rank // 4)
 
     def resolved_accum(self) -> str:
-        """The accumulation strategy that actually runs ("auto" resolves
-        here, next to resolved_cg_iters, so callers — bench artifacts
-        included — can report the real mode, not the knob). Rank-aware:
-        _normal_equations falls back hybrid/stream->stacked above k=256
-        (the segment-flush kernel's VMEM blocks exceed the 16 MB scoped
-        budget), and this mirror applies the same rule so artifacts
-        never report a mode that did not run. packed_a promotes hybrid
-        to stream (packed rows need the streaming flush kernel).
-
-        auto is per-backend: on TPU "hybrid" (XLA batched-MXU blocks +
-        Pallas segment-flush scatter) measured 0.439 s/sweep at the
-        ML-20M shape vs stacked 0.485 / carry 0.499 — the XLA
-        scatter-add emitter runs at ~13% of streaming peak and the
-        kernel writes each A row exactly once instead
-        (eval/ALS_ROOFLINE.md, eval/als_accum_bench.py). auto stays on
-        hybrid — NOT stream — until the on-chip A/B
-        (eval/als_accum_bench.py stream cells) shows the overlapped
-        flush winning on hardware. On CPU the Pallas kernel only exists
-        in interpret mode, and carry measured fastest of the XLA paths,
-        so carry stays."""
+        """The accumulation that runs: the one place that turns `auto`,
+        the backend and the rank into a mode (`_normal_equations` takes
+        the result and nothing else). `auto` is `hybrid` on a TPU — the
+        path of every benchmark cell — and `carry` elsewhere, where the
+        kernel exists in interpret mode only. Above rank
+        `als_pallas.MAX_RANK` the kernel's blocks do not fit VMEM, so
+        `hybrid` and `stream` become `stacked`."""
         mode = self.accum
         if mode == "auto":
             mode = "hybrid" if _accelerator_backend() else "carry"
-        if self.packed_a and mode == "hybrid":
-            mode = "stream"    # packed rows require the streaming flush
-        if mode in ("hybrid", "stream") and self.rank > 256:
-            mode = "stacked"   # keep in sync with _normal_equations
+        if mode in ("hybrid", "stream") and self.rank > als_pallas.MAX_RANK:
+            mode = "stacked"
         return mode
-
-    def resolved_gather(self) -> str:
-        """The slot-gather implementation that actually runs: "auto" is
-        the XLA gather (see `gather`)."""
-        return "xla" if self.gather == "auto" else self.gather
-
-    def resolved_packed(self) -> bool:
-        """True when A actually flows lane-packed: packed_a requested
-        AND the resolved accumulation is the streaming flush kernel
-        (the XLA paths and the k>256 fallback produce (n,k,k))."""
-        return self.packed_a and self.resolved_accum() == "stream"
 
 
 @dataclass(frozen=True)
@@ -299,13 +212,15 @@ def _accelerator_backend() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
+# stacked / hybrid / stream: slots whose (k,k) f32 blocks are materialized
+# at once, in whole chunks (at k=64: 1.2 GB)
+GROUP_SLOTS = 73728
+
+
 def blocks_group_budget_slots(k: int) -> int:
-    """Max slots whose (k,k) f32 blocks may be materialized at once —
-    the ALSParams.group_slots default (73728) is k=64-tuned (1.2 GB);
-    the temp scales k^2, so group sizing caps by BYTES too or rank 128
-    OOMs HBM at the ML-20M shape (measured 22.6G of 15.75G). Shared by
-    the stacked (als.py) and hybrid (als_pallas.py) accumulation
-    paths."""
+    """GROUP_SLOTS is sized for k=64; the blocks grow with k^2, so a
+    group is capped by BYTES too, or rank 128 runs out of HBM at the
+    ML-20M shape."""
     return max(1, (1_200 * 2**20) // (k * k * 4))
 
 
@@ -368,54 +283,7 @@ def _device_slot_layout(u, o, v, n_self: int, width: int, slots_max: int):
     return rows, idx, val, lens
 
 
-def _gather_pow2_rows(m: int, cap: int = 1024) -> int:
-    """Largest power of two <= cap dividing m (pallas grid step size)."""
-    r = 1
-    while r < cap and m % (r * 2) == 0:
-        r *= 2
-    return r
-
-
-def _gather_rows(src, i_c, gather: str):
-    """Rows of the opposing factor table for one slot chunk -> (C, W, k)
-    float32, by the XLA gather or one of the Pallas gather kernels."""
-    if gather == "stream":
-        from pio_tpu.ops.als_pallas import gather_rows_stream
-
-        # double-buffered HBM->VMEM streaming gather: no table-size
-        # precondition, and the output block is written sequentially in
-        # exactly the (C*W, k) layout this reshape consumes — no XLA
-        # copy between the gather and the blocks einsum (the 38 ms
-        # y-copy in the round-5 profile)
-        n, k = src.shape
-        C, W = i_c.shape
-        flat = i_c.reshape(-1)
-        return gather_rows_stream(
-            src, flat,
-            rows_per_step=_gather_pow2_rows(flat.shape[0], cap=512),
-        ).reshape(C, W, k).astype(jnp.float32)
-    if gather.startswith("pallas"):
-        from pio_tpu.ops.als_pallas import (
-            GATHER_VMEM_TABLE_BUDGET, gather_rows_pallas, gather_table_bytes,
-        )
-
-        n, k = src.shape
-        fits = gather_table_bytes(
-            n, k, src.dtype == jnp.bfloat16) <= GATHER_VMEM_TABLE_BUDGET
-        if fits:
-            C, W = i_c.shape
-            flat = i_c.reshape(-1)
-            return gather_rows_pallas(
-                src, flat,
-                rows_per_step=_gather_pow2_rows(flat.shape[0]),
-                variant=gather.split("-", 1)[1],
-            ).reshape(C, W, k).astype(jnp.float32)
-        # big table: the XLA gather's fast emitter
-    return src[i_c].astype(jnp.float32)  # (C, W, k) gather
-
-
-def _chunk_blocks(src, i_c, v_c, l_c, implicit: bool, alpha: float,
-                  gather: str = "xla"):
+def _chunk_blocks(src, i_c, v_c, l_c, implicit: bool, alpha: float):
     """One slot chunk -> per-slot normal-equation blocks
     a_blk (C,k,k), b_blk (C,k) via batched MXU matmuls."""
     W = i_c.shape[1]
@@ -424,7 +292,7 @@ def _chunk_blocks(src, i_c, v_c, l_c, implicit: bool, alpha: float,
             jnp.arange(W, dtype=jnp.int32)[None, :] < l_c[:, None]
         ).astype(jnp.float32)
     with jax.named_scope("als.gather"):
-        y = _gather_rows(src, i_c, gather)
+        y = src[i_c].astype(jnp.float32)  # (C, W, k)
     with jax.named_scope("als.blocks"):
         if implicit:
             # c = 1 + alpha*v; A += (c-1) y y^T ; b += c * y   (p == 1)
@@ -450,81 +318,34 @@ def _chunk_blocks(src, i_c, v_c, l_c, implicit: bool, alpha: float,
 
 def _normal_equations(layout, other_factors, n_self, implicit: bool,
                       alpha: float, chunk_slots: int,
-                      bf16_gather: bool = False, accum: str = "auto",
-                      group_slots: int = 73728, gather: str = "auto",
-                      packed: bool = False):
+                      bf16_gather: bool = False, accum: str = "carry",
+                      group_slots: int = GROUP_SLOTS):
     """Accumulate per-row normal equations A (n_self,k,k), b (n_self,k).
 
-    Slots sharing a row (rows wider than `width`) scatter-add into the same
-    row system; the slot->row index is non-decreasing with a sentinel tail
+    Slots sharing a row (rows wider than `width`) sum into the same row
+    system; the slot->row index is non-decreasing with a sentinel tail
     (see _device_slot_layout), so every scatter declares
-    indices_are_sorted=True.
+    indices_are_sorted=True and drops the sentinel.
 
-    accum="carry" keeps A as a lax.scan carry and scatters each chunk into
-    it — O(1) temp, but a backend that materializes the carry per iteration
-    re-streams the full (n,k,k) accumulator once per chunk (measured as the
-    dominant cost at ML-20M scale on v5e: ~2.3 GB x ~36 chunks per sweep).
-    accum="stacked" emits per-slot blocks as scan OUTPUTS and folds each
-    group of `group_slots` slots into A with ONE sorted scatter-add — the
-    accumulator is written, not carried, at the price of a bounded
-    (group_slots,k,k) temp.
-
-    packed=True requests lane-packed A (n_self, k²); only the streaming
-    flush kernel can produce it, so accum="hybrid" is promoted to
-    "stream" and the XLA paths return (n,k,k) regardless (callers
-    detect the form by A.ndim — see _solve_factors)."""
+    `accum` is a RESOLVED mode (`ALSParams.resolved_accum`): "carry"
+    keeps A as a lax.scan carry and scatters each chunk into it, O(1)
+    temp. The other three build the blocks of a GROUP of chunks as scan
+    outputs (`group_slots`, capped by bytes: a bounded (group,k,k) temp)
+    and differ in what folds a group into A: "stacked" one sorted
+    scatter-add; "hybrid" / "stream" the Pallas segment-flush kernel
+    (ops/als_pallas.py), which writes each row of A once."""
     rows, idx, val, lens = layout
     k = other_factors.shape[1]
     S, W = idx.shape
-    # bf16 source halves the gather's HBM traffic — the build's bottleneck;
-    # the f32 upcast happens in-register before the (still f32-accumulated)
-    # matmuls. RMSE impact measured at 5e-5 relative (ALSParams.bf16_gather)
+    # bf16 source halves the gather's HBM traffic; the f32 upcast happens
+    # in-register before the (still f32-accumulated) matmuls
     with jax.named_scope("als.gather"):
         src = (
             other_factors.astype(jnp.bfloat16) if bf16_gather
             else other_factors
         )
-    if accum == "auto":
-        # keep in sync with ALSParams.resolved_accum (per-backend choice)
-        accum = "hybrid" if _accelerator_backend() else "carry"
-    if gather == "auto":
-        gather = "xla"   # keep in sync with ALSParams.resolved_gather
     # every caller pads S to a chunk_slots multiple via _slots_for
     assert S % chunk_slots == 0, (S, chunk_slots)
-
-    if accum == "pallas":
-        from pio_tpu.ops.als_pallas import normal_equations_pallas
-
-        # the kernel sizes its own VMEM chunk; cap by the layout's chunk
-        return normal_equations_pallas(
-            layout, other_factors, n_self, implicit, alpha,
-            chunk_slots=min(128, chunk_slots),
-            bf16_gather=bf16_gather,
-        )
-
-    if packed and accum == "hybrid":
-        accum = "stream"   # packed rows require the streaming flush
-
-    if accum in ("hybrid", "stream") and k > 256:
-        # the kernel's VMEM blocks block is >=8 slots x k^2 x 4 B double-
-        # buffered; beyond k=256 that exceeds the 16 MB scoped VMEM no
-        # matter the chunk, so high ranks take the XLA scatter path
-        accum = "stacked"
-
-    if accum in ("hybrid", "stream"):
-        from pio_tpu.ops.als_pallas import normal_equations_hybrid
-
-        # XLA batched-MXU blocks + Pallas segment-flush in place of the
-        # XLA scatter-add (the 118 ms/sweep, ~13%-of-peak emitter —
-        # eval/ALS_ROOFLINE.md); "stream" overlaps the flush DMAs and
-        # optionally writes A lane-packed
-        return normal_equations_hybrid(
-            layout, other_factors, n_self, implicit, alpha,
-            chunk_slots=chunk_slots, group_slots=group_slots,
-            bf16_gather=bf16_gather, gather=gather,
-            overlap=(accum == "stream"),
-            packed=packed,  # packed implies accum=="stream" (promoted)
-        )
 
     if accum == "carry":
         n_ch = S // chunk_slots
@@ -532,9 +353,7 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
         def body(carry, xs):
             A, b = carry
             r_c, i_c, v_c, l_c = xs
-            a_blk, b_blk = _chunk_blocks(
-                src, i_c, v_c, l_c, implicit, alpha, gather=gather
-            )
+            a_blk, b_blk = _chunk_blocks(src, i_c, v_c, l_c, implicit, alpha)
             with jax.named_scope("als.blocks"):
                 A = A.at[r_c].add(
                     a_blk, mode="drop", indices_are_sorted=True
@@ -556,35 +375,40 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
         (A, b), _ = jax.lax.scan(body, (A0, b0), xs)
         return A, b
 
-    if accum != "stacked":
-        raise ValueError(f"unknown accum mode {accum!r}")
-    # group = as many whole chunks as fit the temp budget (bytes-capped:
-    # see blocks_group_budget_slots)
-    ch_per_group = max(
+    if accum not in ("stacked", "hybrid", "stream"):
+        raise ValueError(f"accum must be a resolved mode, got {accum!r}")
+    # group = as many whole chunks as fit the temp budget
+    g_slots = chunk_slots * max(
         1, min(group_slots, blocks_group_budget_slots(k)) // chunk_slots)
-    g_slots = ch_per_group * chunk_slots
-    n_groups = math.ceil(S / g_slots)
+
+    def group_blocks():
+        """-> (lo, hi, a_blks (hi-lo,k,k), b_blks (hi-lo,k)) a group,
+        built when asked for: one group's blocks are live at a time."""
+        for lo in range(0, S, g_slots):
+            hi = min(S, lo + g_slots)
+            n_ch = (hi - lo) // chunk_slots
+            xs = (
+                idx[lo:hi].reshape(n_ch, chunk_slots, W),
+                val[lo:hi].reshape(n_ch, chunk_slots, W),
+                lens[lo:hi].reshape(n_ch, chunk_slots),
+            )
+
+            def body(_, xs_c):
+                i_c, v_c, l_c = xs_c
+                return None, _chunk_blocks(
+                    src, i_c, v_c, l_c, implicit, alpha)
+
+            _, (a_blks, b_blks) = jax.lax.scan(body, None, xs)
+            yield lo, hi, a_blks, b_blks
+
+    if accum != "stacked":
+        return als_pallas.segment_flush(
+            rows, n_self, k, chunk_slots, group_blocks(),
+            overlap=(accum == "stream"))
     with jax.named_scope("als.blocks"):
         A = jnp.zeros((n_self, k, k), dtype=jnp.float32)
         b = jnp.zeros((n_self, k), dtype=jnp.float32)
-    for g in range(n_groups):
-        lo = g * g_slots
-        hi = min(S, lo + g_slots)
-        n_ch = (hi - lo) // chunk_slots
-        c_sz = chunk_slots
-        xs = (
-            idx[lo:hi].reshape(n_ch, c_sz, W),
-            val[lo:hi].reshape(n_ch, c_sz, W),
-            lens[lo:hi].reshape(n_ch, c_sz),
-        )
-
-        def body(_, xs_c):
-            i_c, v_c, l_c = xs_c
-            return None, _chunk_blocks(
-                src, i_c, v_c, l_c, implicit, alpha, gather=gather
-            )
-
-        _, (a_blks, b_blks) = jax.lax.scan(body, None, xs)
+    for lo, hi, a_blks, b_blks in group_blocks():
         with jax.named_scope("als.blocks"):
             r_g = rows[lo:hi]
             A = A.at[r_g].add(
@@ -598,9 +422,22 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
     return A, b
 
 
-def _cg_body(mv, dinv, b, x0, n_iter: int):
-    """The Jacobi-CG iteration shared by the lane-padded and packed
-    matvec forms: only `mv` (the batched A@x) and `dinv` differ."""
+def _cg_solve(A, b, x0, n_iter: int):
+    """Batched Jacobi-preconditioned conjugate gradient for SPD systems.
+
+    ALS is block coordinate descent, so the inexact inner solve (relative
+    residual ~1e-4 at 24 iters on k=64) does not change the fixed point it
+    converges to; warm-starting from the previous sweep's factors keeps
+    later sweeps cheap.
+    """
+    dinv = 1.0 / jnp.diagonal(A, axis1=1, axis2=2)
+
+    def mv(x):
+        return jnp.einsum(
+            "bij,bj->bi", A, x, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGH,
+        )
+
     x = x0
     r = b - mv(x)
     z = r * dinv
@@ -623,44 +460,6 @@ def _cg_body(mv, dinv, b, x0, n_iter: int):
     return x
 
 
-def _cg_solve(A, b, x0, n_iter: int):
-    """Batched Jacobi-preconditioned conjugate gradient for SPD systems.
-
-    ALS is block coordinate descent, so the inexact inner solve (relative
-    residual ~1e-4 at 24 iters on k=64) does not change the fixed point it
-    converges to; warm-starting from the previous sweep's factors keeps
-    later sweeps cheap.
-    """
-    dinv = 1.0 / jnp.diagonal(A, axis1=1, axis2=2)
-
-    def mv(x):
-        return jnp.einsum(
-            "bij,bj->bi", A, x, preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGH,
-        )
-
-    return _cg_body(mv, dinv, b, x0, n_iter)
-
-
-def _cg_solve_packed(Ap, b, x0, n_iter: int, block_rows: int):
-    """_cg_solve on LANE-PACKED A (n, k²): the matvec is the Pallas
-    packed batched matvec (ops/als_pallas.py packed_block_matvec), so
-    no (n,k²)->(n,k,k) relayout appears inside the CG loop — the
-    structural property tests/test_als_pallas.py pins on the optimized
-    HLO. The Jacobi diagonal is a k-element strided take per solve
-    (outside the loop)."""
-    from pio_tpu.ops.als_pallas import packed_block_matvec
-
-    k = b.shape[1]
-    diag = Ap[:, jnp.arange(k, dtype=jnp.int32) * (k + 1)]
-    dinv = 1.0 / diag
-
-    def mv(x):
-        return packed_block_matvec(Ap, x, block_rows=block_rows)
-
-    return _cg_body(mv, dinv, b, x0, n_iter)
-
-
 def _shared_yty(other_factors, yty):
     """Shared Y^T Y term (confidence-1 part handled in accumulation).
     The sharded trainer passes a psum-reduced `yty` built from the
@@ -681,59 +480,14 @@ def _chol_solve(A, b):
     return jax.scipy.linalg.cho_solve(chol, b)
 
 
-def _solve_packed(A, b, reg, implicit, alpha, other_factors, yty, x0,
-                  cg_iters: int):
-    """The solve on LANE-PACKED A (n, k²) from the streaming flush
-    kernel: the reg/yty terms are elementwise adds in packed space, and
-    CG runs on the Pallas packed matvec — the packed form survives from
-    the flush to the last CG iteration with no relayout. The one pad to
-    the matvec's row-block multiple happens HERE, once per solve,
-    outside the CG loop (identity rows keep the padded diagonal
-    invertible; padded b/x0 are zero, and CG's per-row arithmetic never
-    mixes rows, so the pad is exact). Exact-Cholesky sides (cg_iters=0:
-    small row batches, bit-exactness escapes) unpack once — also
-    outside any loop."""
-    from pio_tpu.ops.als_pallas import _matvec_block_rows
-
-    n_self, k2 = A.shape
-    k = b.shape[1]
-    with jax.named_scope("als.gram"):
-        eye_flat = jnp.eye(k, dtype=jnp.float32).reshape(k2)
-        if implicit:
-            A = A + _shared_yty(other_factors, yty).reshape(k2)[None, :]
-        A = A + reg * eye_flat[None, :]
-    if cg_iters <= 0:
-        return _chol_solve(A.reshape(n_self, k, k), b)
-    with jax.named_scope("als.cg"):
-        block = _matvec_block_rows(k)
-        pad = -n_self % block
-        if pad:
-            A = jnp.concatenate(
-                [A, jnp.broadcast_to(eye_flat, (pad, k2))])
-            b = jnp.concatenate([b, jnp.zeros((pad, k), b.dtype)])
-            if x0 is not None:
-                x0 = jnp.concatenate(
-                    [x0, jnp.zeros((pad, k), jnp.float32)])
-        if x0 is None:
-            x0 = jnp.zeros_like(b)
-        x = _cg_solve_packed(A, b, x0, cg_iters, block)
-        return x[:n_self]
-
-
 def _solve_factors(layout, other_factors, n_self, reg, implicit, alpha,
                    chunk_slots, x0=None, cg_iters: int = 0,
-                   bf16_gather: bool = False, accum: str = "auto",
-                   group_slots: int = 73728, yty=None,
-                   gather: str = "auto", packed: bool = False):
+                   bf16_gather: bool = False, accum: str = "carry",
+                   yty=None):
     A, b = _normal_equations(
         layout, other_factors, n_self, implicit, alpha, chunk_slots,
-        bf16_gather=bf16_gather, accum=accum, group_slots=group_slots,
-        gather=gather, packed=packed,
+        bf16_gather=bf16_gather, accum=accum,
     )
-    if A.ndim == 2:
-        # the streaming flush produced lane-packed (n, k²) rows
-        return _solve_packed(A, b, reg, implicit, alpha, other_factors,
-                             yty, x0, cg_iters)
     k = other_factors.shape[1]
     with jax.named_scope("als.gram"):
         eye = jnp.eye(k, dtype=jnp.float32)
@@ -833,6 +587,7 @@ def _sweep_factory(by_user, by_item, n_users: int, n_items: int, cs: int,
         reg = params.reg
     if alpha is None:
         alpha = params.alpha
+    accum = params.resolved_accum()
 
     def sweep_with(cg_u_n: int, cg_i_n: int):
         def sweep(carry, _):
@@ -842,18 +597,14 @@ def _sweep_factory(by_user, by_item, n_users: int, n_items: int, cs: int,
                     by_user, items, n_users,
                     reg, params.implicit, alpha, cs,
                     x0=users, cg_iters=cg_u_n,
-                    bf16_gather=params.bf16_gather,
-                    accum=params.accum, group_slots=params.group_slots,
-                    gather=params.gather, packed=params.packed_a,
+                    bf16_gather=params.bf16_gather, accum=accum,
                 )
             with jax.named_scope("als.item"):
                 items = _solve_factors(
                     by_item, users, n_items,
                     reg, params.implicit, alpha, cs,
                     x0=items, cg_iters=cg_i_n,
-                    bf16_gather=params.bf16_gather,
-                    accum=params.accum, group_slots=params.group_slots,
-                    gather=params.gather, packed=params.packed_a,
+                    bf16_gather=params.bf16_gather, accum=accum,
                 )
             return (users, items), None
         return sweep
@@ -1181,15 +932,14 @@ def als_train_validated(
 
 def sweep_safe_params(params: ALSParams) -> ALSParams:
     """The static config the stacked trainer actually runs: the pure-XLA
-    accumulation paths (carry on CPU, stacked on accelerators) with the
-    plain XLA gather. The Pallas kernels (hybrid/stream/packed) are
-    written for a single candidate's block shapes and do not vmap; the
-    stacked program trades them for candidate-level batching — which is
-    the bigger lever for a sweep (Chiu et al. 1612.01437: batch the
-    work, amortize the data movement)."""
+    accumulation paths (carry on CPU, stacked on accelerators). The
+    Pallas kernels (hybrid/stream) are written for a single candidate's
+    block shapes and do not vmap; the stacked program trades them for
+    candidate-level batching — which is the bigger lever for a sweep
+    (Chiu et al. 1612.01437: batch the work, amortize the data
+    movement)."""
     accum = "stacked" if _accelerator_backend() else "carry"
-    return dataclasses.replace(
-        params, accum=accum, gather="xla", packed_a=False)
+    return dataclasses.replace(params, accum=accum)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -1339,6 +1089,7 @@ def _sharded_train_fn(mesh: Mesh, ub: int, ib: int, su: int, si: int,
     # decision keys on the per-device batch size
     cg_u = _sharded_cg_iters(params, ub)
     cg_i = _sharded_cg_iters(params, ib)
+    accum = params.resolved_accum()
 
     @partial(
         jax.shard_map,
@@ -1380,10 +1131,8 @@ def _sharded_train_fn(mesh: Mesh, ub: int, ib: int, su: int, si: int,
                         by_user, all_items, ub,
                         params.reg, params.implicit, params.alpha, cs,
                         x0=users, cg_iters=cg_u_n,
-                        bf16_gather=params.bf16_gather,
-                        accum=params.accum, group_slots=params.group_slots,
-                        yty=yty_i, gather=params.gather,
-                        packed=params.packed_a,
+                        bf16_gather=params.bf16_gather, accum=accum,
+                        yty=yty_i,
                     )
                 with jax.named_scope("als.item"):
                     yty_u = gram_psum(users) if params.implicit else None
@@ -1395,10 +1144,8 @@ def _sharded_train_fn(mesh: Mesh, ub: int, ib: int, su: int, si: int,
                         by_item, all_users, ib,
                         params.reg, params.implicit, params.alpha, cs,
                         x0=items, cg_iters=cg_i_n,
-                        bf16_gather=params.bf16_gather,
-                        accum=params.accum, group_slots=params.group_slots,
-                        yty=yty_u, gather=params.gather,
-                        packed=params.packed_a,
+                        bf16_gather=params.bf16_gather, accum=accum,
+                        yty=yty_u,
                     )
                 return (users, items), None
             return sweep
@@ -1598,9 +1345,7 @@ def _fold_in_jit(u, i, v, item_factors, n_users: int, params: ALSParams):
     by_user = _device_slot_layout(u, i, v, n_users, params.width, su)
     A, b = _normal_equations(
         by_user, item_factors, n_users, params.implicit, params.alpha, cs,
-        bf16_gather=params.bf16_gather, accum=params.accum,
-        group_slots=params.group_slots, gather=params.gather,
-        packed=params.packed_a,
+        bf16_gather=params.bf16_gather, accum=params.resolved_accum(),
     )
     k = item_factors.shape[1]
     if params.implicit:
@@ -1611,14 +1356,14 @@ def _fold_in_jit(u, i, v, item_factors, n_users: int, params: ALSParams):
 
 def fold_in_params(params: ALSParams) -> ALSParams:
     """The bit-conservative variant of `params` a fold-in solve runs
-    under: f32 gather and the plain XLA accumulation/gather paths, so a
+    under: f32 gather and the plain XLA `carry` accumulation, so a
     refreshed row is a pure function of (events, item factors) — the
     same answer on every backend, every batch composition, and every
     restart. Iteration-schedule fields are irrelevant (fold-in is one
     half-sweep); they are zeroed so they cannot fragment the jit cache."""
     return dataclasses.replace(
-        params, bf16_gather=False, accum="carry", gather="xla",
-        packed_a=False, iterations=1, cg_warm_iters=-1, seed=0, chunk=0,
+        params, bf16_gather=False, accum="carry", iterations=1,
+        cg_warm_iters=-1, seed=0, chunk=0,
     )
 
 

@@ -1,7 +1,11 @@
-"""Pallas normal-equation kernel pinned against the XLA accumulation
-paths (interpret mode on CPU). Covers multi-slot rows, empty rows
-(zeros contract), sentinel padding slots, chunk boundaries splitting a
-row's slot run, and both implicit/explicit weightings."""
+"""The Pallas segment-flush accumulation (accum="hybrid", what a TPU
+runs, and accum="stream", its overlapped variant) pinned against the XLA
+accumulation paths (interpret mode on CPU). Covers multi-slot rows, empty
+rows (zeros contract), sentinel padding slots, chunk and group boundaries
+splitting a row's slot run, and both implicit/explicit weightings."""
+
+import ast
+import pathlib
 
 import numpy as np
 import pytest
@@ -9,13 +13,15 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from pio_tpu.ops import als, als_pallas
 from pio_tpu.ops.als import (
     ALSParams,
     _device_slot_layout,
     _normal_equations,
     _slots_for,
 )
-from pio_tpu.ops.als_pallas import normal_equations_pallas
+
+FLUSH_MODES = ["hybrid", "stream"]
 
 
 def _layout_and_factors(n_self=37, n_other=23, nnz=600, width=8,
@@ -40,8 +46,9 @@ def _layout_and_factors(n_self=37, n_other=23, nnz=600, width=8,
     return layout, factors, u
 
 
+@pytest.mark.parametrize("accum", FLUSH_MODES)
 @pytest.mark.parametrize("implicit", [False, True])
-def test_pallas_matches_xla_accumulation(implicit):
+def test_pallas_matches_xla_accumulation(implicit, accum):
     n_self = 37
     cs = 16
     layout, factors, u = _layout_and_factors(n_self=n_self, chunk_slots=cs)
@@ -49,9 +56,9 @@ def test_pallas_matches_xla_accumulation(implicit):
         layout, factors, n_self, implicit, 2.5, cs, accum="carry",
         bf16_gather=False,
     )
-    A_p, b_p = normal_equations_pallas(
-        layout, factors, n_self, implicit, 2.5, chunk_slots=cs,
-        bf16_gather=False, interpret=True,
+    A_p, b_p = _normal_equations(
+        layout, factors, n_self, implicit, 2.5, cs, accum=accum,
+        bf16_gather=False,
     )
     np.testing.assert_allclose(
         np.asarray(A_p), np.asarray(A_ref), atol=1e-4, rtol=1e-4)
@@ -64,27 +71,36 @@ def test_pallas_matches_xla_accumulation(implicit):
         assert np.all(np.asarray(b_p)[empty] == 0)
 
 
-def test_pallas_row_spanning_chunk_boundary():
-    """A single row whose slot run crosses a grid-step boundary must
-    accumulate across steps (the persistent-scratch carry)."""
+def _one_heavy_row(n_heavy: int, seed: int, ones: bool):
+    """Three rows, the middle one with `n_heavy` ratings at width 4:
+    its slot run crosses kernel grid steps (8 slots each)."""
     width, cs, k, n_self, n_other = 4, 8, 8, 3, 11
-    # row 1 owns 60 ratings -> 15 slots, spanning several 8-slot chunks
-    u = np.array([0] * 3 + [1] * 60 + [2] * 5, np.int32)
-    rng = np.random.default_rng(1)
+    u = np.array([0] * 3 + [1] * n_heavy + [2] * 5, np.int32)
+    rng = np.random.default_rng(seed)
     o = rng.integers(0, n_other, len(u)).astype(np.int32)
-    v = np.ones(len(u), np.float32)
+    v = (np.ones(len(u), np.float32) if ones
+         else (rng.random(len(u)) * 2 + 0.5).astype(np.float32))
     su = _slots_for(len(u), n_self, width, cs)
     layout = _device_slot_layout(
         jnp.asarray(u), jnp.asarray(o), jnp.asarray(v), n_self, width, su
     )
     factors = jnp.asarray(rng.normal(size=(n_other, k)).astype(np.float32))
+    return layout, factors, n_self, cs
+
+
+@pytest.mark.parametrize("accum", FLUSH_MODES)
+def test_pallas_row_spanning_chunk_boundary(accum):
+    """A single row whose slot run crosses a grid-step boundary must
+    accumulate across steps (the persistent-scratch carry)."""
+    # row 1 owns 60 ratings -> 15 slots, spanning several 8-slot chunks
+    layout, factors, n_self, cs = _one_heavy_row(60, seed=1, ones=True)
     A_ref, b_ref = _normal_equations(
         layout, factors, n_self, True, 1.5, cs, accum="stacked",
         bf16_gather=False,
     )
-    A_p, b_p = normal_equations_pallas(
-        layout, factors, n_self, True, 1.5, chunk_slots=cs,
-        bf16_gather=False, interpret=True,
+    A_p, b_p = _normal_equations(
+        layout, factors, n_self, True, 1.5, cs, accum=accum,
+        bf16_gather=False,
     )
     np.testing.assert_allclose(
         np.asarray(A_p), np.asarray(A_ref), atol=1e-4, rtol=1e-4)
@@ -92,13 +108,15 @@ def test_pallas_row_spanning_chunk_boundary():
         np.asarray(b_p), np.asarray(b_ref), atol=1e-4, rtol=1e-4)
 
 
-def test_pallas_end_to_end_train_matches_carry():
-    """als_train with accum='pallas' (interpret on CPU, under the training
-    jit/scan) reaches the same solution quality as the carry path.
-    chunk_slots=192 makes the layout's S a multiple of 192 but not of the
-    kernel's 128-capped chunk, so the sentinel slot-padding branch runs."""
+@pytest.mark.parametrize("accum", FLUSH_MODES)
+def test_pallas_end_to_end_train_matches_carry(accum):
+    """als_train through the flush kernel (interpret on CPU, under the
+    training jit/scan) reaches the same solution quality as the carry
+    path. chunk_slots=192 is a multiple of 64 and not of the kernel's
+    128-slot cap, so the kernel chunk is rounded down to divide it."""
     from pio_tpu.ops.als import als_train, rmse
 
+    assert als_pallas._kernel_chunk(8, 192) == 64
     rng = np.random.default_rng(3)
     nu, ni, nnz = 50, 30, 700
     u = rng.integers(0, nu, nnz).astype(np.int64)
@@ -106,35 +124,28 @@ def test_pallas_end_to_end_train_matches_carry():
     v = (rng.random(nnz) * 4 + 1).astype(np.float32)
     kw = dict(rank=8, iterations=6, reg=0.1, chunk=256, width=8,
               chunk_slots=192)
-    m_p = als_train(u, i, v, nu, ni, ALSParams(**kw, accum="pallas"))
+    m_p = als_train(u, i, v, nu, ni, ALSParams(**kw, accum=accum))
     m_c = als_train(u, i, v, nu, ni, ALSParams(**kw, accum="carry"))
     e_p = rmse(m_p, u, i, v)
     e_c = rmse(m_c, u, i, v)
     assert abs(e_p - e_c) < 5e-3, (e_p, e_c)
 
 
-def test_pallas_row_spanning_group_boundary():
+@pytest.mark.parametrize("accum", FLUSH_MODES)
+def test_pallas_row_spanning_group_boundary(accum):
     """A row whose slots span multiple GROUPS: every group emits a trail,
     only the group where the segment ends flushes, and the final trail
     fold reconstructs the row exactly."""
-    width, cs, k, n_self, n_other = 4, 8, 8, 3, 11
-    u = np.array([0] * 3 + [1] * 120 + [2] * 5, np.int32)  # row 1: 30 slots
-    rng = np.random.default_rng(2)
-    o = rng.integers(0, n_other, len(u)).astype(np.int32)
-    v = (rng.random(len(u)) * 2 + 0.5).astype(np.float32)
-    su = _slots_for(len(u), n_self, width, cs)
-    layout = _device_slot_layout(
-        jnp.asarray(u), jnp.asarray(o), jnp.asarray(v), n_self, width, su
-    )
-    factors = jnp.asarray(rng.normal(size=(n_other, k)).astype(np.float32))
+    # row 1: 120 ratings -> 30 slots
+    layout, factors, n_self, cs = _one_heavy_row(120, seed=2, ones=False)
     A_ref, b_ref = _normal_equations(
         layout, factors, n_self, False, 1.0, cs, accum="carry",
         bf16_gather=False,
     )
     # group_slots=16 -> row 1's 30 slots span 2+ groups
-    A_p, b_p = normal_equations_pallas(
-        layout, factors, n_self, False, 1.0, chunk_slots=cs,
-        group_slots=16, bf16_gather=False, interpret=True,
+    A_p, b_p = _normal_equations(
+        layout, factors, n_self, False, 1.0, cs, accum=accum,
+        group_slots=16, bf16_gather=False,
     )
     np.testing.assert_allclose(
         np.asarray(A_p), np.asarray(A_ref), atol=1e-4, rtol=1e-4)
@@ -142,12 +153,11 @@ def test_pallas_row_spanning_group_boundary():
         np.asarray(b_p), np.asarray(b_ref), atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("accum", ["pallas", "hybrid"])
+@pytest.mark.parametrize("accum", FLUSH_MODES)
 def test_pallas_composes_with_shard_map(accum):
-    """accum='pallas'/'hybrid' inside als_train_sharded's shard_map (8
-    virtual devices): the multi-chip path can use both kernel variants
-    unchanged — hybrid is auto's TPU pick, so its shard_map composition
-    is the production multi-chip configuration."""
+    """The flush kernels inside als_train_sharded's shard_map (8 virtual
+    devices): hybrid is auto's TPU pick, so its shard_map composition is
+    the production multi-chip configuration."""
     from pio_tpu.ops.als import als_train, als_train_sharded, rmse
     from pio_tpu.parallel.mesh import MeshConfig, create_mesh
 
@@ -165,17 +175,18 @@ def test_pallas_composes_with_shard_map(accum):
     assert abs(rmse(m, u, i, v) - rmse(m1, u, i, v)) < 5e-3
 
 
-def test_pallas_bf16_gather_close_to_f32():
+@pytest.mark.parametrize("accum", FLUSH_MODES)
+def test_pallas_bf16_gather_close_to_f32(accum):
     n_self, cs = 21, 16
     layout, factors, _ = _layout_and_factors(
         n_self=n_self, chunk_slots=cs, heavy_rows=False, nnz=300)
-    A32, b32 = normal_equations_pallas(
-        layout, factors, n_self, False, 1.0, chunk_slots=cs,
-        bf16_gather=False, interpret=True,
+    A32, b32 = _normal_equations(
+        layout, factors, n_self, False, 1.0, cs, accum=accum,
+        bf16_gather=False,
     )
-    A16, b16 = normal_equations_pallas(
-        layout, factors, n_self, False, 1.0, chunk_slots=cs,
-        bf16_gather=True, interpret=True,
+    A16, b16 = _normal_equations(
+        layout, factors, n_self, False, 1.0, cs, accum=accum,
+        bf16_gather=True,
     )
     np.testing.assert_allclose(
         np.asarray(A16), np.asarray(A32), atol=5e-2, rtol=5e-2)
@@ -212,7 +223,7 @@ def test_hybrid_matches_stacked():
 
     p_h = ALSParams(rank=K, iterations=3, reg=0.05, alpha=10.0,
                     implicit=True, chunk=1024, chunk_slots=CS,
-                    accum="hybrid", cg_iters=12, group_slots=256)
+                    accum="hybrid", cg_iters=12)
     p_s = ALSParams(**{**p_h.__dict__, "accum": "stacked"})
     m_h = als_train(u, i, v, NU, NI, p_h)
     m_s = als_train(u, i, v, NU, NI, p_s)
@@ -231,105 +242,19 @@ def test_hybrid_matches_stacked():
     assert mean_drift < 0.01, mean_drift
 
 
-# ---------------------------------------------------------------------------
-# VMEM-resident gather kernel (round-4)
-# ---------------------------------------------------------------------------
-
-def test_gather_rows_pallas_matches_take():
-    import jax.numpy as jnp
-
-    from pio_tpu.ops.als_pallas import gather_rows_pallas
-
-    rng = np.random.default_rng(0)
-    for n, k, m, dtype in ((50, 8, 256, np.float32),
-                           (33, 64, 512, np.float32),
-                           (200, 16, 1024, np.float32)):
-        table = rng.normal(size=(n, k)).astype(dtype)
-        idx = rng.integers(0, n, m).astype(np.int32)
-        for variant in ("copy", "take"):
-            got = gather_rows_pallas(
-                jnp.asarray(table), jnp.asarray(idx),
-                rows_per_step=min(256, m), variant=variant)
-            np.testing.assert_array_equal(np.asarray(got), table[idx])
-
-
-def test_gather_rows_pallas_bf16():
-    import jax.numpy as jnp
-
-    from pio_tpu.ops.als_pallas import gather_rows_pallas
-
-    rng = np.random.default_rng(1)
-    table = jnp.asarray(rng.normal(size=(40, 32)), jnp.bfloat16)
-    idx = jnp.asarray(rng.integers(0, 40, 128), jnp.int32)
-    got = gather_rows_pallas(table, idx, rows_per_step=128)
-    np.testing.assert_array_equal(
-        np.asarray(got, np.float32), np.asarray(table, np.float32)[idx])
-
-
-def test_gather_budget_helper():
-    from pio_tpu.ops.als_pallas import (
-        GATHER_VMEM_TABLE_BUDGET, gather_table_bytes,
-    )
-
-    # ML-20M items table (bf16, k=64 lane-padded to 128): fits
-    assert gather_table_bytes(26_744, 64, True) < GATHER_VMEM_TABLE_BUDGET
-    # ML-20M users table: does not fit -> XLA path
-    assert gather_table_bytes(138_493, 64, True) > GATHER_VMEM_TABLE_BUDGET
-
-
-# ---------------------------------------------------------------------------
-# round-6 streaming kernels: double-buffered gather, overlapped flush,
-# lane-packed A (all interpret mode — the kernel-parity CI job)
-# ---------------------------------------------------------------------------
-
 def _relerr(got, ref):
     got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
     scale = np.abs(ref).max()
     return float(np.abs(got - ref).max() / (scale if scale else 1.0))
 
 
-@pytest.mark.parametrize("k", [64, 128])
-@pytest.mark.parametrize("bf16", [False, True])
-def test_gather_stream_parity(k, bf16):
-    """Streaming gather vs the plain table[idx] oracle at both lane
-    regimes (k=64 pads to 128 lanes, k=128 is lane-exact), bf16 and
-    f32, with an ODD index count (the internal sentinel padding and
-    the partial trailing mini-group both execute). Exact: a gather
-    moves bytes."""
-    from pio_tpu.ops.als_pallas import gather_rows_stream
-
-    rng = np.random.default_rng(0)
-    n, m = 37, 421   # m % rows_per_step != 0 and m % group != 0
-    table = rng.normal(size=(n, k)).astype(np.float32)
-    tbl = jnp.asarray(table, jnp.bfloat16) if bf16 else jnp.asarray(table)
-    idx = jnp.asarray(rng.integers(0, n, m), jnp.int32)
-    got = gather_rows_stream(tbl, idx, rows_per_step=64, group=16)
-    ref = np.asarray(tbl, np.float32)[np.asarray(idx)]
-    np.testing.assert_array_equal(np.asarray(got, np.float32), ref)
-
-
-def test_gather_stream_single_group_and_tiny():
-    """rows_per_step >= m (one grid step, one mini-group: the prefetch
-    branch never fires) and group clamped to a rows_per_step divisor."""
-    from pio_tpu.ops.als_pallas import gather_rows_stream
-
-    rng = np.random.default_rng(1)
-    table = jnp.asarray(rng.normal(size=(9, 8)).astype(np.float32))
-    idx = jnp.asarray(rng.integers(0, 9, 5), jnp.int32)
-    got = gather_rows_stream(table, idx, rows_per_step=512, group=48)
-    np.testing.assert_array_equal(
-        np.asarray(got), np.asarray(table)[np.asarray(idx)])
-
-
-def test_accum_stream_matches_hybrid_exactly_and_oracle():
-    """accum="stream" (overlapped flush) must be BIT-EXACT vs the
-    hardware-validated plain hybrid kernel — identical adds in an
-    identical order, only the DMA schedule moves — and within 1e-6
-    relerr of the XLA carry oracle, including rows whose slot runs
-    cross kernel-chunk AND group boundaries (cross-group trails)."""
-    from pio_tpu.ops.als import _normal_equations
-    from pio_tpu.ops.als_pallas import normal_equations_hybrid
-
+@pytest.mark.parametrize("implicit", [False, True])
+def test_accum_stream_matches_hybrid_exactly_and_oracle(implicit):
+    """accum="stream" (overlapped flush) must be BIT-EXACT vs the plain
+    hybrid kernel the chip runs — identical adds in an identical order,
+    only the DMA schedule moves — and within 1e-6 relerr of the XLA carry
+    oracle, including rows whose slot runs cross kernel-chunk AND group
+    boundaries (cross-group trails)."""
     rng = np.random.default_rng(7)
     NU, NI, NNZ, K, W, CS = 70, 30, 4000, 16, 8, 64
     u = (rng.zipf(1.2, NNZ) % NU).astype(np.int32)
@@ -340,213 +265,131 @@ def test_accum_stream_matches_hybrid_exactly_and_oracle():
         jnp.asarray(u), jnp.asarray(i), jnp.asarray(v), NU, W, su)
     fac = jnp.asarray(rng.normal(size=(NI, K)).astype(np.float32)) * 0.3
     # group_slots=128 -> several groups; zipf-heavy rows span them
-    kw = dict(chunk_slots=CS, group_slots=128, bf16_gather=False,
-              interpret=True)
-    A_h, b_h = normal_equations_hybrid(lay, fac, NU, True, 5.0, **kw)
-    A_s, b_s = normal_equations_hybrid(lay, fac, NU, True, 5.0,
-                                       overlap=True, **kw)
+    kw = dict(group_slots=128, bf16_gather=False)
+    A_h, b_h = _normal_equations(
+        lay, fac, NU, implicit, 5.0, CS, accum="hybrid", **kw)
+    A_s, b_s = _normal_equations(
+        lay, fac, NU, implicit, 5.0, CS, accum="stream", **kw)
     np.testing.assert_array_equal(np.asarray(A_s), np.asarray(A_h))
     np.testing.assert_array_equal(np.asarray(b_s), np.asarray(b_h))
     A_ref, b_ref = _normal_equations(
-        lay, fac, NU, True, 5.0, CS, accum="carry", bf16_gather=False)
+        lay, fac, NU, implicit, 5.0, CS, accum="carry", bf16_gather=False)
     assert _relerr(A_s, A_ref) < 1e-6
     assert _relerr(b_s, b_ref) < 1e-6
 
 
-@pytest.mark.parametrize("k", [64, 128])
-def test_accum_stream_odd_last_chunk_and_k_lane_regimes(k):
-    """k=64 (lane-padded acc) and k=128 (lane-exact) through the
-    streaming flush, with a slot count that is NOT a multiple of the
-    kernel chunk so the sentinel quantum-padding branch runs (the
-    'odd last chunk')."""
-    from pio_tpu.ops.als import _normal_equations
-
+@pytest.mark.parametrize("accum", FLUSH_MODES)
+@pytest.mark.parametrize("k", [8, 64, 128])
+def test_accum_stream_odd_last_chunk_and_k_lane_regimes(k, accum):
+    """k=8 and 64 (lane-padded acc) and k=128 (lane-exact) through both
+    flush kernels, with chunk_slots=24: the kernel chunk is rounded down
+    to 8 to divide it, and the last group is shorter than the others
+    (the 'odd last chunk')."""
+    assert als_pallas._kernel_chunk(k, 24) == 8
     rng = np.random.default_rng(11)
     NU, NI, NNZ, W, CS = 9, 12, 300, 4, 24
     u = rng.integers(0, NU, NNZ).astype(np.int32)
     i = rng.integers(0, NI, NNZ).astype(np.int32)
     v = (rng.random(NNZ) * 2 + 0.5).astype(np.float32)
-    su = _slots_for(NNZ, NU, W, CS)   # multiple of 24, not of 8/16
+    su = _slots_for(NNZ, NU, W, CS)   # multiple of 24, not of 16 or 72
+    assert su % 72 and su > 72, su
     lay = _device_slot_layout(
         jnp.asarray(u), jnp.asarray(i), jnp.asarray(v), NU, W, su)
     fac = jnp.asarray(rng.normal(size=(NI, k)).astype(np.float32)) * 0.2
     A_ref, b_ref = _normal_equations(
         lay, fac, NU, False, 1.0, CS, accum="carry", bf16_gather=False)
     A_s, b_s = _normal_equations(
-        lay, fac, NU, False, 1.0, CS, accum="stream", bf16_gather=False)
+        lay, fac, NU, False, 1.0, CS, accum=accum, group_slots=72,
+        bf16_gather=False)
     assert _relerr(A_s, A_ref) < 1e-6
     assert _relerr(b_s, b_ref) < 1e-6
 
 
-def test_packed_a_matches_unpacked_bitwise():
-    """The packed flush writes the SAME f32 sums the unpacked flush
-    writes, just lane-packed: bit-exact vs accum="stream" reshaped,
-    empty rows all-zero (the zeros contract survives packing)."""
-    from pio_tpu.ops.als import _normal_equations
+@pytest.mark.parametrize("accum", FLUSH_MODES)
+def test_rank_over_kernel_limit_resolves_to_stacked(accum):
+    """Above als_pallas.MAX_RANK the kernel's blocks do not fit VMEM:
+    `resolved_accum` (the one place that decides) turns hybrid and stream
+    into stacked, the trained factors are stacked's bit for bit, and the
+    kernel itself refuses the rank."""
+    from pio_tpu.ops.als import als_train
 
-    layout, factors, u = _layout_and_factors(
-        n_self=37, chunk_slots=16, k=8)
-    A_s, b_s = _normal_equations(
-        layout, factors, 37, True, 2.5, 16, accum="stream",
-        bf16_gather=False)
-    A_p, b_p = _normal_equations(
-        layout, factors, 37, True, 2.5, 16, accum="stream",
-        bf16_gather=False, packed=True)
-    assert A_p.shape == (37, 64)
-    np.testing.assert_array_equal(
-        np.asarray(A_p), np.asarray(A_s).reshape(37, 64))
-    np.testing.assert_array_equal(np.asarray(b_p), np.asarray(b_s))
-    for empty in (5, 6):
-        assert empty not in set(u.tolist())
-        assert np.all(np.asarray(A_p)[empty] == 0)
-
-
-@pytest.mark.parametrize("k", [8, 64, 128])
-def test_packed_block_matvec_matches_einsum(k):
-    from pio_tpu.ops.als_pallas import packed_block_matvec
-
-    rng = np.random.default_rng(2)
-    n = 24
-    A = rng.normal(size=(n, k, k)).astype(np.float32)
-    A = A + np.swapaxes(A, 1, 2)      # symmetric, like a normal equation
-    x = rng.normal(size=(n, k)).astype(np.float32)
-    got = packed_block_matvec(
-        jnp.asarray(A.reshape(n, k * k)), jnp.asarray(x), block_rows=8)
-    ref = np.einsum("bij,bj->bi", A.astype(np.float64), x)
-    assert _relerr(got, ref) < 1e-6
-
-
-def test_packed_train_end_to_end_and_x0_padding():
-    """als_train with packed_a=True (stream accum + packed CG) reaches
-    the carry path's solution quality; n_self deliberately NOT a
-    multiple of the matvec row block, so the identity-row pad in
-    _solve_packed runs with a warm x0."""
-    from pio_tpu.ops.als import ALSParams, als_train, rmse
-
-    rng = np.random.default_rng(3)
-    nu, ni, nnz = 53, 31, 900
+    k = 264
+    assert k > als_pallas.MAX_RANK
+    kw = dict(rank=k, iterations=1, reg=0.1, chunk=256, width=8,
+              chunk_slots=16, cg_iters=2)
+    assert ALSParams(**kw, accum=accum).resolved_accum() == "stacked"
+    assert ALSParams(rank=als_pallas.MAX_RANK,
+                     accum=accum).resolved_accum() == accum
+    rng = np.random.default_rng(5)
+    nu, ni, nnz = 12, 9, 80
     u = rng.integers(0, nu, nnz).astype(np.int64)
     i = rng.integers(0, ni, nnz).astype(np.int64)
     v = (rng.random(nnz) * 4 + 1).astype(np.float32)
-    kw = dict(rank=8, iterations=5, reg=0.1, chunk=256, width=8,
-              chunk_slots=64, cg_iters=10, bf16_gather=False)
-    m_p = als_train(u, i, v, nu, ni,
-                    ALSParams(**kw, accum="stream", packed_a=True))
-    m_c = als_train(u, i, v, nu, ni, ALSParams(**kw, accum="carry"))
-    assert abs(rmse(m_p, u, i, v) - rmse(m_c, u, i, v)) < 1e-3
-
-
-def test_stream_gather_composes_in_training():
-    """gather="stream" through the full hybrid/stream accumulation:
-    identical math, only the gather implementation moves — factors
-    must match the XLA-gather run bit-for-bit (both gathers produce
-    the same bytes and the downstream program is identical)."""
-    import dataclasses
-
-    from pio_tpu.ops.als import ALSParams, als_train
-
-    rng = np.random.default_rng(4)
-    nu, ni, nnz = 40, 25, 800
-    u = rng.integers(0, nu, nnz).astype(np.int64)
-    i = rng.integers(0, ni, nnz).astype(np.int64)
-    v = (rng.random(nnz) * 4 + 1).astype(np.float32)
-    base = ALSParams(rank=8, iterations=3, reg=0.05, chunk=256, width=8,
-                     chunk_slots=64, cg_iters=8, accum="stream",
-                     bf16_gather=False)
-    ref = als_train(u, i, v, nu, ni, base)
-    got = als_train(u, i, v, nu, ni,
-                    dataclasses.replace(base, gather="stream"))
+    got = als_train(u, i, v, nu, ni, ALSParams(**kw, accum=accum))
+    ref = als_train(u, i, v, nu, ni, ALSParams(**kw, accum="stacked"))
     np.testing.assert_array_equal(
         np.asarray(got.user_factors), np.asarray(ref.user_factors))
+    np.testing.assert_array_equal(
+        np.asarray(got.item_factors), np.asarray(ref.item_factors))
+    with pytest.raises(ValueError, match="rank 264"):
+        als_pallas.segment_flush(
+            jnp.zeros((16,), jnp.int32), nu, k, 16, iter(()))
 
 
-def test_stream_modes_compose_with_shard_map():
-    """The full round-6 configuration — accum="stream",
-    gather="stream", packed_a=True — inside als_train_sharded's
-    shard_map (8 virtual devices) vs the single-device carry ground
-    truth: the production multi-chip composition of every new kernel
-    at once."""
-    from pio_tpu.ops.als import ALSParams, als_train, als_train_sharded, rmse
-    from pio_tpu.parallel.mesh import MeshConfig, create_mesh
-
-    rng = np.random.default_rng(0)
-    nu, ni, nnz = 60, 40, 900
-    u = rng.integers(0, nu, nnz)
-    i = rng.integers(0, ni, nnz)
-    v = (rng.random(nnz) * 4 + 1).astype(np.float32)
-    mesh = create_mesh(MeshConfig(data=8))
-    kw = dict(rank=8, iterations=5, reg=0.1, chunk=256, width=8,
-              chunk_slots=64, cg_iters=8)
-    m = als_train_sharded(
-        u, i, v, nu, ni,
-        ALSParams(**kw, accum="stream", gather="stream", packed_a=True),
-        mesh)
-    m1 = als_train(u, i, v, nu, ni, ALSParams(**kw, accum="carry"))
-    assert abs(rmse(m, u, i, v) - rmse(m1, u, i, v)) < 5e-3
+def test_als_pallas_imports_nothing_from_als():
+    """The arrows point one way: ops/als.py -> ops/als_pallas.py."""
+    tree = ast.parse(pathlib.Path(als_pallas.__file__).read_text())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {a.name for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            imported |= {f"{node.module}.{a.name}" for a in node.names}
+            imported.add(node.module)
+    assert not {m for m in imported
+                if m == "pio_tpu.ops.als"
+                or m.startswith("pio_tpu.ops.als.")}, imported
 
 
-def test_packed_train_step_hlo_has_no_relayout():
-    """The structural property the packed path exists to guarantee,
-    checkable WITHOUT a chip: the optimized HLO of the packed-A
-    training step contains NO (n,k,k)-shaped full-A tensor — no
-    (n,k²)<->(n,k,k) reshape/relayout anywhere, in particular not
-    inside the CG while loop. cg_iters is explicit so BOTH sides take
-    the CG path (the exact-Cholesky escape legitimately unpacks).
-    Absence of the 3-d shape module-wide is strictly stronger than
-    absence inside the loop. The packed shape must be present (the
-    check would pass vacuously if the packed path silently fell back)."""
-    from pio_tpu.ops.als import ALSParams, _init_or, _prep_coo, _train_jit
-
-    rng = np.random.default_rng(9)
-    nu, ni, nnz, k = 57, 41, 600, 8
-    params = ALSParams(rank=k, iterations=2, reg=0.05, chunk=0, width=8,
-                       chunk_slots=64, accum="stream", packed_a=True,
-                       cg_iters=6, bf16_gather=False)
-    u, i, v = _prep_coo(
-        rng.integers(0, nu, nnz).astype(np.int64),
-        rng.integers(0, ni, nnz).astype(np.int64),
-        (rng.random(nnz) * 4 + 1).astype(np.float32), nu, ni, params)
-    user0, item0 = _init_or(None, nu, ni, params)
-    txt = _train_jit.lower(
-        jnp.asarray(u), jnp.asarray(i), jnp.asarray(v),
-        n_users=nu, n_items=ni, params=params,
-        user0=user0, item0=item0,
-    ).compile().as_text()
-    assert f"f32[{nu},{k},{k}]" not in txt, (
-        "full-A (n,k,k) tensor appears in the packed-A program — a "
-        "relayout leaked into the solve")
-    assert f"f32[{ni},{k},{k}]" not in txt
-    assert (f"f32[{nu},{k * k}]" in txt
-            or f"f32[{nu + 1},{k * k}]" in txt), (
-        "packed (n,k²) A absent — the packed path did not run")
+# every ALSParams field is set by something that ships, or by a test with
+# a stated need: a field no caller can reach is a mode nobody runs
+_TEMPLATE_FILES = (
+    "pio_tpu/models/recommendation.py", "pio_tpu/models/similarproduct.py",
+    "pio_tpu/models/ecommerce.py", "pio_tpu/tuning/sweep.py",
+    "pio_tpu/tools/cli.py",
+)
+_SET_IN_OPS_ALS = {
+    "accum": "sweep_safe_params and fold_in_params pin it",
+    "bf16_gather": "fold_in_params turns it off",
+}
+_TESTS_NEED = {
+    "width": "slot layouts with rows wider than a slot at tiny sizes",
+    "chunk_slots": "several chunks and groups at tiny sizes",
+    "auto_cg_rows": "tests/test_als.py forces CG on a small side",
+}
 
 
-def test_als_train_with_pallas_gather_matches_xla():
-    """End-to-end ALS with gather='pallas-*' must match gather='xla'
-    (identical math, only the gather implementation moves)."""
-    from pio_tpu.ops.als import ALSParams, als_train, rmse
+def _alsparams_keywords(path: pathlib.Path) -> set[str]:
+    found = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if (isinstance(node, ast.Call)
+                and getattr(node.func, "attr", getattr(node.func, "id", ""))
+                == "ALSParams"):
+            found |= {kw.arg for kw in node.keywords if kw.arg}
+    return found
 
-    rng = np.random.default_rng(3)
-    nu, ni, nnz = 60, 40, 2000
-    users = rng.integers(0, nu, nnz).astype(np.int64)
-    items = rng.integers(0, ni, nnz).astype(np.int64)
-    vals = rng.integers(1, 6, nnz).astype(np.float32)
-    base = ALSParams(rank=8, iterations=3, reg=0.05, chunk=0, width=8,
-                     chunk_slots=64, bf16_gather=False)
+
+def test_every_alsparams_field_has_a_caller():
     import dataclasses
 
-    ref = als_train(users, items, vals, nu, ni, base)
-    for variant in ("pallas-copy", "pallas-take"):
-        p = dataclasses.replace(base, gather=variant)
-        got = als_train(users, items, vals, nu, ni, p)
-        np.testing.assert_allclose(
-            np.asarray(got.user_factors), np.asarray(ref.user_factors),
-            rtol=2e-5, atol=2e-6)
-    # implicit mode through the hybrid/pallas accumulation path too
-    base_i = dataclasses.replace(base, implicit=True, alpha=5.0,
-                                 accum="stacked")
-    ref_i = als_train(users, items, vals, nu, ni, base_i)
-    got_i = als_train(users, items, vals, nu, ni,
-                      dataclasses.replace(base_i, gather="pallas-copy"))
-    assert abs(rmse(ref_i, users, items, vals)
-               - rmse(got_i, users, items, vals)) < 1e-5
+    root = pathlib.Path(als.__file__).parents[2]
+    passed = set()
+    for rel in _TEMPLATE_FILES:
+        passed |= _alsparams_keywords(root / rel)
+    fields = {f.name for f in dataclasses.fields(ALSParams)}
+    assert passed <= fields, passed - fields
+    for name, setter in _SET_IN_OPS_ALS.items():
+        assert name in fields and name not in passed, (name, setter)
+    unreached = fields - passed - set(_SET_IN_OPS_ALS)
+    assert unreached == set(_TESTS_NEED), unreached
+    assert len(fields) == 15
