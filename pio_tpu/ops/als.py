@@ -20,9 +20,12 @@ TPU formulation is built around three hardware facts measured on v5e:
    Cholesky when bit-exactness matters;
  * the host is slow relative to the chip (single-core sort of 20M ratings
    costs more than the whole train), so the slot layout itself is built
-   ON DEVICE from the raw COO arrays: one stable `lax.sort` by row, then
-   an all-vectorized slot/column assignment and a monotone scatter. Only
-   the three contiguous COO arrays ever cross the host->HBM link.
+   ON DEVICE from the raw COO arrays: one stable `lax.sort` by row; the
+   slots are then runs of the sorted order, found from the rows'
+   boundaries and gathered into place a row of lanes at a time. Nothing
+   scatters the ratings: the chip takes a scattered or gathered scalar
+   one at a time, ~8 ns each (PERF.md, PR 29). Only the three contiguous
+   COO arrays ever cross the host->HBM link.
 
 The multi-chip path (`als_train_sharded`) partitions users/items into
 per-device blocks with `shard_map`; each half-sweep all_gathers the
@@ -235,6 +238,58 @@ def _slots_for(nnz: int, n_self: int, width: int, chunk_slots: int) -> int:
     return math.ceil(s / chunk_slots) * chunk_slots
 
 
+_LANES = 128   # entries of one gathered row: the chip's vector width
+
+
+def _count_below(a, q, inclusive: bool):
+    """Per query of `q`, how many entries of the sorted int32 vector `a`
+    lie below it (`<`; `<=` when `inclusive`): `searchsorted`'s left /
+    right, for queries under the int32 maximum.
+
+    `a` is cut into rows of 128 entries and those into blocks of 128
+    rows. A compare against every block's first entry finds the block,
+    one gathered row of the rows' first entries the row, the gathered
+    row itself the place: two row gathers a query. A binary search
+    gathers one scalar a step, 25 steps at 20 M entries, and the chip
+    takes ~8 ns for each (PERF.md, PR 29)."""
+    n = a.shape[0]
+    n_blocks = max(1, -(-n // (_LANES * _LANES)))
+    rows = jnp.concatenate([a, jnp.full(
+        (n_blocks * _LANES * _LANES - n,), jnp.iinfo(jnp.int32).max,
+        jnp.int32)]).reshape(n_blocks * _LANES, _LANES)
+    row_firsts = rows[:, 0].reshape(n_blocks, _LANES)
+
+    def count(keys):
+        below = keys <= q[:, None] if inclusive else keys < q[:, None]
+        return jnp.sum(below, axis=1, dtype=jnp.int32)
+
+    # everything before the last block (row) that starts below the query
+    # is below it, everything after the next one's start is not
+    block = jnp.maximum(count(row_firsts[None, :, 0]) - 1, 0)
+    row = block * _LANES + jnp.maximum(count(row_firsts[block]) - 1, 0)
+    return row * _LANES + count(rows[row])
+
+
+def _runs_to_slots(x_s, start, lens, width: int):
+    """(S, width): slot s holds x_s[start[s] : start[s] + lens[s]], then
+    zeros. `x_s` is read as rows of `width`; a run lies in two of them,
+    which one row gather each brings, and a shift by the run's offset in
+    its row, one bit of the offset at a time, lines it up. (A gather of
+    width-long windows at unaligned starts runs as a loop of S steps on
+    the chip, and one of single entries takes ~15 ns an entry.)"""
+    nnz = x_s.shape[0]
+    n_rows = -(-nnz // width) + 2           # start <= nnz: row + 1 exists
+    x_rows = jnp.concatenate([x_s, jnp.zeros(
+        (n_rows * width - nnz,), x_s.dtype)]).reshape(n_rows, width)
+    row, shift = start // width, start % width
+    both = jnp.concatenate([x_rows[row], x_rows[row + 1]], axis=1)
+    for bit in range((width - 1).bit_length()):
+        both = jnp.where(((shift >> bit) & 1 == 1)[:, None],
+                         jnp.roll(both, -(1 << bit), axis=1), both)
+    held = jnp.arange(width, dtype=jnp.int32)[None, :] < lens[:, None]
+    return jnp.where(held, both[:, :width], 0)
+
+
 @jax.named_scope("als.layout")
 def _device_slot_layout(u, o, v, n_self: int, width: int, slots_max: int):
     """Build the slot layout on device from (possibly sentinel-padded) COO.
@@ -243,43 +298,36 @@ def _device_slot_layout(u, o, v, n_self: int, width: int, slots_max: int):
     dropped. o: opposing-side ids; v: values. Returns
     (rows (S,), idx (S,width), val (S,width), lens (S,)).
 
-    The scatter destination index slot_id*width+col is strictly increasing
-    in the sorted order, so the writes are sequential in HBM.
+    After the stable sort by row a slot is a run of the sorted ratings:
+    a row's ratings cut every `width`. Where the runs start, how long
+    they are and whose they are follows from the rows' boundaries in the
+    sorted order, work in the rows and slots (10^5), and the runs are
+    then gathered into place. Nothing scatters the ratings: a scatter of
+    20 M updates takes the chip 0.10 s, a combining one 0.175 s, and the
+    layout had four and four of them a job (PERF.md, PR 29).
     """
     nnz = u.shape[0]
     u_s, o_s, v_s = jax.lax.sort((u, o, v), num_keys=1, is_stable=True)
-    t = jnp.arange(nnz, dtype=jnp.int32)
-    newrow = jnp.concatenate(
-        [jnp.ones((1,), bool), u_s[1:] != u_s[:-1]]
-    )
-    row_start = jax.lax.cummax(jnp.where(newrow, t, 0))
-    pos = t - row_start                       # position within the row
-    newslot = newrow | (pos % width == 0)     # heavy rows split every `width`
-    slot_id = jnp.cumsum(newslot.astype(jnp.int32)) - 1
-    col = pos % width
-    valid = u_s < n_self
-
-    slot_id = jnp.where(valid, slot_id, slots_max)  # OOB -> dropped
+    # ratings before each row: padding sorts last and falls outside
+    row_start = _count_below(
+        u_s, jnp.arange(n_self + 1, dtype=jnp.int32), inclusive=False)
+    degree = jnp.diff(row_start)
+    n_slots = (degree + (width - 1)) // width   # heavy rows split
+    slot_end = jnp.cumsum(n_slots)              # slots up to and with a row
+    s = jnp.arange(slots_max, dtype=jnp.int32)
+    # the row whose slots reach past s; empty rows own none
+    row = jnp.minimum(_count_below(slot_end, s, inclusive=True), n_self - 1)
+    used = s < slot_end[-1]
+    k = s - (slot_end - n_slots)[row]           # the slot's place in its row
     # unused slots carry the sentinel row id n_self: the accumulation
     # scatter drops them (mode="drop"), and the slot->row index stays
     # globally NON-DECREASING (real slots ascend, sentinel tail is the
     # max) so scatters can declare indices_are_sorted
-    rows = (
-        jnp.full((slots_max,), n_self, jnp.int32)
-        .at[slot_id].min(u_s, mode="drop")
-    )
-    lens = (
-        jnp.zeros((slots_max,), jnp.int32)
-        .at[slot_id].add(1, mode="drop")
-    )
-    idx = (
-        jnp.zeros((slots_max, width), jnp.int32)
-        .at[slot_id, col].set(o_s, mode="drop")
-    )
-    val = (
-        jnp.zeros((slots_max, width), jnp.float32)
-        .at[slot_id, col].set(v_s, mode="drop")
-    )
+    rows = jnp.where(used, row, n_self)
+    lens = jnp.where(used, jnp.minimum(degree[row] - k * width, width), 0)
+    start = jnp.where(used, row_start[row] + k * width, nnz)
+    idx = _runs_to_slots(o_s, start, lens, width)
+    val = _runs_to_slots(v_s, start, lens, width)
     return rows, idx, val, lens
 
 

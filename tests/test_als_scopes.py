@@ -137,3 +137,38 @@ def test_scopes_reach_the_text_and_change_only_metadata(
     assert not _scopes_in(bare)
     assert _program_only(bare) == _program_only(named)
     jax.clear_caches()
+
+
+def _entry_instructions(hlo: str) -> list[str]:
+    """Names of the entry computation's instructions: what a trace shows
+    as operations when the module has no loop (a fusion's inside runs as
+    the fusion)."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    return [m[1] for line in entry[:entry.index("\n}")].splitlines()
+            if (m := profile._INSTR.match(line))]
+
+
+def test_every_layout_op_carries_the_layout_scope(no_compile_cache):
+    """`obs/profile.py` sums a trace's operations by the scope of their
+    instruction: alone in a module, every instruction of the slot layout
+    that runs as an operation (the sort, the row gathers, the shifts)
+    resolves to `als.layout`, so the layout's seconds stay the layer's."""
+    nnz, width = 4096, 8
+    slots = als._slots_for(nnz, N_USERS, width, 128)
+
+    def side(u, o, v):
+        with jax.named_scope("als.user"):
+            return als._device_slot_layout(u, o, v, N_USERS, width, slots)
+
+    i32 = jax.ShapeDtypeStruct((nnz,), jnp.int32)
+    jax.clear_caches()
+    text = jax.jit(side).lower(
+        i32, i32, jax.ShapeDtypeStruct((nnz,), jnp.float32)
+    ).compile().as_text()
+    assert " while(" not in text and " conditional(" not in text
+    scopes = profile.module_scopes(text)
+    names = _entry_instructions(text)
+    assert len(names) > 20
+    assert {scopes.get(name, (None,))[0] for name in names} == {
+        "als.user/als.layout"}
+    jax.clear_caches()
