@@ -23,6 +23,7 @@ ids (same fold order as the reference's LEventAggregator time ordering).
 
 from __future__ import annotations
 
+import json
 from dataclasses import dataclass
 from functools import partial
 from typing import Any
@@ -101,6 +102,20 @@ class SequenceParams(Params):
     # mid-train step checkpoints (workflow/orbax_ckpt.py); "" = off
     checkpoint_dir: str = ""
     checkpoint_every: int = 100
+    # a block specification (models/seq_blocks.py BlockSpec: the keys of a
+    # published config.json plus this rank's share of it), as a dict in
+    # engine.json; kept as its JSON text so the params stay hashable.
+    # With one, the engine trains that decoder stack (RMSNorm, rotary
+    # positions, grouped-query window / full attention, top-k experts,
+    # untied head) on whole histories of max_len items, and embed_dim,
+    # num_heads, num_layers, ffn_dim, attention and the moe_* fields
+    # above, which size the SASRec-style encoder, are not read.
+    block_spec: Any = None
+
+    def __post_init__(self):
+        if isinstance(self.block_spec, dict):
+            object.__setattr__(self, "block_spec", json.dumps(
+                self.block_spec, sort_keys=True))
 
 
 class Block(nn.Module):
@@ -290,6 +305,15 @@ def train_sequence_model(
     `lifecycle` is a workflow.lifecycle.TrainLifecycle (or None):
     heartbeats at span boundaries; preemption force-saves then raises.
     Returns (params, encoder, final loss)."""
+    if p.block_spec:
+        from pio_tpu.models.seq_blocks import train_lm
+
+        if mesh is not None or checkpoint is not None:
+            raise ValueError(
+                "a block specification trains on one chip, without step "
+                "checkpoints (ROADMAP R1)")
+        params, loss = train_lm(data.seqs, p, lifecycle=lifecycle)
+        return params, None, loss
     encoder = make_encoder(len(data.items), p)
     optimizer = optax.adam(p.learning_rate)
 
@@ -643,7 +667,7 @@ class SequenceAlgorithm(PAlgorithm):
             lifecycle.checkpoint_dir if lifecycle is not None else ""
         )
         ckpt = None
-        if ckpt_dir:
+        if ckpt_dir and not self.params.block_spec:
             from pio_tpu.workflow.orbax_ckpt import (
                 StepCheckpointConfig,
                 StepCheckpointer,
@@ -713,6 +737,13 @@ class SequenceAlgorithm(PAlgorithm):
         varying sizes compile O(log) programs. Serving path: Pallas flash
         attention on TPU, reference on CPU."""
         p = model.config
+        if p.block_spec:
+            from pio_tpu.models.seq_blocks import BlockSpec, last_logits
+
+            # whole histories only: every resolved row is max_len items
+            return last_logits(model.params,
+                               jnp.asarray(rows[:, -(p.max_len - 1):]),
+                               BlockSpec.parse(p.block_spec))
         encoder = make_encoder(len(model.items), p)
         on_cpu = jax.devices()[0].platform == "cpu"
         attn = partial(

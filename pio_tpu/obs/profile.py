@@ -49,7 +49,9 @@ import sys
 from collections import Counter, defaultdict
 
 ROOT = "train"
-SCOPE_PREFIX = "als."
+# the scope families of the device programs: the ALS trainer's and the
+# sequence engine's block stack (models/seq_blocks.py)
+SCOPE_PREFIX = ("als.", "seq.")
 UNSCOPED = "unscoped"
 OUTSIDE = "outside"
 # a span's name: lower-case words with a dot between (or the root's).
@@ -200,14 +202,29 @@ _OPERAND = re.compile(r"%([\w.\-]+)")
 RULES = ("own", "called", "neighbour")
 
 
-def scope_of_op_name(op_name: str, prefix: str = SCOPE_PREFIX) -> str | None:
+def _scope_pattern(prefix: str | tuple[str, ...]) -> re.Pattern:
+    families = (prefix,) if isinstance(prefix, str) else tuple(prefix)
+    return re.compile(r"(?<![\w.])(?:" + "|".join(
+        re.escape(f) for f in families) + r")[\w.]*")
+
+
+def scope_of_op_name(op_name: str,
+                     prefix: str | tuple[str, ...] = SCOPE_PREFIX
+                     ) -> str | None:
     """`jit(_train_jit)/while/body/als.user/als.gather/dot_general` ->
-    `als.user/als.gather`: the path's components that are scopes."""
-    parts = [c for c in op_name.split("/") if c.startswith(prefix)]
+    `als.user/als.gather`: the path's components that are scopes. A
+    differentiated program wraps them (`transpose(jvp(seq.head_loss))`)
+    and an inlined helper repeats the path it was called from: a scope
+    counts wherever it stands, and once where it repeats."""
+    parts: list[str] = []
+    for found in _scope_pattern(prefix).findall(op_name):
+        if not parts or parts[-1] != found:
+            parts.append(found)
     return "/".join(parts) or None
 
 
-def module_scopes(hlo_text: str, prefix: str = SCOPE_PREFIX
+def module_scopes(hlo_text: str,
+                  prefix: str | tuple[str, ...] = SCOPE_PREFIX
                   ) -> dict[str, tuple[str, str]]:
     """{instruction name: (scope, rule)} for a compiled module (text
     printed with `%` before names). Rules, in order:
@@ -367,7 +384,8 @@ def _overlaps(segments, starts, lo: float, hi: float):
 # the reduction
 # ---------------------------------------------------------------------------
 
-def reduce(profile: dict, root: str = ROOT, prefix: str = SCOPE_PREFIX,
+def reduce(profile: dict, root: str = ROOT,
+           prefix: str | tuple[str, ...] = SCOPE_PREFIX,
            longest: int = 10) -> dict:
     """See the module docstring. Seconds throughout. -> {"window_s",
     "jobs": [{"start_s", "wall_s", "devices": {chip: {"busy_s",

@@ -477,3 +477,326 @@ def ulysses_attention(
     # (B, S, H/n, D) -> (B, S/n, H, D)
     return jax.lax.all_to_all(o, axis_name, split_axis=1, concat_axis=2,
                               tiled=True).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Banded flash attention: grouped-query heads, causal window, Pallas
+# forward AND backward (the language-model path of models/seq_blocks.py)
+# ---------------------------------------------------------------------------
+#
+# Layout (B, H, S, D). Query head h reads key-value head h // (Hq // Hkv)
+# through the block index map: no repeated copy of k / v exists. The key
+# blocks a query block needs (`j <= i`, and `i - j < window` on a window
+# layer) are listed on the host, once per shape, as a table of (query
+# block, key block) pairs; the grid walks the table, so a block outside
+# the band is never fetched, in the forward or in either backward kernel.
+
+_FIRST, _LAST, _EDGE = 1, 2, 4
+_LANES = 128
+
+
+def band_pairs(seq_len: int, block_q: int, block_k: int,
+               window: int | None, by_key: bool = False):
+    """The (query block, key block) pairs of the causal band, as int32
+    arrays (qi, kj, flags): sorted by query block (by key block with
+    `by_key`), a run of one query (key) block marked _FIRST / _LAST, and
+    _EDGE where the block holds a masked score."""
+    import numpy as np
+
+    n_q, n_k = -(-seq_len // block_q), -(-seq_len // block_k)
+    pairs = []
+    for i in range(n_q):
+        r_lo, r_hi = i * block_q, (i + 1) * block_q - 1
+        k_lo = 0 if window is None else max(0, r_lo - (window - 1))
+        for j in range(k_lo // block_k, min(r_hi // block_k, n_k - 1) + 1):
+            c_lo, c_hi = j * block_k, (j + 1) * block_k - 1
+            whole = c_hi <= r_lo and (window is None
+                                      or c_lo >= r_hi - (window - 1))
+            pairs.append((i, j, 0 if whole else _EDGE))
+    major = 1 if by_key else 0
+    pairs.sort(key=lambda p: (p[major], p[1 - major]))
+    qi = np.array([p[0] for p in pairs], np.int32)
+    kj = np.array([p[1] for p in pairs], np.int32)
+    fl = np.array([p[2] for p in pairs], np.int32)
+    run = kj if by_key else qi
+    fl[np.r_[True, run[1:] != run[:-1]]] |= _FIRST
+    fl[np.r_[run[1:] != run[:-1], True]] |= _LAST
+    return qi, kj, fl
+
+
+def band_blocks(seq_len: int, block_q: int, block_k: int,
+                window: int | None) -> int:
+    """Key blocks in the causal band, counted without the table: what the
+    kernels' visited-block counter is held against."""
+    total = 0
+    for i in range(-(-seq_len // block_q)):
+        hi = min(((i + 1) * block_q - 1) // block_k,
+                 -(-seq_len // block_k) - 1)
+        lo = 0 if window is None else max(
+            0, i * block_q - (window - 1)) // block_k
+        total += hi - lo + 1
+    return total
+
+
+def _band_scores(q, k, qi, kj, edge, *, scale, window):
+    """(bq, bk) float32 scores of one block pair, -inf where masked."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    bq, bk = s.shape
+
+    def masked(s):
+        rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        cols = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        keep = cols <= rows
+        if window is not None:
+            keep = keep & (rows - cols < window)
+        return jnp.where(keep, s, -jnp.inf)
+
+    return jax.lax.cond(edge, masked, lambda s: s, s)
+
+
+def _band_fwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref,
+                     o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                     *, scale, window):
+    p = pl.program_id(2)
+    fl = fl_ref[p]
+
+    @pl.when((fl & _FIRST) != 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    s = _band_scores(q_ref[0, 0], k_ref[0, 0], qi_ref[p], kj_ref[p],
+                     (fl & _EDGE) != 0, scale=scale, window=window)
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    prob = jnp.exp(s - m_new)            # masked: exp(-inf) = 0
+    alpha = jnp.exp(m_prev - m_new)
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(prob, axis=-1, keepdims=True)
+    acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+        prob.astype(v_ref.dtype), v_ref[0, 0],
+        preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
+
+    @pl.when((fl & _LAST) != 0)
+    def _emit():
+        l = l_scr[...]
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.broadcast_to(m_scr[...] + jnp.log(l),
+                                         lse_ref.shape[2:])
+
+
+def _band_probs(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, qi, kj, edge,
+                *, scale, window):
+    """Recompute one block pair's probabilities and the score gradient:
+    -> (prob, dscore) float32 (bq, bk)."""
+    s = _band_scores(q_ref[0, 0], k_ref[0, 0], qi, kj, edge,
+                     scale=scale, window=window)
+    prob = jnp.exp(s - lse_ref[0, 0][:, :1])
+    do = do_ref[0, 0]
+    dprob = jax.lax.dot_general(do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+    delta = jnp.sum(o_ref[0, 0].astype(jnp.float32)
+                    * do.astype(jnp.float32), axis=-1, keepdims=True)
+    return prob, prob * (dprob - delta) * scale
+
+
+def _band_dq_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
+                    do_ref, lse_ref, dq_ref, acc_scr, *, scale, window):
+    p = pl.program_id(2)
+    fl = fl_ref[p]
+
+    @pl.when((fl & _FIRST) != 0)
+    def _init():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    _, ds = _band_probs(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                        qi_ref[p], kj_ref[p], (fl & _EDGE) != 0,
+                        scale=scale, window=window)
+    acc_scr[...] += jnp.dot(ds.astype(k_ref.dtype), k_ref[0, 0],
+                            preferred_element_type=jnp.float32)
+
+    @pl.when((fl & _LAST) != 0)
+    def _emit():
+        dq_ref[0, 0] = acc_scr[...].astype(dq_ref.dtype)
+
+
+def _band_dkv_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
+                     do_ref, lse_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+                     *, scale, window):
+    p = pl.program_id(2)
+    fl = fl_ref[p]
+
+    @pl.when((fl & _FIRST) != 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    prob, ds = _band_probs(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                           qi_ref[p], kj_ref[p], (fl & _EDGE) != 0,
+                           scale=scale, window=window)
+    t_lhs = (((0,), (0,)), ((), ()))       # lhs^T @ rhs
+    dv_scr[...] += jax.lax.dot_general(
+        prob.astype(do_ref.dtype), do_ref[0, 0], t_lhs,
+        preferred_element_type=jnp.float32)
+    dk_scr[...] += jax.lax.dot_general(
+        ds.astype(q_ref.dtype), q_ref[0, 0], t_lhs,
+        preferred_element_type=jnp.float32)
+
+    @pl.when((fl & _LAST) != 0)
+    def _emit():
+        dk_ref[0, 0] = dk_scr[...]
+        dv_ref[0, 0] = dv_scr[...]
+
+
+def _band_call(kernel, name, table, args, kinds, outs, scratch, *,
+               batch, heads, group, block_q, block_k, head_dim, interpret):
+    """One pallas_call over (batch, query head, table entry). `kinds`
+    says, per tensor argument, which block it is: "q" (query head, block
+    qi[p]), "k" (key-value head h // group, block kj[p]), "lse" (as "q",
+    128 lanes); `outs` likewise, with the out dtype."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(kind):
+        if kind == "k":
+            return pl.BlockSpec(
+                (1, 1, block_k, head_dim),
+                lambda b, h, p, qi, kj, fl: (b, h // group, kj[p], 0))
+        if kind == "kq":       # a key block per QUERY head (dk / dv out)
+            return pl.BlockSpec(
+                (1, 1, block_k, head_dim),
+                lambda b, h, p, qi, kj, fl: (b, h, kj[p], 0))
+        width = _LANES if kind == "lse" else head_dim
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda b, h, p, qi, kj, fl: (b, h, qi[p], 0))
+
+    seq = args[0].shape[2]
+
+    def shape(kind, dtype):
+        width = _LANES if kind == "lse" else head_dim
+        return jax.ShapeDtypeStruct((batch, heads, seq, width), dtype)
+
+    return pl.pallas_call(
+        kernel, name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(batch, heads, len(table[0])),
+            in_specs=[spec(k) for k in kinds],
+            out_specs=[spec(k) for k, _ in outs],
+            scratch_shapes=scratch),
+        out_shape=[shape(k, dt) for k, dt in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(*(jnp.asarray(t) for t in table), *args)
+
+
+def _band_setup(q_shape, hkv, seq_len, block_q, block_k, interpret):
+    """The static arguments of `_band_call` and the padding the sequence
+    needs, for (B, Hq, ., D) queries of `seq_len` real positions."""
+    b, hq, _, d = q_shape
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads do not group over {hkv} "
+                         "key-value heads")
+    if interpret is None:
+        interpret = jax.devices()[0].platform == "cpu"
+    block = max(block_q, block_k)
+    if block % min(block_q, block_k):
+        raise ValueError("block_q and block_k must divide one another")
+    pad = _pad_len(seq_len, block)
+    return dict(batch=b, heads=hq, group=hq // hkv, block_q=block_q,
+                block_k=block_k, head_dim=d, interpret=interpret), pad
+
+
+def _pad_seq(x, pad):
+    return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def banded_flash_attention(q, k, v, window: int | None = None,
+                           scale: float | None = None,
+                           block_q: int = 512, block_k: int = 512,
+                           interpret: bool | None = None):
+    """Causal attention over grouped-query heads, optionally within a
+    window (`i - j < window`), forward and backward as Pallas kernels.
+
+    q: (B, Hq, S, D); k, v: (B, Hkv, S, D), Hq a multiple of Hkv; ->
+    (B, Hq, S, D). The sequence is padded to the block internally (a
+    padded key lies after every real query, so causality masks it)."""
+    return _banded_fwd(q, k, v, window, scale, block_q, block_k,
+                       interpret)[0]
+
+
+def _banded_fwd(q, k, v, window, scale, block_q, block_k, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, d = q.shape[2], q.shape[3]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    block_q, block_k = min(block_q, s), min(block_k, s)
+    dims, pad = _band_setup(q.shape, k.shape[1], s, block_q, block_k,
+                            interpret)
+    qp, kp, vp = _pad_seq(q, pad), _pad_seq(k, pad), _pad_seq(v, pad)
+    table = band_pairs(s + pad, block_q, block_k, window)
+    o, lse = _band_call(
+        partial(_band_fwd_kernel, scale=scale, window=window),
+        "flash_attention_fwd", table, (qp, kp, vp), ("q", "k", "k"),
+        (("q", q.dtype), ("lse", jnp.float32)),
+        [pltpu.VMEM((block_q, 1), jnp.float32),
+         pltpu.VMEM((block_q, 1), jnp.float32),
+         pltpu.VMEM((block_q, d), jnp.float32)], **dims)
+    return o[:, :, :s], (qp, kp, vp, o, lse)
+
+
+def _banded_bwd(window, scale, block_q, block_k, interpret, res, g):
+    from jax.experimental.pallas import tpu as pltpu
+
+    qp, kp, vp, o, lse = res
+    sp, d = qp.shape[2], qp.shape[3]
+    s = g.shape[2]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    block_q, block_k = min(block_q, s), min(block_k, s)
+    dims, pad = _band_setup(qp.shape, kp.shape[1], s, block_q, block_k,
+                            interpret)
+    do = _pad_seq(g.astype(qp.dtype), pad)
+    args = (qp, kp, vp, o, do, lse)
+    kinds = ("q", "k", "k", "q", "q", "lse")
+    dq, = _band_call(
+        partial(_band_dq_kernel, scale=scale, window=window),
+        "flash_attention_dq", band_pairs(sp, block_q, block_k, window),
+        args, kinds, (("q", qp.dtype),),
+        [pltpu.VMEM((block_q, d), jnp.float32)], **dims)
+    dk, dv = _band_call(
+        partial(_band_dkv_kernel, scale=scale, window=window),
+        "flash_attention_dkv",
+        band_pairs(sp, block_q, block_k, window, by_key=True),
+        args, kinds, (("kq", jnp.float32), ("kq", jnp.float32)),
+        [pltpu.VMEM((block_k, d), jnp.float32),
+         pltpu.VMEM((block_k, d), jnp.float32)], **dims)
+    # a key-value head's gradient is the sum over the query heads it serves
+    b, hkv = kp.shape[0], kp.shape[1]
+
+    def grouped(x):
+        return x.reshape(b, hkv, dims["group"], sp, d).sum(2)[
+            :, :, :s].astype(kp.dtype)
+
+    return dq[:, :, :s], grouped(dk), grouped(dv)
+
+
+banded_flash_attention.defvjp(_banded_fwd, _banded_bwd)
+
+
+def banded_attention_reference(q, k, v, window: int | None = None,
+                               scale: float | None = None):
+    """The masked softmax the banded kernels compute, with the key-value
+    heads repeated; (B, H, S, D) layout. The oracle of their tests."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    pos = jnp.arange(q.shape[2])
+    keep = pos[None, :] <= pos[:, None]
+    if window is not None:
+        keep = keep & (pos[:, None] - pos[None, :] < window)
+    s = jnp.where(keep, s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)

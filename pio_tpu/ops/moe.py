@@ -198,3 +198,349 @@ def moe_ffn_ep(params, x, cfg: MoEConfig, mesh: Mesh, axis: str = "data"):
     }
     xs = jax.device_put(x, NamedSharding(mesh, spec_tok))
     return run(shard_p, xs)
+
+
+# ---------------------------------------------------------------------------
+# One expert-parallel rank's part of a dropless top-k layer
+# ---------------------------------------------------------------------------
+#
+# The layer is told which experts it holds (`held = [lo, hi)` of
+# `n_routed`). It routes every token over ALL the experts (top-k of the
+# softmax, weights optionally normalised over the k), and computes what
+# its own experts add: the tokens' rows are sorted by expert into groups
+# whose sizes are whatever the routing gave (no capacity, nothing
+# dropped), each group padded to whole tiles, and the expert products run
+# as one grouped matrix product over the tiles (`grouped_matmul`, a
+# Pallas kernel; a tile belongs to one expert). The buffer is sized for
+# the most the held experts can be sent, and the kernels pass over the
+# tiles behind the last group (`tiles_used`, prefetched with the tiles'
+# experts): the products' time follows what the router really sent. The
+# row gathers around them still run over the whole buffer (ROADMAP R1 d).
+# What the absent experts would add is left out: on the chips of a
+# deployment the exchange adds it, and on one chip the layer runs without
+# its exchange.
+
+
+@dataclass(frozen=True)
+class HeldExperts:
+    n_routed: int                  # the router's width: every expert
+    top_k: int
+    held: tuple[int, int]          # [lo, hi): the experts this rank holds
+    norm_topk: bool = True         # weights divided by their sum over k
+    tile_rows: int = 512           # rows of one tile of the grouped product
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] - self.held[0]
+
+    def row_capacity(self, n_tokens: int) -> int:
+        """Rows of the sorted buffer: the most the held experts can be
+        sent (every token's k choices all held), each group rounded up
+        to whole tiles and never empty."""
+        tm = self.tile_rows
+        most = n_tokens * min(self.top_k, self.n_held)
+        return -(-most // tm) * tm + self.n_held * tm
+
+
+def route_top_k(logits, top_k: int, norm: bool):
+    """(T, E) float32 router logits -> (expert ids (T, k) int32, weights
+    (T, k) float32): the k largest of the softmax, ties to the lower id."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, ids = jax.lax.top_k(probs, top_k)
+    if norm:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), weights
+
+
+def dispatch_plan(ids, cfg: HeldExperts) -> dict:
+    """Where each (token, choice) goes in the buffer sorted by held
+    expert. -> row_choice (M,): the flat (token, choice) a row holds, or
+    T*k where the row is padding; choice_row (T, k): the row of a choice,
+    or M where its expert is not held; tile_expert (M / tile,): the
+    tiles' experts (the last expert's past the last row); tiles_used
+    (1,): the tiles that hold a group, the rest is padding; counts
+    (n_held,): tokens per held expert; dropped (): choices of held
+    experts that found no row (0 by construction; reported, asserted by
+    the trainer)."""
+    t, k = ids.shape
+    lo, hi = cfg.held
+    n_held, tm = cfg.n_held, cfg.tile_rows
+    n_choices, cap = t * k, cfg.row_capacity(t)
+    flat = ids.reshape(-1)
+    local = jnp.where((flat >= lo) & (flat < hi), flat - lo, n_held)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    sorted_local = local[order]
+    counts = jnp.sum(local[:, None] == jnp.arange(n_held)[None, :],
+                     axis=0, dtype=jnp.int32)
+    padded = jnp.maximum(-(-counts // tm) * tm, tm)
+    pad_end = jnp.cumsum(padded)
+    group_start = jnp.cumsum(counts) - counts
+    g = jnp.minimum(sorted_local, n_held - 1)
+    rank = jnp.arange(n_choices, dtype=jnp.int32) - group_start[g]
+    row_sorted = jnp.where(sorted_local < n_held,
+                           (pad_end - padded)[g] + rank, cap)
+    choice_row = jnp.zeros(n_choices, jnp.int32).at[order].set(
+        row_sorted, unique_indices=True).reshape(t, k)
+    row_choice = jnp.full(cap + 1, n_choices, jnp.int32).at[
+        row_sorted].set(order)[:cap]
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(pad_end, jnp.arange(cap // tm) * tm, side="right"),
+        n_held - 1).astype(jnp.int32)
+    placed = jnp.sum(row_choice < n_choices)
+    return {"row_choice": row_choice, "choice_row": choice_row,
+            "tile_expert": tile_expert,
+            "tiles_used": (pad_end[-1:] // tm).astype(jnp.int32),
+            "counts": counts, "dropped": jnp.sum(counts) - placed}
+
+
+def _tile(dim: int, most: int) -> int:
+    """The largest divisor of `dim` that is a multiple of 128 and at most
+    `most`; the whole of a dimension that has none (tiny test sizes)."""
+    for t in range(most - most % 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def _gmm_kernel(te_ref, used_ref, x_ref, w_ref, o_ref, acc_ref):
+    from jax.experimental import pallas as pl
+
+    kk, n_k = pl.program_id(2), pl.num_programs(2)
+    used = pl.program_id(0) < used_ref[0]
+
+    @pl.when(used & (kk == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(used)
+    def _add():
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[0],
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(used & (kk == n_k - 1))
+    def _emit():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _tgmm_kernel(te_ref, used_ref, x_ref, dy_ref, o_ref):
+    from jax.experimental import pallas as pl
+
+    m = pl.program_id(2)
+    used = m < used_ref[0]
+
+    @pl.when(used & ((m == 0) | (te_ref[jnp.maximum(m - 1, 0)] != te_ref[m])))
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(used)
+    def _add():
+        o_ref[0] += jax.lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def _interpret() -> bool:
+    return jax.devices()[0].platform == "cpu"
+
+
+# A tile behind the last group keeps the block indices of the last step
+# of the last tile that holds one: nothing is fetched for it, nothing
+# computed, and its rows of the result are never written.
+
+def _gmm(x, w, tile_expert, tiles_used, tm: int):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = x.shape
+    n = w.shape[2]
+    tk, tn = _tile(k, 1152), _tile(n, 1024)
+    n_j, n_k = n // tn, k // tk
+
+    def at(i, j, kk, used):
+        """(tile, column block, depth block) the grid step works on."""
+        skip = i >= used[0]
+        return (jnp.where(skip, used[0] - 1, i), jnp.where(skip, n_j - 1, j),
+                jnp.where(skip, n_k - 1, kk))
+
+    def x_map(i, j, kk, te, used):
+        i, _, kk = at(i, j, kk, used)
+        return i, kk
+
+    def w_map(i, j, kk, te, used):
+        i, j, kk = at(i, j, kk, used)
+        return te[i], kk, j
+
+    def o_map(i, j, kk, te, used):
+        i, j, _ = at(i, j, kk, used)
+        return i, j
+
+    return pl.pallas_call(
+        _gmm_kernel, name="moe_gmm",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(m // tm, n_j, n_k),
+            in_specs=[pl.BlockSpec((tm, tk), x_map),
+                      pl.BlockSpec((1, tk, tn), w_map)],
+            out_specs=pl.BlockSpec((tm, tn), o_map),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+        interpret=_interpret(),
+    )(tile_expert, tiles_used, x, w)
+
+
+def _tgmm(x, dy, tile_expert, tiles_used, n_groups: int, tm: int):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = x.shape
+    n = dy.shape[1]
+    tk, tn = _tile(k, 768), _tile(n, 1024)
+
+    def tile(mm, used):
+        return jnp.minimum(mm, used[0] - 1)
+
+    return pl.pallas_call(
+        _tgmm_kernel, name="moe_tgmm",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(k // tk, n // tn, m // tm),
+            in_specs=[
+                pl.BlockSpec((tm, tk),
+                             lambda kk, j, mm, te, used: (tile(mm, used), kk)),
+                pl.BlockSpec((tm, tn),
+                             lambda kk, j, mm, te, used: (tile(mm, used), j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, tk, tn),
+                lambda kk, j, mm, te, used: (te[tile(mm, used)], kk, j))),
+        out_shape=jax.ShapeDtypeStruct((n_groups, k, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+        interpret=_interpret(),
+    )(tile_expert, tiles_used, x, dy)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul(x, w, tile_expert, tiles_used, tile_rows: int):
+    """Rows sorted by expert, times their expert's matrix. x: (M, K),
+    every `tile_rows` rows of one expert, padding rows zero; w: (G, K,
+    N); tile_expert: (M / tile_rows,) int32, not decreasing; tiles_used:
+    (1,) int32, the tiles that hold a group. The tiles behind them are
+    passed over, so the product's time follows the groups and not the
+    buffer, and **their rows of the result are undefined**: nothing may
+    read them (the layer's moves gather the rows of choices only). Every
+    expert owns at least one tile (dispatch_plan sees to it), which is
+    what lets the weight gradient write every expert's block. -> (M, N)
+    in x's dtype, accumulated in float32."""
+    return _gmm(x, w, tile_expert, tiles_used, tile_rows)
+
+
+def _gmm_fwd(x, w, tile_expert, tiles_used, tile_rows):
+    return (_gmm(x, w, tile_expert, tiles_used, tile_rows),
+            (x, w, tile_expert, tiles_used))
+
+
+def _gmm_bwd(tile_rows, res, dy):
+    x, w, tile_expert, tiles_used = res
+    dx = _gmm(dy, jnp.swapaxes(w, 1, 2), tile_expert, tiles_used, tile_rows)
+    dw = _tgmm(x, dy, tile_expert, tiles_used, w.shape[0], tile_rows)
+    return dx, dw.astype(w.dtype), None, None
+
+
+grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _rows_of(x, row_choice, k: int):
+    """(T, d) tokens -> (M, d) rows: row r holds token row_choice[r] // k,
+    zeros where it is padding."""
+    n_choices = x.shape[0] * k
+    rows = x[jnp.minimum(row_choice, n_choices - 1) // k]
+    return jnp.where((row_choice < n_choices)[:, None], rows, 0)
+
+
+def _tokens_of(y, choice_row):
+    """(M, d) rows -> (T, d): a token's sum over its choices' rows, in
+    float32; a choice whose expert is not held adds nothing."""
+    m = y.shape[0]
+    rows = y[jnp.minimum(choice_row, m - 1)]                # (T, k, d)
+    rows = jnp.where((choice_row < m)[..., None], rows, 0)
+    return jnp.sum(rows.astype(jnp.float32), axis=1)
+
+
+# the two moves are each other's transpose: written out, so the backward
+# pass gathers rows too instead of scatter-adding them
+@jax.custom_vjp
+def rows_from_tokens(x, row_choice, choice_row):
+    return _rows_of(x, row_choice, choice_row.shape[1])
+
+
+def _rft_fwd(x, row_choice, choice_row):
+    return rows_from_tokens(x, row_choice, choice_row), (
+        row_choice, choice_row)
+
+
+def _rft_bwd(res, g):
+    _, choice_row = res
+    return _tokens_of(g, choice_row).astype(g.dtype), None, None
+
+
+rows_from_tokens.defvjp(_rft_fwd, _rft_bwd)
+
+
+@jax.custom_vjp
+def tokens_from_rows(y, row_choice, choice_row):
+    return _tokens_of(y, choice_row)
+
+
+def _tfr_fwd(y, row_choice, choice_row):
+    return tokens_from_rows(y, row_choice, choice_row), (
+        row_choice, choice_row, jnp.zeros((), y.dtype))
+
+
+def _tfr_bwd(res, g):
+    row_choice, choice_row, like = res
+    return (_rows_of(g.astype(like.dtype), row_choice,
+                     choice_row.shape[1]), None, None)
+
+
+tokens_from_rows.defvjp(_tfr_fwd, _tfr_bwd)
+
+
+def held_moe_ffn(params, x, cfg: HeldExperts, compute_dtype=jnp.bfloat16):
+    """This rank's part of the layer for (T, d) tokens: `sum over the
+    held e in top-k(x) of w_e * W_down,e(silu(x W_gate,e) * (x W_up,e))`.
+    params: router (d, n_routed), w_gate / w_up (n_held, d, f), w_down
+    (n_held, f, d). Products take `compute_dtype` operands and accumulate
+    in float32. -> (y (T, d) float32, {"counts": tokens per held expert,
+    "dropped": 0})."""
+    k, tm = cfg.top_k, cfg.tile_rows
+    xc = x.astype(compute_dtype)
+    with jax.named_scope("seq.moe.route"):
+        logits = jnp.dot(xc, params["router"].astype(compute_dtype),
+                         preferred_element_type=jnp.float32)
+        ids, weights = route_top_k(logits, k, cfg.norm_topk)
+        plan = dispatch_plan(ids, cfg)
+        row_choice, choice_row = plan["row_choice"], plan["choice_row"]
+        n_choices = ids.size
+        w_row = jnp.where(
+            row_choice < n_choices,
+            weights.reshape(-1)[jnp.minimum(row_choice, n_choices - 1)], 0.0)
+        rows = rows_from_tokens(xc, row_choice, choice_row)
+    with jax.named_scope("seq.moe.gmm"):
+        f = params["w_gate"].shape[2]
+        w_in = jnp.concatenate([params["w_gate"].astype(compute_dtype),
+                                params["w_up"].astype(compute_dtype)], axis=2)
+        tiles = plan["tile_expert"], plan["tiles_used"]
+        gate_up = grouped_matmul(rows, w_in, *tiles, tm)
+        gate = gate_up[:, :f].astype(jnp.float32)
+        hidden = (jax.nn.silu(gate) * gate_up[:, f:].astype(jnp.float32)
+                  ).astype(compute_dtype)
+        out_rows = grouped_matmul(
+            hidden, params["w_down"].astype(compute_dtype), *tiles, tm)
+    with jax.named_scope("seq.moe.combine"):
+        weighted = (out_rows.astype(jnp.float32) * w_row[:, None]
+                    ).astype(compute_dtype)
+        y = tokens_from_rows(weighted, row_choice, choice_row)
+    return y, {"counts": plan["counts"], "dropped": plan["dropped"]}

@@ -86,6 +86,25 @@ def frame(payload: bytes, magic: bytes = MAGIC) -> bytes:
     return _HEADER.pack(magic, crc32c(payload), len(payload)) + payload
 
 
+HEADER_BYTES = _HEADER.size
+_CRC_CHUNK = 1 << 20
+
+
+def frame_in_place(buf, magic: bytes = MAGIC) -> bytes:
+    """``frame`` for a payload written into an ``io.BytesIO`` behind
+    ``HEADER_BYTES`` bytes of room: the header is filled in where it
+    lies and the buffer's own bytes are handed over, so a model of
+    gigabytes is not copied once more to put 17 bytes before it. (The
+    checksum is taken over small copies: the C routine reads ``bytes``
+    only.)"""
+    with buf.getbuffer() as view:
+        crc = 0
+        for at in range(HEADER_BYTES, len(view), _CRC_CHUNK):
+            crc = crc32c(bytes(view[at:at + _CRC_CHUNK]), crc)
+        _HEADER.pack_into(view, 0, magic, crc, len(view) - HEADER_BYTES)
+    return buf.getvalue()
+
+
 def is_framed(blob: bytes, magic: bytes = MAGIC) -> bool:
     return blob[:len(magic)] == magic
 

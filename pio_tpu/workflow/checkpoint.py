@@ -23,7 +23,12 @@ import numpy as np
 
 from pio_tpu.data.bimap import EntityIdIndex
 from pio_tpu.utils import tracing
-from pio_tpu.utils.durable import ModelIntegrityError, frame, unframe
+from pio_tpu.utils.durable import (
+    HEADER_BYTES,
+    ModelIntegrityError,
+    frame_in_place,
+    unframe,
+)
 
 __all__ = [
     "ModelIntegrityError", "host_copy", "models_from_bytes",
@@ -54,14 +59,15 @@ def models_to_bytes(models: list[Any]) -> bytes:
             x.nbytes for x in leaves if isinstance(x, np.ndarray))
     with tracing.span("persist.pickle") as sp:
         buf = io.BytesIO()
+        buf.write(bytes(HEADER_BYTES))      # the frame's header goes here
         pickle.dump(on_host, buf, protocol=5)
-        sp["bytes"] = buf.tell()
+        sp["bytes"] = buf.tell() - HEADER_BYTES
         sp["ids"] = sum(
             len(x) for m in on_host
             for x in getattr(m, "__dict__", {}).values()
             if isinstance(x, EntityIdIndex))
     with tracing.span("persist.frame") as sp:
-        blob = frame(buf.getvalue())
+        blob = frame_in_place(buf)
         sp["bytes"] = len(blob)
     return blob
 

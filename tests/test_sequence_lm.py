@@ -1,0 +1,272 @@
+"""models/seq_blocks.py, the sequence engine's block stack, against the
+plain reference (benchmark/reference/sequence_lm.py) on seeded weights at
+a small size: logits, loss, every parameter's gradient; the rotary
+frequencies; and the engine's own path (SequenceAlgorithm.train ->
+train_sequence_model -> persist -> batch_predict)."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import sequence_lm as reference
+from pio_tpu.data.bimap import EntityIdIndex
+from pio_tpu.models import seq_blocks
+from pio_tpu.models.sequence import (
+    SequenceAlgorithm,
+    SequenceData,
+    SequenceParams,
+)
+from pio_tpu.obs import profile
+
+CFG = {
+    "hidden_size": 32, "num_hidden_layers": 4,
+    "layer_types": ["sliding_attention"] * 3 + ["full_attention"] + ["x"] * 4,
+    "mlp_layer_types": ["sparse"] * 8,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 8,
+    "sliding_window": 12,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_type": "yarn", "rope_theta": 10000, "factor": 4,
+            "original_max_position_embeddings": 16, "beta_fast": 8,
+            "beta_slow": 1, "attention_factor": 1.1386},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000}},
+    "rms_norm_eps": 1e-6, "moe_intermediate_size": 16, "num_experts": 4,
+    "num_experts_routed": 8, "experts_held": [2, 6],
+    "num_experts_per_tok": 3, "norm_topk_prob": True, "vocab_size": 50,
+    "hidden_act": "silu", "attention_bias": False,
+    "tie_word_embeddings": False, "initializer_range": 0.3,
+}
+SPEC = seq_blocks.BlockSpec.parse(CFG)
+
+
+def _small(mp):
+    """Float32 operands, so the comparison is of the mathematics, and
+    blocks small enough that a 40-token history spans several."""
+    mp.setattr(seq_blocks, "COMPUTE", jnp.float32)
+    mp.setattr(seq_blocks, "ATTN_BLOCK", 16)
+    mp.setattr(seq_blocks, "MOE_TILE", 8)
+    mp.setattr(seq_blocks, "LOSS_CHUNK", 32)
+
+
+@pytest.fixture(autouse=True)
+def small_blocks(monkeypatch):
+    _small(monkeypatch)
+
+
+@pytest.fixture(scope="module")
+def case():
+    params = seq_blocks.init_params(SPEC, 3)
+    tokens = jnp.asarray(np.random.default_rng(0).integers(1, 50, (2, 41)),
+                         jnp.int32)
+    with pytest.MonkeyPatch.context() as mp:
+        _small(mp)
+        (loss, counters), grads = jax.value_and_grad(
+            seq_blocks.loss_and_counters, has_aux=True)(params, tokens, SPEC)
+    ref_loss, ref_grads = jax.value_and_grad(reference.loss)(
+        params, tokens, CFG)
+    return {"params": params, "tokens": tokens, "loss": loss,
+            "counters": counters, "grads": grads, "ref_loss": ref_loss,
+            "ref_grads": ref_grads}
+
+
+def test_loss_equals_the_references(case):
+    assert abs(float(case["loss"]) - float(case["ref_loss"])) < 2e-5
+    counts = np.asarray(case["counters"]["counts"])
+    assert counts.shape == (4, 2, 4)           # layers, histories, held
+    assert int(np.asarray(case["counters"]["dropped"]).sum()) == 0
+
+
+def _leaf_names():
+    shapes = seq_blocks.param_shapes(SPEC)
+    paths = jax.tree_util.tree_leaves_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    return [jax.tree_util.keystr(p) for p, _ in paths]
+
+
+@pytest.mark.parametrize("leaf", _leaf_names())
+def test_every_parameters_gradient_equals_the_references(case, leaf):
+    got = dict((jax.tree_util.keystr(p), g) for p, g in
+               jax.tree_util.tree_leaves_with_path(case["grads"]))[leaf]
+    want = dict((jax.tree_util.keystr(p), g) for p, g in
+                jax.tree_util.tree_leaves_with_path(case["ref_grads"]))[leaf]
+    err = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    assert err < 5e-5, (leaf, err)
+
+
+def test_last_position_logits_equal_the_references(case):
+    ids = case["tokens"][:, :-1]
+    want = reference.logits(case["params"], ids, CFG)[:, -1]
+    got = seq_blocks.last_logits(case["params"], ids, SPEC)
+    np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("fault", [
+    {"top_k": 2}, {"norm_topk": False}, {"window": 13},
+    {"kv_head": "i//4"}, {"rope_full": "default"}])
+def test_a_faulty_reference_differs(case, fault):
+    """The reference's faults are faults: each moves the loss."""
+    faulty = float(reference.loss(case["params"], case["tokens"], CFG, fault))
+    assert abs(faulty - float(case["ref_loss"])) > 1e-3
+
+
+def _yarn_numpy(rope, dim):
+    """transformers' _compute_yarn_parameters, transcribed."""
+    base, factor = rope["rope_theta"], rope["factor"]
+    orig = rope["original_max_position_embeddings"]
+    pos_freqs = base ** (np.arange(0, dim, 2).astype(np.float64) / dim)
+    extrapolation, interpolation = 1.0 / pos_freqs, 1.0 / (factor * pos_freqs)
+
+    def correction_dim(num_rotations):
+        return (dim * math.log(orig / (num_rotations * 2 * math.pi))
+                ) / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(rope["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(rope["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0, 1)
+    extrapolation_factor = 1 - ramp
+    return (interpolation * (1 - extrapolation_factor)
+            + extrapolation * extrapolation_factor)
+
+
+@pytest.mark.parametrize("rope,dim", [
+    ({"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+      "original_max_position_embeddings": 8192, "beta_fast": 32,
+      "beta_slow": 1, "attention_factor": 1.2772588722239782}, 128),
+    (CFG["rope_parameters"]["full_attention"], 8),
+    ({"rope_type": "yarn", "rope_theta": 10000, "factor": 2,
+      "original_max_position_embeddings": 64, "beta_fast": 4,
+      "beta_slow": 4}, 16),
+])
+def test_yarn_frequencies_against_a_numpy_transcription(rope, dim):
+    inv, scale = seq_blocks.rope_inv_freq(rope, dim)
+    np.testing.assert_allclose(inv, _yarn_numpy(rope, dim), rtol=1e-12)
+    want = rope.get("attention_factor", 0.1 * math.log(rope["factor"]) + 1)
+    assert scale == pytest.approx(want)
+    cos, sin = reference.rope_tables(rope, dim, 9)
+    angle = np.arange(9)[:, None] * inv[None]
+    np.testing.assert_allclose(cos, np.cos(angle) * scale, atol=1e-6)
+    np.testing.assert_allclose(sin, np.sin(angle) * scale, atol=1e-6)
+    # high-frequency pairs keep their frequency, low ones are interpolated
+    plain, _ = seq_blocks.rope_inv_freq(
+        {"rope_type": "default", "rope_theta": rope["rope_theta"]}, dim)
+    assert inv[0] == pytest.approx(plain[0])
+    assert inv[-1] == pytest.approx(plain[-1] / rope["factor"])
+
+
+@pytest.mark.parametrize("change,match", [
+    ({"hidden_act": "gelu"}, "hidden_act"),
+    ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+    ({"attention_bias": True}, "attention_bias"),
+    ({"mlp_layer_types": ["dense"] * 8}, "mlp_layer_types"),
+    ({"layer_types": ["linear_attention"] * 8}, "layer_types"),
+    ({"experts_held": [0, 3]}, "experts_held"),
+])
+def test_what_the_stack_does_not_compute_is_refused(change, match):
+    with pytest.raises(ValueError, match=match):
+        seq_blocks.BlockSpec.parse({**CFG, **change})
+
+
+def test_epoch_order_takes_every_history_once():
+    order = seq_blocks.epoch_order(12, 6, 2, seed=7)
+    assert sorted(order.reshape(-1).tolist()) == list(range(12))
+    assert (order == seq_blocks.epoch_order(12, 6, 2, seed=7)).all()
+    again = seq_blocks.epoch_order(12, 9, 2, seed=7)   # begins again
+    assert (again[6:].reshape(-1) == order.reshape(-1)[:6]).all()
+
+
+@pytest.mark.parametrize("embedding", [None, 1.0])
+def test_the_embeddings_rows_take_their_own_range(embedding):
+    """`embedding_initializer_range` scales the embedding's rows alone,
+    and without the key they take `initializer_range` like the rest."""
+    wide = dict(CFG, hidden_size=64, vocab_size=512)
+    if embedding is not None:
+        wide["embedding_initializer_range"] = embedding
+    params = seq_blocks.init_params(seq_blocks.BlockSpec.parse(wide), 5)
+    want = embedding or CFG["initializer_range"]
+    assert abs(float(jnp.std(params["embed"])) / want - 1) < 0.05
+    for name in ("head", "layers"):
+        leaf = params[name] if name == "head" else params[name][0]["wq"]
+        assert abs(float(jnp.std(leaf)) / CFG["initializer_range"] - 1) < 0.05
+
+
+def test_the_step_program_does_not_hold_the_step_count():
+    """Jobs of 2 and of 5 steps run one compiled step: the step takes a
+    batch, not the job's histories."""
+    _, step = seq_blocks.make_train_step(SPEC, 0.02)
+    for steps in (2, 5):
+        p = SequenceParams(max_len=41, batch_size=2, steps=steps,
+                           learning_rate=0.02, seed=3, block_spec=CFG)
+        seq_blocks.train_lm(_data().seqs, p)
+    assert step._cache_size() == 1
+
+
+def test_params_with_a_specification_stay_hashable():
+    p = SequenceParams(block_spec=CFG)
+    assert isinstance(p.block_spec, str) and hash(p) == hash(
+        SequenceParams(block_spec=dict(CFG)))
+    assert seq_blocks.BlockSpec.parse(p.block_spec) == SPEC
+    assert SequenceParams().block_spec is None       # the toy preset
+
+
+def _data(n=8, length=41, seed=5):
+    seqs = np.random.default_rng(seed).integers(1, 50, (n, length)).astype(
+        np.int32)
+    return SequenceData(seqs, EntityIdIndex([f"u{i}" for i in range(n)]),
+                        EntityIdIndex([f"i{i}" for i in range(1, 50)]))
+
+
+def test_the_engine_trains_persists_and_serves_the_stack():
+    from pio_tpu.workflow.checkpoint import models_from_bytes, models_to_bytes
+
+    p = SequenceParams(max_len=41, batch_size=2, steps=6, learning_rate=0.02,
+                       seed=11, block_spec=CFG)
+    algo = SequenceAlgorithm(p)
+    model = algo.train(None, _data())
+    want = jax.tree_util.tree_map(
+        lambda s: s, seq_blocks.param_shapes(SPEC),
+        is_leaf=lambda x: isinstance(x, tuple))
+    got = jax.tree_util.tree_map(lambda x: x.shape, model.params)
+    assert got == want
+    loaded = models_from_bytes(models_to_bytes([model]))[0]
+    out = algo.batch_predict(loaded, [{"user": "u1", "num": 5},
+                                      {"user": "nobody"}])
+    assert len(out[0]["itemScores"]) == 5 and out[1]["itemScores"] == []
+    # the seeded start is the reference's step-0 loss, and training moved
+    tokens = jnp.asarray(_data().seqs[seq_blocks.epoch_order(8, 6, 2, 11)[0]])
+    start = seq_blocks.init_params(SPEC, 11)
+    first = float(reference.loss(start, tokens, CFG))
+    after = float(reference.loss(
+        jax.tree_util.tree_map(jnp.asarray, model.params), tokens, CFG))
+    assert after < first - 0.05
+
+
+def test_a_history_with_padding_is_refused():
+    data = _data()
+    data.seqs[0, 0] = 0
+    p = SequenceParams(max_len=41, batch_size=2, steps=1, block_spec=CFG)
+    with pytest.raises(ValueError, match="PAD"):
+        SequenceAlgorithm(p).train(None, data)
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(step)/jvp(seq.attn.proj)/dot_general", "seq.attn.proj"),
+    ("jit(step)/transpose(jvp(seq.head_loss))/while/body/dot_general",
+     "seq.head_loss"),
+    ("jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+     "rematted_computation/seq.moe.route/jit(searchsorted)/jit(step)/"
+     "transpose(jvp())/while/body/closed_call/checkpoint/"
+     "rematted_computation/seq.moe.route/sort", "seq.moe.route"),
+    ("jit(step)/seq.optimizer/mul", "seq.optimizer"),
+    ("jit(_train_jit)/while/body/als.user/als.gather/dot_general",
+     "als.user/als.gather"),
+    ("jit(f)/while/body/dot_general", None),
+    ("jit(f)/my.seq.thing/add", None),
+])
+def test_profile_joins_the_stacks_scopes(op_name, scope):
+    assert profile.scope_of_op_name(op_name) == scope

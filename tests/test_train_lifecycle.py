@@ -510,6 +510,21 @@ def test_resume_uses_recorded_checkpoint_dir(tmp_path):
     assert not os.path.isdir(wrong)
 
 
+@pytest.mark.parametrize("payload", [b"", b"x", bytes(range(256)) * 9000])
+def test_frame_in_place_is_frame(payload):
+    """The header filled in behind the payload's back gives the bytes
+    `frame` gives (a payload over the checksum's chunk among them)."""
+    import io
+
+    from pio_tpu.utils.durable import HEADER_BYTES, frame_in_place
+
+    buf = io.BytesIO()
+    buf.write(bytes(HEADER_BYTES))
+    buf.write(payload)
+    blob = frame_in_place(buf)
+    assert blob == frame(payload) and unframe(blob) == payload
+
+
 def test_durable_write_no_double_frame(tmp_path):
     """An already content-framed payload (models_to_bytes output) is
     written verbatim — no second checksum pass — and round-trips
