@@ -99,8 +99,10 @@ def main() -> int:
         print(json.dumps(row), flush=True)
 
     # -- ALS accumulation kernels, in composition ---------------------------
-    # (the gather kernels, lane-packed A and the fused kernel had their
-    # cells here until PR 28: ops/als_pallas.py's header has the verdicts)
+    # (the gather kernels and the fused kernel had their cells here until
+    # PR 28: ops/als_pallas.py's header has the verdicts. Since PR 32 a
+    # half-sweep at this rank also runs `pack_flush` and `packed_matvec`:
+    # a side that CG solves behind a flush kernel holds A lane-packed)
     @functools.cache
     def xla_users():
         return half(by_user, fac_i, N_USERS, fac_u, accum="carry")

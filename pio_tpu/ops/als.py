@@ -12,9 +12,10 @@ TPU formulation is built around three hardware facts measured on v5e:
    work with O(nnz*k) traffic; how the slots' blocks are summed into
    rows is `ALSParams.accum` (one place decides: `resolved_accum`);
  * the solve is short warm-started Jacobi-CG by default: XLA's batched
-   Cholesky does not use the MXU, while CG is pure batched matvecs (the
-   CG phase is 1.0 s of a 6.5 s job at the ML-20M shape: PERF.md section
-   5). Quality at the auto cap max(16, rank//4) is at parity or better
+   Cholesky does not use the MXU, while CG is pure batched matvecs, on a
+   lane-packed A behind the flush kernel (`_a_pack`; the CG phase is
+   0.44 s of a 4.55 s job at the ML-20M shape: PERF.md section 5).
+   Quality at the auto cap max(16, rank//4) is at parity or better
    (eval/RMSE_PARITY.md: the inexact inner solve early-stops the per-row
    overfit that exact ALS commits to). cg_iters=0 selects the exact
    Cholesky when bit-exactness matters;
@@ -367,7 +368,7 @@ def _chunk_blocks(src, i_c, v_c, l_c, implicit: bool, alpha: float):
 def _normal_equations(layout, other_factors, n_self, implicit: bool,
                       alpha: float, chunk_slots: int,
                       bf16_gather: bool = False, accum: str = "carry",
-                      group_slots: int = GROUP_SLOTS):
+                      group_slots: int = GROUP_SLOTS, wide: bool = False):
     """Accumulate per-row normal equations A (n_self,k,k), b (n_self,k).
 
     Slots sharing a row (rows wider than `width`) sum into the same row
@@ -381,7 +382,9 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
     outputs (`group_slots`, capped by bytes: a bounded (group,k,k) temp)
     and differ in what folds a group into A: "stacked" one sorted
     scatter-add; "hybrid" / "stream" the Pallas segment-flush kernel
-    (ops/als_pallas.py), which writes each row of A once."""
+    (ops/als_pallas.py), which writes each row of A once; under those
+    two `wide` hands A over as the kernel wrote it, (n_self + 1, k, lane),
+    for `als_pallas.pack_flush`."""
     rows, idx, val, lens = layout
     k = other_factors.shape[1]
     S, W = idx.shape
@@ -452,7 +455,7 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
     if accum != "stacked":
         return als_pallas.segment_flush(
             rows, n_self, k, chunk_slots, group_blocks(),
-            overlap=(accum == "stream"))
+            overlap=(accum == "stream"), wide=wide)
     with jax.named_scope("als.blocks"):
         A = jnp.zeros((n_self, k, k), dtype=jnp.float32)
         b = jnp.zeros((n_self, k), dtype=jnp.float32)
@@ -470,22 +473,31 @@ def _normal_equations(layout, other_factors, n_self, implicit: bool,
     return A, b
 
 
-def _cg_solve(A, b, x0, n_iter: int):
+def _cg_solve(A, b, x0, n_iter: int, diag=None):
     """Batched Jacobi-preconditioned conjugate gradient for SPD systems.
 
     ALS is block coordinate descent, so the inexact inner solve (relative
     residual ~1e-4 at 24 iters on k=64) does not change the fixed point it
     converges to; warm-starting from the previous sweep's factors keeps
     later sweeps cheap.
+
+    A is (n, k, k); or, with `diag` given, the lane-packed form and the
+    diagonal that `als_pallas.pack_flush` wrote, which the product
+    kernel reads as it lies: the same sums over 1/pack of the bytes.
     """
-    dinv = 1.0 / jnp.diagonal(A, axis1=1, axis2=2)
+    if diag is not None:
+        def mv(x):
+            return als_pallas.packed_matvec(A, x)
+    else:
+        diag = jnp.diagonal(A, axis1=1, axis2=2)
 
-    def mv(x):
-        return jnp.einsum(
-            "bij,bj->bi", A, x, preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGH,
-        )
+        def mv(x):
+            return jnp.einsum(
+                "bij,bj->bi", A, x, preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGH,
+            )
 
+    dinv = 1.0 / diag
     x = x0
     r = b - mv(x)
     z = r * dinv
@@ -528,25 +540,44 @@ def _chol_solve(A, b):
     return jax.scipy.linalg.cho_solve(chol, b)
 
 
+def _a_pack(k: int, accum: str, cg_iters: int) -> int:
+    """Matrix rows a lane row of A as the solve holds it: a side that CG
+    solves behind the flush kernel keeps A lane-packed where the rank
+    leaves lanes empty; 1 (not packed) on every other path."""
+    if cg_iters > 0 and accum in ("hybrid", "stream"):
+        return als_pallas.pack_factor(k)
+    return 1
+
+
 def _solve_factors(layout, other_factors, n_self, reg, implicit, alpha,
                    chunk_slots, x0=None, cg_iters: int = 0,
                    bf16_gather: bool = False, accum: str = "carry",
                    yty=None):
+    k = other_factors.shape[1]
+    pack = _a_pack(k, accum, cg_iters)
     A, b = _normal_equations(
         layout, other_factors, n_self, implicit, alpha, chunk_slots,
-        bf16_gather=bf16_gather, accum=accum,
+        bf16_gather=bf16_gather, accum=accum, wide=pack > 1,
     )
-    k = other_factors.shape[1]
+    diag = None
     with jax.named_scope("als.gram"):
         eye = jnp.eye(k, dtype=jnp.float32)
-        if implicit:
-            A = A + _shared_yty(other_factors, yty)[None, :, :]
-        A = A + reg * eye[None, :, :]
+        if pack > 1:
+            # the pass that adds the Gram term writes the packed form
+            # and the diagonal; the flush's wide buffer dies here
+            gram = reg * eye
+            if implicit:
+                gram = _shared_yty(other_factors, yty) + gram
+            A, diag = als_pallas.pack_flush(A, gram, n_self, pack)
+        else:
+            if implicit:
+                A = A + _shared_yty(other_factors, yty)[None, :, :]
+            A = A + reg * eye[None, :, :]
     if cg_iters > 0:
         with jax.named_scope("als.cg"):
             if x0 is None:
                 x0 = jnp.zeros_like(b)
-            return _cg_solve(A, b, x0, cg_iters)
+            return _cg_solve(A, b, x0, cg_iters, diag)
     return _chol_solve(A, b)
 
 
@@ -599,15 +630,24 @@ def _cg_matvecs(params: ALSParams, cg_u: int, cg_i: int) -> int:
 
 
 def _dispatch_labels(sp: dict, nnz_u: int, nnz_i: int, slots, params,
-                     cg_u: int, cg_i: int) -> None:
+                     cg_u: int, cg_i: int, n_u: int, n_i: int) -> None:
     """The counts at the `als.dispatch` boundary: what the program about
-    to run was sized for (`slots` from `_slot_counts`), and how full the
-    ratings of one side (of its fullest block, when sharded) leave it."""
+    to run was sized for (`slots` from `_slot_counts`), how full the
+    ratings of one side (of its fullest block, when sharded) leave it,
+    and how the solve holds A: `a_pack` matrix rows a lane row (1 = not
+    packed) and the bytes of A a side of `n_u` / `n_i` rows (a block's,
+    when sharded), as tiled on the chip."""
     cs, su, si = slots
+    k, accum = params.rank, params.resolved_accum()
+    packs = [_a_pack(k, accum, cg) for cg in (cg_u, cg_i)]
+    lane = als_pallas._lane_for(k)
+    a_bytes = [n * k * lane * 4 // pack
+               for n, pack in zip((n_u, n_i), packs)]
     sp.update(cs=cs, su=su, si=si,
               fill_u=round(nnz_u / (su * params.width), 4),
               fill_i=round(nnz_i / (si * params.width), 4),
-              cg_matvecs=_cg_matvecs(params, cg_u, cg_i))
+              cg_matvecs=_cg_matvecs(params, cg_u, cg_i),
+              a_pack=max(packs), a_bytes_u=a_bytes[0], a_bytes_i=a_bytes[1])
 
 
 def _build_layouts(u, i, v, n_users: int, n_items: int, params: ALSParams):
@@ -894,7 +934,7 @@ def als_train(
             sp, len(values), len(values),
             _slot_counts(u.shape[0], u.shape[0], n_users, n_items, params),
             params, params.resolved_cg_iters(n_users),
-            params.resolved_cg_iters(n_items))
+            params.resolved_cg_iters(n_items), n_users, n_items)
         users, items = _train_jit(
             u, i, v, n_users, n_items, params, user0, item0
         )
@@ -1352,7 +1392,7 @@ def als_train_sharded(
     with tracing.span("als.dispatch") as sp:
         _dispatch_labels(sp, max(u_counts), max(i_counts), (cs, su, si),
                          params, _sharded_cg_iters(params, ub),
-                         _sharded_cg_iters(params, ib))
+                         _sharded_cg_iters(params, ib), ub, ib)
         users, items = run(*placed)
         blocks_on = sorted(s.device.id for s in users.addressable_shards)
         users = users.reshape(-1, params.rank)[:n_users]

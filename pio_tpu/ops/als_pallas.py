@@ -1,5 +1,5 @@
-"""Pallas TPU kernel for the ALS normal-equation accumulation: the
-segment flush.
+"""Pallas TPU kernels for the ALS normal equations: the segment flush
+that sums them, and the lane-packed form the CG solve holds them in.
 
 XLA builds the per-slot blocks (a_blk (k,k), b_blk (k,)) with batched MXU
 matmuls (`ops/als.py _chunk_blocks`); what is left is summing the blocks of
@@ -22,25 +22,66 @@ DMA where it starts it (`accum="hybrid"`, what `auto` runs on a TPU);
 at the next flush that wants the slot (`accum="stream"`), the same adds in
 the same order. Which is faster on the chip is ROADMAP S1d's to measure.
 
-The record (TPU v5e, jax 0.9.0 / libtpu 0.0.34, PR 21,
-`eval/kernel_parity.py` at the ML-20M factor shapes): both kernels, and a
-fused variant that made the blocks in the kernel, compiled through Mosaic
-and matched the XLA `carry` path through a half-sweep to 1-2e-4 relative.
-`hybrid` is the path of every benchmark cell (PERF.md). The rules they are
-built to: accumulators and outputs are LANE-wide (a (K,K) row slice of a
-lane-padded HBM memref is refused); row ids arrive as (1,1,chunk) SMEM
-blocks (1-d s32 operands tile T(1024), Mosaic wants T(128)); second-minor
-block dims divide 8; the stack of double-buffered blocks and scratch stays
-under the 16 MB of scoped VMEM.
+Lane-packed A (PR 32). A row of A at rank 64 is 64 x 128 floats as the
+flush writes it and as XLA tiles an f32[n,64,64]: half of every lane row
+is padding, and a CG product that runs at the memory's rate reads it all.
+Where `pack_factor(k)` > 1 a side that CG solves holds A as
+(n, k/pack, lane) instead: lane row r holds matrix rows r, r + k/pack, ...
+side by side (`pack_rows`), A's k*k floats and nothing else. `pack_flush`
+is the pass that adds the Gram term: it reads the flush's wide buffer once
+and writes the packed rows and their diagonal; `packed_matvec` is the CG
+product on that form, float32 multiplies and adds (1.4e-7 of the float64
+product where the einsum at Precision.HIGH it replaces has 1.4e-5).
 
-Refused by Mosaic in that run and removed in PR 28 (the fused variant went
-with them: no product path could select it); the git history has the
+The record (TPU v5e, jax 0.9.0 / libtpu 0.0.34, PR 21,
+`eval/kernel_parity.py` at the ML-20M factor shapes): both flush kernels,
+and a fused variant that made the blocks in the kernel, compiled through
+Mosaic and matched the XLA `carry` path through a half-sweep to 1-2e-4
+relative. `hybrid` is the path of every benchmark cell (PERF.md). The
+rules they are built to: accumulators and outputs are LANE-wide (a (K,K)
+row slice of a lane-padded HBM memref is refused); row ids arrive as
+(1,1,chunk) SMEM blocks (1-d s32 operands tile T(1024), Mosaic wants
+T(128)); second-minor block dims divide 8; the stack of double-buffered
+blocks and scratch stays under the 16 MB of scoped VMEM.
+
+The record of PR 32 (the same chip and versions; 138,493 rows at rank 64,
+2.27 GB packed; ms a call alone, 20 calls; PERF.md section 6 has the job):
+
+ * `eval/kernel_parity.py`: a half-sweep through flush, pack and product,
+   compiled, against the XLA `carry` path: 2.5e-4 relative on the user
+   side and 4.8e-5 on the item side, `hybrid` and `stream` equal;
+
+ * the product, every form compiled through Mosaic. Masked whole-lane sums
+   (`_matvec_kernel`): **3.35 ms** alone, 3.21 ms in the train program
+   (the einsum on the padded A it replaces: 5.4 ms there, 17.4 alone).
+   Sums over lane slices [:k], [k:]: 3.64. The same in a loop over 8 rows
+   at a time: 7.68. Lane sums by a 0/1 selection matrix on the MXU: 5.04
+   at Precision.HIGHEST, 3.57 with the product split into three bfloat16
+   parts by hand. The symmetric form (column sums, x transposed): 13.2,
+   its 128 rows unrolled because a dynamic lane index is refused: "cannot
+   statically prove that index in dimension 1 is a multiple of 128".
+   Plain XLA on the packed form: 6.82;
+ * blocks: 128 rows (2 MB of A). 512 rows: "Scoped allocation with size
+   17.00M and limit 16.00M exceeded scoped vmem limit by 1.00M"; with
+   `vmem_limit_bytes` raised, 128 to 1,024 rows all read 3.55-3.58 ms;
+ * the pack. XLA makes four passes of it however it is written (the sum
+   materialised, two slices, a concatenate: 5 formulations, compiled for
+   the chip), so it is a kernel: 10.2 ms for 4.54 GB read and 2.27 GB
+   written, against 13.9 ms for the Gram add it replaces in the program;
+ * the flush accumulating and writing packed rows (a slot's block laid
+   side by side in the kernel, no pack pass, the Gram term beside the
+   product): compiled, bit-equal to the flush packed afterwards, and
+   slower: `als.flush` 0.646 -> 0.766 s a job on the user side and 0.147
+   -> 0.253 on the item side at ML-20M, the sweep +0.16 s. Left out.
+
+Refused by Mosaic in PR 21's run and removed in PR 28 (the fused variant
+went with them: no product path could select it); the git history has the
 code. Whoever takes one up again should start from the compiler's words,
 not from the same design:
 
- * lane-packed A, the (K,LANE)->(1,K*K) pack in the flush: "infer-vector-
-   layout: unsupported shape cast vector<64x64xf32> -> vector<1x4096xf32>"
-   (the packed batched matvec alone compiled and matched to 2e-7);
+ * a (K,LANE)->(1,K*K) pack inside the flush: "infer-vector-layout:
+   unsupported shape cast vector<64x64xf32> -> vector<1x4096xf32>". What
+   PR 32 packs is whole sublane tiles side by side, which needs no cast;
  * a gather from a VMEM-resident table: dynamic single-row loads, "cannot
    statically prove that index in dimension 0 is a multiple of 8" (bf16
    table); `jnp.take` in the kernel, "Can only load scalars from SMEM";
@@ -319,6 +360,11 @@ def _run_segment_group(rows_g, a_blks, b_blks, a_buf, b_buf, *,
     )(rows_g.reshape(n_steps, 1, chunk), a_blks, b_blks, a_buf, b_buf)
 
 
+def _interpret() -> bool:
+    """The kernels run compiled on a TPU and interpreted on a CPU."""
+    return jax.devices()[0].platform == "cpu"
+
+
 def _lane_for(k: int) -> int:
     return max(128, -(-k // 128) * 128)  # round UP to a lane multiple
 
@@ -343,7 +389,8 @@ def _kernel_chunk(k: int, chunk_slots: int) -> int:
 
 
 def segment_flush(rows, n_self: int, k: int, chunk_slots: int, groups,
-                  overlap: bool = False, interpret: bool | None = None):
+                  overlap: bool = False, interpret: bool | None = None,
+                  wide: bool = False):
     """Sum per-slot blocks into A (n_self,k,k), b (n_self,k) by the
     segment-flush kernel. `rows` (S,) is the layout's non-decreasing
     slot->row index with its sentinel tail (row id n_self: one padding row
@@ -351,12 +398,14 @@ def segment_flush(rows, n_self: int, k: int, chunk_slots: int, groups,
     b_blks (hi-lo,k)) in slot order, each a whole number of `chunk_slots`;
     it is consumed one group at a time, so a generator that builds a
     group's blocks when asked holds one group of them at once.
-    `overlap` picks the kernel (module docstring); it changes no sum."""
+    `overlap` picks the kernel (module docstring); it changes no sum.
+    `wide` returns A as the kernel wrote it, (n_self + 1, k, lane) with
+    the padding row and the zero lanes [k:], for `pack_flush` to read."""
     if k > MAX_RANK:
         raise ValueError(f"rank {k} > {MAX_RANK}: the kernel's blocks do "
                          "not fit scoped VMEM")
     if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
+        interpret = _interpret()
     chunk = _kernel_chunk(k, chunk_slots)
     lane = _lane_for(k)
     n_pad = n_self + 1
@@ -384,4 +433,123 @@ def segment_flush(rows, n_self: int, k: int, chunk_slots: int, groups,
         A = a_buf.at[jnp.concatenate(t_rows)].add(t_a, mode="drop")
         b = b_buf.at[jnp.concatenate(t_rows)].add(
             jnp.concatenate(t_bs), mode="drop")
-        return A[:n_self, :, :k], b[:n_self, :k]
+        return (A if wide else A[:n_self, :, :k]), b[:n_self, :k]
+
+
+# ---------------------------------------------------------------------------
+# lane-packed A: the form the CG solve holds the normal equations in
+# ---------------------------------------------------------------------------
+
+def pack_factor(k: int) -> int:
+    """Matrix rows a lane row of the packed A holds: `lane // k` where the
+    rank leaves lanes empty and a packed row is whole (8, lane) tiles
+    (2 at rank 64, 4 at rank 32), else 1: not packed."""
+    lane = _lane_for(k)
+    pack = lane // k
+    return pack if pack > 1 and lane % k == 0 and k % (8 * pack) == 0 else 1
+
+
+def pack_rows(a, pack: int):
+    """(n, k, k) -> (n, k/pack, pack*k): lane row r holds matrix rows
+    r, r + k/pack, r + 2k/pack, ... side by side, so a packed row is A's
+    k*k floats and nothing else: slices of whole sublane tiles and a lane
+    concatenate, in `pack_flush`'s kernel and, for the tests, in XLA."""
+    h = a.shape[1] // pack
+    return jnp.concatenate(
+        [a[:, q * h:(q + 1) * h, :] for q in range(pack)], axis=-1)
+
+
+def unpack_rows(a_p, pack: int):
+    """The inverse of `pack_rows`."""
+    k = a_p.shape[2] // pack
+    return jnp.concatenate(
+        [a_p[:, :, q * k:(q + 1) * k] for q in range(pack)], axis=1)
+
+
+def _pack_kernel(a_ref, g_ref, o_ref, d_ref, *, pack: int):
+    """A block of the flush's rows (B, k, lane), lanes [k:] zero, plus
+    the Gram term g (k, lane) -> the packed rows (B, k/pack, lane) and
+    their diagonal (B, k). Slices of whole sublane tiles laid side by
+    side along the lanes: nothing is reshaped."""
+    k = a_ref.shape[1]
+    a = a_ref[...] + g_ref[...][None]
+    o_ref[...] = pack_rows(a[:, :, :k], pack)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, a.shape[1:], 0)
+           == jax.lax.broadcasted_iota(jnp.int32, a.shape[1:], 1))
+    d_ref[...] = jnp.sum(jnp.where(eye[None], a, 0.0), axis=-1)
+
+
+# bytes of A a grid step of the pack and of the product reads: 2 MB is
+# 128 rows of packed A at rank 64 (64 of the flush's wide rows);
+# double-buffered, with the product beside it, under the 16 MB of scoped
+# VMEM (256 rows overrun it; the module docstring has the runs)
+_BLOCK_BYTES = 2 * 2**20
+
+
+def _block_rows(row_bytes: int, n: int) -> int:
+    return min(max(8, _BLOCK_BYTES // row_bytes), -(-n // 8) * 8)
+
+
+@jax.named_scope("als.gram")
+def pack_flush(a_wide, gram, n_self: int, pack: int,
+               interpret: bool | None = None):
+    """The pass that adds the Gram term, writing the form the solve
+    holds: a_wide (n_self + 1, k, lane) from `segment_flush(wide=True)`,
+    gram (k, k) -> (A packed (n_self, k/pack, lane), diag(A) (n_self, k)).
+    One read of the flush's buffer, one write of 1/pack of its bytes."""
+    from jax.experimental import pallas as pl
+
+    if interpret is None:
+        interpret = _interpret()
+    _, k, lane = a_wide.shape
+    h = k // pack
+    block = _block_rows(k * lane * 4, n_self)
+    return pl.pallas_call(
+        functools.partial(_pack_kernel, pack=pack),
+        grid=(pl.cdiv(n_self, block),),
+        in_specs=[pl.BlockSpec((block, k, lane), lambda i: (i, 0, 0)),
+                  pl.BlockSpec((k, lane), lambda i: (0, 0))],
+        out_specs=[pl.BlockSpec((block, h, lane), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((block, k), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n_self, h, lane), jnp.float32),
+                   jax.ShapeDtypeStruct((n_self, k), jnp.float32)],
+        interpret=interpret, name="als.gram.pack",
+    )(a_wide, _pad_lanes(gram, lane))
+
+
+def _matvec_kernel(a_ref, x_ref, o_ref, *, pack: int):
+    """o[b] = A_b @ x[b] on a block of packed rows: x is laid `pack`
+    times along the lanes, multiplied into the block, and each run of k
+    lanes is summed: float32 multiplies and adds only."""
+    x = x_ref[...]                                       # (B, k)
+    k = x.shape[1]
+    p = a_ref[...] * jnp.concatenate([x] * pack, axis=-1)[:, None, :]
+    run = jax.lax.broadcasted_iota(jnp.int32, p.shape, 2) // k
+    o_ref[...] = jnp.concatenate(
+        [jnp.sum(jnp.where(run == q, p, 0.0), axis=-1)
+         for q in range(pack)], axis=-1)
+
+
+@jax.named_scope("als.cg")
+def packed_matvec(a_p, x, interpret: bool | None = None):
+    """Batched A_b @ x[b] on lane-packed A: a_p (n, k/pack, lane) from
+    `pack_rows`, x (n, k) -> (n, k), float32. The last block may hang
+    over n: rows are independent and what is written past n is dropped."""
+    from jax.experimental import pallas as pl
+
+    if interpret is None:
+        interpret = _interpret()
+    n, h, lane = a_p.shape
+    k = x.shape[1]
+    pack = lane // k
+    assert h * pack == k and x.shape[0] == n, (a_p.shape, x.shape)
+    block = _block_rows(h * lane * 4, n)
+    return pl.pallas_call(
+        functools.partial(_matvec_kernel, pack=pack),
+        grid=(pl.cdiv(n, block),),
+        in_specs=[pl.BlockSpec((block, h, lane), lambda i: (i, 0, 0)),
+                  pl.BlockSpec((block, k), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((block, k), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, k), jnp.float32),
+        interpret=interpret, name="als.cg.matvec",
+    )(a_p, x)

@@ -336,6 +336,154 @@ def test_rank_over_kernel_limit_resolves_to_stacked(accum):
             jnp.zeros((16,), jnp.int32), nu, k, 16, iter(()))
 
 
+# ---------------------------------------------------------------------------
+# lane-packed A: what the CG solve holds behind the flush kernel (PR 32)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("k,n", [(32, 701), (64, 301), (128, 75)])
+def test_packed_matvec_matches_einsum(k, n):
+    """The product kernel on packed rows against float64, at a row count
+    that is no multiple of its block (512 / 128 / 32 rows) nor of 8."""
+    pack = als_pallas.pack_factor(k)
+    assert pack == {32: 4, 64: 2, 128: 1}[k]
+    rng = np.random.default_rng(2)
+    A = rng.normal(size=(n, k, k)).astype(np.float32)
+    A = A + np.swapaxes(A, 1, 2)      # symmetric, like a normal equation
+    x = rng.normal(size=(n, k)).astype(np.float32)
+    a_p = als_pallas.pack_rows(jnp.asarray(A), pack)
+    assert a_p.shape == (n, k // pack, max(k, 128))
+    got = als_pallas.packed_matvec(a_p, jnp.asarray(x))
+    ref = np.einsum("bij,bj->bi", A.astype(np.float64), x)
+    assert _relerr(got, ref) < 1e-6
+
+
+@pytest.mark.parametrize("k", [32, 64])
+def test_pack_unpack_identity_and_pack_flush(k):
+    """pack -> unpack is the identity, and the pass that adds the Gram
+    term writes exactly `pack_rows(A + G)` and its diagonal from the
+    flush's wide buffer (padding row and zero lanes included)."""
+    pack = als_pallas.pack_factor(k)
+    n = 37
+    rng = np.random.default_rng(4)
+    A = rng.normal(size=(n + 1, k, k)).astype(np.float32)
+    G = rng.normal(size=(k, k)).astype(np.float32)
+    a = jnp.asarray(A[:n])
+    np.testing.assert_array_equal(
+        np.asarray(als_pallas.unpack_rows(
+            als_pallas.pack_rows(a, pack), pack)), A[:n])
+    wide = jnp.pad(jnp.asarray(A), ((0, 0), (0, 0), (0, 128 - k)))
+    a_p, diag = als_pallas.pack_flush(wide, jnp.asarray(G), n, pack)
+    want = A[:n] + G[None]
+    np.testing.assert_array_equal(
+        np.asarray(a_p), np.asarray(als_pallas.pack_rows(
+            jnp.asarray(want), pack)))
+    np.testing.assert_array_equal(
+        np.asarray(diag), np.diagonal(want, axis1=1, axis2=2))
+
+
+def _half_sweep(k, accum, cg_iters, n_self=41, n_other=29, nnz=900,
+                implicit=True):
+    rng = np.random.default_rng(9)
+    u = rng.integers(0, n_self, nnz).astype(np.int32)
+    o = rng.integers(0, n_other, nnz).astype(np.int32)
+    v = (rng.random(nnz) * 4 + 1).astype(np.float32)
+    cs, width = 32, 8
+    layout = _device_slot_layout(
+        jnp.asarray(u), jnp.asarray(o), jnp.asarray(v), n_self, width,
+        _slots_for(nnz, n_self, width, cs))
+    other = jnp.asarray(rng.normal(size=(n_other, k)).astype(np.float32)) * .3
+    x0 = jnp.asarray(rng.normal(size=(n_self, k)).astype(np.float32)) * .1
+    solved = als._solve_factors(
+        layout, other, n_self, 0.05, implicit, 4.0, cs, x0=x0,
+        cg_iters=cg_iters, bf16_gather=False, accum=accum)
+    return solved, (layout, other, n_self, x0, cs)
+
+
+def test_solve_factors_packed_matches_carry():
+    """One half-sweep at rank 64: the flush kernel, the pack pass and the
+    product kernel against the XLA carry path and its einsum product."""
+    assert als._a_pack(64, "hybrid", 8) == 2
+    got, _ = _half_sweep(64, "hybrid", 8)
+    ref, _ = _half_sweep(64, "carry", 8)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), atol=1e-4, rtol=1e-4)
+    same, _ = _half_sweep(64, "stream", 8)
+    np.testing.assert_array_equal(np.asarray(same), np.asarray(got))
+
+
+def test_rank_128_solve_is_the_unpacked_path_bit_for_bit():
+    """Where the rank fills the lanes nothing is packed: `_solve_factors`
+    under hybrid is the flush's A plus the two Gram adds and the einsum
+    product, bit for bit what it was before the packed path existed."""
+    k = 128
+    assert als._a_pack(k, "hybrid", 8) == 1
+    got, (layout, other, n_self, x0, cs) = _half_sweep(k, "hybrid", 8)
+    A, b = _normal_equations(layout, other, n_self, True, 4.0, cs,
+                             bf16_gather=False, accum="hybrid")
+    A = A + als._shared_yty(other, None)[None]
+    A = A + 0.05 * jnp.eye(k, dtype=jnp.float32)[None]
+    want = als._cg_solve(A, b, x0, 8)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_exact_side_still_reaches_cholesky_unpacked(monkeypatch):
+    """A side under `auto_cg_rows` (cg_iters resolves to 0) is never
+    packed: `_chol_solve` gets (n, k, k)."""
+    seen = []
+    real = als._chol_solve
+    monkeypatch.setattr(
+        als, "_chol_solve", lambda A, b: seen.append(A.shape) or real(A, b))
+    p = ALSParams(rank=64, accum="hybrid")
+    assert p.resolved_cg_iters(41) == 0 and als._a_pack(64, "hybrid", 0) == 1
+    _half_sweep(64, "hybrid", 0)
+    assert seen == [(41, 64, 64)]
+
+
+def test_sharded_hybrid_rank64_equals_one_device():
+    """The packed path inside `als_train_sharded`'s shard_map (virtual
+    CPU mesh) against the one-device trainer, both through the kernels."""
+    from pio_tpu.ops.als import als_train, als_train_sharded, rmse
+    from pio_tpu.parallel.mesh import MeshConfig, create_mesh
+
+    rng = np.random.default_rng(0)
+    nu, ni, nnz = 60, 40, 900
+    u = rng.integers(0, nu, nnz)
+    i = rng.integers(0, ni, nnz)
+    v = (rng.random(nnz) * 4 + 1).astype(np.float32)
+    mesh = create_mesh(MeshConfig(data=8))
+    kw = dict(rank=64, iterations=2, reg=0.1, chunk=256, width=8,
+              chunk_slots=64, cg_iters=6, accum="hybrid")
+    m = als_train_sharded(u, i, v, nu, ni, ALSParams(**kw), mesh)
+    m1 = als_train(u, i, v, nu, ni, ALSParams(**kw))
+    assert abs(rmse(m, u, i, v) - rmse(m1, u, i, v)) < 5e-3
+    np.testing.assert_allclose(
+        np.asarray(m.user_factors), np.asarray(m1.user_factors),
+        atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("rank,a_pack", [(64, 2), (32, 4), (128, 1)])
+def test_dispatch_labels_report_the_packing(rank, a_pack):
+    p = ALSParams(rank=rank, accum="hybrid")
+    n_u, n_i = 138_493, 26_744
+    sp: dict = {}
+    als._dispatch_labels(
+        sp, 1000, 1000, (8, 16, 16), p, p.resolved_cg_iters(n_u),
+        p.resolved_cg_iters(n_i), n_u, n_i)
+    assert sp["a_pack"] == a_pack
+    lane = max(rank, 128)
+    assert sp["a_bytes_u"] == n_u * rank * lane * 4 // a_pack
+    assert sp["a_bytes_i"] == n_i * rank * lane * 4 // a_pack
+    if rank == 64:      # ISSUE 32: 2.27 GB / 0.44 GB at ML-20M
+        assert (sp["a_bytes_u"], sp["a_bytes_i"]) == (2_269_069_312,
+                                                      438_173_696)
+    # the XLA paths never pack, whatever the rank
+    sp2: dict = {}
+    als._dispatch_labels(
+        sp2, 1000, 1000, (8, 16, 16), ALSParams(rank=rank, accum="carry"),
+        16, 16, n_u, n_i)
+    assert sp2["a_pack"] == 1
+
+
 def test_als_pallas_imports_nothing_from_als():
     """The arrows point one way: ops/als.py -> ops/als_pallas.py."""
     tree = ast.parse(pathlib.Path(als_pallas.__file__).read_text())

@@ -1,0 +1,69 @@
+"""The packed-A kernels of the ALS solve, compiled by the TPU's compiler
+for a described v5e at the benchmark's widths: what Mosaic refuses (a
+slice off the tiling, a stack over the 16 MB of scoped VMEM) shows here,
+on a CPU, at no chip time. Nothing runs: these are compiles, not
+measurements. The topology is described inside a fixture, in this one
+file, so that only the worker that is given the file loads the TPU's
+library (guides: on-chip-measurement, section 2)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from pio_tpu.ops import als_pallas
+
+ML20M_USERS, ML20M_ITEMS, MSD_ITEM_BLOCK = 138_493, 26_744, 96_137
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """The described chip, with the persistent compile cache off: what is
+    compiled for a chip that is not attached cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shape(shape, one_chip):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+
+@pytest.mark.parametrize("n,k", [(ML20M_USERS, 64), (ML20M_ITEMS, 64),
+                                 (MSD_ITEM_BLOCK, 64), (ML20M_USERS, 32)])
+def test_packed_matvec_compiles_for_v5e(one_chip, n, k):
+    pack = als_pallas.pack_factor(k)
+    a_p = _shape((n, k // pack, 128), one_chip)
+    x = _shape((n, k), one_chip)
+    text = jax.jit(lambda a, x: als_pallas.packed_matvec(
+        a, x, interpret=False)).lower(a_p, x).compile().as_text()
+    assert "tpu_custom_call" in text and "als.cg.matvec" in text
+
+
+@pytest.mark.parametrize("n,k", [(ML20M_USERS, 64), (MSD_ITEM_BLOCK, 64),
+                                 (ML20M_USERS, 32)])
+def test_pack_flush_compiles_for_v5e(one_chip, n, k):
+    pack = als_pallas.pack_factor(k)
+    wide = _shape((n + 1, k, 128), one_chip)
+    gram = _shape((k, k), one_chip)
+    compiled = jax.jit(lambda w, g: als_pallas.pack_flush(
+        w, g, n, pack, interpret=False)).lower(wide, gram).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "als.gram.pack" in text
+    # the pass writes 1/pack of what it reads and holds nothing else
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
