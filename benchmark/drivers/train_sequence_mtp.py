@@ -1,0 +1,67 @@
+"""Traffic kind `train_sequence_mtp`: `run_train` jobs of the sequence
+engine back to back in one child that holds the chip, for a block
+specification with a multi-token-prediction module: a history is
+history_events + 2 ids, and the check is benchmark/harness/
+check_latent.py (benchmark/drivers/train_sequence_mtp_child.py does the
+work).
+
+`train_ratings_per_s` = jobs finished x trained main-head target events a
+job (batch_histories x history_events x steps) over the sum of the jobs'
+walls: the module's targets are not counted twice. A job is the whole
+`run_train`, from `DataSource.read_training` to the model persisted and
+the instance COMPLETED.
+"""
+
+from __future__ import annotations
+
+from benchmark.harness.children import Children, child_env, require_devices
+
+
+def run(cell, seed: int, seconds: float, trace: bool, t0: float,
+        rehearse: bool, explore: bool = False) -> dict:
+    kids = Children()
+    try:
+        out = kids.python(
+            "train_sequence_mtp", "benchmark.drivers.train_sequence_mtp_child",
+            {"config": cell.config, "traffic": cell.traffic,
+             "chips": cell.chips, "seed": seed, "seconds": seconds,
+             "trace": trace, "rehearse": rehearse, "explore": explore},
+            child_env(kids.work, on_chip=True, rehearse=rehearse),
+            timeout=3400 if explore else 1500)
+    finally:
+        kids.close()
+    require_devices(out["device"], cell.chips, rehearse)
+    jobs, traffic = out["jobs"], cell.traffic
+    events = (traffic["batch_histories"] * traffic["history_events"]
+              * traffic["steps"])
+    walls = sum(j["wall_s"] for j in jobs)
+    result = {
+        "correct": out["correct"],
+        "attempted": len(jobs),
+        "failed": 0,          # a job that fails ends the child: no result
+        "compared": out["compared"],
+        "device": out["device"],
+        "end_to_end": {
+            "setup_s": out["window"]["open"] - t0,
+            "train_ratings_per_s": len(jobs) * events / walls,
+        },
+        "evidence": {"jobs": jobs, "trace": out.get("trace"),
+                     "counters": [j["counters"] for j in jobs],
+                     "steps_in_window": len(jobs) * traffic["steps"],
+                     "config": cell.config, "traffic": traffic,
+                     "chips": cell.chips,
+                     "device_kind": out["device"]["kind"],
+                     "rehearse": rehearse},
+        "raw": out,
+    }
+    if trace:
+        tr = out["trace"]
+        result["device"].update(busy_s=tr["busy_s"], window_s=tr["window_s"])
+        result["breakdown"] = {"device_ops": tr["device_ops"],
+                               "idle_gaps": tr["idle_gaps"]}
+        if tr.get("scope_s"):
+            steps = len(jobs) * traffic["steps"]
+            result["breakdown"]["scope_step_s"] = {
+                k: v / steps for k, v in sorted(
+                    tr["scope_s"].items(), key=lambda kv: -kv[1])}
+    return result

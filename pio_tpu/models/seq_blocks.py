@@ -2,29 +2,53 @@
 language model over item histories, built from a block specification.
 
 `SequenceParams.block_spec` carries the specification: the keys of a
-published `config.json` (RMSNorm, rotary positions by layer kind, grouped-
-query heads, a pattern of window and full attention layers, SwiGLU experts
-with a top-k router, an untied head), plus what one rank of a deployment
-holds of it (`experts_held` of `num_experts_routed`, `vocab_size` rows of
-the vocabulary). With a specification, `train_sequence_model` trains this
+published `config.json`, plus what one rank of a deployment holds of it
+(`experts_held` of `num_experts_routed`, `vocab_size` rows of the
+vocabulary). With a specification, `train_sequence_model` trains this
 stack in place of the SASRec-style encoder of models/sequence.py:
 
-  x_0 = E[ids];  h = x + Attn_l(RMSNorm(x));  x' = h + MoE_l(RMSNorm(h))
+  x_0 = E[ids];  h = x + Attn_l(RMSNorm(x));  x' = h + FFN_l(RMSNorm(h))
   logits = RMSNorm(x_L) W_head^T;  loss = mean next-item cross-entropy
 
- * attention: ops/attention.py `banded_flash_attention` (Pallas forward
-   and backward; causal, a window on `sliding_attention` layers);
- * experts: ops/moe.py `held_moe_ffn` (dropless top-k, the held experts'
-   part by a grouped matrix product), one history at a time;
+What a specification chooses, by its keys (`BlockSpec.parse` refuses by
+name what the stack does not compute):
+
+ * attention: grouped-query heads (`head_dim`, `num_key_value_heads`,
+   `layer_types` of window and full layers, rotary positions by layer
+   kind, RoPE or YaRN), or latent attention (`q_lora_rank`,
+   `kv_lora_rank`, `qk_nope_head_dim`, `qk_rope_head_dim`, `v_head_dim`:
+   low-rank query and key-value paths with an RMSNorm each, rotary
+   positions on the `qk_rope_head_dim` dimensions only, one rope key
+   shared by all heads). Both end in ops/attention.py
+   `banded_flash_attention` (Pallas forward and backward);
+ * the second half of a layer: a dense SwiGLU of `intermediate_size` in
+   the first `first_k_dense_replace` layers, else ops/moe.py
+   `held_moe_ffn` (dropless top-k, the held experts' part by a grouped
+   matrix product), one history at a time, with `n_shared_experts`
+   shared experts (a dense SwiGLU every token takes) beside it;
+ * the router: softmax top-k, or with `topk_method: noaux_tc` sigmoid
+   scores, selection on score + bias, weights from the unbiased score
+   times `routed_scaling_factor`. The bias is a leaf of the parameter
+   tree (`router_bias`, so it is persisted with the model) that takes no
+   gradient: the train step moves it after the optimizer, `b +=
+   ROUTER_BIAS_RATE * sign(mean(c) - c)` over the step's token counts c
+   of every routed expert;
+ * a multi-token-prediction module (`num_nextn_predict_layers: 1`):
+   u_i = [RMSNorm_h(x_L,i) | RMSNorm_e(E[t_i+1])] W_eh, one more layer
+   of the kind above, its own final norm, the shared embedding and head;
+   it predicts t_i+2, and loss = CE_main + MTP_LOSS_WEIGHT * CE_mtp. A
+   history then has one more id (S + 2 for S trained positions);
  * precision: float32 master weights and Adam state, bfloat16 operands,
    float32 accumulation, float32 residual stream, norms and loss;
- * memory: every layer's two halves are recomputed in the backward pass
-   (`jax.checkpoint` at their boundaries); the loss is computed over
-   chunks of tokens so the (tokens, vocabulary) logits never exist whole.
+ * memory: every layer's two halves (the module's too) are recomputed in
+   the backward pass (`jax.checkpoint` at their boundaries); the loss is
+   computed over chunks of tokens so the (tokens, vocabulary) logits
+   never exist whole.
 
 One chip. Histories are whole (no PAD inside a row): packing and padding
 of short histories, the experts' exchange across chips and a cache for
-serving are not here (ROADMAP R1, R4).
+serving (for latent attention: the compressed key-value cache) are not
+here (ROADMAP R1, R4).
 """
 
 from __future__ import annotations
@@ -53,6 +77,16 @@ LOSS_CHUNK = 2048          # tokens whose logits exist at one time
 ATTN_BLOCK = 512           # query and key block of the attention kernels
 MOE_TILE = 512             # rows of one tile of the grouped product
 ADAM_B1 = 0.9              # after one step from zero, mu = (1 - b1) * gradient
+# what no published config.json has a key for (DeepSeek-V3's report gives
+# both); a specification that states another value is refused
+ROUTER_BIAS_RATE = 0.001   # a step's move of a router's bias
+MTP_LOSS_WEIGHT = 0.3      # the prediction module's loss beside the main one
+
+
+# keys of architectures this stack has no code for: refused, not ignored
+_NOT_COMPUTED = ("index_topk", "index_n_heads", "index_head_dim",
+                 "layers_block_type", "mamba_n_heads", "mamba_d_state",
+                 "mamba_expand", "linear_conv_kernel_dim")
 
 
 @dataclass(frozen=True)
@@ -62,7 +96,7 @@ class BlockSpec:
     layer_types: tuple[str, ...]        # one kind per layer
     num_attention_heads: int
     num_key_value_heads: int
-    head_dim: int
+    head_dim: int                       # of q.k and of p.v alike
     sliding_window: int
     rope: tuple[tuple[str, tuple], ...]  # kind -> sorted rope parameters
     rms_norm_eps: float
@@ -74,56 +108,130 @@ class BlockSpec:
     vocab_size: int                     # rows held, PAD's row 0 among them
     initializer_range: float
     embedding_initializer_range: float  # the embedding's rows alone
+    # latent attention (kv_lora_rank 0: grouped-query heads)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the second half of a layer
+    dense_layers: int = 0               # leading layers with a dense MLP
+    intermediate_size: int = 0          # its width
+    n_shared_experts: int = 0
+    scoring: str = "softmax"            # "sigmoid" with a router bias
+    routed_scaling_factor: float = 1.0
+    # the multi-token-prediction module
+    mtp_layers: int = 0
 
     @classmethod
     def parse(cls, spec: str | dict) -> "BlockSpec":
         c = json.loads(spec) if isinstance(spec, str) else dict(spec)
+        n_layers = c["num_hidden_layers"]
+        latent = bool(c.get("kv_lora_rank"))
+        nope, rope_dim, v_dim = (c.get(k, 0) for k in (
+            "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
+        scoring = ("sigmoid" if c.get("topk_method") == "noaux_tc"
+                   else "softmax")
         wrong = {
             "hidden_act": c.get("hidden_act", "silu") != "silu",
             "attention_bias": bool(c.get("attention_bias", False)),
             "tie_word_embeddings": bool(c.get("tie_word_embeddings", False)),
             "mlp_layer_types": any(
                 t != "sparse" for t in
-                c.get("mlp_layer_types", [])[:c["num_hidden_layers"]]),
+                c.get("mlp_layer_types", [])[:n_layers]),
+            "n_group": c.get("n_group", 1) > 1 or c.get("topk_group", 1) > 1,
+            "topk_method": c.get("topk_method", "greedy") not in (
+                "greedy", "noaux_tc"),
+            "scoring_func": c.get("scoring_func", scoring) != scoring,
+            "router_bias_update_rate": c.get(
+                "router_bias_update_rate", ROUTER_BIAS_RATE
+            ) != ROUTER_BIAS_RATE,
+            "mtp_loss_weight": c.get(
+                "mtp_loss_weight", MTP_LOSS_WEIGHT) != MTP_LOSS_WEIGHT,
+            "num_nextn_predict_layers":
+                c.get("num_nextn_predict_layers", 0) > 1,
+            "partial_rotary_factor": c.get("partial_rotary_factor", 1) != 1,
+            # latent attention as computed here: a low-rank query path,
+            # every head its own keys and values, q.k as wide as p.v
+            "q_lora_rank": latent and not c.get("q_lora_rank"),
+            "num_key_value_heads": latent and c.get(
+                "num_key_value_heads", c["num_attention_heads"]
+            ) != c["num_attention_heads"],
+            "v_head_dim": latent and nope + rope_dim != v_dim,
+            "rope_scaling": latent and c.get("rope_scaling") is not None,
+            "first_k_dense_replace":
+                c.get("first_k_dense_replace", 0) > n_layers,
+            **{k: True for k in _NOT_COMPUTED if c.get(k)},
         }
         if any(wrong.values()):
             raise ValueError(
                 "block specification asks for what this stack does not "
                 f"compute: {sorted(k for k, v in wrong.items() if v)}")
-        n_layers = c["num_hidden_layers"]
-        kinds = tuple(c["layer_types"][:n_layers])
+        kinds = tuple(c["layer_types"][:n_layers]) if "layer_types" in c \
+            or not latent else ("full_attention",) * n_layers
         if len(kinds) != n_layers or set(kinds) - {
                 "sliding_attention", "full_attention"}:
             raise ValueError(f"layer_types {kinds} for {n_layers} layers")
-        held = tuple(c.get("experts_held", (0, c["num_experts"])))
-        if held[1] - held[0] != c["num_experts"]:
+        if latent and "sliding_attention" in kinds:
+            raise ValueError("layer_types: latent attention has no window")
+        n_held = c["num_experts"] if "num_experts" in c \
+            else c["n_routed_experts"]
+        held = tuple(c.get("experts_held", (0, n_held)))
+        if held[1] - held[0] != n_held:
             raise ValueError(
                 f"experts_held {held} is not num_experts "
-                f"{c['num_experts']} experts")
+                f"{n_held} experts")
+        ropes = c.get("rope_parameters") or {
+            "full_attention": {"rope_type": "default",
+                               "rope_theta": c["rope_theta"]}}
         return cls(
             hidden_size=c["hidden_size"], num_hidden_layers=n_layers,
             layer_types=kinds,
             num_attention_heads=c["num_attention_heads"],
-            num_key_value_heads=c["num_key_value_heads"],
-            head_dim=c["head_dim"], sliding_window=c["sliding_window"],
+            num_key_value_heads=c.get("num_key_value_heads",
+                                      c["num_attention_heads"]),
+            head_dim=v_dim if latent else c["head_dim"],
+            sliding_window=c.get("sliding_window") or 0,
             rope=tuple(sorted(
-                (kind, tuple(sorted(c["rope_parameters"][kind].items())))
+                (kind, tuple(sorted(ropes[kind].items())))
                 for kind in set(kinds))),
             rms_norm_eps=c["rms_norm_eps"],
             moe_intermediate_size=c["moe_intermediate_size"],
-            num_experts_routed=c.get("num_experts_routed", c["num_experts"]),
+            num_experts_routed=c.get("num_experts_routed", n_held),
             experts_held=held,
             num_experts_per_tok=c["num_experts_per_tok"],
             norm_topk_prob=c["norm_topk_prob"], vocab_size=c["vocab_size"],
             initializer_range=c.get("initializer_range", 0.02),
             embedding_initializer_range=c.get(
                 "embedding_initializer_range",
-                c.get("initializer_range", 0.02)))
+                c.get("initializer_range", 0.02)),
+            q_lora_rank=c["q_lora_rank"] if latent else 0,
+            kv_lora_rank=c["kv_lora_rank"] if latent else 0,
+            qk_nope_head_dim=nope if latent else 0,
+            qk_rope_head_dim=rope_dim if latent else 0,
+            v_head_dim=v_dim if latent else 0,
+            dense_layers=c.get("first_k_dense_replace", 0),
+            intermediate_size=c.get("intermediate_size", 0),
+            n_shared_experts=c.get("n_shared_experts") or 0,
+            scoring=scoring,
+            routed_scaling_factor=float(c.get("routed_scaling_factor", 1.0)),
+            mtp_layers=c.get("num_nextn_predict_layers", 0))
 
     @property
     def experts(self) -> HeldExperts:
         return HeldExperts(self.num_experts_routed, self.num_experts_per_tok,
-                           self.experts_held, self.norm_topk_prob, MOE_TILE)
+                           self.experts_held, self.norm_topk_prob, MOE_TILE,
+                           self.scoring, self.routed_scaling_factor)
+
+    @property
+    def router_bias(self) -> bool:
+        """Whether a router selects on score + bias (`noaux_tc`)."""
+        return self.scoring == "sigmoid"
+
+    @property
+    def rope_dim(self) -> int:
+        """The dimensions of a head that rotate."""
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
 
     def window(self, kind: str) -> int | None:
         return self.sliding_window if kind == "sliding_attention" else None
@@ -163,10 +271,10 @@ def rope_inv_freq(rope: dict, head_dim: int) -> tuple[np.ndarray, float]:
 
 
 def rope_tables(spec: BlockSpec, seq_len: int) -> dict:
-    """kind -> (cos, sin), each (seq_len, head_dim / 2) float32."""
+    """kind -> (cos, sin), each (seq_len, rope_dim / 2) float32."""
     out = {}
     for kind, items in spec.rope:
-        inv, scale = rope_inv_freq(dict(items), spec.head_dim)
+        inv, scale = rope_inv_freq(dict(items), spec.rope_dim)
         angle = np.arange(seq_len, dtype=np.float64)[:, None] * inv[None]
         out[kind] = (np.float32(np.cos(angle) * scale),
                      np.float32(np.sin(angle) * scale))
@@ -184,19 +292,57 @@ def apply_rope(x, cos, sin):
 # parameters
 # ---------------------------------------------------------------------------
 
-def param_shapes(spec: BlockSpec) -> dict:
-    d, f = spec.hidden_size, spec.moe_intermediate_size
-    hq = spec.num_attention_heads * spec.head_dim
-    hkv = spec.num_key_value_heads * spec.head_dim
+def _layer_shapes(spec: BlockSpec, dense: bool) -> dict:
+    d, f, h = (spec.hidden_size, spec.moe_intermediate_size,
+               spec.num_attention_heads)
+    if spec.kv_lora_rank:
+        rq, rkv = spec.q_lora_rank, spec.kv_lora_rank
+        dn, dr, dv = (spec.qk_nope_head_dim, spec.qk_rope_head_dim,
+                      spec.v_head_dim)
+        layer = {"wq_a": (d, rq), "q_norm": (rq,), "wq_b": (rq, h * (dn + dr)),
+                 "wkv_a": (d, rkv + dr), "kv_norm": (rkv,),
+                 "wkv_b": (rkv, h * (dn + dv)), "wo": (h * dv, d)}
+    else:
+        hq = h * spec.head_dim
+        hkv = spec.num_key_value_heads * spec.head_dim
+        layer = {"wq": (d, hq), "wk": (d, hkv), "wv": (d, hkv), "wo": (hq, d)}
+    layer.update({"norm1": (d,), "norm2": (d,)})
+    if dense:
+        i = spec.intermediate_size
+        layer.update({"mlp_gate": (d, i), "mlp_up": (d, i),
+                      "mlp_down": (i, d)})
+        return layer
     n_held = spec.experts.n_held
-    layer = {"norm1": (d,), "wq": (d, hq), "wk": (d, hkv), "wv": (d, hkv),
-             "wo": (hq, d), "norm2": (d,),
-             "router": (d, spec.num_experts_routed),
-             "w_gate": (n_held, d, f), "w_up": (n_held, d, f),
-             "w_down": (n_held, f, d)}
-    return {"embed": (spec.vocab_size, d), "head": (spec.vocab_size, d),
-            "final_norm": (d,),
-            "layers": [dict(layer) for _ in range(spec.num_hidden_layers)]}
+    layer.update({"router": (d, spec.num_experts_routed),
+                  "w_gate": (n_held, d, f), "w_up": (n_held, d, f),
+                  "w_down": (n_held, f, d)})
+    if spec.router_bias:
+        layer["router_bias"] = (spec.num_experts_routed,)
+    if spec.n_shared_experts:
+        fs = spec.n_shared_experts * f
+        layer.update({"shared_gate": (d, fs), "shared_up": (d, fs),
+                      "shared_down": (fs, d)})
+    return layer
+
+
+def param_shapes(spec: BlockSpec) -> dict:
+    d = spec.hidden_size
+    shapes = {"embed": (spec.vocab_size, d), "head": (spec.vocab_size, d),
+              "final_norm": (d,),
+              "layers": [_layer_shapes(spec, n < spec.dense_layers)
+                         for n in range(spec.num_hidden_layers)]}
+    if spec.mtp_layers:
+        shapes["mtp"] = {"enorm": (d,), "hnorm": (d,), "eh_proj": (2 * d, d),
+                         "final_norm": (d,),
+                         "layer": _layer_shapes(spec, False)}
+    return shapes
+
+
+def expert_layers(params: dict, spec: BlockSpec) -> list[dict]:
+    """The layers that hold a router, in the order of the step's
+    counters: the stack's, then the prediction module's."""
+    layers = list(params["layers"][spec.dense_layers:])
+    return layers + ([params["mtp"]["layer"]] if spec.mtp_layers else [])
 
 
 @lru_cache(maxsize=None)
@@ -214,6 +360,7 @@ def _init_program(spec: BlockSpec):
     def make(key):
         keys = jax.random.split(key, len(leaves))
         return jax.tree_util.tree_unflatten(tree, [
+            jnp.zeros(s, jnp.float32) if path[-1].key == "router_bias" else
             jnp.ones(s, jnp.float32) if len(s) == 1 else
             scale(path) * jax.random.normal(k, s, jnp.float32)
             for (path, s), k in zip(leaves, keys)])
@@ -223,8 +370,8 @@ def _init_program(spec: BlockSpec):
 
 def init_params(spec: BlockSpec, seed: int) -> dict:
     """normal(0, initializer_range) matrices (the embedding's rows
-    normal(0, embedding_initializer_range)) and unit norm gains, float32,
-    a pure function of (spec, seed)."""
+    normal(0, embedding_initializer_range)), unit norm gains and zero
+    router biases, float32, a pure function of (spec, seed)."""
     return _init_program(spec)(jax.random.PRNGKey(seed))
 
 
@@ -239,6 +386,8 @@ def rms_norm(x, gain, eps: float):
 
 
 def _attention_half(lp, x, cos, sin, *, spec: BlockSpec, kind: str):
+    if spec.kv_lora_rank:
+        return _latent_attention_half(lp, x, cos, sin, spec=spec)
     b, s, d = x.shape
     hq, hkv, dh = (spec.num_attention_heads, spec.num_key_value_heads,
                    spec.head_dim)
@@ -264,39 +413,143 @@ def _attention_half(lp, x, cos, sin, *, spec: BlockSpec, kind: str):
     return x + out
 
 
+def _latent_attention_half(lp, x, cos, sin, *, spec: BlockSpec):
+    """Latent attention: queries through a rank-`q_lora_rank` latent,
+    keys and values through one of `kv_lora_rank`, an RMSNorm on each;
+    a head is [nope | rope] wide, the rope part rotates, and the keys'
+    rope part is one vector a position, the same for every head."""
+    b, s, d = x.shape
+    h, dn, dr, dv = (spec.num_attention_heads, spec.qk_nope_head_dim,
+                     spec.qk_rope_head_dim, spec.v_head_dim)
+    rkv, eps = spec.kv_lora_rank, spec.rms_norm_eps
+    with jax.named_scope("seq.attn.latent"):
+        y = rms_norm(x, lp["norm1"], eps).astype(COMPUTE)
+
+        def down(w):
+            return jnp.dot(y, w.astype(COMPUTE),
+                           preferred_element_type=jnp.float32)
+
+        def up(c, w, width):
+            return jnp.einsum(
+                "bsr,rhk->bhsk", c.astype(COMPUTE),
+                w.astype(COMPUTE).reshape(c.shape[-1], h, width),
+                preferred_element_type=jnp.float32)
+
+        q = up(rms_norm(down(lp["wq_a"]), lp["q_norm"], eps),
+               lp["wq_b"], dn + dr)
+        kv_a = down(lp["wkv_a"])
+        kv = up(rms_norm(kv_a[..., :rkv], lp["kv_norm"], eps),
+                lp["wkv_b"], dn + dv)
+        k_pe = apply_rope(kv_a[:, None, :, rkv:], cos, sin)
+        q = jnp.concatenate(
+            [q[..., :dn], apply_rope(q[..., dn:], cos, sin)],
+            axis=-1).astype(COMPUTE)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe, (b, h, s, dr))],
+            axis=-1).astype(COMPUTE)
+        v = kv[..., dn:].astype(COMPUTE)
+    with jax.named_scope("seq.attn.full"):
+        # q.k is qk_nope + qk_rope wide and p.v v_head_dim: equal here
+        # (BlockSpec.parse holds them to it), so the one kernel serves
+        o = banded_flash_attention(q, k, v, None, None,
+                                   ATTN_BLOCK, ATTN_BLOCK)
+    with jax.named_scope("seq.attn.proj"):
+        out = jnp.einsum("bhsk,hkd->bsd", o,
+                         lp["wo"].astype(COMPUTE).reshape(h, dv, d),
+                         preferred_element_type=jnp.float32)
+    return x + out
+
+
+def _swiglu(y, gate, up, down):
+    """(T, d) in COMPUTE -> (T, d) float32: (silu(y Wg) * (y Wu)) Wd."""
+    def dot(a, w):
+        return jnp.dot(a, w.astype(COMPUTE),
+                       preferred_element_type=jnp.float32)
+
+    return dot((jax.nn.silu(dot(y, gate)) * dot(y, up)).astype(COMPUTE), down)
+
+
 def _experts_half(lp, h, *, spec: BlockSpec):
-    """One history (S, d): -> (h + its held experts' part, counters)."""
+    """One history (S, d): -> (h + its held experts' part (+ the shared
+    expert's), counters)."""
     with jax.named_scope("seq.moe.route"):
         y = rms_norm(h, lp["norm2"], spec.rms_norm_eps)
     out, aux = held_moe_ffn(
-        {k: lp[k] for k in ("router", "w_gate", "w_up", "w_down")},
+        {k: lp[k] for k in ("router", "w_gate", "w_up", "w_down",
+                            "router_bias") if k in lp},
         y, spec.experts, COMPUTE)
+    if spec.n_shared_experts:
+        with jax.named_scope("seq.moe.shared"):
+            out = out + _swiglu(y.astype(COMPUTE), lp["shared_gate"],
+                                lp["shared_up"], lp["shared_down"])
     return h + out, aux
 
 
+def _dense_half(lp, h, *, spec: BlockSpec):
+    """One history (S, d) through a dense layer's SwiGLU."""
+    with jax.named_scope("seq.mlp.dense"):
+        y = rms_norm(h, lp["norm2"], spec.rms_norm_eps).astype(COMPUTE)
+        return h + _swiglu(y, lp["mlp_gate"], lp["mlp_up"], lp["mlp_down"])
+
+
+def _layer(lp, x, table, *, spec: BlockSpec, kind: str, dense: bool):
+    """One layer, each half recomputed in the backward pass, the second
+    a history at a time. -> (x', the router's counters or None)."""
+    x = jax.checkpoint(partial(_attention_half, spec=spec, kind=kind))(
+        lp, x, *table)
+    if dense:
+        return jax.lax.map(
+            jax.checkpoint(partial(_dense_half, lp, spec=spec)), x), None
+    return jax.lax.map(
+        jax.checkpoint(partial(_experts_half, lp, spec=spec)), x)
+
+
 def hidden_states(params, ids, spec: BlockSpec):
-    """ids (B, S) int32 -> (x_L (B, S, d) float32, counters: `counts`
-    (layers, B, held experts), `dropped` (layers, B))."""
+    """ids (B, S) int32 -> (x_L (B, S, d) float32, counters of the
+    layers that route: `counts` (layers, B, held experts), `dropped`
+    (layers, B), with a router bias `counts_all` (layers, B, routed))."""
     tables = rope_tables(spec, ids.shape[1])
     with jax.named_scope("seq.embed"):
         x = params["embed"][ids]
     counters = []
-    for lp, kind in zip(params["layers"], spec.layer_types):
-        cos, sin = tables[kind]
-        x = jax.checkpoint(partial(_attention_half, spec=spec, kind=kind))(
-            lp, x, cos, sin)
-        x, aux = jax.lax.map(
-            jax.checkpoint(partial(_experts_half, lp, spec=spec)), x)
-        counters.append(aux)
+    for n, (lp, kind) in enumerate(zip(params["layers"], spec.layer_types)):
+        x, aux = _layer(lp, x, tables[kind], spec=spec, kind=kind,
+                        dense=n < spec.dense_layers)
+        if aux is not None:
+            counters.append(aux)
     return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *counters)
 
 
-def head_loss(params, x, targets, spec: BlockSpec):
+def _mtp_join(mp, x, e, *, spec: BlockSpec):
+    u = jnp.concatenate(
+        [rms_norm(x, mp["hnorm"], spec.rms_norm_eps),
+         rms_norm(e, mp["enorm"], spec.rms_norm_eps)], axis=-1)
+    return jnp.dot(u.astype(COMPUTE), mp["eh_proj"].astype(COMPUTE),
+                   preferred_element_type=jnp.float32)
+
+
+def mtp_hidden_states(params, x, next_ids, spec: BlockSpec):
+    """The prediction module: x (B, S, d) the stack's output before its
+    final norm, next_ids (B, S) the ids one position on. -> (its hidden
+    states (B, S, d), its router's counters)."""
+    mp = params["mtp"]
+    with jax.named_scope("seq.embed"):
+        e = params["embed"][next_ids]
+    u = jax.checkpoint(partial(_mtp_join, spec=spec))(
+        {k: mp[k] for k in ("hnorm", "enorm", "eh_proj")}, x, e)
+    kind = spec.layer_types[-1]
+    return _layer(mp["layer"], u, rope_tables(spec, x.shape[1])[kind],
+                  spec=spec, kind=kind, dense=False)
+
+
+def head_loss(params, x, targets, spec: BlockSpec, final_norm=None):
     """Mean cross-entropy of the untied head over the targets that are
-    not PAD, the logits made a chunk of tokens at a time."""
+    not PAD, the logits made a chunk of tokens at a time. `final_norm`:
+    another gain than the stack's (the prediction module's own)."""
     with jax.named_scope("seq.head_loss"):
         d = x.shape[-1]
-        xn = rms_norm(x, params["final_norm"], spec.rms_norm_eps
+        gain = params["final_norm"] if final_norm is None else final_norm
+        xn = rms_norm(x, gain, spec.rms_norm_eps
                       ).astype(COMPUTE).reshape(-1, d)
         tgt = targets.reshape(-1)
         chunk = min(LOSS_CHUNK, xn.shape[0])
@@ -326,9 +579,24 @@ def head_loss(params, x, targets, spec: BlockSpec):
 
 def loss_and_counters(params, tokens, spec: BlockSpec):
     """tokens (B, S + 1): inputs tokens[:, :-1], targets tokens[:, 1:].
-    The function the train step differentiates."""
-    x, counters = hidden_states(params, tokens[:, :-1], spec)
-    return head_loss(params, x, tokens[:, 1:], spec), counters
+    With a prediction module tokens (B, S + 2): position i of the S
+    also predicts tokens[:, i + 2] through the module, the counters gain
+    the module's router as their last layer and `losses` (main, module),
+    and the loss is main + MTP_LOSS_WEIGHT * module. The function the
+    train step differentiates."""
+    s = tokens.shape[1] - 1 - spec.mtp_layers
+    x, counters = hidden_states(params, tokens[:, :s], spec)
+    loss = head_loss(params, x, tokens[:, 1:s + 1], spec)
+    if not spec.mtp_layers:
+        return loss, counters
+    with jax.named_scope("seq.mtp"):
+        x2, aux = mtp_hidden_states(params, x, tokens[:, 1:s + 1], spec)
+        loss2 = head_loss(params, x2, tokens[:, 2:], spec,
+                          params["mtp"]["final_norm"])
+    counters = jax.tree_util.tree_map(
+        lambda a, b: jnp.concatenate([a, b[None]]), counters, aux)
+    counters["losses"] = jnp.stack([loss, loss2])
+    return loss + MTP_LOSS_WEIGHT * loss2, counters
 
 
 def last_logits(params, ids, spec: BlockSpec):
@@ -358,9 +626,48 @@ def make_train_step(spec: BlockSpec, learning_rate: float):
         with jax.named_scope("seq.optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
+            if spec.router_bias:
+                params = balance_routers(params, counters["counts_all"],
+                                         spec)
         return params, opt_state, loss, counters
 
     return optimizer, step
+
+
+def bias_step(counts, rate: float):
+    """The aux-loss-free balancing rule: (..., routed) token counts of a
+    step -> what is added to the router's bias: +rate for an expert under
+    the mean load, -rate for one over it."""
+    counts = counts.astype(jnp.float32)
+    return rate * jnp.sign(
+        jnp.mean(counts, axis=-1, keepdims=True) - counts)
+
+
+def balance_routers(params, counts_all, spec: BlockSpec):
+    """params with every router's bias moved by `bias_step` of its
+    counts (counts_all: (routers, B, routed), in `expert_layers`' order).
+    The bias takes no gradient, so the optimizer left it where it was."""
+    moves = bias_step(counts_all.sum(axis=1), ROUTER_BIAS_RATE)
+
+    def moved(lp, move):
+        return {**lp, "router_bias": lp["router_bias"] + move}
+
+    n_stack = spec.num_hidden_layers - spec.dense_layers
+    layers = list(params["layers"])
+    for i in range(n_stack):
+        n = spec.dense_layers + i
+        layers[n] = moved(layers[n], moves[i])
+    out = {**params, "layers": layers}
+    if spec.mtp_layers:
+        out["mtp"] = {**params["mtp"], "layer": moved(
+            params["mtp"]["layer"], moves[n_stack])}
+    return out
+
+
+def history_ids(spec: BlockSpec, positions: int) -> int:
+    """The ids of a history that trains `positions` positions: each one's
+    next id, and with a prediction module the one after it."""
+    return positions + 1 + spec.mtp_layers
 
 
 def epoch_order(n: int, steps: int, batch: int, seed: int) -> np.ndarray:
@@ -384,8 +691,9 @@ def band_counters(spec: BlockSpec, seq_len: int) -> dict:
 
 
 def train_lm(seqs: np.ndarray, p, lifecycle=None):
-    """Train the block stack on (N, S + 1) whole histories for p.steps
-    steps of p.batch_size histories. -> (params on the host, last loss).
+    """Train the block stack on (N, `history_ids`) whole histories for
+    p.steps steps of p.batch_size histories. -> (params on the host,
+    last loss).
 
     Host spans (under `train.algorithms`): `seq.batch` stages every
     step's histories on the device, `seq.dispatch` enqueues the steps,
@@ -401,6 +709,9 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
         raise ValueError(
             f"item id {int(seqs.max())} outside the {spec.vocab_size} "
             "vocabulary rows the specification holds")
+    positions = seqs.shape[1] - 1 - spec.mtp_layers
+    if positions < 1:
+        raise ValueError(f"histories of {seqs.shape[1]} ids train nothing")
     steps, batch = p.steps, p.batch_size
     with tracing.span("seq.batch", steps=steps, histories=batch):
         order = epoch_order(len(seqs), steps, batch, p.seed)
@@ -429,8 +740,9 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
                 f"{dropped} routed tokens found no row: the expert layer "
                 "must drop none")
         per_expert = counts.sum(axis=2)                  # a step, a layer
+        held = spec.experts
         sp.update(
-            tokens_per_step=batch * (seqs.shape[1] - 1),
+            tokens_per_step=batch * positions,
             loss_first=repr(float(losses[0])),
             loss_last=repr(float(losses[-1])),
             expert_tokens_min=int(per_expert.min()),
@@ -441,7 +753,25 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
             expert_load_max_over_mean=repr(float(
                 per_expert.max(axis=-1).sum()
                 / max(per_expert.mean(axis=-1).sum(), 1e-9))),
-            dropped_tokens=dropped,
-            **band_counters(spec, seqs.shape[1] - 1))
+            # choices sent to held experts over the held experts' share of
+            # all choices: 1.0 is what a balanced router sends this rank
+            expert_tokens_held_share=repr(float(
+                per_expert.sum() / (steps * counts.shape[1] * batch
+                                    * positions * held.top_k
+                                    * held.n_held / held.n_routed))),
+            dropped_tokens=dropped)
+        if "sliding_attention" in spec.layer_types:
+            sp.update(**band_counters(spec, positions))
+        if spec.mtp_layers:
+            parts = np.stack([c["losses"] for c in counters]).astype(
+                np.float64)
+            sp.update(loss_main_first=repr(float(parts[0, 0])),
+                      loss_mtp_first=repr(float(parts[0, 1])),
+                      loss_main_last=repr(float(parts[-1, 0])),
+                      loss_mtp_last=repr(float(parts[-1, 1])))
         params = jax.device_get(params)
+        if spec.router_bias:
+            sp.update(router_bias_abs_max=repr(float(max(
+                np.abs(lp["router_bias"]).max()
+                for lp in expert_layers(params, spec)))))
     return params, float(losses[-1])

@@ -106,10 +106,13 @@ class SequenceParams(Params):
     # published config.json plus this rank's share of it), as a dict in
     # engine.json; kept as its JSON text so the params stay hashable.
     # With one, the engine trains that decoder stack (RMSNorm, rotary
-    # positions, grouped-query window / full attention, top-k experts,
-    # untied head) on whole histories of max_len items, and embed_dim,
-    # num_heads, num_layers, ffn_dim, attention and the moe_* fields
-    # above, which size the SASRec-style encoder, are not read.
+    # positions, grouped-query window / full or latent attention, dense
+    # or top-k expert layers, untied head) on whole histories of max_len
+    # items (max_len + 1 where the specification has a multi-token-
+    # prediction module: every position then also trains the id after
+    # its next), and embed_dim, num_heads, num_layers, ffn_dim, attention
+    # and the moe_* fields above, which size the SASRec-style encoder,
+    # are not read.
     block_spec: Any = None
 
     def __post_init__(self):
@@ -647,11 +650,18 @@ class SequenceAlgorithm(PAlgorithm):
         # position table); adapt rather than explode on a mismatch —
         # right-aligned truncate (keep the most recent items) or left-pad
         s = data.seqs
-        if s.shape[1] != self.params.max_len:
-            if s.shape[1] > self.params.max_len:
-                s = s[:, -self.params.max_len:]
+        want = self.params.max_len
+        if self.params.block_spec:
+            from pio_tpu.models.seq_blocks import BlockSpec, history_ids
+
+            # a prediction module trains one id more of every history
+            want = history_ids(BlockSpec.parse(self.params.block_spec),
+                               want - 1)
+        if s.shape[1] != want:
+            if s.shape[1] > want:
+                s = s[:, -want:]
             else:
-                s = np.pad(s, ((0, 0), (self.params.max_len - s.shape[1], 0)))
+                s = np.pad(s, ((0, 0), (want - s.shape[1], 0)))
             data = SequenceData(
                 seqs=np.ascontiguousarray(s), users=data.users,
                 items=data.items,
@@ -740,7 +750,9 @@ class SequenceAlgorithm(PAlgorithm):
         if p.block_spec:
             from pio_tpu.models.seq_blocks import BlockSpec, last_logits
 
-            # whole histories only: every resolved row is max_len items
+            # whole histories only: every resolved row is max_len items;
+            # the scores are the main head's (a prediction module trains
+            # the stack and serves nothing)
             return last_logits(model.params,
                                jnp.asarray(rows[:, -(p.max_len - 1):]),
                                BlockSpec.parse(p.block_spec))
@@ -770,7 +782,9 @@ class SequenceAlgorithm(PAlgorithm):
         user = query.get("user", "")
         row = self._live_history(model, user)
         if row is None and user in model.users:
-            row = model.seqs[model.users.index_of(user)]
+            # (a prediction module's snapshot rows hold one id more)
+            row = model.seqs[model.users.index_of(user)][
+                -model.config.max_len:]
         return row
 
     def predict(self, model: SequenceModel, query: dict) -> dict:

@@ -212,13 +212,18 @@ def scope_of_op_name(op_name: str,
                      prefix: str | tuple[str, ...] = SCOPE_PREFIX
                      ) -> str | None:
     """`jit(_train_jit)/while/body/als.user/als.gather/dot_general` ->
-    `als.user/als.gather`: the path's components that are scopes. A
-    differentiated program wraps them (`transpose(jvp(seq.head_loss))`)
-    and an inlined helper repeats the path it was called from: a scope
-    counts wherever it stands, and once where it repeats."""
+    `als.user/als.gather`: the path's components that are scopes, nested
+    as the program nested them (`seq.mtp/seq.attn.full`: the prediction
+    module's attention). A differentiated program wraps them
+    (`transpose(jvp(seq.head_loss))`) and an inlined helper repeats the
+    path it was called from: a scope counts wherever it stands, and a
+    scope that comes again takes the path back to where it first
+    stood."""
     parts: list[str] = []
     for found in _scope_pattern(prefix).findall(op_name):
-        if not parts or parts[-1] != found:
+        if found in parts:
+            del parts[parts.index(found) + 1:]
+        else:
             parts.append(found)
     return "/".join(parts) or None
 

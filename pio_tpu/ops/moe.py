@@ -1,27 +1,35 @@
-"""Mixture-of-experts FFN with expert parallelism (ep) over the mesh.
+"""Mixture-of-experts FFNs: two layers for two engines.
 
-Net-new beyond the reference's capability set (like the sequence family it
-plugs into — SURVEY.md §5 notes the reference has no sequence models at
-all), this is the framework's expert-parallel building block: the MoE FFN
-drops in for the dense FFN of the sequential recommender's transformer
-blocks.
-
-TPU-first design:
+**The capacity-slot Switch layer** (`moe_ffn`, `moe_ffn_ep`; the SASRec-
+style encoder of models/sequence.py, `moe_experts > 0`). Net-new beyond
+the reference's capability set (SURVEY.md section 5 notes the reference
+has no sequence models at all):
  * routing and dispatch are ONE-HOT MATMULS, not gathers: tokens are
    combined into per-expert capacity slots with a (tokens, experts*cap)
-   dispatch matrix — einsums the MXU tiles well, and shapes stay static
+   dispatch matrix, einsums the MXU tiles well, and shapes stay static
    (capacity-dropped tokens pass through on the residual path, the
    standard Switch-Transformer treatment);
  * expert parallelism shards the EXPERT axis over mesh devices with
    `shard_map`: tokens are exchanged to their experts' devices via
-   `jax.lax.all_to_all` over ICI (the collective the reference's Spark
-   shuffle would have played), expert FFNs run local dense matmuls, and a
-   second all_to_all returns expert outputs to the tokens' devices;
- * the router's load-balance auxiliary loss (mean fraction x mean prob per
-   expert) keeps experts busy so capacity drops stay rare.
-
+   `jax.lax.all_to_all` over ICI, expert FFNs run local dense matmuls,
+   and a second all_to_all returns expert outputs to the tokens' devices;
+ * the router's load-balance auxiliary loss (mean fraction x mean prob
+   per expert) keeps experts busy so capacity drops stay rare.
 Single-device (ep=1) and expert-parallel paths compute the same function;
 tests pin them together and pin top-1 routing against a per-token loop.
+
+**One expert-parallel rank's part of a dropless top-k layer**
+(`held_moe_ffn`; the block stack of models/seq_blocks.py). The layer is
+told which experts it holds (`HeldExperts.held` of `n_routed`), routes
+every token over ALL of them and computes what its own add, by a grouped
+matrix product (`grouped_matmul`, Pallas) over rows sorted by expert: no
+capacity, nothing dropped, no exchange on one chip. The router scores by
+softmax (top-k of the probabilities) or by sigmoid (`score="sigmoid"`:
+selection on score + a bias that takes no gradient, weights from the
+unbiased score, normalised and scaled: the aux-loss-free balancing of
+`topk_method: noaux_tc`); with a bias it also counts the tokens of every
+routed expert, which the bias rule of the train step needs. A shared
+expert is the caller's (it is a dense SwiGLU beside this layer).
 """
 
 from __future__ import annotations
@@ -228,6 +236,8 @@ class HeldExperts:
     held: tuple[int, int]          # [lo, hi): the experts this rank holds
     norm_topk: bool = True         # weights divided by their sum over k
     tile_rows: int = 512           # rows of one tile of the grouped product
+    score: str = "softmax"         # or "sigmoid": each expert scored alone
+    scale: float = 1.0             # on the weights (`routed_scaling_factor`)
 
     @property
     def n_held(self) -> int:
@@ -242,13 +252,33 @@ class HeldExperts:
         return -(-most // tm) * tm + self.n_held * tm
 
 
-def route_top_k(logits, top_k: int, norm: bool):
+def route_top_k(logits, top_k: int, norm: bool, score: str = "softmax",
+                bias=None, scale: float = 1.0):
     """(T, E) float32 router logits -> (expert ids (T, k) int32, weights
-    (T, k) float32): the k largest of the softmax, ties to the lower id."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, ids = jax.lax.top_k(probs, top_k)
-    if norm:
+    (T, k) float32): the k largest scores, ties to the lower id. Scores
+    are the softmax over the experts, or with `score="sigmoid"` each
+    expert's own sigmoid. `bias` (E,) moves the selection only: the k
+    largest of score + bias are chosen and weighed by their unbiased
+    scores (no gradient reaches the bias). `norm` divides the weights by
+    their sum over the k, `scale` multiplies them."""
+    logits = logits.astype(jnp.float32)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"router score {score!r}")
+    if bias is None:
+        weights, ids = jax.lax.top_k(scores, top_k)
+    else:
+        _, ids = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+        weights = jnp.take_along_axis(scores, ids, axis=-1)
+    if norm and score == "sigmoid":    # sigmoids can all be tiny
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    elif norm:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return ids.astype(jnp.int32), weights
 
 
@@ -512,15 +542,19 @@ def held_moe_ffn(params, x, cfg: HeldExperts, compute_dtype=jnp.bfloat16):
     """This rank's part of the layer for (T, d) tokens: `sum over the
     held e in top-k(x) of w_e * W_down,e(silu(x W_gate,e) * (x W_up,e))`.
     params: router (d, n_routed), w_gate / w_up (n_held, d, f), w_down
-    (n_held, f, d). Products take `compute_dtype` operands and accumulate
-    in float32. -> (y (T, d) float32, {"counts": tokens per held expert,
-    "dropped": 0})."""
+    (n_held, f, d), and optionally router_bias (n_routed,), which moves
+    the selection and takes no gradient. Products take `compute_dtype`
+    operands and accumulate in float32. -> (y (T, d) float32, {"counts":
+    tokens per held expert, "dropped": 0}, and with a bias "counts_all":
+    tokens per routed expert, held or not, which the bias rule needs)."""
     k, tm = cfg.top_k, cfg.tile_rows
     xc = x.astype(compute_dtype)
     with jax.named_scope("seq.moe.route"):
         logits = jnp.dot(xc, params["router"].astype(compute_dtype),
                          preferred_element_type=jnp.float32)
-        ids, weights = route_top_k(logits, k, cfg.norm_topk)
+        bias = params.get("router_bias")
+        ids, weights = route_top_k(logits, k, cfg.norm_topk, cfg.score,
+                                   bias, cfg.scale)
         plan = dispatch_plan(ids, cfg)
         row_choice, choice_row = plan["row_choice"], plan["choice_row"]
         n_choices = ids.size
@@ -543,4 +577,9 @@ def held_moe_ffn(params, x, cfg: HeldExperts, compute_dtype=jnp.bfloat16):
         weighted = (out_rows.astype(jnp.float32) * w_row[:, None]
                     ).astype(compute_dtype)
         y = tokens_from_rows(weighted, row_choice, choice_row)
-    return y, {"counts": plan["counts"], "dropped": plan["dropped"]}
+    aux = {"counts": plan["counts"], "dropped": plan["dropped"]}
+    if bias is not None:
+        aux["counts_all"] = jnp.sum(
+            ids.reshape(-1, 1) == jnp.arange(cfg.n_routed)[None, :],
+            axis=0, dtype=jnp.int32)
+    return y, aux

@@ -139,6 +139,40 @@ def test_scopes_reach_the_text_and_change_only_metadata(
     jax.clear_caches()
 
 
+def consecutive_rule(op_name: str) -> str | None:
+    """`scope_of_op_name` as it was before the sequence stack nested its
+    scopes (PR 33): a scope counted once only where it repeated at
+    once."""
+    parts: list[str] = []
+    for found in profile._scope_pattern(profile.SCOPE_PREFIX).findall(
+            op_name):
+        if not parts or parts[-1] != found:
+            parts.append(found)
+    return "/".join(parts) or None
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_repeated_scope_rule_moves_only_joined_names_of_one_scope(case):
+    """A scope that comes again now takes the path back to where it
+    first stood (the prediction module's `seq.mtp/...` under an inlined
+    helper). In an ALS program that moves only a fused operation whose
+    name joins several paths of ONE scope with `;`: the older rule read
+    `als.item/als.flush;als.item/als.flush` as a scope four deep that
+    nothing asks for, this one as `als.item/als.flush`. Every other
+    operation keeps its scope. (No benchmark metric reads an ALS scope:
+    theirs come from benchmark/harness/trace.py.)"""
+    lower, cg_iters, _ = CASES[case]
+    names = set(profile._OP_NAME.findall(lower(cg_iters).compile().as_text()))
+    assert len(names) > 100
+    moved = {n for n in names
+             if profile.scope_of_op_name(n) != consecutive_rule(n)}
+    assert len(moved) < len(names) // 10
+    for name in moved:
+        parts = {consecutive_rule(part) for part in name.split(";")}
+        assert len(name.split(";")) > 1 and len(parts) == 1
+        assert profile.scope_of_op_name(name) == parts.pop()
+
+
 def _entry_instructions(hlo: str) -> list[str]:
     """Names of the entry computation's instructions: what a trace shows
     as operations when the module has no loop (a fusion's inside runs as

@@ -267,6 +267,171 @@ def test_a_history_with_padding_is_refused():
      "als.user/als.gather"),
     ("jit(f)/while/body/dot_general", None),
     ("jit(f)/my.seq.thing/add", None),
+    # the prediction module's scopes nest under its own
+    ("jit(step)/jvp(seq.mtp)/seq.attn.latent/dot_general",
+     "seq.mtp/seq.attn.latent"),
+    ("jit(step)/transpose(jvp(seq.mtp))/seq.head_loss/while/body/"
+     "dot_general", "seq.mtp/seq.head_loss"),
+    ("jit(step)/transpose(jvp(seq.mtp))/while/body/closed_call/checkpoint/"
+     "rematted_computation/seq.mtp/seq.moe.route/jit(searchsorted)/"
+     "jit(step)/transpose(jvp(seq.mtp))/while/body/closed_call/checkpoint/"
+     "rematted_computation/seq.mtp/seq.moe.route/sort",
+     "seq.mtp/seq.moe.route"),
+    ("jit(step)/jvp(seq.mtp)/seq.embed/gather", "seq.mtp/seq.embed"),
+    ("jit(step)/jvp(seq.mtp)/concatenate", "seq.mtp"),
+    ("jit(step)/seq.optimizer/sign", "seq.optimizer"),
 ])
 def test_profile_joins_the_stacks_scopes(op_name, scope):
     assert profile.scope_of_op_name(op_name) == scope
+
+
+# -- what PR 33 added to the stack leaves the older configuration alone ------
+
+def _step_lowered(cfg, learning_rate, batch):
+    spec = seq_blocks.BlockSpec.parse(cfg)
+    optimizer, step = seq_blocks.make_train_step(spec, learning_rate)
+    shapes = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32),
+        seq_blocks.param_shapes(spec), is_leaf=lambda x: isinstance(x, tuple))
+    return step.lower(shapes, jax.eval_shape(optimizer.init, shapes),
+                      jax.ShapeDtypeStruct(batch, jnp.int32))
+
+
+def _step_text(cfg, learning_rate, batch):
+    return _step_lowered(cfg, learning_rate, batch).as_text()
+
+
+def _sha(text):
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def test_the_window_and_full_stacks_step_program_is_pr_32s():
+    """Latent attention, the dense layer, the shared expert, the sigmoid
+    router and the prediction module are chosen by the specification: a
+    specification without them lowers to the program text it lowered to
+    before they existed (the hash is of commit 2ef95e1's text, at this
+    file's small blocks)."""
+    assert _sha(_step_text(CFG, 0.0625, (2, 41))) == (
+        "aa11e810e4f869eb5715e71d199f621dcca3eaf2335d867f8148239aafe03fa3")
+
+
+def test_mellum2_12b_ep4s_step_program_is_pr_32s(monkeypatch):
+    """The benchmark's configuration at its timed shapes and the
+    program's own blocks: the parent's text, by hash."""
+    import json
+    import os
+
+    from benchmark import engines_sequence as es
+
+    monkeypatch.undo()                   # the published widths' blocks
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", "mellum2-12b-ep4.json")
+    with open(path) as f:
+        cfg = es.block_spec_of(json.load(f))
+    assert _sha(_step_text(cfg, 1e-4, (2, 8193))) == (
+        "7474c5325816f267abeb78211d9f88bdb632e8297836a89e47764ebfa962a156")
+
+
+def test_the_repeated_scope_rule_moves_no_path_of_the_older_stack():
+    """`scope_of_op_name` now takes a path back to a scope that comes
+    again (the prediction module's nested scopes need it). The window
+    and full stack's scopes are flat: every operation of its compiled
+    step keeps the scope the older rule gave it, so no metric of the
+    cell `mellum2-12b-ep4.train-8k` reads other operations than before
+    (at the published widths, compiled for a described v5e: PERF.md)."""
+    from test_als_scopes import consecutive_rule
+
+    names = set(profile._OP_NAME.findall(
+        _step_lowered(CFG, 0.0625, (2, 41)).compile().as_text()))
+    scopes = {profile.scope_of_op_name(n) for n in names}
+    assert {"seq.attn.window", "seq.attn.full", "seq.moe.route",
+            "seq.moe.gmm", "seq.head_loss", "seq.optimizer"} <= scopes
+    assert len(names) > 300
+    assert not {n for n in names
+                if profile.scope_of_op_name(n) != consecutive_rule(n)}
+
+
+LATENT_CFG = {
+    "hidden_size": 32, "num_hidden_layers": 3, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "q_lora_rank": 12, "kv_lora_rank": 10,
+    "qk_nope_head_dim": 12, "qk_rope_head_dim": 4, "v_head_dim": 16,
+    "rope_theta": 10000, "rms_norm_eps": 1e-5, "first_k_dense_replace": 1,
+    "intermediate_size": 48, "moe_intermediate_size": 16,
+    "n_routed_experts": 4, "num_experts_routed": 8, "experts_held": [0, 4],
+    "n_shared_experts": 1, "num_experts_per_tok": 3, "norm_topk_prob": True,
+    "routed_scaling_factor": 1.8, "topk_method": "noaux_tc",
+    "num_nextn_predict_layers": 1, "vocab_size": 50,
+    "tie_word_embeddings": False, "initializer_range": 0.3,
+}
+
+
+def test_the_latent_stacks_step_program_does_not_hold_the_step_count():
+    spec = seq_blocks.BlockSpec.parse(LATENT_CFG)
+    _, step = seq_blocks.make_train_step(spec, 0.02)
+    for steps in (2, 4):
+        p = SequenceParams(max_len=41, batch_size=2, steps=steps,
+                           learning_rate=0.02, seed=3, block_spec=LATENT_CFG)
+        seq_blocks.train_lm(_data(length=42).seqs, p)
+    assert step._cache_size() == 1
+
+
+def test_the_engine_trains_persists_and_serves_the_latent_stack(monkeypatch):
+    """`SequenceAlgorithm.train` keeps max_len + 1 ids of a history where
+    the specification has a prediction module, the job's counters carry
+    both losses and the bias rule's sums, the persisted model holds the
+    biases, and the scores are the main head's."""
+    from benchmark.reference import latent_moe_lm
+    import contextlib
+
+    from pio_tpu.workflow.checkpoint import models_from_bytes, models_to_bytes
+
+    spans = {}
+
+    @contextlib.contextmanager
+    def span(name, **labels):
+        spans[name] = dict(labels)
+        yield spans[name]
+
+    monkeypatch.setattr(seq_blocks.tracing, "span", span)
+    p = SequenceParams(max_len=41, batch_size=2, steps=4, learning_rate=0.02,
+                       seed=11, block_spec=LATENT_CFG)
+    algo = SequenceAlgorithm(p)
+    model = algo.train(None, _data(length=44))     # 2 ids too many
+    assert model.seqs.shape == (8, 42)
+    spec = seq_blocks.BlockSpec.parse(LATENT_CFG)
+    want = jax.tree_util.tree_map(
+        lambda s: s, seq_blocks.param_shapes(spec),
+        is_leaf=lambda x: isinstance(x, tuple))
+    assert jax.tree_util.tree_map(lambda x: x.shape, model.params) == want
+    labels = spans["seq.wait"]
+    for key in ("loss_main_first", "loss_mtp_first", "loss_main_last",
+                "loss_mtp_last", "router_bias_abs_max",
+                "expert_tokens_held_share"):
+        assert key in labels, key
+    assert "window_blocks_visited" not in labels      # no window layer
+    assert float(labels["loss_first"]) == pytest.approx(
+        float(labels["loss_main_first"])
+        + 0.3 * float(labels["loss_mtp_first"]), rel=1e-6)
+    got = np.stack([lp["router_bias"]
+                    for lp in seq_blocks.expert_layers(model.params, spec)])
+    assert got.shape == (3, 8)                  # 2 stack routers, 1 module
+    moves = got / seq_blocks.ROUTER_BIAS_RATE   # whole, at most one a step
+    np.testing.assert_allclose(moves, np.round(moves), atol=1e-4)
+    assert 1 <= np.abs(moves).max() <= 4
+    assert float(labels["router_bias_abs_max"]) == pytest.approx(
+        np.abs(got).max())
+    loaded = models_from_bytes(models_to_bytes([model]))[0]
+    out = algo.batch_predict(loaded, [{"user": "u1", "num": 5},
+                                      {"user": "nobody"}])
+    assert len(out[0]["itemScores"]) == 5 and out[1]["itemScores"] == []
+    # the scores are the reference's main head at the last position
+    # (the reference's main logits read every id but the last it is given)
+    rows = model.seqs[[1]]
+    want_scores = latent_moe_lm.logits(
+        jax.tree_util.tree_map(jnp.asarray, model.params),
+        jnp.asarray(np.pad(rows[:, -40:], ((0, 0), (0, 1)),
+                           constant_values=1)), LATENT_CFG)[0][:, -1]
+    got_scores = algo._score_last_batch(loaded, rows[:, -41:])
+    np.testing.assert_allclose(got_scores, want_scores, atol=2e-4, rtol=2e-4)
