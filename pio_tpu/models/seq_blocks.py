@@ -678,6 +678,18 @@ def epoch_order(n: int, steps: int, batch: int, seed: int) -> np.ndarray:
     return order[np.arange(steps * batch) % n].reshape(steps, batch)
 
 
+def tiles_used_share(counts: np.ndarray, held: HeldExperts,
+                     positions: int) -> float:
+    """Tiles of the sorted buffer that hold a group, which are the tiles
+    the grouped products and the move into the buffer visit, over the
+    buffer's tiles. counts (..., held experts): a history's tokens per
+    held expert; the mean over everything before. An empty group owns
+    one tile."""
+    tm = held.tile_rows
+    used = np.maximum(-(-counts // tm), 1).sum(axis=-1)
+    return float(used.mean() / (held.row_capacity(positions) // tm))
+
+
 def band_counters(spec: BlockSpec, seq_len: int) -> dict:
     """Key blocks the attention kernels visit in one (batch, head)
     program of a window layer, against the blocks in the band."""
@@ -759,6 +771,10 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
                 per_expert.sum() / (steps * counts.shape[1] * batch
                                     * positions * held.top_k
                                     * held.n_held / held.n_routed))),
+            # a history's share of the worst-case buffer that holds rows;
+            # only every choice of every token held fills it
+            expert_tiles_used_share=repr(tiles_used_share(
+                counts, held, positions)),
             dropped_tokens=dropped)
         if "sliding_attention" in spec.layer_types:
             sp.update(**band_counters(spec, positions))

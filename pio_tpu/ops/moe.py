@@ -220,10 +220,22 @@ def moe_ffn_ep(params, x, cfg: MoEConfig, mesh: Mesh, axis: str = "data"):
 # dropped), each group padded to whole tiles, and the expert products run
 # as one grouped matrix product over the tiles (`grouped_matmul`, a
 # Pallas kernel; a tile belongs to one expert). The buffer is sized for
-# the most the held experts can be sent, and the kernels pass over the
-# tiles behind the last group (`tiles_used`, prefetched with the tiles'
-# experts): the products' time follows what the router really sent. The
-# row gathers around them still run over the whole buffer (ROADMAP R1 d).
+# the most the held experts can be sent, but little follows its size:
+# the groups are packed at its front, `tiles_used` tiles hold them, and
+# the kernels (which prefetch it with the tiles' experts) and the move
+# into the buffer visit those tiles alone. The plan (`dispatch_plan`) is
+# one sort of the choices by expert, one running count of each expert's
+# tokens and a few numbers a tile: a tile's rows are a slice of the
+# sorted order (so `rows_from_tokens` is a loop of `tiles_used` steps,
+# each gathering one tile's token rows), and a choice's row is its
+# group's first row plus the tokens before it that chose the expert (so
+# `tokens_from_rows` gathers k rows a token). Nothing is scattered,
+# forward or backward, and no scalar is gathered for every row or every
+# choice: on this chip a scatter takes 0.3 ms to begin and 45-120 ns an
+# update, a gathered row 5-8 ns (PR 34, PERF.md section 6). Rows of
+# tiles behind `tiles_used` are never written and never added up. What
+# is left over the whole buffer is SwiGLU between the products, and over
+# every choice the gather of the return (ROADMAP R1 d).
 # What the absent experts would add is left out: on the chips of a
 # deployment the exchange adds it, and on one chip the layer runs without
 # its exchange.
@@ -268,11 +280,14 @@ def route_top_k(logits, top_k: int, norm: bool, score: str = "softmax",
         scores = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"router score {score!r}")
-    if bias is None:
-        weights, ids = jax.lax.top_k(scores, top_k)
-    else:
-        _, ids = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
-        weights = jnp.take_along_axis(scores, ids, axis=-1)
+    chosen_by = scores if bias is None else (
+        scores + jax.lax.stop_gradient(bias))
+    _, ids = jax.lax.top_k(chosen_by, top_k)
+    # a choice's own score by comparison, not `take_along_axis`: no scalar
+    # is gathered, and none scattered when the gradient comes back
+    weights = jnp.sum(jnp.where(
+        ids[..., None] == jnp.arange(scores.shape[-1]),
+        scores[..., None, :], 0.0), axis=-1)
     if norm and score == "sigmoid":    # sigmoids can all be tiny
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
     elif norm:
@@ -284,43 +299,52 @@ def route_top_k(logits, top_k: int, norm: bool, score: str = "softmax",
 
 def dispatch_plan(ids, cfg: HeldExperts) -> dict:
     """Where each (token, choice) goes in the buffer sorted by held
-    expert. -> row_choice (M,): the flat (token, choice) a row holds, or
-    T*k where the row is padding; choice_row (T, k): the row of a choice,
-    or M where its expert is not held; tile_expert (M / tile,): the
-    tiles' experts (the last expert's past the last row); tiles_used
-    (1,): the tiles that hold a group, the rest is padding; counts
-    (n_held,): tokens per held expert; dropped (): choices of held
-    experts that found no row (0 by construction; reported, asserted by
-    the trainer)."""
+    expert, both ways, for ids (T, k) that name an expert at most once a
+    token. -> order (T*k + tile,): the flat (token, choice) indices
+    sorted by held expert, ties in their own order; tile_expert (M /
+    tile,): the tiles' experts (the last expert's past the last group);
+    tile_start, tile_valid (M / tile,): tile i's first `tile_valid[i]`
+    rows hold the choices `order[tile_start[i]:][:tile_valid[i]]`, its
+    other rows are padding; tiles_used (1,): the tiles that hold a group
+    (an empty group owns one tile of padding), nothing behind them is
+    written or added up; choice_row (T, k): the row of a choice, or M
+    where its expert is not held; counts (n_held,): tokens per held
+    expert; dropped (): choices of held experts that found no row (0 by
+    construction; reported, asserted by the trainer)."""
     t, k = ids.shape
     lo, hi = cfg.held
     n_held, tm = cfg.n_held, cfg.tile_rows
-    n_choices, cap = t * k, cfg.row_capacity(t)
-    flat = ids.reshape(-1)
-    local = jnp.where((flat >= lo) & (flat < hi), flat - lo, n_held)
-    order = jnp.argsort(local, stable=True).astype(jnp.int32)
-    sorted_local = local[order]
-    counts = jnp.sum(local[:, None] == jnp.arange(n_held)[None, :],
-                     axis=0, dtype=jnp.int32)
+    cap = cfg.row_capacity(t)
+    n_tiles = cap // tm
+    experts = jnp.arange(n_held, dtype=jnp.int32)
+    local = jnp.where((ids >= lo) & (ids < hi), ids - lo, n_held)
+    order = jnp.argsort(local.reshape(-1), stable=True).astype(jnp.int32)
+    is_expert = local[:, :, None] == experts        # (T, k, n_held)
+    chose = jnp.sum(is_expert, axis=1, dtype=jnp.int32)
+    before = jnp.cumsum(chose, axis=0)              # (T, n_held)
+    counts = before[-1]
     padded = jnp.maximum(-(-counts // tm) * tm, tm)
     pad_end = jnp.cumsum(padded)
-    group_start = jnp.cumsum(counts) - counts
-    g = jnp.minimum(sorted_local, n_held - 1)
-    rank = jnp.arange(n_choices, dtype=jnp.int32) - group_start[g]
-    row_sorted = jnp.where(sorted_local < n_held,
-                           (pad_end - padded)[g] + rank, cap)
-    choice_row = jnp.zeros(n_choices, jnp.int32).at[order].set(
-        row_sorted, unique_indices=True).reshape(t, k)
-    row_choice = jnp.full(cap + 1, n_choices, jnp.int32).at[
-        row_sorted].set(order)[:cap]
+    pad_start = pad_end - padded
+    first_row = jnp.arange(n_tiles, dtype=jnp.int32) * tm
     tile_expert = jnp.minimum(
-        jnp.searchsorted(pad_end, jnp.arange(cap // tm) * tm, side="right"),
-        n_held - 1).astype(jnp.int32)
-    placed = jnp.sum(row_choice < n_choices)
-    return {"row_choice": row_choice, "choice_row": choice_row,
-            "tile_expert": tile_expert,
-            "tiles_used": (pad_end[-1:] // tm).astype(jnp.int32),
-            "counts": counts, "dropped": jnp.sum(counts) - placed}
+        jnp.sum(pad_end[None, :] <= first_row[:, None], axis=1,
+                dtype=jnp.int32), n_held - 1)
+    # a tile's place in its group, from a handful of numbers an expert
+    into = first_row - pad_start[tile_expert]
+    tile_valid = jnp.clip(counts[tile_expert] - into, 0, tm)
+    tile_start = (jnp.cumsum(counts) - counts)[tile_expert] + into
+    tiles_used = jnp.minimum(pad_end[-1:] // tm, n_tiles).astype(jnp.int32)
+    # a choice's row: its group's first, and the earlier tokens' of it
+    row = (pad_start + before - chose)[:, None, :]  # (T, 1, n_held)
+    choice_row = jnp.sum(jnp.where(is_expert, row, 0), axis=2)
+    choice_row = jnp.where((local < n_held) & (choice_row < cap),
+                           choice_row, cap)
+    return {"order": jnp.concatenate([order, jnp.zeros(tm, jnp.int32)]),
+            "tile_expert": tile_expert, "tile_start": tile_start,
+            "tile_valid": tile_valid, "tiles_used": tiles_used,
+            "choice_row": choice_row, "counts": counts,
+            "dropped": jnp.sum(counts) - jnp.sum(choice_row < cap)}
 
 
 def _tile(dim: int, most: int) -> int:
@@ -482,57 +506,139 @@ def _gmm_bwd(tile_rows, res, dy):
 grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
 
 
-def _rows_of(x, row_choice, k: int):
-    """(T, d) tokens -> (M, d) rows: row r holds token row_choice[r] // k,
-    zeros where it is padding."""
-    n_choices = x.shape[0] * k
-    rows = x[jnp.minimum(row_choice, n_choices - 1) // k]
-    return jnp.where((row_choice < n_choices)[:, None], rows, 0)
+def _unwritten(shape, dtype):
+    """The sorted buffer before its tiles are written; what a row behind
+    `tiles_used` holds is never read (a test fills it with NaN). Zeros: a
+    buffer taken unwritten from a kernel that does nothing saved the
+    pass over it and cost the step program 0.26 GB (PR 34)."""
+    return jnp.zeros(shape, dtype)
 
 
-def _tokens_of(y, choice_row):
-    """(M, d) rows -> (T, d): a token's sum over its choices' rows, in
-    float32; a choice whose expert is not held adds nothing."""
-    m = y.shape[0]
-    rows = y[jnp.minimum(choice_row, m - 1)]                # (T, k, d)
-    rows = jnp.where((choice_row < m)[..., None], rows, 0)
-    return jnp.sum(rows.astype(jnp.float32), axis=1)
+def _tile_rows(plan) -> int:
+    """Rows of a tile: `order` is the T * k choices and one tile more."""
+    return plan["order"].shape[0] - plan["choice_row"].size
 
 
-# the two moves are each other's transpose: written out, so the backward
-# pass gathers rows too instead of scatter-adding them
+def _tile_choices(plan, i):
+    """Tile i -> (its rows' place in the sorted order, their flat choices
+    (tile,), which of the rows hold one)."""
+    tm = _tile_rows(plan)
+    start = plan["tile_start"][i]
+    choice = jax.lax.dynamic_slice(plan["order"], (start,), (tm,))
+    valid = jnp.arange(tm, dtype=jnp.int32) < plan["tile_valid"][i]
+    return start, choice, valid
+
+
+def _rows_of(x, plan, weights=None):
+    """(T, d) tokens -> (M, d) rows, `tiles_used` tiles of them: a row
+    holds its choice's token, times the choice's weight where (T, k)
+    weights are given, padding rows of a tile zero."""
+    k, tm = plan["choice_row"].shape[1], _tile_rows(plan)
+
+    def tile(i, rows):
+        _, choice, valid = _tile_choices(plan, i)
+        part = x[choice // k]
+        if weights is not None:
+            part = (part.astype(jnp.float32)
+                    * weights.reshape(-1)[choice][:, None]).astype(x.dtype)
+        part = jnp.where(valid[:, None], part, 0)
+        return jax.lax.dynamic_update_slice(rows, part, (i * tm, 0))
+
+    return jax.lax.fori_loop(
+        0, plan["tiles_used"][0], tile,
+        _unwritten((plan["tile_expert"].shape[0] * tm, x.shape[1]), x.dtype))
+
+
+# The chip gathers rows 4-5 times faster from a table the compiler can
+# keep in its fast memory (128 MiB on a v5e; 2.4 against 0.55-0.64 ms for
+# 65,536 rows of 2,304 bf16: PR 34). The groups are packed at the
+# buffer's front, so while they end inside this many bytes the return
+# gathers from that front alone.
+FRONT_BYTES = 96 << 20
+
+
+def _tokens_of(y, plan, weights=None):
+    """(M, d) rows -> (T, d): a token's sum over its held choices' rows,
+    times their weights where (T, k) weights are given, in float32. A
+    choice a (T, d) slab, so the sum adds whole slabs; a choice that is
+    not held reads the row of its own token's number and counts as zero
+    (all of them reading one row was slower still)."""
+    row = plan["choice_row"].T                              # (k, T)
+    m, d = y.shape
+    held = row < m
+    tm = _tile_rows(plan)
+    front = min(m, FRONT_BYTES // (d * y.dtype.itemsize) // tm * tm)
+    at = jnp.where(held, row, jnp.arange(row.shape[1]) % front)
+    rows = jax.lax.cond(plan["tiles_used"][0] * tm <= front,
+                        lambda: y[:front][at], lambda: y[at])
+    total = None
+    for j in range(row.shape[0]):       # one pass: no (k, T, d) in float32
+        part = rows[j].astype(jnp.float32)
+        if weights is not None:
+            part = part * weights[:, j, None]
+        part = jnp.where(held[j][:, None], part, 0.0)
+        total = part if total is None else total + part
+    return total
+
+
+# the two moves are each other's transpose: written out, because a loop
+# whose trip count the routing gives has no derivative of its own, and
+# so that the backward pass gathers rows too instead of scattering them
 @jax.custom_vjp
-def rows_from_tokens(x, row_choice, choice_row):
-    return _rows_of(x, row_choice, choice_row.shape[1])
+def rows_from_tokens(x, plan):
+    """(T, d) tokens -> the (M, d) buffer sorted by held expert."""
+    return _rows_of(x, plan)
 
 
-def _rft_fwd(x, row_choice, choice_row):
-    return rows_from_tokens(x, row_choice, choice_row), (
-        row_choice, choice_row)
+def _rft_fwd(x, plan):
+    return _rows_of(x, plan), plan
 
 
-def _rft_bwd(res, g):
-    _, choice_row = res
-    return _tokens_of(g, choice_row).astype(g.dtype), None, None
+def _rft_bwd(plan, g):
+    return _tokens_of(g, plan).astype(g.dtype), None
 
 
 rows_from_tokens.defvjp(_rft_fwd, _rft_bwd)
 
 
 @jax.custom_vjp
-def tokens_from_rows(y, row_choice, choice_row):
-    return _tokens_of(y, choice_row)
+def tokens_from_rows(y, weights, plan):
+    """(M, d) rows and the (T, k) weights of every choice -> (T, d)
+    float32: each token's weighted sum over its held choices' rows."""
+    return _tokens_of(y, plan, weights)
 
 
-def _tfr_fwd(y, row_choice, choice_row):
-    return tokens_from_rows(y, row_choice, choice_row), (
-        row_choice, choice_row, jnp.zeros((), y.dtype))
+def _tfr_fwd(y, weights, plan):
+    return _tokens_of(y, plan, weights), (y, weights, plan)
 
 
 def _tfr_bwd(res, g):
-    row_choice, choice_row, like = res
-    return (_rows_of(g.astype(like.dtype), row_choice,
-                     choice_row.shape[1]), None, None)
+    """d rows: the tokens' cotangents moved like the tokens, times the
+    weights. d weights: a row's product with its token's cotangent,
+    written at the row's place in the sorted order a tile at a time, and
+    brought back to the choices' order by a sort on that order."""
+    y, weights, plan = res
+    t, k = weights.shape
+    tm = _tile_rows(plan)
+    gc = g.astype(y.dtype)
+
+    def tile(i, at_place):
+        start, choice, valid = _tile_choices(plan, i)
+        own = jnp.sum(
+            jax.lax.dynamic_slice(y, (i * tm, 0), (tm, y.shape[1])
+                                  ).astype(jnp.float32)
+            * gc[choice // k].astype(jnp.float32), axis=1)
+        # a padding row's place is the next group's first: left as it is
+        kept = jax.lax.dynamic_slice(at_place, (start,), (tm,))
+        return jax.lax.dynamic_update_slice(
+            at_place, jnp.where(valid, own, kept), (start,))
+
+    at_place = jax.lax.fori_loop(
+        0, plan["tiles_used"][0], tile,
+        jnp.zeros(plan["order"].shape, jnp.float32))
+    _, dw = jax.lax.sort((plan["order"][:t * k], at_place[:t * k]),
+                         num_keys=1)
+    return _rows_of(gc, plan, weights), dw.reshape(t, k), None
 
 
 tokens_from_rows.defvjp(_tfr_fwd, _tfr_bwd)
@@ -556,12 +662,7 @@ def held_moe_ffn(params, x, cfg: HeldExperts, compute_dtype=jnp.bfloat16):
         ids, weights = route_top_k(logits, k, cfg.norm_topk, cfg.score,
                                    bias, cfg.scale)
         plan = dispatch_plan(ids, cfg)
-        row_choice, choice_row = plan["row_choice"], plan["choice_row"]
-        n_choices = ids.size
-        w_row = jnp.where(
-            row_choice < n_choices,
-            weights.reshape(-1)[jnp.minimum(row_choice, n_choices - 1)], 0.0)
-        rows = rows_from_tokens(xc, row_choice, choice_row)
+        rows = rows_from_tokens(xc, plan)
     with jax.named_scope("seq.moe.gmm"):
         f = params["w_gate"].shape[2]
         w_in = jnp.concatenate([params["w_gate"].astype(compute_dtype),
@@ -574,9 +675,7 @@ def held_moe_ffn(params, x, cfg: HeldExperts, compute_dtype=jnp.bfloat16):
         out_rows = grouped_matmul(
             hidden, params["w_down"].astype(compute_dtype), *tiles, tm)
     with jax.named_scope("seq.moe.combine"):
-        weighted = (out_rows.astype(jnp.float32) * w_row[:, None]
-                    ).astype(compute_dtype)
-        y = tokens_from_rows(weighted, row_choice, choice_row)
+        y = tokens_from_rows(out_rows, weights, plan)
     aux = {"counts": plan["counts"], "dropped": plan["dropped"]}
     if bias is not None:
         aux["counts_all"] = jnp.sum(

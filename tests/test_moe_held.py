@@ -114,24 +114,205 @@ def test_route_top_k_weights():
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_dispatch_plan_places_every_held_choice_once(seed):
-    cfg = HeldExperts(E, K, (2, 5), True, 8)
-    ids = jnp.asarray(np.random.default_rng(seed).integers(0, E, (T, K)),
-                      jnp.int32)
-    plan = jax.tree_util.tree_map(np.asarray, dispatch_plan(ids, cfg))
+    """The plan's contract: every held choice is one row of a tile of
+    its expert, a tile's rows that hold one are its first, `tiles_used`
+    counts the tiles that hold a group, and `choice_row` says the same
+    from the choices' side."""
+    tm = 8
+    cfg = HeldExperts(E, K, (2, 5), True, tm)
+    rng = np.random.default_rng(seed)
+    ids = np.argsort(rng.random((T, E)), axis=1)[:, :K]  # distinct a token
+    plan = jax.tree_util.tree_map(
+        np.asarray, dispatch_plan(jnp.asarray(ids, jnp.int32), cfg))
+    n_tiles = cfg.row_capacity(T) // tm
+    assert plan["order"].shape == (T * K + tm,)
+    for name in ("tile_expert", "tile_start", "tile_valid"):
+        assert plan[name].shape == (n_tiles,), name
+    flat = ids.reshape(-1)
+    counts = [(flat == e).sum() for e in range(2, 5)]
+    assert plan["counts"].tolist() == counts
+    used = int(plan["tiles_used"][0])
+    assert used == sum(max(-(-c // tm), 1) for c in counts) < n_tiles
     cap = cfg.row_capacity(T)
-    assert plan["row_choice"].shape == (cap,) and cap % 8 == 0
-    flat = np.asarray(ids).reshape(-1)
-    rows = plan["choice_row"].reshape(-1)
+    choice_row = plan["choice_row"].reshape(-1)
+    placed, tiles_of = [], {0: [], 1: [], 2: []}
+    for i in range(used):
+        valid = int(plan["tile_valid"][i])
+        assert 0 <= valid <= tm
+        rows = plan["order"][plan["tile_start"][i]:][:valid]
+        assert (flat[rows] == plan["tile_expert"][i] + 2).all()
+        assert (np.diff(rows // K) > 0).all()   # distinct tokens, ascending
+        assert (choice_row[rows] == i * tm + np.arange(valid)).all()
+        placed += rows.tolist()
+        tiles_of[int(plan["tile_expert"][i])].append(valid)
     held = (flat >= 2) & (flat < 5)
-    assert (rows[~held] == cap).all() and (rows[held] < cap).all()
-    assert len(set(rows[held].tolist())) == held.sum()
-    assert (plan["row_choice"][rows[held]] == np.nonzero(held)[0]).all()
-    # a tile's rows are one expert's, and every expert owns a tile
-    tile_of = plan["tile_expert"][rows[held] // 8]
-    assert (tile_of == flat[held] - 2).all()
-    assert set(plan["tile_expert"].tolist()) == {0, 1, 2}
+    assert sorted(placed) == np.nonzero(held)[0].tolist()
+    assert (choice_row[~held] == cap).all() and (choice_row[held] < cap).all()
+    for e, valids in tiles_of.items():          # full tiles, then the rest
+        assert valids and all(v == tm for v in valids[:-1])
+        assert sum(valids) == counts[e]
     assert (np.diff(plan["tile_expert"]) >= 0).all()
+    assert (plan["tile_expert"][used:] == 2).all()
     assert int(plan["dropped"]) == 0
+
+
+T_R = 200                    # tokens of the routings below
+
+
+def _routed(routing: str, tm: int, seed=5):
+    """A layer whose router sends every token where `routing` says:
+    feature c of a token of class c is 1, and the router's row c holds
+    that class's logits (3, 2, 1 for its three choices in order), far
+    over what the other features add."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    classes = {"one held expert gets none": [(T_R, (2, 3, 6))],
+               "every token to one expert": [(T_R, (3, 0, 1))],
+               "every choice held": [(T_R, (2, 3, 4))],
+               "groups of tile - 1, tile, tile + 1": [
+                   (tm - 1, (2, 0, 1)), (tm, (3, 0, 1)), (tm + 1, (4, 0, 1)),
+                   (T_R - 3 * tm, (0, 1, 5))]}[routing]
+    router = np.array(jax.random.normal(ks[0], (D, E))) * 0.01
+    x = np.array(jax.random.normal(ks[4], (T_R, D)))
+    x[:, :len(classes)] = 0.0
+    at = 0
+    for c, (n, chosen) in enumerate(classes):
+        x[at:at + n, c] = 1.0
+        router[c] = 0.0
+        router[c, list(chosen)] = [3.0, 2.0, 1.0]
+        at += n
+    x = x[np.random.default_rng(seed).permutation(T_R)]
+    full = {"router": jnp.asarray(router),
+            "w_gate": jax.random.normal(ks[1], (E, D, F)) * 0.2,
+            "w_up": jax.random.normal(ks[2], (E, D, F)) * 0.2,
+            "w_down": jax.random.normal(ks[3], (E, F, D)) * 0.2}
+    want = {e: sum(n for n, chosen in classes if e in chosen)
+            for e in range(2, 5)}
+    return (full, jnp.asarray(x), jax.random.normal(ks[5], (T_R, D)),
+            [want[e] for e in range(2, 5)])
+
+
+def _equals_the_reference(routing, tile):
+    full, x, w, counts = _routed(routing, tile)
+
+    def part(f, x):
+        return _part(f, x, 2, 5, tile=tile)
+
+    y, aux = part(full, x)
+    assert np.asarray(aux["counts"]).tolist() == counts
+    assert int(aux["dropped"]) == 0
+    np.testing.assert_allclose(y, _whole(full, x, True, (2, 5)),
+                               atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda f, x: jnp.sum(part(f, x)[0] * w), (0, 1))(full, x)
+    want = jax.grad(lambda f, x: jnp.sum(_whole(f, x, True, (2, 5)) * w),
+                    (0, 1))(full, x)
+    for name in ("w_gate", "w_up", "w_down"):
+        np.testing.assert_allclose(got[0][name][2:5], want[0][name][2:5],
+                                   atol=5e-5, rtol=5e-5, err_msg=name)
+    np.testing.assert_allclose(got[0]["router"], want[0]["router"],
+                               atol=5e-5, rtol=5e-5)
+    np.testing.assert_allclose(got[1], want[1], atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("tile", [8, 16, 64])
+@pytest.mark.parametrize("routing", [
+    "one held expert gets none", "every token to one expert",
+    "every choice held", "groups of tile - 1, tile, tile + 1"])
+def test_output_and_gradients_at_the_routings_edges(routing, tile):
+    """Empty groups, one group with everything, the buffer full, groups
+    that straddle a tile's edge: the layer and every gradient equal the
+    float32 reference's."""
+    _equals_the_reference(routing, tile)
+
+
+@pytest.mark.parametrize("routing", [
+    "groups of tile - 1, tile, tile + 1",     # 4 tiles: inside the front
+    "every token to one expert",              # 27 tiles: past its end
+])
+def test_the_return_reads_the_front_or_the_whole_buffer(routing, monkeypatch):
+    """The return gathers from the buffer's front while the groups end
+    inside `FRONT_BYTES`: here the front is 4 tiles of the 78."""
+    from pio_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "FRONT_BYTES", 4 * 8 * D * 4)
+    _equals_the_reference(routing, 8)
+
+
+def _moves(jaxpr, inside_loop=False):
+    """(primitive, entries moved, their width, inside a loop) of every
+    gather and scatter of a jaxpr, through every jaxpr its equations
+    hold."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            indices = eqn.invars[1].aval.shape
+            moved = eqn.outvars[0] if name == "gather" else eqn.invars[2]
+            entries = int(np.prod(indices[:-1]))
+            found.append((name, entries, moved.aval.size // entries,
+                          inside_loop))
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else [val]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _moves(sub, inside_loop or name == "while")
+    return found
+
+
+@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
+def test_nothing_is_scattered_and_no_scalar_gathered_a_row_or_choice(score):
+    """The regression PR 34 removed, forward and backward, with and
+    without a router bias: scatters (0.3 ms to begin and 45-120 ns an
+    update on the chip), and gathers of one scalar a row of the
+    worst-case buffer or a (token, choice). What is left that long: the
+    return's k rows a token, forward and in the backward pass of the
+    move into the buffer. The tile loops move a tile at a time."""
+    full, x, w = _layer(seed=6)
+    cfg = HeldExperts(E, K, (2, 5), True, 8, score)
+    params = {"router": full["router"],
+              **{n: full[n][2:5] for n in ("w_gate", "w_up", "w_down")}}
+    if score == "sigmoid":
+        params["router_bias"] = jnp.zeros(E)
+
+    def loss(p, x):
+        return jnp.sum(held_moe_ffn(p, x, cfg, jnp.float32)[0] * w)
+
+    moves = _moves(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(
+        params, x).jaxpr)
+    assert not [m for m in moves if m[0] != "gather"], moves
+    in_loops = [m for m in moves if m[3]]
+    assert len(in_loops) >= 3                   # rows in, rows and weights back
+    assert all(m[1] == cfg.tile_rows for m in in_loops), in_loops
+    rows = min(cfg.row_capacity(T), T * K)
+    long = [m for m in moves if not m[3] and m[1] >= rows // 2]
+    # each return once from the buffer's front and once from all of it
+    assert long == [("gather", T * K, D, False)] * 4, long
+
+
+def test_rows_behind_tiles_used_are_never_read(monkeypatch):
+    """The buffers start as NaN instead of zeros: whatever the tiles
+    behind the last group hold reaches neither output nor gradients."""
+    from pio_tpu.ops import moe
+
+    full, x, w = _layer(seed=7)
+
+    def run():
+        return jax.value_and_grad(
+            lambda f, x: jnp.sum(_part(f, x, 2, 5)[0] * w), (0, 1))(full, x)
+
+    want = run()
+    made = []
+
+    def nans(shape, dtype):
+        made.append(shape)
+        return jnp.full(shape, jnp.nan, dtype)
+
+    monkeypatch.setattr(moe, "_unwritten", nans)
+    got = run()
+    cfg = HeldExperts(E, K, (2, 5), True, 8)
+    assert made and all(s[0] == cfg.row_capacity(T) for s in made)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(a, b)
 
 
 @pytest.mark.parametrize("sizes", [[8, 8, 8], [3, 0, 13], [0, 0, 24],

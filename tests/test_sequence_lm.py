@@ -246,6 +246,23 @@ def test_the_engine_trains_persists_and_serves_the_stack():
     assert after < first - 0.05
 
 
+def test_tiles_used_share_of_a_hand_counted_routing():
+    """`expert_tiles_used_share` on `seq.wait`: tiles of 8 rows, three
+    held experts, histories of 40 positions whose every choice could be
+    held: (40 x 3 + 3 x 8) / 8 = 18 tiles. By hand: a history that sent
+    0, 8 and 9 tokens fills 1 + 1 + 2 tiles (an empty group owns one),
+    one that sent 1, 16 and 17 fills 1 + 2 + 3, one that sent 40 to each
+    fills 15: the mean of 4, 6 and 15 over 18."""
+    held = seq_blocks.HeldExperts(8, 3, (2, 5), True, 8)
+    assert held.row_capacity(40) == 18 * 8
+    counts = np.array([[[[0, 8, 9], [1, 16, 17]]], [[[40, 40, 40]] * 2]])
+    assert counts.shape == (2, 1, 2, 3)         # steps, routers, histories
+    assert seq_blocks.tiles_used_share(counts[:1], held, 40) == \
+        pytest.approx((4 + 6) / 2 / 18)
+    assert seq_blocks.tiles_used_share(counts, held, 40) == \
+        pytest.approx((4 + 6 + 15 + 15) / 4 / 18)
+
+
 def test_a_history_with_padding_is_refused():
     data = _data()
     data.seqs[0, 0] = 0
@@ -286,6 +303,8 @@ def test_profile_joins_the_stacks_scopes(op_name, scope):
 
 
 # -- what PR 33 added to the stack leaves the older configuration alone ------
+# (the two hashes below were commit 2ef95e1's until PR 34 changed the expert
+# layer's moves on purpose; they pin PR 34's text the same way)
 
 def _step_lowered(cfg, learning_rate, batch):
     spec = seq_blocks.BlockSpec.parse(cfg)
@@ -307,19 +326,19 @@ def _sha(text):
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-def test_the_window_and_full_stacks_step_program_is_pr_32s():
+def test_the_window_and_full_stacks_step_program_is_pr_34s():
     """Latent attention, the dense layer, the shared expert, the sigmoid
     router and the prediction module are chosen by the specification: a
-    specification without them lowers to the program text it lowered to
-    before they existed (the hash is of commit 2ef95e1's text, at this
+    specification without them lowers to one program text whatever a
+    later specification's keys add (the hash is of PR 34's text, at this
     file's small blocks)."""
     assert _sha(_step_text(CFG, 0.0625, (2, 41))) == (
-        "aa11e810e4f869eb5715e71d199f621dcca3eaf2335d867f8148239aafe03fa3")
+        "0801c55cf00bfed04844d9364a47b1ca8b3b2cf037499fc18a625b8ae9841b47")
 
 
-def test_mellum2_12b_ep4s_step_program_is_pr_32s(monkeypatch):
+def test_mellum2_12b_ep4s_step_program_is_pr_34s(monkeypatch):
     """The benchmark's configuration at its timed shapes and the
-    program's own blocks: the parent's text, by hash."""
+    program's own blocks: PR 34's text, by hash."""
     import json
     import os
 
@@ -331,7 +350,7 @@ def test_mellum2_12b_ep4s_step_program_is_pr_32s(monkeypatch):
     with open(path) as f:
         cfg = es.block_spec_of(json.load(f))
     assert _sha(_step_text(cfg, 1e-4, (2, 8193))) == (
-        "7474c5325816f267abeb78211d9f88bdb632e8297836a89e47764ebfa962a156")
+        "ad48f6f8e4fa6fb569da11540b529a5d10f0730709eb1b0c057254d36e5a0c96")
 
 
 def test_the_repeated_scope_rule_moves_no_path_of_the_older_stack():
@@ -408,7 +427,7 @@ def test_the_engine_trains_persists_and_serves_the_latent_stack(monkeypatch):
     labels = spans["seq.wait"]
     for key in ("loss_main_first", "loss_mtp_first", "loss_main_last",
                 "loss_mtp_last", "router_bias_abs_max",
-                "expert_tokens_held_share"):
+                "expert_tokens_held_share", "expert_tiles_used_share"):
         assert key in labels, key
     assert "window_blocks_visited" not in labels      # no window layer
     assert float(labels["loss_first"]) == pytest.approx(
