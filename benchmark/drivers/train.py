@@ -9,6 +9,7 @@ COMPLETED, which is when `pio deploy` can serve it.
 
 from __future__ import annotations
 
+from benchmark.harness import program
 from benchmark.harness.children import Children, child_env, require_devices
 
 
@@ -40,15 +41,14 @@ def run(cell, seed: int, seconds: float, trace: bool, t0: float,
             "setup_s": out["window"]["open"] - t0,
             "train_ratings_per_s": len(jobs) * out["nnz"] * sweeps / walls,
         },
-        "evidence": {"jobs": jobs, "trace": out.get("trace"),
-                     "config": cell.config, "chips": cell.chips,
-                     "device_kind": out["device"]["kind"],
-                     "rehearse": rehearse},
+        "evidence": program.evidence(out, cell, rehearse),
         "raw": out,
     }
     if trace:
         tr = out["trace"]
         result["device"].update(busy_s=tr["busy_s"], window_s=tr["window_s"])
-        result["breakdown"] = {"device_ops": tr["device_ops"],
-                               "idle_gaps": tr["idle_gaps"]}
+        result["breakdown"] = program.breakdown(tr)
+        if tr["profile"]:
+            result["breakdown"]["scope_job_s"] = program.by_seconds(
+                tr["profile"]["scopes"])
     return result

@@ -7,8 +7,9 @@ Set-up: the device report, the seeded ratings, one whole `run_train`
 window will use). Window: `run_train` back to back until the time is up;
 the job in flight then is finished and counted whole. After the window:
 the last job's model is loaded by the path `pio deploy` uses and checked
-(benchmark/harness/check_train.py). With `trace`, the window runs under
-the jax profiler and the trace is reduced here, where jax is.
+(benchmark/harness/check_train.py). Every job carries its `train spans:`
+record (benchmark/harness/program.py). With `trace`, the window runs
+under the jax profiler and the trace is reduced here, where jax is.
 """
 
 from __future__ import annotations
@@ -110,7 +111,7 @@ def main(spec_path: str) -> int:
     from pio_tpu.workflow.train import load_models, run_train
 
     from benchmark.engines import seeded_engine
-    from benchmark.harness import check_train, data
+    from benchmark.harness import check_train, data, program
 
     config, traffic, seed = spec["config"], spec["traffic"], spec["seed"]
     shape = config["data"]
@@ -133,16 +134,17 @@ def main(spec_path: str) -> int:
     # one chip: the single-device trainer, whatever the machine holds;
     # more: the mesh over all of them, as `pio train` makes it
     ctx = create_workflow_context(storage, use_mesh=spec["chips"] > 1)
-    job_log = JobLog()
+    job_log, span_log = JobLog(), program.SpanLog()
     logging.getLogger("pio_tpu.workflow").addHandler(job_log)
+    logging.getLogger("pio_tpu.workflow").addHandler(span_log)
 
     def job() -> dict:
-        job_log.job = {}
+        job_log.job, span_log.rows = {}, []
         t_a = time.monotonic()
         instance = run_train(engine, ep, storage, engine_id="bench", ctx=ctx)
         t_b = time.monotonic()
-        return dict(job_log.job, instance=instance, start=t_a, end=t_b,
-                    wall_s=t_b - t_a)
+        return dict(job_log.job, spans=span_log.rows, instance=instance,
+                    start=t_a, end=t_b, wall_s=t_b - t_a)
 
     warm = job()
     log.info("warm job %.2fs", warm["wall_s"])
@@ -218,24 +220,7 @@ def main(spec_path: str) -> int:
     }
     log.info("checked: %s", compared)
     if tracing:
-        from benchmark.harness import trace
-
-        planes = trace.read_planes(trace.find_xplane(trace_dir))
-        marks = [(s, s + d) for n, s, d in planes["host"]
-                 if n == trace.WINDOW]
-        # the jobs' phases on the trace's clock: the window annotation
-        # opened at t_open on ours
-        off = (marks[0][0] if marks else 0) - t_open * 1e9
-        phases = []
-        for j in jobs:
-            a = j["start"] * 1e9 + off
-            for name in ("read_s", "prepare_s", "algorithms_s", "persist_s"):
-                b = a + j.get(name, 0.0) * 1e9
-                phases.append((f"run_train: {name[:-2]}", a, b))
-                a = b
-            phases.append(("run_train: bookkeeping", a, j["end"] * 1e9 + off))
-        out["trace"] = trace.reduce(planes, phases)
-        out["trace"].pop("op_seconds")
+        out["trace"] = program.reduce_trace(trace_dir, len(jobs), log)
     with open(spec["out"], "w") as f:
         json.dump(out, f)
     return 0

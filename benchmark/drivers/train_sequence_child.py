@@ -11,7 +11,8 @@ then is finished and counted whole. After the window: the check
 persisted. With `trace`, the window runs under the jax profiler and the
 trace is reduced here, where jax is: busy and idle as for the ALS cells
 (benchmark/harness/trace.py), and device seconds by the program's
-`seq.*` scopes (pio_tpu/obs/profile.py joins them to the operations).
+`seq.*` scopes (benchmark/harness/program.py). A job's `counters` are
+the labels of its `seq.wait` span.
 """
 
 from __future__ import annotations
@@ -26,22 +27,6 @@ import time
 from benchmark.drivers.train_child import JobLog, device_report, memory_peak
 
 WARM_STEPS = 2
-
-
-class SpanLog(logging.Handler):
-    """The labels of the `seq.wait` span in a job's `train spans:` record:
-    the trainer's counters."""
-
-    def __init__(self):
-        super().__init__(logging.INFO)
-        self.counters: dict = {}
-
-    def emit(self, record: logging.LogRecord) -> None:
-        text = record.getMessage()
-        if text.startswith("train spans: "):
-            for row in json.loads(text[len("train spans: "):]):
-                if row["name"] == "seq.wait":
-                    self.counters = dict(row["labels"])
 
 
 def main(spec_path: str) -> int:
@@ -79,7 +64,7 @@ def main(spec_path: str) -> int:
     from pio_tpu.workflow.train import load_models, run_train
 
     from benchmark import engines_sequence as es
-    from benchmark.harness import check_sequence
+    from benchmark.harness import check_sequence, program as intake
 
     config, traffic, seed = spec["config"], spec["traffic"], spec["seed"]
     log = logging.getLogger("benchmark")
@@ -92,18 +77,19 @@ def main(spec_path: str) -> int:
     alg = es.algorithm_params(config, traffic, seed)
     ep = EngineParams(datasource=("", None), algorithms=[("sasrec", alg)])
     ctx = create_workflow_context(storage, use_mesh=False)
-    job_log, span_log = JobLog(), SpanLog()
+    job_log, span_log = JobLog(), intake.SpanLog()
     logging.getLogger("pio_tpu.workflow").addHandler(job_log)
     logging.getLogger("pio_tpu.workflow").addHandler(span_log)
 
     def job(params: EngineParams = ep) -> dict:
-        job_log.job, span_log.counters = {}, {}
+        job_log.job, span_log.rows = {}, []
         t_a = time.monotonic()
         instance = run_train(engine, params, storage, engine_id="bench",
                              ctx=ctx)
         t_b = time.monotonic()
-        return dict(job_log.job, counters=span_log.counters,
-                    instance=instance, start=t_a, end=t_b, wall_s=t_b - t_a)
+        return dict(job_log.job, spans=span_log.rows,
+                    counters=span_log.labels("seq.wait"), instance=instance,
+                    start=t_a, end=t_b, wall_s=t_b - t_a)
 
     warm = job(EngineParams(datasource=("", None), algorithms=[
         ("sasrec", dict(alg, steps=WARM_STEPS))]))
@@ -226,39 +212,10 @@ def main(spec_path: str) -> int:
     log.info("check numbers: %s", json.dumps(
         {k: v for k, v in numbers.items() if k != "explore"}))
     if tracing:
-        from benchmark.harness import trace
-
-        planes = trace.read_planes(trace.find_xplane(trace_dir))
-        marks = [(s, s + d) for n, s, d in planes["host"]
-                 if n == trace.WINDOW]
-        off = (marks[0][0] if marks else 0) - t_open * 1e9
-        phases = []
-        for j in jobs:
-            a = j["start"] * 1e9 + off
-            for name in ("read_s", "prepare_s", "algorithms_s", "persist_s"):
-                b = a + j.get(name, 0.0) * 1e9
-                phases.append((f"run_train: {name[:-2]}", a, b))
-                a = b
-            phases.append(("run_train: bookkeeping", a, j["end"] * 1e9 + off))
-        out["trace"] = trace.reduce(planes, phases)
-        out["trace"].pop("op_seconds")
-        out["trace"]["scope_s"] = scope_seconds(trace_dir, len(jobs), log)
+        out["trace"] = intake.reduce_trace(trace_dir, len(jobs), log)
     with open(spec["out"], "w") as f:
         json.dump(out, f)
     return 0
-
-
-def scope_seconds(trace_dir: str, n_jobs: int, log) -> dict | None:
-    """Device seconds of the window by the program's scopes, or None
-    where the trace has no device plane (a CPU rehearsal)."""
-    from pio_tpu.obs import profile
-
-    try:
-        per_job = profile.reduce(profile.read_profile(trace_dir))["per_job"]
-    except ValueError as e:
-        log.info("no scope seconds: %s", e)
-        return None
-    return {scope: sec * n_jobs for scope, sec in per_job["scopes"].items()}
 
 
 if __name__ == "__main__":

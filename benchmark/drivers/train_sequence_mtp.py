@@ -14,6 +14,7 @@ the instance COMPLETED.
 
 from __future__ import annotations
 
+from benchmark.harness import program
 from benchmark.harness.children import Children, child_env, require_devices
 
 
@@ -45,23 +46,17 @@ def run(cell, seed: int, seconds: float, trace: bool, t0: float,
             "setup_s": out["window"]["open"] - t0,
             "train_ratings_per_s": len(jobs) * events / walls,
         },
-        "evidence": {"jobs": jobs, "trace": out.get("trace"),
-                     "counters": [j["counters"] for j in jobs],
-                     "steps_in_window": len(jobs) * traffic["steps"],
-                     "config": cell.config, "traffic": traffic,
-                     "chips": cell.chips,
-                     "device_kind": out["device"]["kind"],
-                     "rehearse": rehearse},
+        "evidence": dict(program.evidence(out, cell, rehearse),
+                         counters=[j["counters"] for j in jobs],
+                         steps_in_window=len(jobs) * traffic["steps"],
+                         traffic=traffic),
         "raw": out,
     }
     if trace:
         tr = out["trace"]
         result["device"].update(busy_s=tr["busy_s"], window_s=tr["window_s"])
-        result["breakdown"] = {"device_ops": tr["device_ops"],
-                               "idle_gaps": tr["idle_gaps"]}
+        result["breakdown"] = program.breakdown(tr)
         if tr.get("scope_s"):
-            steps = len(jobs) * traffic["steps"]
-            result["breakdown"]["scope_step_s"] = {
-                k: v / steps for k, v in sorted(
-                    tr["scope_s"].items(), key=lambda kv: -kv[1])}
+            result["breakdown"]["scope_step_s"] = program.by_seconds(
+                tr["scope_s"], per=len(jobs) * traffic["steps"])
     return result

@@ -2,11 +2,10 @@
 
     python -m benchmark.drivers.train_sequence_mtp_child <spec.json>
 
-As benchmark/drivers/train_sequence_child.py (whose span log, warm job
-and scope reduction it imports), for a block specification with a
-prediction module: histories of history_events + 2 ids, and after the
-window the check of benchmark/harness/check_latent.py on what the last
-job logged and persisted. A checkout whose block stack has no latent
+As benchmark/drivers/train_sequence_child.py, for a block specification
+with a prediction module: histories of history_events + 2 ids, and after
+the window the check of benchmark/harness/check_latent.py on what the
+last job logged and persisted. A checkout whose block stack has no latent
 attention ends here at once, with exit code 1 and a line that says so.
 """
 
@@ -20,11 +19,7 @@ import sys
 import time
 
 from benchmark.drivers.train_child import JobLog, device_report, memory_peak
-from benchmark.drivers.train_sequence_child import (
-    WARM_STEPS,
-    SpanLog,
-    scope_seconds,
-)
+from benchmark.drivers.train_sequence_child import WARM_STEPS
 
 
 def main(spec_path: str) -> int:
@@ -64,7 +59,7 @@ def main(spec_path: str) -> int:
     from pio_tpu.workflow.train import load_models, run_train
 
     from benchmark import engines_sequence as es
-    from benchmark.harness import check_latent
+    from benchmark.harness import check_latent, program as intake
 
     config, traffic, seed = spec["config"], spec["traffic"], spec["seed"]
     log = logging.getLogger("benchmark")
@@ -77,18 +72,19 @@ def main(spec_path: str) -> int:
     alg = es.algorithm_params(config, traffic, seed)
     ep = EngineParams(datasource=("", None), algorithms=[("sasrec", alg)])
     ctx = create_workflow_context(storage, use_mesh=False)
-    job_log, span_log = JobLog(), SpanLog()
+    job_log, span_log = JobLog(), intake.SpanLog()
     logging.getLogger("pio_tpu.workflow").addHandler(job_log)
     logging.getLogger("pio_tpu.workflow").addHandler(span_log)
 
     def job(params: EngineParams = ep) -> dict:
-        job_log.job, span_log.counters = {}, {}
+        job_log.job, span_log.rows = {}, []
         t_a = time.monotonic()
         instance = run_train(engine, params, storage, engine_id="bench",
                              ctx=ctx)
         t_b = time.monotonic()
-        return dict(job_log.job, counters=span_log.counters,
-                    instance=instance, start=t_a, end=t_b, wall_s=t_b - t_a)
+        return dict(job_log.job, spans=span_log.rows,
+                    counters=span_log.labels("seq.wait"), instance=instance,
+                    start=t_a, end=t_b, wall_s=t_b - t_a)
 
     warm = job(EngineParams(datasource=("", None), algorithms=[
         ("sasrec", dict(alg, steps=WARM_STEPS))]))
@@ -229,23 +225,7 @@ def main(spec_path: str) -> int:
     log.info("check numbers: %s", json.dumps(
         {k: v for k, v in numbers.items() if k != "explore"}))
     if tracing:
-        from benchmark.harness import trace
-
-        planes = trace.read_planes(trace.find_xplane(trace_dir))
-        marks = [(s, s + d) for n, s, d in planes["host"]
-                 if n == trace.WINDOW]
-        off = (marks[0][0] if marks else 0) - t_open * 1e9
-        phases = []
-        for j in jobs:
-            a = j["start"] * 1e9 + off
-            for name in ("read_s", "prepare_s", "algorithms_s", "persist_s"):
-                b = a + j.get(name, 0.0) * 1e9
-                phases.append((f"run_train: {name[:-2]}", a, b))
-                a = b
-            phases.append(("run_train: bookkeeping", a, j["end"] * 1e9 + off))
-        out["trace"] = trace.reduce(planes, phases)
-        out["trace"].pop("op_seconds")
-        out["trace"]["scope_s"] = scope_seconds(trace_dir, len(jobs), log)
+        out["trace"] = intake.reduce_trace(trace_dir, len(jobs), log)
     with open(spec["out"], "w") as f:
         json.dump(out, f)
     return 0

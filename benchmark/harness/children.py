@@ -55,8 +55,10 @@ class Children:
         self._procs: list[tuple[str, subprocess.Popen]] = []
 
     def log_path(self, name: str) -> str:
-        # BENCH_LOG_DIR keeps the children's logs (rehearsals on the chip
-        # machine point it into chiprun_out/)
+        # BENCH_LOG_DIR keeps the children's logs and a traced run's
+        # trace/ (rehearsals on the chip machine point it into
+        # chiprun_out/; `python -m pio_tpu.obs.profile <it>/trace` then
+        # prints the seconds by scope and span)
         keep = os.environ.get("BENCH_LOG_DIR")
         if keep:
             os.makedirs(keep, exist_ok=True)
@@ -111,6 +113,11 @@ class Children:
                 except ProcessLookupError:
                     pass
             p.wait()
+        keep = os.environ.get("BENCH_LOG_DIR")
+        traced = os.path.join(self.work, "trace")
+        if keep and os.path.isdir(traced):
+            shutil.rmtree(os.path.join(keep, "trace"), ignore_errors=True)
+            shutil.move(traced, os.path.join(keep, "trace"))
         shutil.rmtree(self.work, ignore_errors=True)
 
 

@@ -113,12 +113,12 @@ def exposed_collective_s(own: dict[str, float]) -> float:
                if name.startswith(_COLLECTIVE))
 
 
-def reduce(planes: dict, phases: list[tuple[str, float, float]] = ()) -> dict:
+def reduce(planes: dict) -> dict:
     """-> window_s, busy_s (mean over devices), per-device busy, the ten
-    operations with most own time, the ten longest idle gaps, each named
-    by the phase (name, start_ns, end_ns on the trace's clock) that held
-    its middle. Without a `bench:window` annotation the window is the
-    span of the device operations."""
+    operations with most own time, the seconds of the ten longest idle
+    gaps (benchmark/harness/program.py names them by the program's
+    spans). Without a `bench:window` annotation the window is the span
+    of the device operations."""
     spans = [(s, s + d) for n, s, d in planes["host"] if n == WINDOW]
     every = [o for ops in planes["devices"].values() for o in ops]
     if not every:
@@ -129,7 +129,7 @@ def reduce(planes: dict, phases: list[tuple[str, float, float]] = ()) -> dict:
         lo = min(s for _, s, _ in every)
         hi = max(s + d for _, s, d in every)
     busy, op_s, exposed = {}, defaultdict(float), []
-    gaps: list[tuple[float, float]] = []
+    gaps: list[float] = []
     for dev, ops in planes["devices"].items():
         inside = [(n, s, d) for n, s, d in ops if s + d > lo and s < hi]
         covered = clip(union([(s, s + d) for _, s, d in inside]), lo, hi)
@@ -139,20 +139,8 @@ def reduce(planes: dict, phases: list[tuple[str, float, float]] = ()) -> dict:
         for name, sec in own.items():
             op_s[name] += sec / len(planes["devices"])
         edges = [lo] + [x for ab in covered for x in ab] + [hi]
-        gaps += [(edges[k], edges[k + 1]) for k in range(0, len(edges), 2)
-                 if edges[k + 1] > edges[k]]
-    gaps.sort(key=lambda g: g[0] - g[1])
-
-    def phase_of(t: float) -> str:
-        for name, a, b in phases:
-            if a <= t < b:
-                return name
-        return "outside any phase"
-
-    gap_s: dict[str, float] = defaultdict(float)
-    for a, b in gaps:
-        gap_s[phase_of(0.5 * (a + b))] += (b - a) / 1e9 / len(busy)
-    longest = [[phase_of(0.5 * (a + b)), (b - a) / 1e9] for a, b in gaps[:10]]
+        gaps += [(edges[k + 1] - edges[k]) / 1e9
+                 for k in range(0, len(edges), 2) if edges[k + 1] > edges[k]]
     return {
         "window_s": (hi - lo) / 1e9,
         "busy_s": sum(busy.values()) / len(busy),
@@ -162,6 +150,5 @@ def reduce(planes: dict, phases: list[tuple[str, float, float]] = ()) -> dict:
         "op_seconds": dict(op_s),
         "collective_exposed_s": (sum(exposed) / len(exposed)
                                  if len(exposed) > 1 else None),
-        "idle_gaps": longest,
-        "idle_by_phase": dict(gap_s),
+        "longest_gaps_s": sorted(gaps, reverse=True)[:10],
     }

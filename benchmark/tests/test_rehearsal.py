@@ -1,6 +1,8 @@
-"""Every cell at a tiny size through run.py on the CPU, both --trace
+"""Every ALS cell at a tiny size through run.py on the CPU, both --trace
 values, `source: "events"` included, four virtual devices for the
-sharded cell; and the shape of the last line."""
+sharded cell; and the shape of the last line. (The block-stack cells have
+overlays and files of their own: test_rehearsal_sequence.py,
+test_rehearsal_latent.py.)"""
 
 import json
 import os
@@ -14,8 +16,24 @@ from benchmark.harness import cells
 TESTS = os.path.dirname(__file__)
 BENCH = cells.load_json(os.path.join(cells.ROOT, "BENCHMARK.json"))
 CASES = [(w["name"], "als-tiny.json", t)
-         for w in BENCH["workloads"] for t in (0, 1)]
+         for w in BENCH["workloads"] for t in (0, 1)
+         if cells.load_cell(w["name"]).traffic["kind"] == "train"]
 CASES.append(("ml20m-r64.train-coo", "als-tiny-events.json", 0))
+
+
+def on_the_cpu(cell) -> set[str]:
+    """The per-layer metrics of a cell whose readers have evidence in a
+    CPU rehearsal: the job records, the span trees, the warm job and the
+    busy seconds. A roofline and the profile view (seconds by scope and
+    by span, which need a device plane) have none."""
+    readers = {"train-log", "span-self", "warm-job", "trace-busy",
+               "seq-counter"}
+    names = set()
+    for m in cell.per_layer:
+        spec = cells.layer_metric_spec(m["name"])
+        if spec["reader"] in readers or spec.get("scopes") == "all":
+            names.add(m["name"])
+    return names
 
 
 def run_py(*args, cwd=cells.ROOT):
@@ -45,10 +63,22 @@ def test_cell_at_tiny_size(workload, overlay, trace):
         assert isinstance(value["value"], (int, float))
     if trace:
         assert 0 < device["busy_s"] <= device["window_s"]
-        assert set(line["metrics"]) <= {m["name"] for m in cell.per_layer}
-        assert "sweep_device_s" in line["metrics"]
+        want = on_the_cpu(cell)
+        assert set(line["metrics"]) == want
+        assert {"sweep_device_s", "stage_persist_s", "device_idle_pct.train",
+                "host_prep_s", "persist_serialize_s", "persist_store_s",
+                "setup_warm_job_s", "setup_compile_s"} <= want
+        assert ("read_scan_s" in want) == (
+            cell.traffic["source"] == "events")
+        parts = (line["metrics"]["persist_serialize_s"]["value"]
+                 + line["metrics"]["persist_store_s"]["value"])
+        assert 0 < parts <= line["metrics"]["stage_persist_s"]["value"] + 5e-3
         assert len(line["breakdown"]["device_ops"]) <= 10
-        assert len(line["breakdown"]["idle_gaps"]) <= 10
+        gaps = line["breakdown"]["idle_gaps"]
+        # no device plane on the CPU: no view to name a gap from
+        assert 0 < len(gaps) <= 10
+        assert all(name == "no profile view" for name, _ in gaps)
+        assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
     else:
         assert set(line["metrics"]) == {m["name"] for m in cell.end_to_end}
         assert all(v["value"] > 0 for v in line["metrics"].values())

@@ -11,14 +11,16 @@ import sys
 import pytest
 
 from benchmark.harness import cells
-from benchmark.tests.test_rehearsal import TESTS, run_py
+from benchmark.tests.test_rehearsal import TESTS, on_the_cpu, run_py
 
 CELL = "glm-4.7-flash-ep8.train-8k-mtp"
 ON_THE_CPU = {"seq_step_device_s.train-sequence-mtp",
               "seq_expert_load_max_over_mean.train-sequence-mtp",
-              "seq_expert_held_share", "stage_persist_s.train-sequence-mtp",
+              "seq_expert_held_share", "seq_expert_tiles_used_share",
+              "stage_persist_s.train-sequence-mtp",
               "stage_algorithms_s.train-sequence-mtp",
-              "device_idle_pct.train-sequence-mtp"}
+              "device_idle_pct.train-sequence-mtp", "persist_serialize_s",
+              "persist_store_s", "setup_warm_job_s", "setup_compile_s"}
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -49,10 +51,10 @@ def test_the_cell_reports_every_metric_it_lists():
     cell = cells.load_cell(CELL)
     assert [m["name"] for m in cell.end_to_end] == [
         "setup_s", "train_ratings_per_s"]
-    assert len(cell.per_layer) == 16 and cell.chips == 1
-    assert ON_THE_CPU <= {m["name"] for m in cell.per_layer}
+    assert cell.chips == 1 and on_the_cpu(cell) == ON_THE_CPU
+    assert len(cell.per_layer) > len(ON_THE_CPU)
     for m in cell.per_layer:
-        assert m["workloads"] == [CELL]
+        assert CELL in m["workloads"]
         spec = cells.layer_metric_spec(m["name"])
         assert hasattr(cells.module_for("readers", spec["reader"]), "read")
         assert spec["layer"] == m["layer"] and spec["moves"] == m["moves"]

@@ -8,7 +8,7 @@ import os
 import pytest
 
 from benchmark.harness import cells
-from benchmark.tests.test_rehearsal import TESTS, run_py
+from benchmark.tests.test_rehearsal import TESTS, on_the_cpu, run_py
 
 CELL = "mellum2-12b-ep4.train-8k"
 
@@ -28,14 +28,16 @@ def test_cell_at_tiny_size(trace):
     assert line["device"]["platform"] == "cpu"
     cell = cells.load_cell(CELL)
     if trace:
-        # the CPU backend has no device plane: the scope and roofline
-        # metrics have nothing to read and are left out
-        assert set(line["metrics"]) == {
-            "seq_step_device_s", "seq_expert_load_max_over_mean",
-            "stage_persist_s.train-sequence",
-            "stage_algorithms_s.train-sequence",
-            "device_idle_pct.train-sequence"}
-        assert set(line["metrics"]) <= {m["name"] for m in cell.per_layer}
+        # the CPU backend has no device plane: the scope, roofline and
+        # idle-by-span metrics have nothing to read and are left out
+        assert set(line["metrics"]) == on_the_cpu(cell)
+        assert {"seq_step_device_s", "seq_expert_load_max_over_mean",
+                "seq_expert_tiles_used_share",
+                "stage_persist_s.train-sequence",
+                "stage_algorithms_s.train-sequence",
+                "device_idle_pct.train-sequence", "persist_serialize_s",
+                "persist_store_s", "setup_warm_job_s",
+                "setup_compile_s"} == on_the_cpu(cell)
         assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
     else:
         assert set(line["metrics"]) == {"setup_s", "train_ratings_per_s"}
@@ -46,7 +48,10 @@ def test_the_cell_reports_every_metric_it_lists():
     cell = cells.load_cell(CELL)
     assert [m["name"] for m in cell.end_to_end] == [
         "setup_s", "train_ratings_per_s"]
-    assert len(cell.per_layer) == 11
+    bench = cells.load_json(os.path.join(cells.ROOT, "BENCHMARK.json"))
+    assert [m["name"] for m in cell.per_layer] == [
+        m["name"] for m in bench["per_layer"] if CELL in m["workloads"]]
+    assert len(cell.per_layer) > len(on_the_cpu(cell)) >= 10
     for m in cell.per_layer:
         spec = cells.layer_metric_spec(m["name"])
         assert hasattr(cells.module_for("readers", spec["reader"]), "read")

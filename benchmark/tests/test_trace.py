@@ -19,7 +19,7 @@ def test_recorded_tpu_trace():
     # three calls, 50 ms of sleep after each, inside the annotation
     assert 0.15 < out["window_s"] < 0.5
     assert 0 < out["busy_s"] < out["window_s"] - 0.14
-    gaps = [g for _, g in out["idle_gaps"]]
+    gaps = out["longest_gaps_s"]
     assert gaps == sorted(gaps, reverse=True) and gaps[2] > 0.045
     # own times count nested operations once: they add up to the busy time
     assert sum(out["op_seconds"].values()) == pytest.approx(
@@ -38,7 +38,7 @@ def test_nested_operations_count_once():
                                                             (200, 250)]
 
 
-def test_window_phases_devices_and_collectives():
+def test_window_devices_and_collectives():
     planes = {
         "host": [(trace.WINDOW, 0, 1000)],
         "devices": {
@@ -48,13 +48,16 @@ def test_window_phases_devices_and_collectives():
             "/device:TPU:1": [("fusion.1 f32[8]", 0, 500),
                               ("all-reduce.3 f32[8]", 500, 300)],
         }}
-    out = trace.reduce(planes, phases=[("a", 0, 450), ("b", 450, 1000)])
+    out = trace.reduce(planes)
     assert out["window_s"] == pytest.approx(1000e-9)
     assert out["busy_by_device"]["/device:TPU:0"] == pytest.approx(500e-9)
     assert out["busy_s"] == pytest.approx(650e-9)
     assert out["collective_exposed_s"] == pytest.approx(200e-9)
-    assert out["idle_gaps"][0] == ["b", pytest.approx(400e-9)]
-    assert out["idle_by_phase"]["a"] == pytest.approx(50e-9)
+    # every device's gaps, the longest first: 500-900 and 0-100 on the
+    # first, 800-1000 on the second (program.name_gaps names a gap by the
+    # span that holds most of it: test_program_view.py)
+    assert out["longest_gaps_s"] == pytest.approx(
+        [400e-9, 200e-9, 100e-9])
 
 
 def test_short_names():
