@@ -118,8 +118,6 @@ class Engine:
         stop_after_read: bool = False,
         stop_after_prepare: bool = False,
     ) -> list[Any]:
-        from pio_tpu.utils.compilecache import CompileMeter
-
         ds, prep, algos, _ = self._doers(engine_params)
         tracer = tracing.current_tracer()
         with tracer.span("train.read") as sp:
@@ -134,7 +132,11 @@ class Engine:
             _label_ratings(sp, pd)
         if stop_after_prepare:
             raise TrainingInterruption("prepare")
-        with tracer.span("train.algorithms") as sp, CompileMeter() as meter:
+        # the job's meter (`run_train` makes it): the span is labelled
+        # with what it counted over it; outside a job there is none
+        compile_meter = getattr(ctx, "compile_meter", None)
+        with tracer.span("train.algorithms") as sp:
+            before = compile_meter and compile_meter.totals()
             models = [algo.train(ctx, pd) for algo in algos]
             for m in models:
                 sanity_check(m)
@@ -145,8 +147,12 @@ class Engine:
             # anyway)
             with tracer.span("als.wait"):
                 jax.block_until_ready(models)
-            sp.update(programs=meter.programs, cache_hits=meter.cache_hits,
-                      compile_s=round(meter.seconds, 3))
+            if compile_meter is not None:
+                seconds, programs, hits = (
+                    now - was for now, was in zip(
+                        compile_meter.totals(), before))
+                sp.update(programs=programs, cache_hits=hits,
+                          compile_s=round(seconds, 3))
         log.info("train stages: read %.3fs, prepare %.3fs, algorithms "
                  "%.3fs", *(tracer.histogram(name).last for name in (
                      "train.read", "train.prepare", "train.algorithms")))

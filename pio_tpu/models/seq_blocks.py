@@ -708,8 +708,12 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
     last loss).
 
     Host spans (under `train.algorithms`): `seq.batch` stages every
-    step's histories on the device, `seq.dispatch` enqueues the steps,
-    `seq.wait` waits for the last one and carries the job's counters."""
+    step's histories on the device, `seq.init` makes the step and the
+    initial parameters and optimizer state (a process's first job traces
+    and loads the initialisers here), `seq.dispatch` enqueues the steps
+    (and the first job gets the step's program ready), `seq.wait` waits
+    for the last one and carries the job's counters, `seq.d2h` brings the
+    parameters to the host."""
     spec = BlockSpec.parse(p.block_spec)
     if jax.process_count() > 1:
         raise ValueError("the block stack trains on one host")
@@ -729,9 +733,10 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
         order = epoch_order(len(seqs), steps, batch, p.seed)
         # a batch an array: no program's shapes hold the step count
         batches = [jax.device_put(seqs[rows]) for rows in order]
-    optimizer, step = make_train_step(spec, p.learning_rate)
-    params = init_params(spec, p.seed)
-    opt_state = optimizer.init(params)
+    with tracing.span("seq.init"):
+        optimizer, step = make_train_step(spec, p.learning_rate)
+        params = init_params(spec, p.seed)
+        opt_state = optimizer.init(params)
     losses, counters = [], []
     with tracing.span("seq.dispatch", steps=steps):
         for s in range(steps):
@@ -785,9 +790,13 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
                       loss_mtp_first=repr(float(parts[0, 1])),
                       loss_main_last=repr(float(parts[-1, 0])),
                       loss_mtp_last=repr(float(parts[-1, 1])))
-        params = jax.device_get(params)
         if spec.router_bias:
             sp.update(router_bias_abs_max=repr(float(max(
-                np.abs(lp["router_bias"]).max()
-                for lp in expert_layers(params, spec)))))
+                np.abs(bias).max() for bias in jax.device_get(
+                    [lp["router_bias"]
+                     for lp in expert_layers(params, spec)])))))
+    with tracing.span("seq.d2h") as sp:
+        params = jax.device_get(params)
+        sp["bytes"] = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(params))
     return params, float(losses[-1])

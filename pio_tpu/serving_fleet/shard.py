@@ -184,6 +184,37 @@ def _slice_with_rows(sl: PartitionSlice, rows: dict) -> PartitionSlice:
     return dataclasses.replace(sl, user_ids=user_ids, user_rows=user_rows)
 
 
+def _arm_with_user_rows(arm: "_ArmState", rows: dict) -> "_ArmState":
+    """Copy-on-write user-row upsert into a prepared arm (dual-written
+    rows of arriving partitions, fold-ins of resident ones); the rows'
+    rank is the caller's to check. The item side is shared."""
+    if not rows:
+        return arm
+    import dataclasses
+
+    part = arm.partition
+    user_rows = np.array(part.user_rows, dtype=np.float32, copy=True)
+    user_ids = list(part.user_ids)
+    row_of = dict(arm.user_row_of)
+    appended: list[np.ndarray] = []
+    for uid, row in rows.items():
+        vec = np.asarray(row, dtype=np.float32)
+        at = row_of.get(uid)
+        if at is not None:
+            user_rows[at] = vec
+        else:
+            row_of[uid] = len(user_ids)
+            user_ids.append(uid)
+            appended.append(vec)
+    if appended:
+        user_rows = np.concatenate(
+            [user_rows.reshape(-1, len(appended[0])),
+             np.stack(appended)]).astype(np.float32)
+    return dataclasses.replace(
+        arm, user_row_of=row_of, partition=dataclasses.replace(
+            part, user_ids=user_ids, user_rows=user_rows))
+
+
 def _prepare_arm(part: ShardPartition, rparams=None) -> "_ArmState":
     import jax
 
@@ -559,6 +590,11 @@ class ShardServer:
                     "incoming": {int(p) for p in incoming},
                     "staged": {},
                     "pending": {},
+                    # owned fold-in rows applied during the epoch for
+                    # partitions this shard KEEPS: the prepared arm is
+                    # merged from a snapshot of the partition, so they
+                    # are replayed onto it (_keep_resident_rows)
+                    "resident": {},
                     "prepared": None,
                 }
             self._retired = None    # a new epoch retires the retiree
@@ -713,7 +749,9 @@ class ShardServer:
             with self._lock:
                 rs2 = self._reshard
                 if rs2 is not None and rs2["planVersion"] == int(plan_version):
-                    rs2["prepared"] = arm
+                    # fold-ins that landed since `part` was read
+                    rs2["prepared"] = _arm_with_user_rows(
+                        arm, rs2["resident"])
             return {"prepared": True, "planVersion": int(plan_version),
                     "users": len(new_part.user_ids),
                     "items": len(new_part.item_ids),
@@ -1164,6 +1202,7 @@ class ShardServer:
                         "partition changed during fold-in apply; retry")
                 self.partition = new_part
                 self._user_row_of = row_of
+                self._keep_resident_rows(dict(owned))
                 self.foldin_applied_users += len(owned)
                 self.foldin_last_time = utcnow()
                 if staleness_s is not None:
@@ -1251,6 +1290,23 @@ class ShardServer:
         return {"applied": len(owned), "rejected": rejected,
                 "engineInstanceId": part.instance_id}
 
+    def _keep_resident_rows(self, owned: dict) -> None:
+        """During a reshard epoch, owned fold-in rows of partitions this
+        shard keeps under the new plan: remembered for `prepare_reshard`
+        and applied to the prepared arm once it exists, so activation
+        serves them (rows of partitions moving OUT reach their new owner
+        as the router's dual-writes). Called with the lock held, in the
+        same hold as the active apply: no activation can fall between."""
+        rs = self._reshard
+        if rs is None:
+            return
+        me = self.config.shard_index
+        keep = {uid: row for uid, row in owned.items()
+                if rs["newOwners"][partition_of(uid)] == me}
+        rs["resident"].update(keep)
+        if rs["prepared"] is not None:
+            rs["prepared"] = _arm_with_user_rows(rs["prepared"], keep)
+
     def _apply_reshard_rows(self, moving: dict) -> int:
         """Land dual-written fold-in rows for partitions this shard is
         RECEIVING: into the prepared arm when it exists (so activation
@@ -1259,8 +1315,6 @@ class ShardServer:
         it is newer). Returns the rows left queued. Never raises — the
         dual-write is best-effort on top of the primary owner's apply,
         which is the folder's durability contract."""
-        import dataclasses
-
         queued = 0
         with self._lock:
             rs = self._reshard
@@ -1297,31 +1351,7 @@ class ShardServer:
                             partition_of(uid), {})[uid] = row
                     queued += len(prep_rows)
                 else:
-                    user_rows = np.array(part.user_rows, dtype=np.float32,
-                                         copy=True)
-                    user_ids = list(part.user_ids)
-                    row_of = dict(prep.user_row_of)
-                    appended: list[np.ndarray] = []
-                    for uid, row in prep_rows.items():
-                        at = row_of.get(uid)
-                        vec = np.asarray(row, dtype=np.float32)
-                        if at is not None:
-                            user_rows[at] = vec
-                        else:
-                            row_of[uid] = len(user_ids)
-                            user_ids.append(uid)
-                            appended.append(vec)
-                    if appended:
-                        user_rows = np.concatenate(
-                            [user_rows.reshape(-1, k),
-                             np.stack(appended)]).astype(np.float32)
-                    rs["prepared"] = _ArmState(
-                        partition=dataclasses.replace(
-                            part, user_ids=user_ids, user_rows=user_rows),
-                        item_factors_dev=prep.item_factors_dev,
-                        user_row_of=row_of,
-                        item_local_of=prep.item_local_of,
-                        retrieval=prep.retrieval)
+                    rs["prepared"] = _arm_with_user_rows(prep, prep_rows)
         return queued
 
     def _upsert_candidate_rows(self, owned: dict) -> int:

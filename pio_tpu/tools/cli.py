@@ -1106,7 +1106,6 @@ def cmd_build(args) -> int:
 
 
 def cmd_train(args) -> int:
-    from pio_tpu.workflow.context import create_workflow_context
     from pio_tpu.workflow.lifecycle import EXIT_PREEMPTED, TrainingPreempted
     from pio_tpu.workflow.train import run_train
 
@@ -1131,7 +1130,6 @@ def cmd_train(args) -> int:
         marker = f"from-eval:{eval_id}"
         batch = f"{batch} {marker}".strip()
         print(f"Training with best params from evaluation {eval_id}")
-    ctx = create_workflow_context(storage, use_mesh=not args.no_mesh)
     if args.device_profile:
         from pio_tpu.utils.tracing import start_device_profile
 
@@ -1143,7 +1141,9 @@ def cmd_train(args) -> int:
             engine_variant=engine_variant,
             engine_factory=variant["engineFactory"],
             batch=batch,
-            ctx=ctx,
+            # the run makes its context: reaching the chip (~15 s in a
+            # fresh process) is then its `train.devices` span
+            use_mesh=not args.no_mesh,
             stop_after_read=args.stop_after_read,
             stop_after_prepare=args.stop_after_prepare,
             resume_instance_id=args.resume or None,

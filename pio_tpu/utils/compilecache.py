@@ -34,6 +34,8 @@ import logging
 import os
 import threading
 
+from pio_tpu.utils.tracing import ambient_tracer
+
 log = logging.getLogger("pio_tpu.compilecache")
 
 _OFF_VALUES = ("off", "0", "false", "no")
@@ -154,44 +156,143 @@ class CacheProbe:
         }
 
 
+class _Phase:
+    """One trace, lowering or backend call jax has begun on a thread."""
+
+    __slots__ = ("event", "span", "labels", "cache")
+
+    def __init__(self, event: str, span, labels):
+        self.event = event
+        self.span = span          # the entered span, or None
+        self.labels = labels      # its label dict
+        self.cache: dict = {}     # what the cache said inside a backend call
+
+
+class _OpenPhases(threading.local):
+    """A thread's stack of open phases (phases of one thread nest)."""
+
+    def __init__(self):
+        self.phases: list[_Phase] = []
+
+
 class CompileMeter:
     """What this process spent getting programs ready, from jax's own
-    monitoring events: seconds in trace + lowering + backend
-    compile-or-cache-load, programs counted, persistent-cache hits
-    among them. Listens from construction until :meth:`close` (or the
-    end of its ``with`` block)."""
+    monitoring events, counted once: seconds in trace + lowering + backend
+    compile-or-cache-load, programs counted, persistent-cache hits among
+    them. Listens from construction until :meth:`close` (or the end of its
+    ``with`` block).
 
-    _DURATION_EVENTS = (
-        "/jax/core/compile/jaxpr_trace_duration",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        "/jax/core/compile/backend_compile_duration",
-    )
+    jax calls its listeners at both ends of each phase (the scalar
+    listener with the start time as the phase begins, the duration
+    listener as it ends, both with ``fun_name``), and fires a trace event
+    for EVERY jitted function it traces, the ones traced inside another
+    trace too (an inner ``jax.jit``, every ``jnp`` function). So the meter
+    keeps a stack of open phases a thread: only a phase begun with none
+    open on its thread adds to ``seconds``, and only that one opens a span
+    of the ambient tracer (``utils/tracing.py``), closed when jax says the
+    phase ended. The span then has the parent that was open where the
+    program was first called (``als.dispatch``, ``seq.init``, ...), a
+    ``TraceAnnotation`` on a device tracer, and a row in ``train spans:``:
+
+    * ``compile.trace``: Python tracing of the outermost function;
+    * ``compile.lower``: jaxpr to MLIR module;
+    * ``compile.backend``: ``compile_or_get_cached``, with ``cache`` =
+      ``hit`` / ``miss`` / ``off`` (the cache was not asked) and, on a
+      hit, ``retrieval_s`` (the read and deserialize) and ``saved_s``
+      (what the compile took when the entry was written, less that);
+
+    each with ``program`` = jax's ``fun_name``. A thread with no ambient
+    tracer (a partition worker) opens no span; its seconds count.
+    """
+
+    _SPAN_OF = {
+        "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+        "/jax/core/compile/backend_compile_duration": "compile.backend",
+    }
+    _CACHE_SECONDS = {
+        "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+        "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+    }
 
     def __init__(self):
         import jax
 
         self._monitoring = jax.monitoring
         self._lock = threading.Lock()
+        self._open = _OpenPhases()
         self.seconds = 0.0
         self.programs = 0
         self.cache_hits = 0
+        jax.monitoring.register_scalar_listener(self._on_begin)
         jax.monitoring.register_event_duration_secs_listener(
             self._on_duration)
         jax.monitoring.register_event_listener(self._on_event)
 
+    def _on_begin(self, event: str, _start: float, fun_name: str = "",
+                  **_kw) -> None:
+        name = self._SPAN_OF.get(event)
+        if name is None:
+            return
+        phases = self._open.phases
+        span = labels = None
+        if not phases:
+            tracer = ambient_tracer()
+            if tracer is not None:
+                span = tracer.span(name, program=fun_name)
+                labels = span.__enter__()
+        phases.append(_Phase(event, span, labels))
+
     def _on_duration(self, event: str, duration: float, **_kw) -> None:
-        if event in self._DURATION_EVENTS:
-            with self._lock:
+        phases = self._open.phases
+        if event in self._CACHE_SECONDS:
+            if phases:
+                phases[-1].cache[self._CACHE_SECONDS[event]] = duration
+            return
+        if event not in self._SPAN_OF:
+            return
+        backend = self._SPAN_OF[event] == "compile.backend"
+        # a phase begun before the meter listened has no entry: it counts
+        phase = (phases.pop() if phases and phases[-1].event == event
+                 else None)
+        with self._lock:
+            if not phases:
                 self.seconds += duration
-                if event.endswith("backend_compile_duration"):
-                    self.programs += 1
+            if backend:
+                self.programs += 1
+        if phase is None or phase.span is None:
+            return
+        if backend:
+            said = phase.cache
+            phase.labels["cache"] = (
+                "hit" if "hit" in said else
+                "miss" if "asked" in said else "off")
+            for key in self._CACHE_SECONDS.values():
+                if key in said:
+                    phase.labels[key] = round(said[key], 4)
+        phase.span.__exit__(None, None, None)
 
     def _on_event(self, event: str, **_kw) -> None:
         if event == "/jax/compilation_cache/cache_hits":
             with self._lock:
                 self.cache_hits += 1
+            said = "hit"
+        elif event == "/jax/compilation_cache/compile_requests_use_cache":
+            said = "asked"
+        else:
+            return
+        phases = self._open.phases
+        if phases:
+            phases[-1].cache[said] = True
+
+    def totals(self) -> tuple[float, int, int]:
+        """(seconds, programs, cache_hits) so far: a stage's share is the
+        difference of two readings."""
+        with self._lock:
+            return self.seconds, self.programs, self.cache_hits
 
     def close(self) -> None:
+        self._monitoring.unregister_scalar_listener(self._on_begin)
         self._monitoring.unregister_event_duration_listener(
             self._on_duration)
         self._monitoring.unregister_event_listener(self._on_event)

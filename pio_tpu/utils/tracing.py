@@ -223,6 +223,14 @@ _ambient: ContextVar["Tracer | None"] = ContextVar(
     "pio_tpu_tracer", default=None)
 
 
+def ambient_tracer() -> "Tracer | None":
+    """The tracer of the `Tracer.trace` this thread is inside, or None:
+    for a caller that opens a span only where a tree is being kept (the
+    compile meter, whose events also fire on worker threads and outside
+    any job)."""
+    return _ambient.get()
+
+
 def current_tracer() -> Tracer:
     """The ambient tracer; outside any `Tracer.trace`, a fresh
     recorderless one (its spans are histogram-only and die with it:

@@ -29,6 +29,10 @@ class WorkflowContext:
     # heartbeats, preemption checks, and the per-instance checkpoint dir.
     # Set by run_train; None outside a supervised training run.
     lifecycle: Any = None
+    # the job's one CompileMeter (utils/compilecache.py): `Engine.train`
+    # labels `train.algorithms` with what it counted over that span. Set
+    # by run_train; None outside a job (`pio eval`).
+    compile_meter: Any = None
 
     @property
     def event_store(self) -> EventStore:

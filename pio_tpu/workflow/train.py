@@ -29,6 +29,7 @@ from __future__ import annotations
 import json
 import logging
 import os
+import time
 import traceback
 from contextlib import nullcontext
 from dataclasses import replace
@@ -139,6 +140,20 @@ def _resolve_instance(
     return instances.get(instance_id)
 
 
+def process_age_s() -> float | None:
+    """Seconds since the operating system started this process, or None
+    where it does not say (`/proc/self/stat` holds the start in clock
+    ticks since boot: Linux)."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # the fields after the command, which may itself hold spaces
+            fields = f.read().rsplit(b")", 1)[1].split()
+        started = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        return time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
 def _seconds(tracer: Tracer, *spans: str) -> float:
     """What this job's spans of those names took, together (each ran
     once in it: a tracer serves one `run_train`)."""
@@ -163,6 +178,7 @@ def run_train(
     supervise: bool = True,
     heartbeat_every_steps: int = 10,
     sweep_stale_s: float | None = None,
+    use_mesh: bool = True,
 ) -> str:
     """Returns the EngineInstance id (status COMPLETED on success).
 
@@ -171,23 +187,34 @@ def run_train(
     (raises TrainingPreempted; instance INTERRUPTED), heartbeats, and a
     startup zombie sweep. ``resume_instance_id`` re-enters a resumable
     (INTERRUPTED/FAILED) instance; ``auto_resume`` picks the most recent
-    one with checkpoints on disk.
+    one with checkpoints on disk. Without a ``ctx`` the run makes its
+    own (over every device, or with ``use_mesh=False`` none) inside the
+    `train.devices` span: a fresh process reaches the chip there.
 
     The run is one `train` trace on a recorder of its own (surface
     `train`; docs/observability.md "Training"): every stage below is a
     span in it, emitted where the work happens, and the finished tree
     is logged as one `train spans:` record.
     """
+    from pio_tpu.utils.compilecache import CompileMeter
+
     tracer = Tracer(recorder=make_recorder("train"), device=True)
     trace_id = None
     try:
-        with tracer.trace("train", engine=engine_id) as (trace_id, root):
+        # the job's one set of jax listeners, from the root's start: what
+        # it hears inside the trace it opens as `compile.*` spans
+        with tracer.trace("train", engine=engine_id) as (trace_id, root), \
+                CompileMeter() as compile_meter:
+            age = process_age_s()
+            if age is not None:
+                root["process_age_s"] = round(age, 3)
             return _run_train(
-                tracer, root, engine, engine_params, storage, engine_id,
-                engine_version, engine_variant, engine_factory, batch, ctx,
-                stop_after_read, stop_after_prepare, resume_instance_id,
-                auto_resume, checkpoint_root, supervise,
-                heartbeat_every_steps, sweep_stale_s)
+                tracer, root, compile_meter, engine, engine_params,
+                storage, engine_id, engine_version, engine_variant,
+                engine_factory, batch, ctx, use_mesh, stop_after_read,
+                stop_after_prepare, resume_instance_id, auto_resume,
+                checkpoint_root, supervise, heartbeat_every_steps,
+                sweep_stale_s)
     finally:
         if trace_id is not None:
             log.info("train spans: %s", json.dumps(
@@ -196,22 +223,26 @@ def run_train(
 
 
 def _run_train(
-    tracer: Tracer, root: dict, engine, engine_params, storage, engine_id,
-    engine_version, engine_variant, engine_factory, batch, ctx,
-    stop_after_read, stop_after_prepare, resume_instance_id, auto_resume,
-    checkpoint_root, supervise, heartbeat_every_steps, sweep_stale_s,
+    tracer: Tracer, root: dict, compile_meter, engine, engine_params,
+    storage, engine_id, engine_version, engine_variant, engine_factory,
+    batch, ctx, use_mesh, stop_after_read, stop_after_prepare,
+    resume_instance_id, auto_resume, checkpoint_root, supervise,
+    heartbeat_every_steps, sweep_stale_s,
 ) -> str:
     # persistent XLA compile cache BEFORE any engine compile: the second
     # consecutive train of the same engine deserializes its executables
     # instead of re-running XLA (utils/compilecache.py; PIO_TPU_COMPILE_
     # CACHE=off disables)
     from pio_tpu.parallel.mesh import describe_device_memory, describe_devices
-    from pio_tpu.utils.compilecache import CompileMeter, enable_compile_cache
+    from pio_tpu.utils.compilecache import enable_compile_cache
 
     with tracer.span("train.setup"):
         enable_compile_cache()
-        ctx = ctx or create_workflow_context(storage)
-        log.info("train devices: %s", describe_devices())
+        # the first thing that asks jax for its devices: in a fresh
+        # process, the seconds it takes to reach the chip
+        with tracer.span("train.devices"):
+            ctx = ctx or create_workflow_context(storage, use_mesh=use_mesh)
+            log.info("train devices: %s", describe_devices())
         instances = storage.get_metadata_engine_instances()
         from pio_tpu.parallel.distributed import barrier, is_primary
 
@@ -287,7 +318,7 @@ def _run_train(
         lifecycle.start()  # wall-clock liveness beat (see TrainLifecycle)
 
     ctx.lifecycle = lifecycle
-    compile_meter = CompileMeter()
+    ctx.compile_meter = compile_meter
     try:
         with handler if handler is not None else nullcontext():
             models = engine.train(
@@ -334,6 +365,11 @@ def _run_train(
                               "persist.frame", "persist.insert",
                               "train.barrier", "train.complete"))
             log.info("train device memory: %s", describe_device_memory())
+            # the blob and the models in hand go back to the allocator
+            # here, not unseen as the frame unwinds: gigabytes of host
+            # pages for a block stack, with the chip idle
+            with tracer.span("train.release", bytes=len(blob)):
+                del blob, models
             return instance_id
     except TrainingPreempted as preempted:
         try:
@@ -366,9 +402,9 @@ def _run_train(
             raise train_error from update_error
         raise
     finally:
-        compile_meter.close()
         lifecycle.stop()
         ctx.lifecycle = None
+        ctx.compile_meter = None
 
 
 def load_models(

@@ -346,6 +346,70 @@ def test_midflight_dual_route_and_foldin(trained, monkeypatch):
         handle.close()
 
 
+@pytest.mark.parametrize("when", ["during_prepare", "after_prepare"])
+def test_midflight_resident_foldin_survives_cutover(trained, monkeypatch,
+                                                    when):
+    """A fold-in for a user whose partition STAYS on its shard, landing
+    after that shard read its partition for the new arm (mid-prepare) or
+    after the arm was built (before activate): the activated arm serves
+    the acked row, not the snapshot's."""
+    from pio_tpu.serving_fleet import shard as shard_mod
+
+    storage, *_ = trained
+    handle = _fleet(storage)
+    port = handle.router_http.port
+    old = default_owners(2)
+    new = compute_reshard_owners(old, 3)
+    uid = next(f"u{u}" for u in range(20)
+               if old[partition_of(f"u{u}")] == new[partition_of(f"u{u}")])
+    folded: list[list[float]] = []
+    fold_lock = threading.Lock()
+
+    def fold():
+        with fold_lock:
+            row = [len(folded) + 0.5, -0.25, 2.0, 1.5]
+            out = handle.router.upsert_users({uid: row}, staleness_s=0.1)
+            assert out.get("ok"), out
+            folded.append(row)
+
+    patched, reached, release = _pause_at("reshard.cutover")
+    monkeypatch.setattr(chaos, "maybe_inject", patched)
+    if when == "during_prepare":
+        orig = shard_mod._prepare_arm
+
+        def prepare_arm(part, rparams=None):
+            # each owner replica has read its partition by now
+            if uid in part.user_ids:
+                fold()
+            return orig(part, rparams)
+
+        monkeypatch.setattr(shard_mod, "_prepare_arm", prepare_arm)
+    new_servers, urls = _join_group(storage, shard_index=2, n_shards=3)
+    try:
+        s, out = call(port, "POST", "/reshard/begin",
+                      body={"nShards": 3, "endpoints": [urls]})
+        assert s == 200, out
+        assert reached.wait(timeout=60), "migration never hit the cutover"
+        if when == "after_prepare":
+            fold()
+        assert folded
+        release.set()
+        st = _wait_reshard_done(port)
+        assert st["verdict"] == VERDICT_COMMITTED, st
+        owner = handle.router.plan.owner_of(uid)
+        assert owner == old[partition_of(uid)]
+        for url in handle.endpoints[owner]:
+            s, got = call(int(url.rsplit(":", 1)[1]), "POST",
+                          "/shard/user_row", body={"user": uid})
+            assert s == 200 and got["found"], got
+            assert got["row"] == folded[-1], (got, folded)
+    finally:
+        release.set()
+        for http, _ in new_servers:
+            http.stop()
+        handle.close()
+
+
 def test_abort_midflight_restores_old_plan_bit_identical(trained,
                                                          monkeypatch):
     storage, *_ = trained

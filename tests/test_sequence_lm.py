@@ -430,6 +430,12 @@ def test_the_engine_trains_persists_and_serves_the_latent_stack(monkeypatch):
                 "expert_tokens_held_share", "expert_tiles_used_share"):
         assert key in labels, key
     assert "window_blocks_visited" not in labels      # no window layer
+    # in the order they open; the copy to the host is a span of its own
+    assert list(spans) == ["seq.batch", "seq.init", "seq.dispatch",
+                           "seq.wait", "seq.d2h"]
+    assert spans["seq.init"] == {} and spans["seq.d2h"] == {
+        "bytes": sum(leaf.nbytes for leaf in
+                     jax.tree_util.tree_leaves(model.params))}
     assert float(labels["loss_first"]) == pytest.approx(
         float(labels["loss_main_first"])
         + 0.3 * float(labels["loss_mtp_first"]), rel=1e-6)

@@ -23,7 +23,7 @@ from tests._tiny_train import memory_storage, tiny_engine, tiny_params
 UNDER_ROOT = ["train.setup", "train.read", "train.prepare",
               "train.algorithms", "persist.d2h", "persist.pickle",
               "persist.frame", "persist.insert", "train.barrier",
-              "train.complete"]
+              "train.complete", "train.release"]
 # under train.algorithms, in order, by path; then the labels a span
 # must carry (the counts at its boundary)
 PATHS = {
@@ -33,7 +33,10 @@ PATHS = {
                       "als.dispatch", "als.wait"],
 }
 LABELS = {
-    "train": {"engine", "instance", "chips"},
+    "train": {"engine", "instance", "chips", "process_age_s"},
+    "compile.trace": {"program"},
+    "compile.lower": {"program"},
+    "compile.backend": {"program", "cache"},
     "train.read": {"ratings", "users", "items"},
     "train.algorithms": {"programs", "cache_hits", "compile_s"},
     "als.partition": {"rows_u", "rows_i", "nnz_max_u", "nnz_min_u",
@@ -45,6 +48,7 @@ LABELS = {
     "persist.pickle": {"bytes", "ids"},
     "persist.frame": {"bytes"},
     "persist.insert": {"bytes"},
+    "train.release": {"bytes"},
 }
 
 
@@ -69,7 +73,9 @@ def _spans(messages: list[str]) -> list[dict]:
 
 @pytest.mark.parametrize("path", PATHS)
 def test_one_tree_with_every_span_of_the_path(path, caplog):
-    # the second job: the first one compiles inside `als.dispatch`
+    # the second job: the first one's `compile.*` rows (the programs got
+    # ready inside `als.init` and `als.dispatch`) are
+    # tests/test_compile_spans.py's
     rows = _spans(_train(path, caplog, jobs=2))
     names = [r["name"] for r in rows]
     assert names.count("train") == 1 and rows[0]["name"] == "train"
@@ -79,15 +85,19 @@ def test_one_tree_with_every_span_of_the_path(path, caplog):
                                        else "1")
     assert [r["name"] for r in rows if r["parent"] == "train"] == UNDER_ROOT
     assert [r["name"] for r in rows
+            if r["parent"] == "train.setup"] == ["train.devices"]
+    assert [r["name"] for r in rows
             if r["parent"] == "train.algorithms"] == PATHS[path]
-    # a DataSource that reads no events: no `events.*` span
-    assert len(rows) == 1 + len(UNDER_ROOT) + len(PATHS[path])
+    # a DataSource that reads no events: no `events.*` span; a job after
+    # the process's first: no `compile.*` span
+    assert len(rows) == 2 + len(UNDER_ROOT) + len(PATHS[path])
     assert not [n for n in names if n.startswith("events.")]
     for row in rows:
         assert LABELS.get(row["name"], set()) <= set(row["labels"]), row
         assert "status" not in row
         assert 0.0 <= row["start_s"] <= root["duration_s"]
     by_name = {r["name"]: r for r in rows}
+    assert float(root["labels"]["process_age_s"]) >= 0.0
     assert by_name["train.read"]["labels"] == {
         "ratings": "5000", "users": "300", "items": "200"}
     assert by_name["persist.pickle"]["labels"]["ids"] == "500"
@@ -245,7 +255,7 @@ def test_the_benchmarks_expressions_still_read_the_records(tracing, caplog):
         rows = {r["name"]: r for r in _spans(messages)}
         assert float(algorithms) == pytest.approx(
             rows["train.algorithms"]["duration_s"], abs=1e-3)
-        persist = sum(rows[n]["duration_s"] for n in UNDER_ROOT[4:])
+        persist = sum(rows[n]["duration_s"] for n in UNDER_ROOT[4:10])
         assert float(timing[5]) == pytest.approx(persist, abs=1e-3)
     else:
         # PIO_TPU_TRACE=off: no recorder, no tree; the records stay
