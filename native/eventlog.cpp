@@ -33,6 +33,7 @@
 // record. Deletes are tombstones kept by the Python layer and passed into
 // scans for exclusion (the log itself is immutable).
 
+#include <atomic>
 #include <cctype>
 #include <chrono>
 #include <cstdint>
@@ -58,19 +59,43 @@ constexpr uint64_t kHeaderSize = 8;
 // crc32 (IEEE, table-driven) — matches Python's zlib.crc32
 // ---------------------------------------------------------------------------
 
-uint32_t crc_table[256];
+// Slicing-by-8: crc_table[0] is the byte-at-a-time table; crc_table[k][b] is
+// the remainder of byte b followed by k zero bytes, so eight bytes fold in
+// one step of eight independent loads (the one-table loop is one dependent
+// load a byte). Same polynomial, same bit order, same values.
+uint32_t crc_table[8][256];
 bool crc_init_done = []() {
   for (uint32_t i = 0; i < 256; i++) {
     uint32_t c = i;
     for (int k = 0; k < 8; k++) c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
-    crc_table[i] = c;
+    crc_table[0][i] = c;
   }
+  for (int k = 1; k < 8; k++)
+    for (uint32_t i = 0; i < 256; i++) {
+      uint32_t c = crc_table[k - 1][i];
+      crc_table[k][i] = (c >> 8) ^ crc_table[0][c & 0xFF];
+    }
   return true;
 }();
 
+// Bytes checksummed by this process (every caller of crc32_of: appends,
+// reads, scans, sweeps). A read that checks each record once raises it by
+// the log's payload bytes; see el_crc_bytes.
+std::atomic<uint64_t> crc_bytes_done{0};
+
 uint32_t crc32_of(const uint8_t* p, size_t n) {
+  crc_bytes_done.fetch_add(n, std::memory_order_relaxed);
   uint32_t c = 0xFFFFFFFFu;
-  for (size_t i = 0; i < n; i++) c = crc_table[(c ^ p[i]) & 0xFF] ^ (c >> 8);
+  for (; n >= 8; p += 8, n -= 8) {
+    uint64_t w;
+    memcpy(&w, p, 8);  // little-endian hosts only, as load_le below
+    w ^= c;
+    c = crc_table[7][w & 0xFF] ^ crc_table[6][(w >> 8) & 0xFF] ^
+        crc_table[5][(w >> 16) & 0xFF] ^ crc_table[4][(w >> 24) & 0xFF] ^
+        crc_table[3][(w >> 32) & 0xFF] ^ crc_table[2][(w >> 40) & 0xFF] ^
+        crc_table[1][(w >> 48) & 0xFF] ^ crc_table[0][w >> 56];
+  }
+  for (size_t i = 0; i < n; i++) c = crc_table[0][(c ^ p[i]) & 0xFF] ^ (c >> 8);
   return c ^ 0xFFFFFFFFu;
 }
 
@@ -1409,6 +1434,8 @@ int64_t el_append(void* h, const uint8_t* payload, uint32_t len) {
   return off;
 }
 
+// Logical end and whole valid records, by a checked walk of the log (every
+// CRC, every envelope): what a read would accept. el_end is the end alone.
 void el_stats(void* h, uint64_t* end, uint64_t* n_records) {
   auto* lg = static_cast<Log*>(h);
   *end = lg->end;
@@ -1420,6 +1447,17 @@ void el_stats(void* h, uint64_t* end, uint64_t* n_records) {
       return true;
     });
   *n_records = n;
+}
+
+// The log's logical end (after the last whole record), kept by el_open and
+// el_append: no pass over the log.
+uint64_t el_end(void* h) { return static_cast<Log*>(h)->end; }
+
+uint32_t el_crc32(const uint8_t* p, uint64_t n) { return crc32_of(p, n); }
+
+// Payload bytes this process has checksummed so far, by any call.
+uint64_t el_crc_bytes() {
+  return crc_bytes_done.load(std::memory_order_relaxed);
 }
 
 uint64_t el_hash(const uint8_t* s, uint32_t len) { return fnv1a(s, len); }

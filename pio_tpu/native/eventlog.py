@@ -57,6 +57,12 @@ def _lib() -> C.CDLL:
     lib.el_append.restype = C.c_int64
     lib.el_append.argtypes = [C.c_void_p, C.c_char_p, C.c_uint32]
     lib.el_stats.argtypes = [C.c_void_p, u64p, u64p]
+    lib.el_end.restype = C.c_uint64
+    lib.el_end.argtypes = [C.c_void_p]
+    lib.el_crc32.restype = C.c_uint32
+    lib.el_crc32.argtypes = [C.c_void_p, C.c_uint64]
+    lib.el_crc_bytes.restype = C.c_uint64
+    lib.el_crc_bytes.argtypes = []
     lib.el_hash.restype = C.c_uint64
     lib.el_hash.argtypes = [C.c_char_p, C.c_uint32]
     lib.el_free.argtypes = [C.c_void_p]
@@ -93,6 +99,20 @@ def _lib() -> C.CDLL:
 def el_hash(s: str) -> int:
     b = s.encode("utf-8")
     return _lib().el_hash(b, len(b))
+
+
+def el_crc32(data) -> int:
+    """The log's record checksum of a bytes-like, read in place: equal to
+    ``zlib.crc32(data)``."""
+    a = np.frombuffer(data, np.uint8)
+    return _lib().el_crc32(a.ctypes.data, a.size)
+
+
+def crc_bytes() -> int:
+    """Payload bytes this process's library has checksummed so far (appends,
+    reads, scans and sweeps alike). A read that checks every record once
+    raises it by the log's end - 8 - 8 x framed records."""
+    return _lib().el_crc_bytes()
 
 
 def _micros(dt: datetime) -> int:
@@ -337,7 +357,14 @@ class EventLog:
             results.append((status, *fields))
         return results
 
+    def end(self) -> int:
+        """The log's logical end in bytes (header + whole records), as the
+        handle keeps it: no pass over the log."""
+        return self._lib.el_end(self._h)
+
     def stats(self) -> tuple[int, int]:
+        """(logical end, whole valid records): a checked walk of the whole
+        log, every CRC and envelope. Not for a hot path; `end()` is free."""
         end = C.c_uint64()
         n = C.c_uint64()
         self._lib.el_stats(self._h, C.byref(end), C.byref(n))
@@ -385,7 +412,10 @@ class EventLog:
         value_event restricts value_key extraction to that event name.
         Two spans of the job in flight (`pio train`'s `train.read`):
         `events.scan`, the sweep, and `events.tables`, its output copied
-        into NumPy columns and Python id strings."""
+        into NumPy columns and Python id strings. The sweep's span says
+        what it checked: `crc_bytes`, the payload bytes checksummed under
+        it, is `log_bytes` - 8 - 8 x framed records when every record was
+        verified once."""
         (flags, start, until, h_etype, _h_eid, events_arr, n_events,
          h_tetype, _h_teid, _h_eventid) = f.to_c()
         u8p = C.POINTER(C.c_uint8)
@@ -397,6 +427,7 @@ class EventLog:
         ulen, ilen = C.c_uint64(), C.c_uint64()
         nu, ni = C.c_uint32(), C.c_uint32()
         with tracing.span("events.scan") as sp:
+            crc_before = self._lib.el_crc_bytes()
             n = self._lib.el_columnarize(
                 self._h, flags, start, until, h_etype, events_arr, n_events,
                 h_tetype,
@@ -411,7 +442,8 @@ class EventLog:
             if n < 0:
                 raise OSError(f"columnarize failed on {self.path}")
             sp.update(rows=n, users=nu.value, items=ni.value,
-                      log_bytes=self.stats()[0])
+                      log_bytes=self.end(),
+                      crc_bytes=self._lib.el_crc_bytes() - crc_before)
         with tracing.span("events.tables"):
             try:
                 cols = Columns(

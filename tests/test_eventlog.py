@@ -9,6 +9,8 @@ memory and the native log, asserting identical results.
 from __future__ import annotations
 
 import os
+import struct
+import zlib
 from datetime import datetime, timedelta, timezone
 
 import numpy as np
@@ -20,6 +22,9 @@ from pio_tpu.data.datamap import DataMap
 from pio_tpu.data.event import Event
 from pio_tpu.data.eventstore import to_interactions
 from pio_tpu.data.storage import StorageClientConfig, StorageError
+from pio_tpu.native.eventlog import (
+    DEDUP_NONE, EventLog, ScanFilter, crc_bytes, el_crc32, pack_event,
+)
 
 UTC = timezone.utc
 T0 = datetime(2026, 1, 1, tzinfo=UTC)
@@ -214,6 +219,157 @@ class TestDurability:
         found = list(dao2.find(1, limit=-1))
         assert len(found) == len(CORPUS) - 1  # bad crc record dropped
         b2.close()
+
+
+def _frames(log_path) -> list[tuple[int, int]]:
+    """(payload offset, payload length) of every frame, by `struct` alone."""
+    with open(log_path, "rb") as f:
+        blob = f.read()
+    out, pos = [], 8
+    while pos + 8 <= len(blob):
+        (n,) = struct.unpack_from("<I", blob, pos)
+        out.append((pos + 8, n))
+        pos += 8 + n
+    assert pos == len(blob)
+    return out
+
+
+def _fnv1a(s: str) -> int:
+    h = 1469598103934665603
+    for b in s.encode("utf-8"):
+        h = ((h ^ b) * 1099511628211) & 0xFFFFFFFFFFFFFFFF
+    return h
+
+
+def _payload_by_hand(e: Event) -> bytes:
+    """The record payload of native/eventlog.cpp's header comment, packed
+    without a call into the library."""
+    def us(t):
+        return (t - datetime(1970, 1, 1, tzinfo=UTC)) // timedelta(
+            microseconds=1)
+
+    def s16(s):
+        b = (s or "").encode("utf-8")
+        return struct.pack("<H", len(b)) + b
+
+    target = e.target_entity_type is not None
+    props = e.properties.to_json().encode("utf-8")
+    return (
+        struct.pack(
+            "<qhqh6QB", us(e.event_time), 0, us(e.creation_time), 0,
+            _fnv1a(e.event), _fnv1a(e.entity_type), _fnv1a(e.entity_id),
+            _fnv1a(e.target_entity_type) if target else 0,
+            _fnv1a(e.target_entity_id) if target else 0,
+            _fnv1a(e.event_id), 1 if target else 0)
+        + s16(e.event) + s16(e.entity_type) + s16(e.entity_id)
+        + s16(e.target_entity_type) + s16(e.target_entity_id)
+        + s16(e.event_id) + s16(None) + s16("")
+        + struct.pack("<I", len(props)) + props)
+
+
+def _rows(cols):
+    return sorted(
+        (cols.users[u], cols.items[i], float(v), int(t))
+        for u, i, v, t in zip(cols.user_idx, cols.item_idx, cols.values,
+                              cols.times_us))
+
+
+_RATES = ScanFilter(entity_type="user", event_names=["rate", "buy"])
+
+
+class TestChecksum:
+    """The record check (native/eventlog.cpp crc32_of, several bytes a
+    step): zlib's values on every length and alignment, a log framed
+    without the library read back whole, and one flipped byte anywhere in
+    a payload costing that record and no other."""
+
+    @pytest.mark.parametrize("start", [*range(8), "1MB+5"])
+    def test_crc_is_zlibs(self, start):
+        rng = np.random.default_rng(38)
+        if start == "1MB+5":
+            buf = rng.integers(0, 256, (1 << 20) + 5, np.uint8).tobytes()
+            assert el_crc32(buf) == zlib.crc32(buf)
+            return
+        buf = rng.integers(0, 256, 112, np.uint8)
+        # read in place, `start` bytes past a multiple of 8
+        start += -buf.ctypes.data % 8
+        before = crc_bytes()
+        for n in range(81):
+            part = buf[start:start + n]
+            assert n == 0 or part.ctypes.data % 8 == start % 8
+            assert el_crc32(part) == zlib.crc32(part.tobytes()), n
+        assert crc_bytes() - before == sum(range(81))
+
+    def test_a_log_framed_with_struct_and_zlib_reads_back_whole(self, tmp_path):
+        path = tmp_path / "events.log"
+        with open(path, "wb") as f:
+            f.write(b"PIOEVLG1")
+            for e in CORPUS:
+                payload = _payload_by_hand(e)
+                f.write(struct.pack("<II", len(payload), zlib.crc32(payload)))
+                f.write(payload)
+        payload_bytes = os.path.getsize(path) - 8 - 8 * len(CORPUS)
+        log = EventLog(str(path), create=False)
+        try:
+            assert log.end() == os.path.getsize(path)
+            before = crc_bytes()
+            assert log.stats() == (os.path.getsize(path), len(CORPUS))
+            assert crc_bytes() - before == payload_bytes
+            assert log.scan(ScanFilter()) == CORPUS
+            cols = log.columnarize(_RATES, value_key="rating",
+                                   default_value=4.0)
+            assert {(cols.users[u], cols.items[i]): v for u, i, v in zip(
+                cols.user_idx, cols.item_idx, cols.values)} == {
+                    ("u1", "i1"): 1.0, ("u1", "i2"): 4.0, ("u2", "i1"): 2.5,
+                    ("u3", "i2"): 5.0}
+        finally:
+            log.close()
+        # and what the library writes is what `struct` + zlib would
+        mine = tmp_path / "mine.log"
+        log = EventLog(str(mine))
+        for e in CORPUS:
+            log.append(e)
+        log.close()
+        with open(mine, "rb") as f:
+            blob = f.read()
+        for (at, n), e in zip(_frames(mine), CORPUS, strict=True):
+            assert blob[at:at + n] == pack_event(e) == _payload_by_hand(e)
+            assert struct.unpack_from("<I", blob, at - 4)[0] == zlib.crc32(
+                blob[at:at + n])
+
+    @pytest.mark.parametrize("where", [0, 16 * 2 + 8 + 3, *range(-7, 0)],
+                             ids=lambda o: f"payload[{o}]")
+    def test_one_flipped_byte_drops_that_record_alone(self, where, tmp_path):
+        """A payload's first byte, one inside a whole step, and each of
+        its last seven."""
+        # the victim's payload ends 15 bytes past a multiple of 16, so its
+        # last 7 bytes are past the last whole step of 8 or of 16
+        victim = next(
+            e for pad in range(16)
+            for e in [mk(3, u="u2" + "x" * pad, it="i3", rating=3.0)]
+            if len(pack_event(e)) % 16 == 15)
+        corpus = CORPUS[:3] + [victim] + CORPUS[4:]
+        path = tmp_path / "events.log"
+        log = EventLog(str(path))
+        for e in corpus:
+            log.append(e)
+        whole = _rows(log.columnarize(_RATES, dedup=DEDUP_NONE))
+        log.close()
+        at, n = _frames(path)[3]
+        with open(path, "r+b") as f:
+            f.seek(at + where % n)
+            c = f.read(1)
+            f.seek(at + where % n)
+            f.write(bytes([c[0] ^ 0x10]))
+        log = EventLog(str(path), create=False)
+        try:
+            kept = corpus[:3] + corpus[4:]
+            assert log.stats() == (os.path.getsize(path), len(kept))
+            assert log.scan(ScanFilter()) == kept
+            assert _rows(log.columnarize(_RATES, dedup=DEDUP_NONE)) == [
+                r for r in whole if r[0] != victim.entity_id]
+        finally:
+            log.close()
 
 
 class TestColumnarize:

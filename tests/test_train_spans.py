@@ -186,7 +186,8 @@ def test_a_job_that_reads_events_says_where_the_read_went(
         backend, under_read, tmp_path, caplog):
     """The template's own DataSource over an event store: under
     `train.read`, `events.scan` (the store's columnarize: one native
-    sweep of the `eventlog` store, which knows its log's bytes),
+    sweep of the `eventlog` store, which says its log's bytes and the
+    payload bytes it checksummed: every record, once),
     `events.tables` (that sweep's output copied into NumPy columns and
     Python strings; the other stores fold ids as strings already) and
     `events.index` (the two id indexes)."""
@@ -226,7 +227,23 @@ def test_a_job_that_reads_events_says_where_the_read_went(
     assert [r["name"] for r in under] == under_read
     scan = dict(by_name["events.scan"]["labels"])
     if backend == "eventlog":
-        assert int(scan.pop("log_bytes")) > 900 * 100
+        from pio_tpu.native.eventlog import EventLog, ScanFilter, crc_bytes
+
+        path = tmp_path / "log" / "app_1" / "events.log"
+        payload = path.stat().st_size - 8 - 8 * 900
+        assert int(scan.pop("log_bytes")) == path.stat().st_size
+        assert int(scan.pop("crc_bytes")) == payload
+        # one sweep a read; the checked count of `stats` is a second one
+        log = EventLog(str(path), create=False)
+        try:
+            at = [crc_bytes()]
+            assert len(log.columnarize(ScanFilter()).values) == 900
+            at.append(crc_bytes())
+            assert log.stats() == (path.stat().st_size, 900)
+            at.append(crc_bytes())
+        finally:
+            log.close()
+        assert np.diff(at).tolist() == [payload, payload]
     assert scan == {"rows": "900", **counts}
     assert all(not r["labels"] and "status" not in r for r in under[1:])
     # in order, inside the read, and covering it
