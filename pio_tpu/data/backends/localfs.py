@@ -8,8 +8,12 @@ covers the opaque-blob path used by pickled local models.
 Durability: ``insert`` goes through ``utils.durable.durable_write`` (tmp
 file + fsync + atomic rename + CRC32C header) — the reference's bare
 FileOutputStream left a truncated ``pio_model_*.bin`` behind any crash
-mid-write, and ``get`` happily returned it. ``get`` now verifies the
-frame and raises ``ModelIntegrityError`` on a torn or bit-rotted file;
+mid-write, and ``get`` happily returned it. A train job's models arrive
+not yet serialized (``workflow/checkpoint.py HostModels``) and write
+themselves into the tmp file, one pass from their arrays. ``get`` verifies
+and strips the wrapper of a raw payload and hands a content-framed model
+on as it is: every reader unframes it (``models_from_bytes``), which
+raises ``ModelIntegrityError`` on a torn or bit-rotted file, once;
 pre-durability files (no frame header) pass through unverified.
 """
 
@@ -49,7 +53,9 @@ class _FSModels(d.ModelsDAO):
         p = self._path(model_id)
         if not os.path.exists(p):
             return None
-        return d.Model(model_id, durable_read(p))
+        # every reader of a model unframes it (models_from_bytes): the
+        # content frame is checked there, once
+        return d.Model(model_id, durable_read(p, verify_content=False))
 
     def delete(self, model_id):
         p = self._path(model_id)

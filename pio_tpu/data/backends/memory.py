@@ -239,8 +239,9 @@ class _MemModels(d.ModelsDAO):
         self.t = t
 
     def insert(self, m: d.Model):
+        stored = d.Model(m.id, m.blob_bytes())
         with self.t.lock:
-            self.t.models[m.id] = m
+            self.t.models[m.id] = stored
 
     def get(self, model_id):
         return self.t.models.get(model_id)

@@ -377,7 +377,7 @@ class SqlModels(d.ModelsDAO):
     def insert(self, m: d.Model):
         self.db.exec(
             self.db.upsert_sql("models", ("id", "models"), ("id",)),
-            (m.id, m.models),
+            (m.id, m.blob_bytes()),
         )
 
     def get(self, model_id):

@@ -12,7 +12,9 @@ schema/migration, and its own Models DAO.
 
 Model blobs of `EXTERNAL_BLOB_BYTES` (1 MiB) or more live OUTSIDE the
 database, one file each under `PATH.models/`, written by
-`utils.durable.durable_write`; their `models` row holds NULL. Inline, a
+`utils.durable.durable_write` (a train job's models, which arrive not yet
+serialized, write themselves into it: one pass from their arrays to the
+file); their `models` row holds NULL. Inline, a
 212 MB model was 52,000 overflow pages that went to the WAL and fsync,
 and the same commit's auto-checkpoint then copied every one of them
 into PATH and fsynced again: each byte written twice. A file is one
@@ -182,11 +184,17 @@ class _SqliteModels(sc.SqlModels):
 
     def insert(self, m: d.Model):
         blob = m.models
-        external = (self._dir is not None
-                    and len(blob) >= EXTERNAL_BLOB_BYTES)
+        # a source that is sure to reach the size writes itself into the
+        # file: nothing of its size is built on the way. Any other is
+        # made in memory and goes by its length, as bytes do
+        streamed = (self._dir is not None and not isinstance(blob, bytes)
+                    and blob.min_bytes >= EXTERNAL_BLOB_BYTES)
+        if not streamed:
+            blob = m.blob_bytes()
+        external = streamed or (self._dir is not None
+                                and len(blob) >= EXTERNAL_BLOB_BYTES)
         if external:
-            with tracing.span("models.file", bytes=len(blob)), \
-                    self._blob_lock:
+            with tracing.span("models.file") as sp, self._blob_lock:
                 if not os.path.isdir(self._dir):
                     os.makedirs(self._dir, exist_ok=True)
                     # pio: lint-ok[blocking-under-lock] as below; once
@@ -195,10 +203,10 @@ class _SqliteModels(sc.SqlModels):
                 # keep two writers off one tmp file; only writers of
                 # large model files ever wait for it (seconds, where
                 # the inline insert held the database's own lock)
-                durable_write(self._file(m.id), blob)
+                sp["bytes"] = durable_write(self._file(m.id), blob)
         with tracing.span("models.row",
                           inline_bytes=0 if external else len(blob)):
-            super().insert(d.Model(m.id, None) if external else m)
+            super().insert(d.Model(m.id, None if external else blob))
         if not external:
             # the id may have been stored the other way: one copy, not two
             self._remove_file(m.id)

@@ -129,7 +129,8 @@ def evaluation_instance_from_wire(w: dict) -> d.EvaluationInstance:
 
 
 def model_to_wire(m: d.Model) -> dict:
-    return {"id": m.id, "models": base64.b64encode(m.models).decode("ascii")}
+    return {"id": m.id,
+            "models": base64.b64encode(m.blob_bytes()).decode("ascii")}
 
 
 def model_from_wire(w: dict) -> d.Model:

@@ -107,6 +107,38 @@ class EntityIdIndex:
         self.bimap = BiMap.string_int(ids)
         self._id_array = np.array(list(self.bimap.keys()), dtype=object)
 
+    # -- pickling -------------------------------------------------------------
+    # As its `__dict__` an index of n ids pickles 3n Python strings and 2n
+    # ints (the two dictionaries and the object array): half a second and
+    # 26 MB for 745,000 ids, with every chip idle under the persist. Its
+    # state is the ids alone, in dense order, joined into one UTF-8 buffer
+    # by a separator that none of them contains.
+    _SEPARATORS = tuple(chr(c) for c in range(32))
+
+    def __getstate__(self) -> dict:
+        ids = self._id_array.tolist()
+        for sep in self._SEPARATORS:
+            try:
+                joined = sep.join(ids)
+            except TypeError:       # ids that are not strings
+                break
+            if joined.count(sep) == max(len(ids) - 1, 0):
+                return {"n": len(ids), "sep": sep,
+                        "utf8": joined.encode("utf-8", "surrogatepass")}
+        return self.__dict__        # as before this state
+
+    def __setstate__(self, state: dict) -> None:
+        if "utf8" not in state:     # written before the joined state, or
+            self.__dict__.update(state)         # the fallback above
+            return
+        text = state["utf8"].decode("utf-8", "surrogatepass")
+        ids = text.split(state["sep"]) if state["n"] else []
+        self.bimap = BiMap.__new__(BiMap)
+        self.bimap._fwd = dict(zip(ids, range(len(ids))))
+        self.bimap._rev = dict(enumerate(ids))
+        self._id_array = np.empty(len(ids), dtype=object)
+        self._id_array[:] = ids
+
     def __len__(self) -> int:
         return len(self.bimap)
 

@@ -14,7 +14,7 @@ import re
 import string
 from dataclasses import dataclass, field, replace
 from datetime import datetime
-from typing import Iterable, Iterator, Sequence
+from typing import Iterable, Iterator, Protocol, Sequence
 
 from pio_tpu.data.datamap import PropertyMap
 from pio_tpu.data.event import Event
@@ -107,12 +107,38 @@ class EvaluationInstance:
     evaluator_results_json: str = ""
 
 
+class BlobSource(Protocol):
+    """A blob that is not made yet and writes itself
+    (`workflow/checkpoint.py HostModels`: a train job's models, still
+    arrays)."""
+
+    min_bytes: int      # a length the blob is sure to reach
+
+    def write_framed(self, f) -> int:
+        """The framed blob into the binary file `f`; -> its length."""
+
+    def __bytes__(self) -> bytes:
+        """The same blob, in memory."""
+
+
 @dataclass(frozen=True)
 class Model:
-    """Serialized model blob (reference Models.scala:30-48)."""
+    """Serialized model blob (reference Models.scala:30-48).
+
+    `models` is the blob's `bytes`: always, in what a DAO's `get`
+    returns. Into `insert` it may be a `BlobSource` instead: a store
+    that keeps a model as a file has it `write_framed` there, so that
+    nothing of the blob's size is built on the way; every other store
+    takes `blob_bytes()`."""
 
     id: str
-    models: bytes
+    models: bytes | BlobSource | None
+
+    def blob_bytes(self) -> bytes | None:
+        """`models` as the bytes a row or a wire message holds."""
+        if self.models is None or isinstance(self.models, bytes):
+            return self.models
+        return bytes(self.models)
 
 
 # ---------------------------------------------------------------------------

@@ -209,6 +209,30 @@ class Tracer:
             self.histogram(name).record(time.monotonic() - t0)
             _ambient.reset(token)
 
+    def emit(self, name: str, seconds: float, start: float,
+             **labels) -> None:
+        """A span whose owner timed it itself, by adding up many short
+        pieces that alternate with another span's (a sink that checksums
+        a chunk, then writes it, a hundred times a model): one row of
+        `seconds`, a child of the span open now and begun at `start` (the
+        monotonic clock), where a `span` a piece would be hundreds of
+        rows a job. It has no TraceAnnotation: under a device profile
+        its seconds lie under the enclosing span's name."""
+        self.histogram(name).record(seconds)
+        recorder = self.recorder
+        ctx = _tracectx.current() if recorder is not None else None
+        if ctx is None:
+            return
+        recorder.record(_SpanRecord(
+            trace_id=ctx.trace_id, span_id=ctx.child().span_id,
+            parent_id=ctx.span_id, name=name, surface=recorder.surface,
+            # pio: lint-ok[bench-clock] as in `span`: the wall clock
+            # orders rows across processes, the duration is monotonic
+            start_s=time.time() - (time.monotonic() - start),
+            duration_s=seconds,
+            labels={str(k): str(v) for k, v in labels.items()},
+            start_mono_s=start))
+
     def record(self, name: str, seconds: float) -> None:
         self.histogram(name).record(seconds)
 
@@ -243,6 +267,12 @@ def span(name: str, **labels):
     `run_train` (the ALS trainer, model persistence) makes to put its
     own work in the job's span tree."""
     return current_tracer().span(name, **labels)
+
+
+def emit(name: str, seconds: float, start: float, **labels) -> None:
+    """`current_tracer().emit(...)`: a row of the job's span tree for
+    seconds the caller added up itself."""
+    current_tracer().emit(name, seconds, start, **labels)
 
 
 # ---------------------------------------------------------------------------

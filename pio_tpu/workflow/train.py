@@ -43,7 +43,7 @@ from pio_tpu.obs import make_recorder
 from pio_tpu.resilience import chaos
 from pio_tpu.utils.tracing import Tracer
 from pio_tpu.utils.time import format_time, utcnow
-from pio_tpu.workflow.checkpoint import models_from_bytes, models_to_bytes
+from pio_tpu.workflow.checkpoint import models_from_bytes, models_on_host
 from pio_tpu.workflow.context import WorkflowContext, create_workflow_context
 from pio_tpu.workflow.lifecycle import (
     RESUMABLE_STATUSES,
@@ -333,15 +333,22 @@ def _run_train(
             # COMPLETED-without-a-blob. The barrier is reached on BOTH
             # outcomes: a host whose persist epoch failed must not leave
             # its peers blocked in sync_global_devices forever.
+            # The models go from their host arrays to the store in one
+            # pass: where the store keeps a file they are pickled,
+            # checksummed and written a piece at a time under
+            # `persist.insert` (`persist.pickle` and `persist.frame` are
+            # the pickler's and the checksum's seconds inside it), and no
+            # blob is ever held here.
             persist_error: Exception | None = None
             try:
-                blob = models_to_bytes(models)
-                with tracer.span("persist.insert", bytes=len(blob)):
+                on_host = models_on_host(models)
+                with tracer.span("persist.insert") as sp:
                     chaos.maybe_inject("train.persist")
                     if primary:
                         storage.get_model_data_models().insert(
-                            Model(instance_id, blob)
+                            Model(instance_id, on_host)
                         )
+                    sp["bytes"] = on_host.framed_bytes or 0
             except Exception as e:  # noqa: BLE001 - re-raised after barrier
                 persist_error = e
             # the COMPLETED transition must not outrun any host's part of
@@ -353,23 +360,22 @@ def _run_train(
             with tracer.span("train.complete"):
                 record("COMPLETED")
             log.info("training %s COMPLETED (%d bytes of models)",
-                     instance_id, len(blob))
+                     instance_id, on_host.framed_bytes or 0)
             # from the spans: engine.train is its three stages, persist
             # everything from the models in hand to the instance COMPLETED
+            # (the pickle, the checksum and the file lie inside the insert)
             log.info("train timing: engine.train %.3fs, of which compile "
                      "%s; persist %.3fs",
                      _seconds(tracer, "train.read", "train.prepare",
                               "train.algorithms"),
                      compile_meter,
-                     _seconds(tracer, "persist.d2h", "persist.pickle",
-                              "persist.frame", "persist.insert",
+                     _seconds(tracer, "persist.d2h", "persist.insert",
                               "train.barrier", "train.complete"))
             log.info("train device memory: %s", describe_device_memory())
-            # the blob and the models in hand go back to the allocator
-            # here, not unseen as the frame unwinds: gigabytes of host
-            # pages for a block stack, with the chip idle
-            with tracer.span("train.release", bytes=len(blob)):
-                del blob, models
+            # the models in hand and their host arrays go back to the
+            # allocator here, not unseen as the frame unwinds
+            with tracer.span("train.release", bytes=on_host.min_bytes):
+                del on_host, models
             return instance_id
     except TrainingPreempted as preempted:
         try:

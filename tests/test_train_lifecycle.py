@@ -510,19 +510,27 @@ def test_resume_uses_recorded_checkpoint_dir(tmp_path):
     assert not os.path.isdir(wrong)
 
 
+@pytest.mark.parametrize("piece", [1 << 30, 1 << 20, 7])
 @pytest.mark.parametrize("payload", [b"", b"x", bytes(range(256)) * 9000])
-def test_frame_in_place_is_frame(payload):
-    """The header filled in behind the payload's back gives the bytes
-    `frame` gives (a payload over the checksum's chunk among them)."""
+def test_stream_frame_is_frame(payload, piece):
+    """A payload handed over in pieces, the header filled in behind them,
+    gives the bytes `frame` gives: whole, in pieces of 1 MiB, and in
+    pieces of 7 bytes that no boundary of anything falls on."""
     import io
 
-    from pio_tpu.utils.durable import HEADER_BYTES, frame_in_place
+    from pio_tpu.utils.durable import HEADER_BYTES, stream_frame
 
-    buf = io.BytesIO()
-    buf.write(bytes(HEADER_BYTES))
-    buf.write(payload)
-    blob = frame_in_place(buf)
+    def produce(sink):
+        for at in range(0, len(payload), piece):
+            sink.write(payload[at:at + piece])
+
+    buf = io.BytesIO(b"before")
+    buf.seek(0, io.SEEK_END)
+    sink = stream_frame(buf, produce)
+    blob = buf.getvalue()[len(b"before"):]
     assert blob == frame(payload) and unframe(blob) == payload
+    assert sink.length == len(payload)
+    assert buf.tell() == len(b"before") + HEADER_BYTES + len(payload)
 
 
 def test_durable_write_no_double_frame(tmp_path):

@@ -21,9 +21,12 @@ from pio_tpu.workflow.train import load_models, run_train
 from tests._tiny_train import memory_storage, tiny_engine, tiny_params
 
 UNDER_ROOT = ["train.setup", "train.read", "train.prepare",
-              "train.algorithms", "persist.d2h", "persist.pickle",
-              "persist.frame", "persist.insert", "train.barrier",
-              "train.complete", "train.release"]
+              "train.algorithms", "persist.d2h", "persist.insert",
+              "train.barrier", "train.complete", "train.release"]
+# the models are pickled and checksummed on their way into the store (PR
+# 39): the two spans lie under the store's own, rows of seconds the sink
+# added up, not blocks that were entered
+UNDER_INSERT = ["persist.pickle", "persist.frame"]
 # under train.algorithms, in order, by path; then the labels a span
 # must carry (the counts at its boundary)
 PATHS = {
@@ -88,9 +91,12 @@ def test_one_tree_with_every_span_of_the_path(path, caplog):
             if r["parent"] == "train.setup"] == ["train.devices"]
     assert [r["name"] for r in rows
             if r["parent"] == "train.algorithms"] == PATHS[path]
+    assert [r["name"] for r in rows
+            if r["parent"] == "persist.insert"] == UNDER_INSERT
     # a DataSource that reads no events: no `events.*` span; a job after
     # the process's first: no `compile.*` span
-    assert len(rows) == 2 + len(UNDER_ROOT) + len(PATHS[path])
+    assert len(rows) == (2 + len(UNDER_ROOT) + len(UNDER_INSERT)
+                         + len(PATHS[path]))
     assert not [n for n in names if n.startswith("events.")]
     for row in rows:
         assert LABELS.get(row["name"], set()) <= set(row["labels"]), row
@@ -101,6 +107,19 @@ def test_one_tree_with_every_span_of_the_path(path, caplog):
     assert by_name["train.read"]["labels"] == {
         "ratings": "5000", "users": "300", "items": "200"}
     assert by_name["persist.pickle"]["labels"]["ids"] == "500"
+    # the frame is the pickle behind its 17-byte header, and the insert
+    # says the frame's length once it is known
+    framed = int(by_name["persist.pickle"]["labels"]["bytes"]) + 17
+    assert by_name["persist.frame"]["labels"]["bytes"] == str(framed)
+    assert by_name["persist.insert"]["labels"]["bytes"] == str(framed)
+    assert (int(by_name["train.release"]["labels"]["bytes"])
+            == int(by_name["persist.d2h"]["labels"]["bytes"]) > 0)
+    # the sink's two sums lie end to end inside the insert
+    insert, pickled, crc = (by_name[n] for n in (
+        "persist.insert", "persist.pickle", "persist.frame"))
+    assert insert["start_s"] <= pickled["start_s"] <= crc["start_s"]
+    assert (crc["start_s"] + crc["duration_s"]
+            <= insert["start_s"] + insert["duration_s"] + 1e-6)
     assert int(by_name["als.transfer"]["labels"]["bytes"]) > 0
     if path == "eight-devices":
         # what crosses from the host is the six COO stacks and nothing
@@ -132,7 +151,9 @@ def test_failing_persist_leaves_the_span_in_error(path, caplog):
     assert failed["status"] == "error" and "ChaosError" in failed["error"]
     assert failed["labels"]["chaos"] == "train.persist"
     assert rows["train"]["status"] == "error"
-    assert "status" not in rows["persist.frame"]
+    assert "status" not in rows["persist.d2h"]
+    # the store was never reached: nothing was pickled
+    assert "persist.pickle" not in rows and "persist.frame" not in rows
     # the barrier is reached on both outcomes; COMPLETED is not
     assert "train.barrier" in rows and "train.complete" not in rows
 
@@ -161,17 +182,35 @@ def test_a_sqlite_store_says_which_way_the_blob_went(users, way, tmp_path,
     under = [r for r in rows if r["parent"] == "persist.insert"]
     blob = insert["labels"]["bytes"]
     assert (int(blob) >= 1 << 20) == (way == "file")
+    sink = ["persist.pickle", "persist.frame"]
     if way == "file":
+        # the models write themselves into the file: the sink's two
+        # sums are the file span's children
         assert [r["name"] for r in under] == ["models.file", "models.row"]
+        assert [r["name"] for r in rows
+                if r["parent"] == "models.file"] == sink
         assert under[0]["labels"] == {"bytes": blob}
         assert under[1]["labels"] == {"inline_bytes": "0"}
         # the file is whole and synced before its row is written
         assert (under[0]["start_s"] + under[0]["duration_s"]
                 <= under[1]["start_s"] + 1e-6)
     else:
-        assert [r["name"] for r in under] == ["models.row"]
-        assert under[0]["labels"] == {"inline_bytes": blob}
+        # under the line the blob is made in memory, then it is a row
+        assert [r["name"] for r in under] == sink + ["models.row"]
+        assert under[2]["labels"] == {"inline_bytes": blob}
     assert all("status" not in r for r in under)
+    # each of the six names once, and the two metrics the benchmark reads
+    # from them come to the insert and the d2h, whatever the nesting
+    from benchmark.readers.span_self import job_seconds
+    for name in ("persist.d2h", "persist.insert", *sink, "models.row"):
+        assert [r["name"] for r in rows].count(name) == 1, name
+    d2h = next(r for r in rows if r["name"] == "persist.d2h")
+    serialize = job_seconds(rows, ["persist.d2h", *sink], total=False)
+    store = job_seconds(
+        rows, ["persist.insert", "models.file", "models.row"], total=False)
+    assert serialize + store == pytest.approx(
+        d2h["duration_s"] + insert["duration_s"], abs=1e-5)
+    assert serialize >= d2h["duration_s"] and store > 0.0
     # the benchmark's "persist" still holds the whole of it
     assert sum(r["duration_s"] for r in under) <= insert["duration_s"]
     [model] = load_models(storage, engine, params, instance, ctx)
