@@ -41,9 +41,11 @@ name what the stack does not compute):
  * precision: float32 master weights and Adam state, bfloat16 operands,
    float32 accumulation, float32 residual stream, norms and loss;
  * memory: every layer's two halves (the module's too) are recomputed in
-   the backward pass (`jax.checkpoint` at their boundaries); the loss is
-   computed over chunks of tokens so the (tokens, vocabulary) logits
-   never exist whole.
+   the backward pass (`jax.checkpoint` at their boundaries), but for the
+   attention forward kernel: its output o and the rows' log-sum-exp,
+   (B, Hq, S) float32, cross the attention half's checkpoint, so the
+   kernel runs once a layer and step; the loss is computed over chunks
+   of tokens so the (tokens, vocabulary) logits never exist whole.
 
 One chip. Histories are whole (no PAD inside a row): packing and padding
 of short histories, the experts' exchange across chips and a cache for
@@ -62,8 +64,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.extend.core import Var
+from jax.extend.core.primitives import name_p, remat_p
 
 from pio_tpu.ops.attention import (
+    KEPT_RESIDUALS,
     band_blocks,
     band_pairs,
     banded_flash_attention,
@@ -494,9 +499,18 @@ def _dense_half(lp, h, *, spec: BlockSpec):
 
 def _layer(lp, x, table, *, spec: BlockSpec, kind: str, dense: bool):
     """One layer, each half recomputed in the backward pass, the second
-    a history at a time. -> (x', the router's counters or None)."""
-    x = jax.checkpoint(partial(_attention_half, spec=spec, kind=kind))(
-        lp, x, *table)
+    a history at a time. -> (x', the router's counters or None).
+
+    The attention half keeps, beside its input x, what the attention
+    forward kernel alone can make (ops/attention.py KEPT_RESIDUALS): o
+    (B, Hq, S, D) in COMPUTE and the rows' log-sum-exp (B, Hq, S) float32,
+    B * Hq * S * (2 D + 4) bytes a layer, so the backward pass does not
+    run the kernel again; the norm, the projections and the rotations are
+    recomputed."""
+    x = jax.checkpoint(
+        partial(_attention_half, spec=spec, kind=kind),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *KEPT_RESIDUALS))(lp, x, *table)
     if dense:
         return jax.lax.map(
             jax.checkpoint(partial(_dense_half, lp, spec=spec)), x), None
@@ -702,6 +716,55 @@ def band_counters(spec: BlockSpec, seq_len: int) -> dict:
                 padded, block, block, window)}
 
 
+def attention_counters(jaxpr) -> dict:
+    """What a traced step program holds of the attention forward, read
+    off its equations (through every jaxpr they hold): the calls of the
+    forward kernel, and the bytes of the arrays named KEPT_RESIDUALS that
+    a checkpoint's backward pass takes in, which are the ones kept."""
+    found = {"attn_fwd_kernels": 0, "attn_residual_bytes": 0}
+
+    def walk(jaxpr, named):
+        def is_named(v):
+            return isinstance(v, Var) and v in named
+
+        for eqn in jaxpr.eqns:
+            if eqn.primitive is name_p:
+                if eqn.params["name"] in KEPT_RESIDUALS:
+                    named.add(eqn.outvars[0])
+            elif eqn.primitive is jax.lax.reduce_precision_p:
+                # jax passes a kept array that the forward pass reads too
+                # through one that changes nothing
+                if is_named(eqn.invars[0]):
+                    named.add(eqn.outvars[0])
+            elif eqn.primitive is remat_p:
+                found["attn_residual_bytes"] += sum(
+                    v.aval.size * v.aval.dtype.itemsize
+                    for v in eqn.invars if is_named(v))
+            elif (eqn.primitive.name == "pallas_call"
+                  and eqn.params["name"] == "flash_attention_fwd"):
+                found["attn_fwd_kernels"] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, set())
+
+    walk(jaxpr, set())
+    return found
+
+
+@lru_cache(maxsize=None)
+def step_attention_counters(spec: BlockSpec, learning_rate: float,
+                            batch_shape: tuple) -> dict:
+    """`attention_counters` of the train step's program for batches of
+    that shape, read once a process: the step is traced here, on shapes
+    alone, and its first call then finds the trace made."""
+    optimizer, step = make_train_step(spec, learning_rate)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32), param_shapes(spec),
+        is_leaf=lambda x: isinstance(x, tuple))
+    traced = step.trace(params, jax.eval_shape(optimizer.init, params),
+                        jax.ShapeDtypeStruct(batch_shape, jnp.int32))
+    return attention_counters(traced.jaxpr.jaxpr)
+
+
 def train_lm(seqs: np.ndarray, p, lifecycle=None):
     """Train the block stack on (N, `history_ids`) whole histories for
     p.steps steps of p.batch_size histories. -> (params on the host,
@@ -711,8 +774,9 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
     step's histories on the device, `seq.init` makes the step and the
     initial parameters and optimizer state (a process's first job traces
     and loads the initialisers here), `seq.dispatch` enqueues the steps
-    (and the first job gets the step's program ready), `seq.wait` waits
-    for the last one and carries the job's counters, `seq.d2h` brings the
+    (the first job gets the step's program ready, and reads
+    `step_attention_counters` off its trace), `seq.wait` waits for the
+    last one and carries the job's counters, `seq.d2h` brings the
     parameters to the host."""
     spec = BlockSpec.parse(p.block_spec)
     if jax.process_count() > 1:
@@ -739,6 +803,8 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
         opt_state = optimizer.init(params)
     losses, counters = [], []
     with tracing.span("seq.dispatch", steps=steps):
+        program = step_attention_counters(spec, p.learning_rate,
+                                          batches[0].shape)
         for s in range(steps):
             params, opt_state, loss, aux = step(
                 params, opt_state, batches[s])
@@ -780,7 +846,7 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
             # only every choice of every token held fills it
             expert_tiles_used_share=repr(tiles_used_share(
                 counts, held, positions)),
-            dropped_tokens=dropped)
+            dropped_tokens=dropped, **program)
         if "sliding_attention" in spec.layer_types:
             sp.update(**band_counters(spec, positions))
         if spec.mtp_layers:

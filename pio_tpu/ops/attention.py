@@ -32,6 +32,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
@@ -493,6 +494,9 @@ def ulysses_attention(
 
 _FIRST, _LAST, _EDGE = 1, 2, 4
 _LANES = 128
+# `checkpoint_name`s of the forward's two residuals that only the kernel
+# can make: o (B, Hq, S, D) and the rows' log-sum-exp (B, Hq, S) float32
+KEPT_RESIDUALS = ("banded_attention_o", "banded_attention_lse")
 
 
 def band_pairs(seq_len: int, block_q: int, block_k: int,
@@ -745,6 +749,15 @@ def _banded_fwd(q, k, v, window, scale, block_q, block_k, interpret):
         [pltpu.VMEM((block_q, 1), jnp.float32),
          pltpu.VMEM((block_q, 1), jnp.float32),
          pltpu.VMEM((block_q, d), jnp.float32)], **dims)
+    # what only the kernel can make of a row, under the names a
+    # `jax.checkpoint` policy keeps them by: o as the kernel wrote it, and
+    # column 0 of lse's 128 equal lanes. The barrier ties the slice to o,
+    # which the forward pass reads next: alone, XLA schedules the slice
+    # where the backward pass wants it and keeps the 128-lane array alive
+    # until then in the column's place (PERF.md, PR 40)
+    o, lse = jax.lax.optimization_barrier((o, lse[..., 0]))
+    o = checkpoint_name(o, KEPT_RESIDUALS[0])
+    lse = checkpoint_name(lse, KEPT_RESIDUALS[1])
     return o[:, :, :s], (qp, kp, vp, o, lse)
 
 
@@ -759,6 +772,8 @@ def _banded_bwd(window, scale, block_q, block_k, interpret, res, g):
     dims, pad = _band_setup(qp.shape, kp.shape[1], s, block_q, block_k,
                             interpret)
     do = _pad_seq(g.astype(qp.dtype), pad)
+    # the backward kernels' "lse" blocks are 128 lanes wide
+    lse = jnp.broadcast_to(lse[..., None], (*lse.shape, _LANES))
     args = (qp, kp, vp, o, do, lse)
     kinds = ("q", "k", "k", "q", "q", "lse")
     dq, = _band_call(

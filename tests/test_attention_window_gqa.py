@@ -6,7 +6,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_sequence_lm import _small as small_blocks
 
+from pio_tpu.models import seq_blocks
 from pio_tpu.ops.attention import (
     band_blocks,
     band_pairs,
@@ -116,3 +118,142 @@ def test_table_visits_exactly_the_band(s, bq, bk, window):
         assert listed == pairs
     if s == 8192 and bq == bk == 512:
         assert len(qi) == (16 * 17 // 2 if window is None else 1 + 2 + 14 * 3)
+
+
+# -- the forward's residuals, bare and across the block stack's checkpoint ---
+# (models/seq_blocks.py `_layer`; tests/test_latent_attention.py runs the
+# same cases on a latent specification with a prediction module)
+
+GQA_CFG = {
+    "hidden_size": 32, "num_hidden_layers": 2,
+    "layer_types": ["sliding_attention", "full_attention"],
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 8,
+    "sliding_window": 12,
+    "rope_parameters": {
+        "full_attention": {"rope_type": "default", "rope_theta": 10000},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000}},
+    "rms_norm_eps": 1e-6, "moe_intermediate_size": 16, "num_experts": 4,
+    "num_experts_routed": 8, "experts_held": [2, 6],
+    "num_experts_per_tok": 3, "norm_topk_prob": True, "vocab_size": 50,
+    "initializer_range": 0.3,
+}
+
+
+def stack_case(cfg, seed=3):
+    """(spec, params, tokens, the gradient function of the stack's loss)
+    for 40 trained positions of two histories."""
+    spec = seq_blocks.BlockSpec.parse(cfg)
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        1, 50, (2, seq_blocks.history_ids(spec, 40))), jnp.int32)
+    grad = jax.grad(
+        lambda p: seq_blocks.loss_and_counters(p, tokens, spec)[0])
+    return spec, seq_blocks.init_params(spec, seed), tokens, grad
+
+
+def holds_one_forward_kernel_a_layer(cfg, layers):
+    """The gradient's jaxpr calls `flash_attention_fwd` once an attention
+    layer (twice before the residuals crossed the checkpoint), and keeps
+    o and a (B, Hq, S) lse of each, 48 padded positions of 4 heads."""
+    spec, params, _, grad = stack_case(cfg)
+    jaxpr = jax.make_jaxpr(grad)(params)
+    assert str(jaxpr).count("name=flash_attention_fwd") == layers
+    assert str(jaxpr).count("name=flash_attention_dq") == layers
+    assert seq_blocks.attention_counters(jaxpr.jaxpr) == {
+        "attn_fwd_kernels": layers,
+        "attn_residual_bytes": layers * 2 * 4 * 48 * (
+            spec.head_dim * 4 + 4)}
+
+
+# jax.checkpoint as the block stack's halves could have called it, and
+# how far the gradients may then lie (norm of the difference over the norm)
+REAL_CHECKPOINT = jax.checkpoint
+OTHER_CHECKPOINTS = {
+    # everything but the half's input recomputed, the forward kernel too:
+    # the same operations on the same operands, so the same bits
+    "default_policy": (lambda f=None, **kw: REAL_CHECKPOINT(f), 0.0),
+    # no checkpoint at all around the attention half: float32 rounding,
+    # since XLA compiles (and fuses) a checkpointed half as one program
+    # and a bare one runs an operation at a time
+    "attention_half_bare": (lambda f=None, **kw: (
+        f if "policy" in kw else REAL_CHECKPOINT(f)), 1e-5),
+}
+
+
+def gradients_equal(mp, cfg, other):
+    _, params, _, grad = stack_case(cfg)
+    got = grad(params)
+    checkpoint, limit = OTHER_CHECKPOINTS[other]
+    mp.setattr(jax, "checkpoint", checkpoint)
+    want = grad(params)
+    flat = jax.tree_util.tree_leaves_with_path(want)
+    assert len(flat) > 20
+    for (path, w), g in zip(flat, jax.tree_util.tree_leaves(got)):
+        w, g = np.asarray(w, np.float64), np.asarray(g, np.float64)
+        if limit == 0.0:
+            assert np.array_equal(g, w), path
+        elif w.any():                  # a router bias takes no gradient
+            err = np.linalg.norm(g - w) / np.linalg.norm(w)
+            assert err < limit, (path, err)
+
+
+def half_keeps_x_o_and_a_compact_lse(cfg, kind="full_attention"):
+    """What one layer keeps for its backward pass: of the attention half
+    its input x, o and lse (B, Hq, S) beside the parameters; no second
+    array of o's shape (q), none of a key-value head's, nothing 128 wide."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    spec, params, _, _ = stack_case(cfg)
+    lp = params["mtp"]["layer"] if spec.mtp_layers else params["layers"][-1]
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 40, spec.hidden_size))
+    table = seq_blocks.rope_tables(spec, 40)[kind]
+    kept = saved_residuals(
+        lambda lp, x: seq_blocks._layer(
+            lp, x, table, spec=spec, kind=kind, dense=False)[0], lp, x)
+    half = [(a.shape, said) for a, said in kept
+            if "_attention_half" in said or said == "from the argument x"]
+    hq, dh = spec.num_attention_heads, spec.head_dim
+    assert sorted(shape for shape, _ in half) == sorted(
+        [(2, 40, spec.hidden_size),             # x
+         (2, hq, 48, dh),                       # o, as the kernel wrote it
+         (2, hq, 48),                           # lse, no lane padding
+         (2, 40, spec.hidden_size)])            # x + out, the next half's
+    assert any("banded_attention_lse" in said for _, said in half)
+    assert not [a for a, _ in kept if a.shape[-1:] == (128,)]
+
+
+def test_the_gradient_holds_one_forward_kernel_a_layer(monkeypatch):
+    small_blocks(monkeypatch)   # float32 operands, blocks of 16
+    holds_one_forward_kernel_a_layer(GQA_CFG, 2)
+
+
+@pytest.mark.parametrize("other", sorted(OTHER_CHECKPOINTS))
+def test_kept_residuals_change_no_gradient(monkeypatch, other):
+    small_blocks(monkeypatch)
+    gradients_equal(monkeypatch, GQA_CFG, other)
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_the_attention_half_keeps_x_o_and_a_compact_lse(monkeypatch, kind):
+    small_blocks(monkeypatch)
+    half_keeps_x_o_and_a_compact_lse(GQA_CFG, kind)
+
+
+@pytest.mark.parametrize("s,window,bq,bk,hq,hkv", SHAPES[1:4])
+def test_bare_gradient_under_jit_reads_the_compact_residual(
+        s, window, bq, bk, hq, hkv):
+    """As the benchmark's window probe calls it: jitted `jax.vjp` of the
+    kernel alone. The residual is (B, Hq, padded S), and the backward
+    kernels that widen it give the reference's gradients."""
+    from pio_tpu.ops.attention import _banded_fwd
+
+    q, k, v, ct = _inputs(s, hq, hkv, seed=6)
+    lse = _banded_fwd(q, k, v, window, None, bq, bk, None)[1][-1]
+    assert lse.shape == (2, hq, -(-s // max(bq, bk)) * max(bq, bk))
+    assert lse.dtype == jnp.float32
+    got = jax.jit(lambda q, k, v, ct: jax.vjp(
+        lambda q, k, v: banded_flash_attention(q, k, v, window, None, bq, bk),
+        q, k, v)[1](ct))(q, k, v, ct)
+    want = jax.vjp(lambda q, k, v: banded_attention_reference(
+        q, k, v, window), q, k, v)[1](ct)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, atol=5e-5, rtol=5e-5)

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import test_attention_window_gqa as attention_cases
 
 from benchmark.reference import latent_moe_lm as reference
 from pio_tpu.models import seq_blocks
@@ -264,3 +265,23 @@ def test_the_kernel_at_head_dim_256_group_1(wide, which):
 def test_what_the_stack_does_not_compute_is_refused_by_name(change, match):
     with pytest.raises(ValueError, match=match):
         seq_blocks.BlockSpec.parse({**CFG, **change})
+
+
+# -- the forward's residuals across the halves' checkpoint -------------------
+# (the cases of tests/test_attention_window_gqa.py, on latent attention with
+# a dense layer and a prediction module: three attention layers)
+
+MODULE_CFG = {**CFG, "num_hidden_layers": 2}
+
+
+def test_the_gradient_holds_one_forward_kernel_a_layer():
+    attention_cases.holds_one_forward_kernel_a_layer(MODULE_CFG, 3)
+
+
+@pytest.mark.parametrize("other", sorted(attention_cases.OTHER_CHECKPOINTS))
+def test_kept_residuals_change_no_gradient(monkeypatch, other):
+    attention_cases.gradients_equal(monkeypatch, MODULE_CFG, other)
+
+
+def test_the_modules_attention_half_keeps_x_o_and_a_compact_lse():
+    attention_cases.half_keeps_x_o_and_a_compact_lse(MODULE_CFG)

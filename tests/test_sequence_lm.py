@@ -304,7 +304,8 @@ def test_profile_joins_the_stacks_scopes(op_name, scope):
 
 # -- what PR 33 added to the stack leaves the older configuration alone ------
 # (the two hashes below were commit 2ef95e1's until PR 34 changed the expert
-# layer's moves on purpose; they pin PR 34's text the same way)
+# layer's moves on purpose, and PR 34's until PR 40 let the attention
+# forward's o and lse cross the checkpoint; they pin PR 40's text the same way)
 
 def _step_lowered(cfg, learning_rate, batch):
     spec = seq_blocks.BlockSpec.parse(cfg)
@@ -326,19 +327,19 @@ def _sha(text):
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-def test_the_window_and_full_stacks_step_program_is_pr_34s():
+def test_the_window_and_full_stacks_step_program_is_pr_40s():
     """Latent attention, the dense layer, the shared expert, the sigmoid
     router and the prediction module are chosen by the specification: a
     specification without them lowers to one program text whatever a
-    later specification's keys add (the hash is of PR 34's text, at this
+    later specification's keys add (the hash is of PR 40's text, at this
     file's small blocks)."""
     assert _sha(_step_text(CFG, 0.0625, (2, 41))) == (
-        "0801c55cf00bfed04844d9364a47b1ca8b3b2cf037499fc18a625b8ae9841b47")
+        "e138f1a3bf6bd0307a4246057c938fd69557b10fd5df09c49e577d167006dd40")
 
 
-def test_mellum2_12b_ep4s_step_program_is_pr_34s(monkeypatch):
+def test_mellum2_12b_ep4s_step_program_is_pr_40s(monkeypatch):
     """The benchmark's configuration at its timed shapes and the
-    program's own blocks: PR 34's text, by hash."""
+    program's own blocks: PR 40's text, by hash."""
     import json
     import os
 
@@ -350,7 +351,7 @@ def test_mellum2_12b_ep4s_step_program_is_pr_34s(monkeypatch):
     with open(path) as f:
         cfg = es.block_spec_of(json.load(f))
     assert _sha(_step_text(cfg, 1e-4, (2, 8193))) == (
-        "ad48f6f8e4fa6fb569da11540b529a5d10f0730709eb1b0c057254d36e5a0c96")
+        "c62b0b8e71aafd910921f61c91a3c26a2d813c0a62a3a8d5b378886c2b28c562")
 
 
 def test_the_repeated_scope_rule_moves_no_path_of_the_older_stack():
@@ -394,6 +395,44 @@ def test_the_latent_stacks_step_program_does_not_hold_the_step_count():
                            learning_rate=0.02, seed=3, block_spec=LATENT_CFG)
         seq_blocks.train_lm(_data(length=42).seqs, p)
     assert step._cache_size() == 1
+
+
+@pytest.mark.parametrize("which,head_dim", [("window_full", 8),
+                                            ("latent_module", 16)])
+def test_a_jobs_record_says_what_the_step_program_holds(
+        monkeypatch, which, head_dim):
+    """`attn_fwd_kernels` and `attn_residual_bytes` on `seq.wait`, read
+    off the traced step: one forward kernel an attention layer (4 stack
+    layers; 3 and the module's), o (float32 at this file's widths) and a
+    (B, Hq, S) lse of each kept, 48 padded positions of 4 heads. Reading
+    them traces nothing: two jobs, one trace of the loss."""
+    import contextlib
+
+    cfg = {"window_full": CFG, "latent_module": LATENT_CFG}[which]
+    waits, traced = [], []
+
+    @contextlib.contextmanager
+    def span(name, **labels):
+        labels = dict(labels)
+        if name == "seq.wait":
+            waits.append(labels)
+        yield labels
+
+    real = seq_blocks.loss_and_counters
+    monkeypatch.setattr(seq_blocks.tracing, "span", span)
+    monkeypatch.setattr(seq_blocks, "loss_and_counters",
+                        lambda *a: traced.append(1) or real(*a))
+    spec = seq_blocks.BlockSpec.parse(cfg)
+    p = SequenceParams(max_len=41, batch_size=2, steps=2,
+                       learning_rate=0.0271, seed=3, block_spec=cfg)
+    for _ in range(2):
+        seq_blocks.train_lm(
+            _data(length=seq_blocks.history_ids(spec, 40)).seqs, p)
+    assert len(traced) == 1
+    for labels in waits:
+        assert labels["attn_fwd_kernels"] == 4
+        assert labels["attn_residual_bytes"] == 4 * 2 * 4 * 48 * (
+            head_dim * 4 + 4)
 
 
 def test_the_engine_trains_persists_and_serves_the_latent_stack(monkeypatch):
