@@ -10,6 +10,22 @@ stack in place of the SASRec-style encoder of models/sequence.py:
   x_0 = E[ids];  h = x + Attn_l(RMSNorm(x));  x' = h + FFN_l(RMSNorm(h))
   logits = RMSNorm(x_L) W_head^T;  loss = mean next-item cross-entropy
 
+With `total_ut_steps` (T) the stack is a looped one: the L layers run T
+times over one set of parameters, every pass ends in an exit, and a
+learned gate gives each token a distribution over the T exits:
+
+  x = E[ids]
+  for t = 1..T, the same parameters every t:
+      for l = 1..L:
+          h = x + N1b_l(Attn_l(N1a_l(x)))
+          x = h + N2b_l(SwiGLU_l(N2a_l(h)))
+      y_t = RMSNorm_final(x);  x = y_t         (the normed state goes on)
+      CE_t[i] = -log softmax(y_t[i] W_head^T)[target_i]
+      lam_t[i] = sigmoid(y_t[i] . w_gate + b_gate)
+  p_1 = lam_1;  p_t = lam_t prod_{j<t}(1 - lam_j) (1 < t < T);
+  p_T = prod_{j<T}(1 - lam_j)                  (the rest of the mass)
+  loss = mean_i [ sum_t p_t[i] CE_t[i] - EXIT_ENTROPY_WEIGHT * H(p[i]) ]
+
 What a specification chooses, by its keys (`BlockSpec.parse` refuses by
 name what the stack does not compute):
 
@@ -21,8 +37,10 @@ name what the stack does not compute):
    positions on the `qk_rope_head_dim` dimensions only, one rope key
    shared by all heads). Both end in ops/attention.py
    `banded_flash_attention` (Pallas forward and backward);
- * the second half of a layer: a dense SwiGLU of `intermediate_size` in
-   the first `first_k_dense_replace` layers, else ops/moe.py
+ * the second half of a layer: a dense SwiGLU of `intermediate_size`, in
+   every layer of a specification without expert keys (no `num_experts`
+   / `n_routed_experts`: no router, no held experts) and in the first
+   `first_k_dense_replace` layers of one with them; else ops/moe.py
    `held_moe_ffn` (dropless top-k, the held experts' part by a grouped
    matrix product), one history at a time, with `n_shared_experts`
    shared experts (a dense SwiGLU every token takes) beside it;
@@ -38,19 +56,32 @@ name what the stack does not compute):
    of the kind above, its own final norm, the shared embedding and head;
    it predicts t_i+2, and loss = CE_main + MTP_LOSS_WEIGHT * CE_mtp. A
    history then has one more id (S + 2 for S trained positions);
+ * the loop (`total_ut_steps`: T, the equations above): a Python loop
+   over T whose body is the L layers, so the step's program holds the
+   stack T times (the faster of the two on the chip: `looped_states`)
+   and the weights' gradients add up over the passes; a layer then has
+   four norms (`norm1_post` / `norm2_post`
+   on each half's output before the residual add), the final norm is
+   inside the loop, and the loss is the one over the T exits: a head
+   loss a pass and token, weighted by the exit gate's distribution
+   (`exit_gate`, `exit_bias`: a Linear(d, 1) on the normed state), less
+   the entropy term. Only the (T, B, S) losses and gate logits leave the
+   loop. Serving runs the T passes and reads exit T
+   (`early_exit_threshold` 1: no early exit; a lower one is refused);
  * precision: float32 master weights and Adam state, bfloat16 operands,
    float32 accumulation, float32 residual stream, norms and loss;
- * memory: every layer's two halves (the module's too) are recomputed in
-   the backward pass (`jax.checkpoint` at their boundaries), but for the
-   attention forward kernel: its output o and the rows' log-sum-exp,
+ * memory: every layer's two halves (the module's too; a looped stack's
+   layer as one) are recomputed in the backward pass (`jax.checkpoint`
+   at their boundaries), but for the attention forward kernel: its output o and the rows' log-sum-exp,
    (B, Hq, S) float32, cross the attention half's checkpoint, so the
    kernel runs once a layer and step; the loss is computed over chunks
    of tokens so the (tokens, vocabulary) logits never exist whole.
 
 One chip. Histories are whole (no PAD inside a row): packing and padding
-of short histories, the experts' exchange across chips and a cache for
-serving (for latent attention: the compressed key-value cache) are not
-here (ROADMAP R1, R4).
+of short histories, the experts' exchange across chips, a cache for
+serving (for latent attention: the compressed key-value cache), early
+exit at serve time and the exit gate's second training stage are not
+here (ROADMAP R1, R4, R15).
 """
 
 from __future__ import annotations
@@ -86,6 +117,8 @@ ADAM_B1 = 0.9              # after one step from zero, mu = (1 - b1) * gradient
 # both); a specification that states another value is refused
 ROUTER_BIAS_RATE = 0.001   # a step's move of a router's bias
 MTP_LOSS_WEIGHT = 0.3      # the prediction module's loss beside the main one
+# the looped family's report, first training stage (config.json has no key)
+EXIT_ENTROPY_WEIGHT = 0.1  # beta: the exit distribution's entropy in the loss
 
 
 # keys of architectures this stack has no code for: refused, not ignored
@@ -127,6 +160,11 @@ class BlockSpec:
     routed_scaling_factor: float = 1.0
     # the multi-token-prediction module
     mtp_layers: int = 0
+    # the looped stack (`total_ut_steps`): passes over the one set of
+    # layers, each with its exit; 0 where the stack is not a looped one.
+    # A looped layer has the two post-norms, and the loss is the one
+    # over the exits, with its gate
+    loop_steps: int = 0
 
     @classmethod
     def parse(cls, spec: str | dict) -> "BlockSpec":
@@ -137,6 +175,10 @@ class BlockSpec:
             "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
         scoring = ("sigmoid" if c.get("topk_method") == "noaux_tc"
                    else "softmax")
+        # no expert keys at all: every layer a dense SwiGLU
+        experts_key = next((k for k in ("num_experts", "n_routed_experts")
+                            if k in c), None)
+        looped = "total_ut_steps" in c
         wrong = {
             "hidden_act": c.get("hidden_act", "silu") != "silu",
             "attention_bias": bool(c.get("attention_bias", False)),
@@ -153,8 +195,6 @@ class BlockSpec:
             ) != ROUTER_BIAS_RATE,
             "mtp_loss_weight": c.get(
                 "mtp_loss_weight", MTP_LOSS_WEIGHT) != MTP_LOSS_WEIGHT,
-            "num_nextn_predict_layers":
-                c.get("num_nextn_predict_layers", 0) > 1,
             "partial_rotary_factor": c.get("partial_rotary_factor", 1) != 1,
             # latent attention as computed here: a low-rank query path,
             # every head its own keys and values, q.k as wide as p.v
@@ -166,6 +206,21 @@ class BlockSpec:
             "rope_scaling": latent and c.get("rope_scaling") is not None,
             "first_k_dense_replace":
                 c.get("first_k_dense_replace", 0) > n_layers,
+            "intermediate_size": experts_key is None
+                and not c.get("intermediate_size"),
+            # the looped stack as computed here: dense layers of
+            # grouped-query heads, every pass to its end, no module
+            "total_ut_steps": looped and c["total_ut_steps"] < 1,
+            "early_exit_threshold": c.get("early_exit_threshold", 1) < 1,
+            "exit_entropy_weight": c.get(
+                "exit_entropy_weight", EXIT_ENTROPY_WEIGHT
+            ) != EXIT_ENTROPY_WEIGHT,
+            experts_key or "num_experts": looped and experts_key is not None,
+            "kv_lora_rank": looped and latent,
+            # a prediction module's layer is an expert layer
+            "num_nextn_predict_layers": c.get(
+                "num_nextn_predict_layers", 0) > (
+                    0 if looped or experts_key is None else 1),
             **{k: True for k in _NOT_COMPUTED if c.get(k)},
         }
         if any(wrong.values()):
@@ -179,8 +234,7 @@ class BlockSpec:
             raise ValueError(f"layer_types {kinds} for {n_layers} layers")
         if latent and "sliding_attention" in kinds:
             raise ValueError("layer_types: latent attention has no window")
-        n_held = c["num_experts"] if "num_experts" in c \
-            else c["n_routed_experts"]
+        n_held = c[experts_key] if experts_key else 0
         held = tuple(c.get("experts_held", (0, n_held)))
         if held[1] - held[0] != n_held:
             raise ValueError(
@@ -201,11 +255,12 @@ class BlockSpec:
                 (kind, tuple(sorted(ropes[kind].items())))
                 for kind in set(kinds))),
             rms_norm_eps=c["rms_norm_eps"],
-            moe_intermediate_size=c["moe_intermediate_size"],
+            moe_intermediate_size=c["moe_intermediate_size"] if n_held else 0,
             num_experts_routed=c.get("num_experts_routed", n_held),
             experts_held=held,
-            num_experts_per_tok=c["num_experts_per_tok"],
-            norm_topk_prob=c["norm_topk_prob"], vocab_size=c["vocab_size"],
+            num_experts_per_tok=c["num_experts_per_tok"] if n_held else 0,
+            norm_topk_prob=bool(n_held and c["norm_topk_prob"]),
+            vocab_size=c["vocab_size"],
             initializer_range=c.get("initializer_range", 0.02),
             embedding_initializer_range=c.get(
                 "embedding_initializer_range",
@@ -215,12 +270,14 @@ class BlockSpec:
             qk_nope_head_dim=nope if latent else 0,
             qk_rope_head_dim=rope_dim if latent else 0,
             v_head_dim=v_dim if latent else 0,
-            dense_layers=c.get("first_k_dense_replace", 0),
+            dense_layers=(c.get("first_k_dense_replace", 0) if n_held
+                          else n_layers),
             intermediate_size=c.get("intermediate_size", 0),
             n_shared_experts=c.get("n_shared_experts") or 0,
             scoring=scoring,
             routed_scaling_factor=float(c.get("routed_scaling_factor", 1.0)),
-            mtp_layers=c.get("num_nextn_predict_layers", 0))
+            mtp_layers=c.get("num_nextn_predict_layers", 0),
+            loop_steps=c.get("total_ut_steps", 0))
 
     @property
     def experts(self) -> HeldExperts:
@@ -312,6 +369,8 @@ def _layer_shapes(spec: BlockSpec, dense: bool) -> dict:
         hkv = spec.num_key_value_heads * spec.head_dim
         layer = {"wq": (d, hq), "wk": (d, hkv), "wv": (d, hkv), "wo": (hq, d)}
     layer.update({"norm1": (d,), "norm2": (d,)})
+    if spec.loop_steps:
+        layer.update({"norm1_post": (d,), "norm2_post": (d,)})
     if dense:
         i = spec.intermediate_size
         layer.update({"mlp_gate": (d, i), "mlp_up": (d, i),
@@ -340,6 +399,8 @@ def param_shapes(spec: BlockSpec) -> dict:
         shapes["mtp"] = {"enorm": (d,), "hnorm": (d,), "eh_proj": (2 * d, d),
                          "final_norm": (d,),
                          "layer": _layer_shapes(spec, False)}
+    if spec.loop_steps:
+        shapes.update({"exit_gate": (d, 1), "exit_bias": (1,)})
     return shapes
 
 
@@ -365,7 +426,8 @@ def _init_program(spec: BlockSpec):
     def make(key):
         keys = jax.random.split(key, len(leaves))
         return jax.tree_util.tree_unflatten(tree, [
-            jnp.zeros(s, jnp.float32) if path[-1].key == "router_bias" else
+            jnp.zeros(s, jnp.float32)
+            if path[-1].key in ("router_bias", "exit_bias") else
             jnp.ones(s, jnp.float32) if len(s) == 1 else
             scale(path) * jax.random.normal(k, s, jnp.float32)
             for (path, s), k in zip(leaves, keys)])
@@ -375,8 +437,9 @@ def _init_program(spec: BlockSpec):
 
 def init_params(spec: BlockSpec, seed: int) -> dict:
     """normal(0, initializer_range) matrices (the embedding's rows
-    normal(0, embedding_initializer_range)), unit norm gains and zero
-    router biases, float32, a pure function of (spec, seed)."""
+    normal(0, embedding_initializer_range)), unit norm gains, zero
+    router biases and a zero exit bias, float32, a pure function of
+    (spec, seed)."""
     return _init_program(spec)(jax.random.PRNGKey(seed))
 
 
@@ -415,6 +478,8 @@ def _attention_half(lp, x, cos, sin, *, spec: BlockSpec, kind: str):
         out = jnp.einsum("bhsk,hkd->bsd", o,
                          lp["wo"].astype(COMPUTE).reshape(hq, dh, d),
                          preferred_element_type=jnp.float32)
+        if spec.loop_steps:
+            out = rms_norm(out, lp["norm1_post"], spec.rms_norm_eps)
     return x + out
 
 
@@ -494,7 +559,10 @@ def _dense_half(lp, h, *, spec: BlockSpec):
     """One history (S, d) through a dense layer's SwiGLU."""
     with jax.named_scope("seq.mlp.dense"):
         y = rms_norm(h, lp["norm2"], spec.rms_norm_eps).astype(COMPUTE)
-        return h + _swiglu(y, lp["mlp_gate"], lp["mlp_up"], lp["mlp_down"])
+        out = _swiglu(y, lp["mlp_gate"], lp["mlp_up"], lp["mlp_down"])
+        if spec.loop_steps:
+            out = rms_norm(out, lp["norm2_post"], spec.rms_norm_eps)
+        return h + out
 
 
 def _layer(lp, x, table, *, spec: BlockSpec, kind: str, dense: bool):
@@ -506,11 +574,23 @@ def _layer(lp, x, table, *, spec: BlockSpec, kind: str, dense: bool):
     (B, Hq, S, D) in COMPUTE and the rows' log-sum-exp (B, Hq, S) float32,
     B * Hq * S * (2 D + 4) bytes a layer, so the backward pass does not
     run the kernel again; the norm, the projections and the rotations are
-    recomputed."""
-    x = jax.checkpoint(
-        partial(_attention_half, spec=spec, kind=kind),
-        policy=jax.checkpoint_policies.save_only_these_names(
-            *KEPT_RESIDUALS))(lp, x, *table)
+    recomputed.
+
+    A looped stack keeps a layer's residuals `loop_steps` times, so its
+    layer is one checkpoint over both halves: x, o and the log-sum-exp
+    are kept and the dense half's input is made again with the rest (the
+    same work once more as the two checkpoints recompute, B * S * d * 4
+    bytes a layer and pass fewer)."""
+    keep = jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS)
+    if spec.loop_steps:
+        def both(lp, x, cos, sin):
+            return jax.lax.map(
+                jax.checkpoint(partial(_dense_half, lp, spec=spec)),
+                _attention_half(lp, x, cos, sin, spec=spec, kind=kind))
+
+        return jax.checkpoint(both, policy=keep)(lp, x, *table), None
+    x = jax.checkpoint(partial(_attention_half, spec=spec, kind=kind),
+                       policy=keep)(lp, x, *table)
     if dense:
         return jax.lax.map(
             jax.checkpoint(partial(_dense_half, lp, spec=spec)), x), None
@@ -518,20 +598,67 @@ def _layer(lp, x, table, *, spec: BlockSpec, kind: str, dense: bool):
         jax.checkpoint(partial(_experts_half, lp, spec=spec)), x)
 
 
-def hidden_states(params, ids, spec: BlockSpec):
-    """ids (B, S) int32 -> (x_L (B, S, d) float32, counters of the
-    layers that route: `counts` (layers, B, held experts), `dropped`
-    (layers, B), with a router bias `counts_all` (layers, B, routed))."""
-    tables = rope_tables(spec, ids.shape[1])
-    with jax.named_scope("seq.embed"):
-        x = params["embed"][ids]
+def _stack(params, x, tables, spec: BlockSpec):
+    """x (B, S, d) through the L layers once -> (x', the counters of the
+    layers that route, one entry each)."""
     counters = []
     for n, (lp, kind) in enumerate(zip(params["layers"], spec.layer_types)):
         x, aux = _layer(lp, x, tables[kind], spec=spec, kind=kind,
                         dense=n < spec.dense_layers)
         if aux is not None:
             counters.append(aux)
+    return x, counters
+
+
+def hidden_states(params, ids, spec: BlockSpec):
+    """ids (B, S) int32 -> (x_L (B, S, d) float32, counters of the
+    layers that route: `counts` (layers, B, held experts), `dropped`
+    (layers, B), with a router bias `counts_all` (layers, B, routed);
+    {} where no layer routes). One pass: a looped stack goes through
+    `looped_states`."""
+    tables = rope_tables(spec, ids.shape[1])
+    with jax.named_scope("seq.embed"):
+        x = params["embed"][ids]
+    x, counters = _stack(params, x, tables, spec)
+    if not counters:
+        return x, {}
     return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *counters)
+
+
+def looped_states(params, ids, spec: BlockSpec, at_exit=None):
+    """The stack `loop_steps` times over its one set of parameters, the
+    final norm at every pass's end and its output the next pass's input:
+    ids (B, S) -> (y_T (B, S, d) float32, normed; what `at_exit(y_t)`
+    gave at every exit, stacked over the passes). A Python loop over the
+    passes: the step's program holds the stack `loop_steps` times (each
+    layer with its checkpoint, as `_layer` has it). The exit is
+    recomputed in the backward pass from the last layer's output, the
+    one array it keeps.
+
+    On the v5e at the published widths (T 4, L 4, 2 x 8,192 tokens) one
+    `jax.lax.scan` over the passes read 1.9-2.2 % slower a step on each
+    of three seeds (it stacks the kept x, o and log-sum-exp and adds the
+    head's gradient up pass by pass), at 1.04 GB less of peak memory and
+    a warm job 18.6 s shorter from the compile cache (PERF.md section 6,
+    PR 41)."""
+    tables = rope_tables(spec, ids.shape[1])
+    with jax.named_scope("seq.embed"):
+        x = params["embed"][ids]
+
+    @jax.checkpoint
+    def exit_of(x):
+        with jax.named_scope("seq.head_loss"):
+            y = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+        return y, None if at_exit is None else at_exit(y)
+
+    exits = []
+    with jax.named_scope("seq.loop"):
+        for _ in range(spec.loop_steps):
+            x, out = exit_of(_stack(params, x, tables, spec)[0])
+            exits.append(out)
+        if at_exit is None:
+            return x, None
+        return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *exits)
 
 
 def _mtp_join(mp, x, e, *, spec: BlockSpec):
@@ -556,39 +683,111 @@ def mtp_hidden_states(params, x, next_ids, spec: BlockSpec):
                   spec=spec, kind=kind, dense=False)
 
 
+def _token_chunks(xn, targets):
+    """(tokens, d) normed states and their targets -> (the states in
+    chunks of at most LOSS_CHUNK tokens (chunks, chunk, d), the targets
+    alike, the targets flat), padded to whole chunks with PAD targets,
+    which every loss masks."""
+    d = xn.shape[-1]
+    tgt = targets.reshape(-1)
+    chunk = min(LOSS_CHUNK, xn.shape[0])
+    pad = (-xn.shape[0]) % chunk
+    if pad:
+        xn = jnp.pad(xn, ((0, pad), (0, 0)))
+        tgt = jnp.pad(tgt, (0, pad))           # PAD targets: masked
+    return xn.reshape(-1, chunk, d), tgt.reshape(-1, chunk), tgt
+
+
+def _chunk_ce(head, x_c, t_c):
+    """One chunk's cross-entropies (chunk,), 0 at a PAD target. The head
+    is rounded inside the chunk: its gradient then adds up over the
+    chunks in float32, not in bfloat16."""
+    logits = jax.lax.dot_general(
+        x_c, head.astype(COMPUTE), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ce = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+        logits, t_c[:, None], axis=1)[:, 0]
+    return jnp.where(t_c != PAD, ce, 0.0)
+
+
 def head_loss(params, x, targets, spec: BlockSpec, final_norm=None):
     """Mean cross-entropy of the untied head over the targets that are
     not PAD, the logits made a chunk of tokens at a time. `final_norm`:
     another gain than the stack's (the prediction module's own)."""
     with jax.named_scope("seq.head_loss"):
-        d = x.shape[-1]
         gain = params["final_norm"] if final_norm is None else final_norm
-        xn = rms_norm(x, gain, spec.rms_norm_eps
-                      ).astype(COMPUTE).reshape(-1, d)
-        tgt = targets.reshape(-1)
-        chunk = min(LOSS_CHUNK, xn.shape[0])
-        pad = (-xn.shape[0]) % chunk
-        if pad:
-            xn = jnp.pad(xn, ((0, pad), (0, 0)))
-            tgt = jnp.pad(tgt, (0, pad))           # PAD targets: masked
+        x_chunks, t_chunks, tgt = _token_chunks(
+            rms_norm(x, gain, spec.rms_norm_eps
+                     ).astype(COMPUTE).reshape(-1, x.shape[-1]), targets)
         head = params["head"]
 
         @jax.checkpoint
         def chunk_sum(carry, xs):
-            # the head is rounded inside the chunk: its gradient then
-            # adds up over the chunks in float32, not in bfloat16
-            x_c, t_c = xs
-            logits = jax.lax.dot_general(
-                x_c, head.astype(COMPUTE), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ce = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-                logits, t_c[:, None], axis=1)[:, 0]
-            return carry + jnp.sum(jnp.where(t_c != PAD, ce, 0.0)), None
+            return carry + jnp.sum(_chunk_ce(head, *xs)), None
 
-        total, _ = jax.lax.scan(
-            chunk_sum, jnp.float32(0.0),
-            (xn.reshape(-1, chunk, d), tgt.reshape(-1, chunk)))
+        total, _ = jax.lax.scan(chunk_sum, jnp.float32(0.0),
+                                (x_chunks, t_chunks))
         return total / jnp.maximum(jnp.sum(tgt != PAD), 1)
+
+
+def token_losses(params, y, targets):
+    """The untied head's cross-entropy of every token, (B, S) float32 (0
+    at a PAD target), from a state y (B, S, d) that is normed already,
+    in `head_loss`'s chunks."""
+    with jax.named_scope("seq.head_loss"):
+        x_chunks, t_chunks, _ = _token_chunks(
+            y.astype(COMPUTE).reshape(-1, y.shape[-1]), targets)
+        head = params["head"]
+        ce = jax.lax.map(
+            jax.checkpoint(lambda xs: _chunk_ce(head, *xs)),
+            (x_chunks, t_chunks))
+        return ce.reshape(-1)[:targets.size].reshape(targets.shape)
+
+
+def exit_gate_logits(params, y):
+    """The exit gate on normed states y (..., d): z = y . w_gate + b_gate,
+    float32 throughout (a sum of d products a token, no matrix unit)."""
+    return jnp.sum(y * params["exit_gate"][:, 0], axis=-1) \
+        + params["exit_bias"][0]
+
+
+def exit_probabilities(gate_logits):
+    """(T, ...) gate logits z_t -> p_t, with lam = sigmoid(z): p_t =
+    lam_t prod_{j<t}(1 - lam_j), and the last exit takes what mass is
+    left: p_T = prod_{j<T}(1 - lam_j) (z_T is not read). 1 - lam is
+    sigmoid(-z), so the masses sum to 1 to float32's last digits."""
+    stay = jnp.cumprod(jax.nn.sigmoid(-gate_logits), axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    leave = jax.nn.sigmoid(gate_logits).at[-1].set(1.0)
+    return before * leave
+
+
+def exit_loss(params, tokens, spec: BlockSpec):
+    """A looped stack's loss over its exits: tokens (B, S + 1) -> (mean
+    over the tokens of sum_t p_t CE_t - EXIT_ENTROPY_WEIGHT * H(p),
+    counters: `exit_losses` (T,) the mean CE_t, `exit_mass` (T,) the mean
+    p_t, `exit_entropy` the mean H(p))."""
+    targets = tokens[:, 1:]
+
+    def at_exit(y):
+        with jax.named_scope("seq.exit"):
+            z = exit_gate_logits(params, y)
+        return token_losses(params, y, targets), z
+
+    _, (ce, z) = looped_states(params, tokens[:, :-1], spec, at_exit)
+    with jax.named_scope("seq.loop"), jax.named_scope("seq.exit"):
+        p = exit_probabilities(z)
+        # a mass of 0 adds 0 to the entropy
+        entropy = -jnp.sum(p * jnp.log(jnp.maximum(p, 1e-30)), axis=0)
+        kept = targets != PAD
+        n = jnp.maximum(jnp.sum(kept), 1)
+
+        def mean(a):
+            return jnp.sum(jnp.where(kept, a, 0.0), axis=(-2, -1)) / n
+
+        loss = mean(jnp.sum(p * ce, axis=0) - EXIT_ENTROPY_WEIGHT * entropy)
+        return loss, {"exit_losses": mean(ce), "exit_mass": mean(p),
+                      "exit_entropy": mean(entropy)}
 
 
 def loss_and_counters(params, tokens, spec: BlockSpec):
@@ -596,8 +795,10 @@ def loss_and_counters(params, tokens, spec: BlockSpec):
     With a prediction module tokens (B, S + 2): position i of the S
     also predicts tokens[:, i + 2] through the module, the counters gain
     the module's router as their last layer and `losses` (main, module),
-    and the loss is main + MTP_LOSS_WEIGHT * module. The function the
-    train step differentiates."""
+    and the loss is main + MTP_LOSS_WEIGHT * module. A looped stack's
+    loss is `exit_loss`. The function the train step differentiates."""
+    if spec.loop_steps:
+        return exit_loss(params, tokens, spec)
     s = tokens.shape[1] - 1 - spec.mtp_layers
     x, counters = hidden_states(params, tokens[:, :s], spec)
     loss = head_loss(params, x, tokens[:, 1:s + 1], spec)
@@ -614,9 +815,13 @@ def loss_and_counters(params, tokens, spec: BlockSpec):
 
 
 def last_logits(params, ids, spec: BlockSpec):
-    """Next-item logits (B, vocab) after the last position of ids."""
-    x, _ = hidden_states(params, ids, spec)
-    xn = rms_norm(x[:, -1], params["final_norm"], spec.rms_norm_eps)
+    """Next-item logits (B, vocab) after the last position of ids; of a
+    looped stack the last exit's, after every pass (no early exit)."""
+    if spec.loop_steps:
+        xn = looped_states(params, ids, spec)[0][:, -1]
+    else:
+        x, _ = hidden_states(params, ids, spec)
+        xn = rms_norm(x[:, -1], params["final_norm"], spec.rms_norm_eps)
     return jnp.dot(xn.astype(COMPUTE), params["head"].astype(COMPUTE).T,
                    preferred_element_type=jnp.float32)
 
@@ -717,11 +922,14 @@ def band_counters(spec: BlockSpec, seq_len: int) -> dict:
 
 
 def attention_counters(jaxpr) -> dict:
-    """What a traced step program holds of the attention forward, read
+    """What a traced step program holds of the attention layers, read
     off its equations (through every jaxpr they hold): the calls of the
-    forward kernel, and the bytes of the arrays named KEPT_RESIDUALS that
-    a checkpoint's backward pass takes in, which are the ones kept."""
-    found = {"attn_fwd_kernels": 0, "attn_residual_bytes": 0}
+    forward kernel; the layer applications, which are the calls of the
+    backward kernel `flash_attention_dq` (one a layer and pass); and the
+    bytes of the arrays named KEPT_RESIDUALS that a checkpoint's
+    backward pass takes in, which are the ones kept."""
+    found = {"attn_fwd_kernels": 0, "attn_residual_bytes": 0,
+             "layer_applications": 0}
 
     def walk(jaxpr, named):
         def is_named(v):
@@ -740,9 +948,11 @@ def attention_counters(jaxpr) -> dict:
                 found["attn_residual_bytes"] += sum(
                     v.aval.size * v.aval.dtype.itemsize
                     for v in eqn.invars if is_named(v))
-            elif (eqn.primitive.name == "pallas_call"
-                  and eqn.params["name"] == "flash_attention_fwd"):
-                found["attn_fwd_kernels"] += 1
+            elif eqn.primitive.name == "pallas_call":
+                if eqn.params["name"] == "flash_attention_fwd":
+                    found["attn_fwd_kernels"] += 1
+                elif eqn.params["name"] == "flash_attention_dq":
+                    found["layer_applications"] += 1
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub, set())
 
@@ -763,6 +973,58 @@ def step_attention_counters(spec: BlockSpec, learning_rate: float,
     traced = step.trace(params, jax.eval_shape(optimizer.init, params),
                         jax.ShapeDtypeStruct(batch_shape, jnp.int32))
     return attention_counters(traced.jaxpr.jaxpr)
+
+
+def _expert_counters(counters: list, spec: BlockSpec,
+                     positions: int) -> dict:
+    """The `seq.wait` labels only a stack with routed layers has, from
+    its steps' counters (`counts`: (routers, B, held) a step)."""
+    counts = np.stack([c["counts"] for c in counters])  # steps, L, B, held
+    dropped = int(sum(np.sum(c["dropped"]) for c in counters))
+    if dropped:
+        raise AssertionError(
+            f"{dropped} routed tokens found no row: the expert layer "
+            "must drop none")
+    steps, _, batch, _ = counts.shape
+    per_expert = counts.sum(axis=2)                  # a step, a layer
+    held = spec.experts
+    return dict(
+        expert_tokens_min=int(per_expert.min()),
+        expert_tokens_mean=repr(float(per_expert.mean())),
+        expert_tokens_max=int(per_expert.max()),
+        # the fullest held expert over the mean one, a step and layer;
+        # as a ratio of sums, so a layer that sent none here counts 0
+        expert_load_max_over_mean=repr(float(
+            per_expert.max(axis=-1).sum()
+            / max(per_expert.mean(axis=-1).sum(), 1e-9))),
+        # choices sent to held experts over the held experts' share of
+        # all choices: 1.0 is what a balanced router sends this rank
+        expert_tokens_held_share=repr(float(
+            per_expert.sum() / (steps * counts.shape[1] * batch
+                                * positions * held.top_k
+                                * held.n_held / held.n_routed))),
+        # a history's share of the worst-case buffer that holds rows;
+        # only every choice of every token held fills it
+        expert_tiles_used_share=repr(tiles_used_share(
+            counts, held, positions)),
+        dropped_tokens=dropped)
+
+
+def _exit_counters(counters: list) -> dict:
+    """The `seq.wait` labels only a looped stack has, from its steps'
+    counters; a list is the T exits', as JSON."""
+    def exits(step: dict, key: str) -> str:
+        return json.dumps([float(v) for v in step[key]])
+
+    mass = np.stack([c["exit_mass"] for c in counters]).astype(np.float64)
+    return dict(
+        loss_exit_first=exits(counters[0], "exit_losses"),
+        loss_exit_last=exits(counters[-1], "exit_losses"),
+        exit_mass_last=exits(counters[-1], "exit_mass"),
+        # the passes a token takes to its exit, by the gate's masses
+        exit_expected_steps=repr(float(
+            (mass * np.arange(1, mass.shape[1] + 1)).sum(axis=1).mean())),
+        exit_entropy_last=repr(float(counters[-1]["exit_entropy"])))
 
 
 def train_lm(seqs: np.ndarray, p, lifecycle=None):
@@ -816,39 +1078,17 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
     with tracing.span("seq.wait") as sp:
         losses = np.asarray(jax.device_get(losses), np.float64)
         counters = jax.device_get(counters)
-        counts = np.stack([c["counts"] for c in counters])  # steps, L, B, held
-        dropped = int(sum(np.sum(c["dropped"]) for c in counters))
-        if dropped:
-            raise AssertionError(
-                f"{dropped} routed tokens found no row: the expert layer "
-                "must drop none")
-        per_expert = counts.sum(axis=2)                  # a step, a layer
-        held = spec.experts
-        sp.update(
-            tokens_per_step=batch * positions,
-            loss_first=repr(float(losses[0])),
-            loss_last=repr(float(losses[-1])),
-            expert_tokens_min=int(per_expert.min()),
-            expert_tokens_mean=repr(float(per_expert.mean())),
-            expert_tokens_max=int(per_expert.max()),
-            # the fullest held expert over the mean one, a step and layer;
-            # as a ratio of sums, so a layer that sent none here counts 0
-            expert_load_max_over_mean=repr(float(
-                per_expert.max(axis=-1).sum()
-                / max(per_expert.mean(axis=-1).sum(), 1e-9))),
-            # choices sent to held experts over the held experts' share of
-            # all choices: 1.0 is what a balanced router sends this rank
-            expert_tokens_held_share=repr(float(
-                per_expert.sum() / (steps * counts.shape[1] * batch
-                                    * positions * held.top_k
-                                    * held.n_held / held.n_routed))),
-            # a history's share of the worst-case buffer that holds rows;
-            # only every choice of every token held fills it
-            expert_tiles_used_share=repr(tiles_used_share(
-                counts, held, positions)),
-            dropped_tokens=dropped, **program)
+        # what every stack has
+        sp.update(tokens_per_step=batch * positions,
+                  loss_first=repr(float(losses[0])),
+                  loss_last=repr(float(losses[-1])),
+                  loop_steps=spec.loop_steps or 1, **program)
+        if "counts" in counters[0]:
+            sp.update(**_expert_counters(counters, spec, positions))
         if "sliding_attention" in spec.layer_types:
             sp.update(**band_counters(spec, positions))
+        if spec.loop_steps:
+            sp.update(**_exit_counters(counters))
         if spec.mtp_layers:
             parts = np.stack([c["losses"] for c in counters]).astype(
                 np.float64)

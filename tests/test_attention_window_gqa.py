@@ -159,7 +159,7 @@ def holds_one_forward_kernel_a_layer(cfg, layers):
     assert str(jaxpr).count("name=flash_attention_fwd") == layers
     assert str(jaxpr).count("name=flash_attention_dq") == layers
     assert seq_blocks.attention_counters(jaxpr.jaxpr) == {
-        "attn_fwd_kernels": layers,
+        "attn_fwd_kernels": layers, "layer_applications": layers,
         "attn_residual_bytes": layers * 2 * 4 * 48 * (
             spec.head_dim * 4 + 4)}
 

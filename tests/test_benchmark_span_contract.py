@@ -19,6 +19,13 @@ from benchmark.tests.test_span_contract import (
 )
 
 READERS = ("span-self", "idle-span", "warm-span", "seq-counter")
+# the looped cell's copies of the generic persist and idle metrics go by
+# readers that are these under a name of their own
+# (benchmark/readers/span_self_loop.py says why)
+ALIASES = {"span-self-loop": "span-self", "idle-span-loop": "idle-span"}
+# a traffic kind a later PR added brings its rehearsal's overlay here
+# (benchmark/tests/test_contract_loop.py holds the looped cell's scopes)
+OVERLAYS = {**OVERLAYS, "train_sequence_loop": "loop-tiny.json"}
 LISTED = {m["name"]: m for m in BENCH["per_layer"]}
 
 
@@ -28,7 +35,10 @@ def _metrics() -> list:
             cells.BENCH_DIR, "layer_metrics", "*.json"))):
         name = os.path.basename(path)[:-len(".json")]
         spec = cells.load_json(path)
+        reader = cells.module_for("readers", spec["reader"]).read
+        spec["reader"] = ALIASES.get(spec["reader"], spec["reader"])
         if spec["reader"] in READERS:
+            assert reader is cells.module_for("readers", spec["reader"]).read
             out.append(pytest.param(LISTED[name], spec, id=name))
     return out
 
@@ -87,7 +97,8 @@ def test_what_a_metric_reads_is_what_a_tiny_job_writes(metric, spec,
     elif spec["spans"] == "rest":
         # what the others leave: they are `idle-span` metrics of its cells
         for name in spec["besides"]:
-            assert cells.layer_metric_spec(name)["reader"] == "idle-span"
+            theirs = cells.layer_metric_spec(name)["reader"]
+            assert ALIASES.get(theirs, theirs) == "idle-span"
             assert set(LISTED[name]["workloads"]) <= set(
                 metric["workloads"])
     else:
