@@ -36,7 +36,7 @@ name what the stack does not compute):
    low-rank query and key-value paths with an RMSNorm each, rotary
    positions on the `qk_rope_head_dim` dimensions only, one rope key
    shared by all heads). Both end in ops/attention.py
-   `banded_flash_attention` (Pallas forward and backward);
+   `banded_flash_attention` (one Pallas kernel forward, one backward);
  * the second half of a layer: a dense SwiGLU of `intermediate_size`, in
    every layer of a specification without expert keys (no `num_experts`
    / `n_routed_experts`: no router, no held experts) and in the first
@@ -924,12 +924,14 @@ def band_counters(spec: BlockSpec, seq_len: int) -> dict:
 def attention_counters(jaxpr) -> dict:
     """What a traced step program holds of the attention layers, read
     off its equations (through every jaxpr they hold): the calls of the
-    forward kernel; the layer applications, which are the calls of the
-    backward kernel `flash_attention_dq` (one a layer and pass); and the
-    bytes of the arrays named KEPT_RESIDUALS that a checkpoint's
-    backward pass takes in, which are the ones kept."""
-    found = {"attn_fwd_kernels": 0, "attn_residual_bytes": 0,
-             "layer_applications": 0}
+    forward kernel; the calls of the backward kernel
+    `flash_attention_bwd`, which are the layer applications (one a layer
+    and pass); the backward kernels, which are every other kernel of the
+    name `flash_attention_*` (one a layer application, two while dq and
+    dk / dv had a kernel each); and the bytes of the arrays named KEPT_RESIDUALS
+    that a checkpoint's backward pass takes in, which are the ones kept."""
+    found = {"attn_fwd_kernels": 0, "attn_bwd_kernels": 0,
+             "attn_residual_bytes": 0, "layer_applications": 0}
 
     def walk(jaxpr, named):
         def is_named(v):
@@ -949,10 +951,13 @@ def attention_counters(jaxpr) -> dict:
                     v.aval.size * v.aval.dtype.itemsize
                     for v in eqn.invars if is_named(v))
             elif eqn.primitive.name == "pallas_call":
-                if eqn.params["name"] == "flash_attention_fwd":
+                name = eqn.params["name"]
+                if name == "flash_attention_fwd":
                     found["attn_fwd_kernels"] += 1
-                elif eqn.params["name"] == "flash_attention_dq":
-                    found["layer_applications"] += 1
+                elif name.startswith("flash_attention_"):
+                    found["attn_bwd_kernels"] += 1
+                    found["layer_applications"] += (
+                        name == "flash_attention_bwd")
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub, set())
 

@@ -481,8 +481,9 @@ def ulysses_attention(
 
 
 # ---------------------------------------------------------------------------
-# Banded flash attention: grouped-query heads, causal window, Pallas
-# forward AND backward (the language-model path of models/seq_blocks.py)
+# Banded flash attention: grouped-query heads, causal window, one Pallas
+# kernel forward and one backward (the language-model path of
+# models/seq_blocks.py)
 # ---------------------------------------------------------------------------
 #
 # Layout (B, H, S, D). Query head h reads key-value head h // (Hq // Hkv)
@@ -490,7 +491,11 @@ def ulysses_attention(
 # blocks a query block needs (`j <= i`, and `i - j < window` on a window
 # layer) are listed on the host, once per shape, as a table of (query
 # block, key block) pairs; the grid walks the table, so a block outside
-# the band is never fetched, in the forward or in either backward kernel.
+# the band is never fetched. The forward kernel walks the table by query
+# block (a block's softmax statistics and output stay in VMEM while its
+# key blocks go by); the backward kernel walks it by key block, dk and dv
+# of the block in VMEM and dq of the whole head beside them, so a block
+# pair's scores and probabilities are made once for all three.
 
 _FIRST, _LAST, _EDGE = 1, 2, 4
 _LANES = 128
@@ -542,15 +547,19 @@ def band_blocks(seq_len: int, block_q: int, block_k: int,
     return total
 
 
-def _band_scores(q, k, qi, kj, edge, *, scale, window):
-    """(bq, bk) float32 scores of one block pair, -inf where masked."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+def _band_scores(q, k, qi, kj, edge, *, scale, window, by_key=False):
+    """(bq, bk) float32 scores of one block pair, -inf where masked;
+    with `by_key` their transpose, (bk, bq), as the product k.q^T."""
+    lhs, rhs = (k, q) if by_key else (q, k)
+    s = jax.lax.dot_general(lhs, rhs, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    bq, bk = s.shape
+    q_axis = int(by_key)
 
     def masked(s):
-        rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        rows = qi * q.shape[0] + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, q_axis)
+        cols = kj * k.shape[0] + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1 - q_axis)
         keep = cols <= rows
         if window is not None:
             keep = keep & (rows - cols < window)
@@ -591,91 +600,105 @@ def _band_fwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref,
                                          lse_ref.shape[2:])
 
 
-def _band_probs(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, qi, kj, edge,
-                *, scale, window):
-    """Recompute one block pair's probabilities and the score gradient:
-    -> (prob, dscore) float32 (bq, bk)."""
-    s = _band_scores(q_ref[0, 0], k_ref[0, 0], qi, kj, edge,
-                     scale=scale, window=window)
-    prob = jnp.exp(s - lse_ref[0, 0][:, :1])
-    do = do_ref[0, 0]
-    dprob = jax.lax.dot_general(do, v_ref[0, 0], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    delta = jnp.sum(o_ref[0, 0].astype(jnp.float32)
-                    * do.astype(jnp.float32), axis=-1, keepdims=True)
-    return prob, prob * (dprob - delta) * scale
-
-
-def _band_dq_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
-                    do_ref, lse_ref, dq_ref, acc_scr, *, scale, window):
-    p = pl.program_id(2)
-    fl = fl_ref[p]
-
-    @pl.when((fl & _FIRST) != 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    _, ds = _band_probs(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                        qi_ref[p], kj_ref[p], (fl & _EDGE) != 0,
-                        scale=scale, window=window)
-    acc_scr[...] += jnp.dot(ds.astype(k_ref.dtype), k_ref[0, 0],
-                            preferred_element_type=jnp.float32)
-
-    @pl.when((fl & _LAST) != 0)
-    def _emit():
-        dq_ref[0, 0] = acc_scr[...].astype(dq_ref.dtype)
-
-
-def _band_dkv_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
-                     do_ref, lse_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+def _band_bwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
+                     row_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
                      *, scale, window):
+    """The whole backward pass of one (batch, query head): the band by
+    key block, a block pair's scores, probabilities and score gradient
+    made once, and made transposed, (bk, bq): dv += P^T.dO and dk +=
+    dS^T.Q then take them as they are, into the key block's scratches,
+    and dq's product alone transposes an operand. The head's whole dq
+    stays in `dq_scr`, each pair adding dS.K to its query block's rows:
+    the table axis is sequential, and a query block's pairs come in
+    ascending key order."""
     p = pl.program_id(2)
     fl = fl_ref[p]
+    qi = qi_ref[p]
+
+    @pl.when(p == 0)
+    def _init_head():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
     @pl.when((fl & _FIRST) != 0)
-    def _init():
+    def _init_key_block():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    prob, ds = _band_probs(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                           qi_ref[p], kj_ref[p], (fl & _EDGE) != 0,
-                           scale=scale, window=window)
-    t_lhs = (((0,), (0,)), ((), ()))       # lhs^T @ rhs
-    dv_scr[...] += jax.lax.dot_general(
-        prob.astype(do_ref.dtype), do_ref[0, 0], t_lhs,
-        preferred_element_type=jnp.float32)
-    dk_scr[...] += jax.lax.dot_general(
-        ds.astype(q_ref.dtype), q_ref[0, 0], t_lhs,
-        preferred_element_type=jnp.float32)
+    q, k, do = q_ref[0, 0], k_ref[0, 0], do_ref[0, 0]
+    s = _band_scores(q, k, qi, kj_ref[p], (fl & _EDGE) != 0,
+                     scale=scale, window=window, by_key=True)
+    row = row_ref[0, 0]            # (2, bq): the rows' log-sum-exp, delta
+    prob = jnp.exp(s - row[0:1])
+    dprob = jax.lax.dot_general(v_ref[0, 0], do, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+    ds = (prob * (dprob - row[1:2]) * scale).astype(q.dtype)
+    dv_scr[...] += jnp.dot(prob.astype(do.dtype), do,
+                           preferred_element_type=jnp.float32)
+    dk_scr[...] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+    block_q = q.shape[0]
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+    dq_scr[rows, :] += jax.lax.dot_general(
+        ds, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     @pl.when((fl & _LAST) != 0)
-    def _emit():
-        dk_ref[0, 0] = dk_scr[...]
-        dv_ref[0, 0] = dv_scr[...]
+    def _emit_key_block():
+        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when(p == pl.num_programs(2) - 1)
+    def _emit_head():
+        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+# what the backward kernel may ask of the chip's 128 MiB of VMEM
+BWD_VMEM_BUDGET = 96 << 20
+
+
+def bwd_vmem_bytes(seq: int, block_q: int, block_k: int, head_dim: int,
+                   itemsize: int) -> int:
+    """VMEM `flash_attention_bwd` asks for (`vmem_limit_bytes`): its
+    blocks twice (the pipeline's two buffers), dq of the whole head among
+    them and dk / dv as float32; the three accumulators; room for a block
+    pair's scores, probabilities, their gradients and the rounded copies
+    of two; 2 MiB for the compiler's own."""
+    blocks = ((2 * block_q + 2 * block_k + seq) * head_dim * itemsize
+              + 8 * block_q * 4 + 2 * block_k * head_dim * 4)
+    scratch = (seq + 2 * block_k) * head_dim * 4
+    return 2 * blocks + scratch + 6 * block_q * block_k * 4 + (2 << 20)
 
 
 def _band_call(kernel, name, table, args, kinds, outs, scratch, *,
-               batch, heads, group, block_q, block_k, head_dim, interpret):
+               batch, heads, group, block_q, block_k, head_dim, interpret,
+               vmem_limit_bytes=None):
     """One pallas_call over (batch, query head, table entry). `kinds`
     says, per tensor argument, which block it is: "q" (query head, block
     qi[p]), "k" (key-value head h // group, block kj[p]), "lse" (as "q",
-    128 lanes); `outs` likewise, with the out dtype."""
+    128 lanes), "row" (two row statistics of the query block, (2,
+    block_q)); `outs` likewise, with the out dtype: "kq" (a key block a
+    QUERY head) and "head" (the query head's whole sequence, written back
+    when the head is done)."""
     from jax.experimental.pallas import tpu as pltpu
+
+    seq = args[0].shape[2]
 
     def spec(kind):
         if kind == "k":
             return pl.BlockSpec(
                 (1, 1, block_k, head_dim),
                 lambda b, h, p, qi, kj, fl: (b, h // group, kj[p], 0))
-        if kind == "kq":       # a key block per QUERY head (dk / dv out)
+        if kind == "kq":
             return pl.BlockSpec(
                 (1, 1, block_k, head_dim),
                 lambda b, h, p, qi, kj, fl: (b, h, kj[p], 0))
+        if kind == "head":
+            return pl.BlockSpec((1, 1, seq, head_dim),
+                                lambda b, h, p, qi, kj, fl: (b, h, 0, 0))
+        if kind == "row":
+            return pl.BlockSpec((1, 1, 2, block_q),
+                                lambda b, h, p, qi, kj, fl: (b, h, 0, qi[p]))
         width = _LANES if kind == "lse" else head_dim
         return pl.BlockSpec((1, 1, block_q, width),
                             lambda b, h, p, qi, kj, fl: (b, h, qi[p], 0))
-
-    seq = args[0].shape[2]
 
     def shape(kind, dtype):
         width = _LANES if kind == "lse" else head_dim
@@ -691,14 +714,17 @@ def _band_call(kernel, name, table, args, kinds, outs, scratch, *,
             scratch_shapes=scratch),
         out_shape=[shape(k, dt) for k, dt in outs],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(*(jnp.asarray(t) for t in table), *args)
 
 
-def _band_setup(q_shape, hkv, seq_len, block_q, block_k, interpret):
-    """The static arguments of `_band_call` and the padding the sequence
-    needs, for (B, Hq, ., D) queries of `seq_len` real positions."""
+def _band_setup(q_shape, hkv, seq_len, block_q, block_k, interpret,
+                itemsize):
+    """The static arguments of `_band_call`, the padding the sequence
+    needs and the VMEM the backward kernel will ask for, for (B, Hq, .,
+    D) queries of `seq_len` real positions, `itemsize` bytes a number."""
     b, hq, _, d = q_shape
     if hq % hkv:
         raise ValueError(f"{hq} query heads do not group over {hkv} "
@@ -709,8 +735,14 @@ def _band_setup(q_shape, hkv, seq_len, block_q, block_k, interpret):
     if block % min(block_q, block_k):
         raise ValueError("block_q and block_k must divide one another")
     pad = _pad_len(seq_len, block)
+    vmem = bwd_vmem_bytes(seq_len + pad, block_q, block_k, d, itemsize)
+    if vmem > BWD_VMEM_BUDGET:
+        raise ValueError(
+            f"the backward kernel keeps a head's dq in VMEM: {vmem} bytes "
+            f"for {seq_len + pad} positions {d} wide, over the budget of "
+            f"{BWD_VMEM_BUDGET}")
     return dict(batch=b, heads=hq, group=hq // hkv, block_q=block_q,
-                block_k=block_k, head_dim=d, interpret=interpret), pad
+                block_k=block_k, head_dim=d, interpret=interpret), pad, vmem
 
 
 def _pad_seq(x, pad):
@@ -723,7 +755,11 @@ def banded_flash_attention(q, k, v, window: int | None = None,
                            block_q: int = 512, block_k: int = 512,
                            interpret: bool | None = None):
     """Causal attention over grouped-query heads, optionally within a
-    window (`i - j < window`), forward and backward as Pallas kernels.
+    window (`i - j < window`): one Pallas kernel forward
+    (`flash_attention_fwd`) and one backward (`flash_attention_bwd`: dq,
+    dk and dv from one walk of the band, five products a block pair; a
+    head's dq stays in VMEM, so a sequence whose dq does not fit
+    `BWD_VMEM_BUDGET` is refused).
 
     q: (B, Hq, S, D); k, v: (B, Hkv, S, D), Hq a multiple of Hkv; ->
     (B, Hq, S, D). The sequence is padded to the block internally (a
@@ -738,8 +774,8 @@ def _banded_fwd(q, k, v, window, scale, block_q, block_k, interpret):
     s, d = q.shape[2], q.shape[3]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     block_q, block_k = min(block_q, s), min(block_k, s)
-    dims, pad = _band_setup(q.shape, k.shape[1], s, block_q, block_k,
-                            interpret)
+    dims, pad, _ = _band_setup(q.shape, k.shape[1], s, block_q, block_k,
+                               interpret, q.dtype.itemsize)
     qp, kp, vp = _pad_seq(q, pad), _pad_seq(k, pad), _pad_seq(v, pad)
     table = band_pairs(s + pad, block_q, block_k, window)
     o, lse = _band_call(
@@ -769,31 +805,32 @@ def _banded_bwd(window, scale, block_q, block_k, interpret, res, g):
     s = g.shape[2]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     block_q, block_k = min(block_q, s), min(block_k, s)
-    dims, pad = _band_setup(qp.shape, kp.shape[1], s, block_q, block_k,
-                            interpret)
+    dims, pad, vmem = _band_setup(qp.shape, kp.shape[1], s, block_q,
+                                  block_k, interpret, qp.dtype.itemsize)
     do = _pad_seq(g.astype(qp.dtype), pad)
-    # the backward kernels' "lse" blocks are 128 lanes wide
-    lse = jnp.broadcast_to(lse[..., None], (*lse.shape, _LANES))
-    args = (qp, kp, vp, o, do, lse)
-    kinds = ("q", "k", "k", "q", "q", "lse")
-    dq, = _band_call(
-        partial(_band_dq_kernel, scale=scale, window=window),
-        "flash_attention_dq", band_pairs(sp, block_q, block_k, window),
-        args, kinds, (("q", qp.dtype),),
-        [pltpu.VMEM((block_q, d), jnp.float32)], **dims)
-    dk, dv = _band_call(
-        partial(_band_dkv_kernel, scale=scale, window=window),
-        "flash_attention_dkv",
+    # a row's two statistics, made once a row: (B, Hq, 2, S) float32
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    group = dims["group"]
+    # a key-value head's gradient is the sum over the query heads it
+    # serves: float32 out of the kernel where there is a sum to make
+    kv_dtype = kp.dtype if group == 1 else jnp.float32
+    dq, dk, dv = _band_call(
+        partial(_band_bwd_kernel, scale=scale, window=window),
+        "flash_attention_bwd",
         band_pairs(sp, block_q, block_k, window, by_key=True),
-        args, kinds, (("kq", jnp.float32), ("kq", jnp.float32)),
-        [pltpu.VMEM((block_k, d), jnp.float32),
-         pltpu.VMEM((block_k, d), jnp.float32)], **dims)
-    # a key-value head's gradient is the sum over the query heads it serves
+        (qp, kp, vp, do, jnp.stack([lse, delta], axis=2)),
+        ("q", "k", "k", "q", "row"),
+        (("head", qp.dtype), ("kq", kv_dtype), ("kq", kv_dtype)),
+        [pltpu.VMEM((sp, d), jnp.float32),
+         pltpu.VMEM((block_k, d), jnp.float32),
+         pltpu.VMEM((block_k, d), jnp.float32)],
+        vmem_limit_bytes=vmem, **dims)
     b, hkv = kp.shape[0], kp.shape[1]
 
     def grouped(x):
-        return x.reshape(b, hkv, dims["group"], sp, d).sum(2)[
-            :, :, :s].astype(kp.dtype)
+        if group > 1:
+            x = x.reshape(b, hkv, group, sp, d).sum(2)
+        return x[:, :, :s].astype(kp.dtype)
 
     return dq[:, :, :s], grouped(dk), grouped(dv)
 

@@ -1,5 +1,6 @@
-"""The packed-A kernels of the ALS solve, compiled by the TPU's compiler
-for a described v5e at the benchmark's widths: what Mosaic refuses (a
+"""The packed-A kernels of the ALS solve and the banded attention's two
+kernels, compiled by the TPU's compiler for a described v5e at the
+benchmark's widths: what Mosaic refuses (a
 slice off the tiling, a stack over the 16 MB of scoped VMEM) shows here,
 on a CPU, at no chip time. Nothing runs: these are compiles, not
 measurements. The topology is described inside a fixture, in this one
@@ -11,7 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from pio_tpu.ops import als_pallas
+from pio_tpu.ops import als_pallas, attention
 
 ML20M_USERS, ML20M_ITEMS, MSD_ITEM_BLOCK = 138_493, 26_744, 96_137
 
@@ -67,3 +68,39 @@ def test_pack_flush_compiles_for_v5e(one_chip, n, k):
     assert "tpu_custom_call" in text and "als.gram.pack" in text
     # the pass writes 1/pack of what it reads and holds nothing else
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+# (batch, query heads, key-value heads, positions, head width, window):
+# the sequence cells' attention layers, and the longest head the backward
+# kernel's VMEM budget admits at 128 wide
+ATTENTION = {
+    "glm_latent": (2, 20, 20, 8192, 256, None),
+    "mellum2_full": (2, 32, 4, 8192, 128, None),
+    "mellum2_window": (2, 32, 4, 8192, 128, 1024),
+    "ouro": (2, 16, 16, 8192, 128, None),
+    "64k_positions": (1, 2, 1, 65536, 128, 1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ATTENTION))
+def test_banded_attention_gradient_compiles_for_v5e(one_chip, case):
+    """One forward and one backward kernel; the backward kernel's dq of
+    a whole head, its accumulate at a dynamic row offset and the VMEM it
+    asks for (`bwd_vmem_bytes`, over the 16 MB a kernel gets unasked)
+    pass Mosaic."""
+    b, hq, hkv, s, d, window = ATTENTION[case]
+
+    def shape(h):
+        return jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def gradient(q, k, v, ct):
+        return jax.vjp(lambda q, k, v: attention.banded_flash_attention(
+            q, k, v, window, None, 512, 512, False), q, k, v)[1](ct)
+
+    assert attention.bwd_vmem_bytes(s, 512, 512, d, 2) > 16 << 20
+    text = jax.jit(gradient).lower(
+        shape(hq), shape(hkv), shape(hkv), shape(hq)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert "flash_attention_dq" not in text

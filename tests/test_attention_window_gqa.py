@@ -1,5 +1,6 @@
 """ops/attention.py `banded_flash_attention`: grouped-query heads, a
-causal window, Pallas forward and backward (interpret mode on the CPU),
+causal window, one Pallas kernel forward and one backward (interpret
+mode on the CPU),
 against the masked softmax with the key-value heads repeated."""
 
 import jax
@@ -30,12 +31,12 @@ SHAPES = [
 ]
 
 
-def _inputs(s, hq, hkv, d=16, seed=0):
+def _inputs(s, hq, hkv, d=16, seed=0, b=2):
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    return (jax.random.normal(ks[0], (2, hq, s, d)),
-            jax.random.normal(ks[1], (2, hkv, s, d)),
-            jax.random.normal(ks[2], (2, hkv, s, d)),
-            jax.random.normal(ks[3], (2, hq, s, d)))
+    return (jax.random.normal(ks[0], (b, hq, s, d)),
+            jax.random.normal(ks[1], (b, hkv, s, d)),
+            jax.random.normal(ks[2], (b, hkv, s, d)),
+            jax.random.normal(ks[3], (b, hq, s, d)))
 
 
 @pytest.mark.parametrize("s,window,bq,bk,hq,hkv", SHAPES)
@@ -56,6 +57,85 @@ def test_backward_equals_masked_reference(s, window, bq, bk, hq, hkv):
         banded_attention_reference(q, k, v, window) * w), (0, 1, 2))(q, k, v)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, atol=5e-5, rtol=5e-5)
+
+
+# what the one backward kernel makes new: a head's whole dq stays in the
+# kernel while the band goes by key block, so a query block's rows are
+# found by offset. (batch, sequence, window, block_q, block_k, query
+# heads, key-value heads, head width)
+RESIDENT_DQ = {
+    "block_q_over_block_k": (1, 192, None, 64, 16, 2, 1, 16),
+    "block_k_over_block_q": (1, 192, None, 16, 64, 2, 1, 16),
+    "block_k_over_block_q_window": (2, 160, 40, 16, 32, 2, 2, 16),
+    "window_narrower_than_a_block": (1, 128, 5, 32, 32, 2, 1, 16),
+    "padded_last_block": (1, 150, None, 32, 32, 2, 2, 16),
+    "padded_last_block_window": (1, 70, 33, 32, 16, 2, 1, 16),
+    "group_8": (1, 96, 40, 32, 32, 8, 1, 16),
+    "head_256_wide": (1, 64, None, 32, 32, 2, 1, 256),
+    # programs run one after another over one accumulator: every head of
+    # every history must find it zeroed
+    "programs_in_a_row": (3, 64, None, 16, 16, 4, 4, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RESIDENT_DQ))
+def test_resident_dq_equals_masked_reference(case):
+    b, s, window, bq, bk, hq, hkv, d = RESIDENT_DQ[case]
+    q, k, v, w = _inputs(s, hq, hkv, d, seed=7, b=b)
+    got = jax.grad(lambda q, k, v: jnp.sum(
+        banded_flash_attention(q, k, v, window, None, bq, bk) * w),
+        (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda q, k, v: jnp.sum(
+        banded_attention_reference(q, k, v, window) * w), (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, atol=1e-4, rtol=1e-4)
+    if case == "programs_in_a_row":
+        # a head's dq is its own: the heads of one history in another
+        # order give the same rows
+        flip = jax.grad(lambda q: jnp.sum(banded_flash_attention(
+            q, k[:, ::-1], v[:, ::-1], window, None, bq, bk)
+            * w[:, ::-1]))(q[:, ::-1])
+        np.testing.assert_array_equal(flip[:, ::-1], got[0])
+
+
+def test_the_gradient_is_one_backward_kernel_a_call():
+    """`jax.grad` of two calls: two `flash_attention_bwd`, and neither of
+    the kernels dq and dk / dv had to themselves."""
+    q, k, v, w = _inputs(64, 4, 2, seed=4)
+
+    def two_layers(q, k, v):
+        o = banded_flash_attention(q, k, v, 24, None, 32, 32)
+        return jnp.sum(banded_flash_attention(o, k, v, None, None, 32, 32)
+                       * w)
+
+    text = str(jax.make_jaxpr(jax.grad(two_layers, (0, 1, 2)))(q, k, v))
+    assert text.count("name=flash_attention_bwd") == 2
+    assert text.count("name=flash_attention_fwd") == 2
+    assert text.count("name=flash_attention_") == 4
+    assert "flash_attention_dq" not in text
+    assert "flash_attention_dkv" not in text
+
+
+@pytest.mark.parametrize("s,d,fits", [
+    (8192, 256, True), (65536, 128, True), (32768, 256, True),
+    (65536, 256, False), (131072, 128, False)])
+def test_a_head_whose_dq_overflows_the_vmem_budget_is_refused(s, d, fits):
+    """The kernel asks for its VMEM by the shape (`bwd_vmem_bytes`); a
+    sequence whose dq does not fit the budget is refused before any
+    kernel is built, with both numbers in the message."""
+    from pio_tpu.ops import attention
+
+    need = attention.bwd_vmem_bytes(s, 512, 512, d, 2)
+    assert (need <= attention.BWD_VMEM_BUDGET) == fits
+    # dq in float32, and twice in the operands' type for the pipeline
+    assert need > s * d * (4 + 2 * 2)
+    if fits:
+        return
+    shapes = [jax.ShapeDtypeStruct((1, 1, s, d), jnp.bfloat16)] * 3
+    with pytest.raises(ValueError, match=(
+            rf"{need} bytes for {s} positions {d} wide, over the budget "
+            rf"of {attention.BWD_VMEM_BUDGET}")):
+        jax.eval_shape(banded_flash_attention, *shapes)
 
 
 def test_grouped_heads_equal_repeated_key_value_heads():
@@ -157,9 +237,10 @@ def holds_one_forward_kernel_a_layer(cfg, layers):
     spec, params, _, grad = stack_case(cfg)
     jaxpr = jax.make_jaxpr(grad)(params)
     assert str(jaxpr).count("name=flash_attention_fwd") == layers
-    assert str(jaxpr).count("name=flash_attention_dq") == layers
+    assert str(jaxpr).count("name=flash_attention_bwd") == layers
     assert seq_blocks.attention_counters(jaxpr.jaxpr) == {
-        "attn_fwd_kernels": layers, "layer_applications": layers,
+        "attn_fwd_kernels": layers, "attn_bwd_kernels": layers,
+        "layer_applications": layers,
         "attn_residual_bytes": layers * 2 * 4 * 48 * (
             spec.head_dim * 4 + 4)}
 
@@ -243,7 +324,8 @@ def test_bare_gradient_under_jit_reads_the_compact_residual(
         s, window, bq, bk, hq, hkv):
     """As the benchmark's window probe calls it: jitted `jax.vjp` of the
     kernel alone. The residual is (B, Hq, padded S), and the backward
-    kernels that widen it give the reference's gradients."""
+    kernel, which reads it as rows beside delta, gives the reference's
+    gradients."""
     from pio_tpu.ops.attention import _banded_fwd
 
     q, k, v, ct = _inputs(s, hq, hkv, seed=6)

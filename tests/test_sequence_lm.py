@@ -304,8 +304,9 @@ def test_profile_joins_the_stacks_scopes(op_name, scope):
 
 # -- what PR 33 added to the stack leaves the older configuration alone ------
 # (the two hashes below were commit 2ef95e1's until PR 34 changed the expert
-# layer's moves on purpose, and PR 34's until PR 40 let the attention
-# forward's o and lse cross the checkpoint; they pin PR 40's text the same way)
+# layer's moves on purpose, PR 34's until PR 40 let the attention
+# forward's o and lse cross the checkpoint, and PR 40's until PR 42 made the
+# attention's backward pass one kernel; they pin PR 42's text the same way)
 
 def _step_lowered(cfg, learning_rate, batch):
     spec = seq_blocks.BlockSpec.parse(cfg)
@@ -327,19 +328,19 @@ def _sha(text):
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-def test_the_window_and_full_stacks_step_program_is_pr_40s():
+def test_the_window_and_full_stacks_step_program_is_pr_42s():
     """Latent attention, the dense layer, the shared expert, the sigmoid
     router and the prediction module are chosen by the specification: a
     specification without them lowers to one program text whatever a
-    later specification's keys add (the hash is of PR 40's text, at this
+    later specification's keys add (the hash is of PR 42's text, at this
     file's small blocks)."""
     assert _sha(_step_text(CFG, 0.0625, (2, 41))) == (
-        "e138f1a3bf6bd0307a4246057c938fd69557b10fd5df09c49e577d167006dd40")
+        "b588116ee9acf9bbf6bf8207991c949919173e02f2d1b5882c33da6bb67a528e")
 
 
-def test_mellum2_12b_ep4s_step_program_is_pr_40s(monkeypatch):
+def test_mellum2_12b_ep4s_step_program_is_pr_42s(monkeypatch):
     """The benchmark's configuration at its timed shapes and the
-    program's own blocks: PR 40's text, by hash."""
+    program's own blocks: PR 42's text, by hash."""
     import json
     import os
 
@@ -351,7 +352,7 @@ def test_mellum2_12b_ep4s_step_program_is_pr_40s(monkeypatch):
     with open(path) as f:
         cfg = es.block_spec_of(json.load(f))
     assert _sha(_step_text(cfg, 1e-4, (2, 8193))) == (
-        "c62b0b8e71aafd910921f61c91a3c26a2d813c0a62a3a8d5b378886c2b28c562")
+        "902c3a2e64ba8ac49a3cfc30d7f659931234faec95095864cd3b4aad5a77b79a")
 
 
 def test_the_repeated_scope_rule_moves_no_path_of_the_older_stack():
