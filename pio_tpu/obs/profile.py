@@ -1,6 +1,6 @@
 """From a device profile of `pio train` to seconds by name.
 
-    python -m pio_tpu.obs.profile <profile dir or .xplane.pb>
+    python -m pio_tpu.obs.profile <profile dir or .xplane.pb> [--ops SCOPE]
 
 Two sets of names meet in a profile (docs/observability.md "Training"):
 
@@ -499,6 +499,35 @@ def reduce(profile: dict, root: str = ROOT,
                              for a, b, chip in gaps[:longest]]}
 
 
+def operations(profile: dict, scope: str,
+               prefix: str | tuple[str, ...] = SCOPE_PREFIX) -> list[dict]:
+    """The operations behind one scope's seconds: every instruction
+    whose scope path has `scope` as an element, over the whole trace and
+    every chip -> [{"scope", "rule", "op": the HLO line (its result's
+    shape, what it reads), "s": own seconds, "calls"}], longest first.
+    What `reduce` sums, before the sum: which fusions, copies and
+    kernels a scope's time is, and how each came by the scope."""
+    scopes_by_module = {name: module_scopes(text, prefix)
+                        for name, text in profile["hlo"].items()}
+    rows: dict[tuple, list] = defaultdict(lambda: [0.0, 0])
+    for lines in profile["devices"].values():
+        modules = sorted((s, s + d, n) for n, s, d in lines["modules"])
+        mod_starts = [m[0] for m in modules]
+        for name, start, own_ns in own_events(lines["ops"]):
+            m = bisect.bisect_right(mod_starts, start) - 1
+            if m < 0 or start >= modules[m][1]:
+                continue
+            path, rule = scopes_by_module.get(modules[m][2], {}).get(
+                instruction_name(name), (UNSCOPED, UNSCOPED))
+            if scope in path.split("/"):
+                row = rows[path, rule, name]
+                row[0] += own_ns / 1e9
+                row[1] += 1
+    return [{"scope": path, "rule": rule, "op": op, "s": sec, "calls": n}
+            for (path, rule, op), (sec, n) in sorted(
+                rows.items(), key=lambda kv: -kv[1][0])]
+
+
 # ---------------------------------------------------------------------------
 # the table
 # ---------------------------------------------------------------------------
@@ -535,12 +564,21 @@ def render(result: dict) -> str:
 
 
 def main(argv: list[str]) -> int:
-    if len(argv) != 1:
+    if len(argv) not in (1, 3) or (len(argv) == 3 and argv[1] != "--ops"):
         print(__doc__.split("\n\n")[0] + "\n\n    python -m "
-              "pio_tpu.obs.profile <profile dir or .xplane.pb>",
-              file=sys.stderr)
+              "pio_tpu.obs.profile <profile dir or .xplane.pb> "
+              "[--ops SCOPE]", file=sys.stderr)
         return 2
-    print(render(reduce(read_profile(argv[0]))))
+    profile = read_profile(argv[0])
+    if len(argv) == 1:
+        print(render(reduce(profile)))
+        return 0
+    rows = operations(profile, argv[2])
+    print(f"{argv[2]}: {sum(r['s'] for r in rows):.4f} s over the trace, "
+          f"{len(rows)} instructions")
+    for r in rows:
+        print(f"{r['s']:10.4f} s {r['calls']:6d} x  [{r['rule']}] "
+              f"{r['scope']}  {r['op'][:240]}")
     return 0
 
 

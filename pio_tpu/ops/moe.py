@@ -21,8 +21,8 @@ tests pin them together and pin top-1 routing against a per-token loop.
 **One expert-parallel rank's part of a dropless top-k layer**
 (`held_moe_ffn`; the block stack of models/seq_blocks.py). The layer is
 told which experts it holds (`HeldExperts.held` of `n_routed`), routes
-every token over ALL of them and computes what its own add, by a grouped
-matrix product (`grouped_matmul`, Pallas) over rows sorted by expert: no
+every token over ALL of them and computes what its own add, by one fused
+grouped op (`grouped_swiglu`, Pallas) over rows sorted by expert: no
 capacity, nothing dropped, no exchange on one chip. The router scores by
 softmax (top-k of the probabilities) or by sigmoid (`score="sigmoid"`:
 selection on score + a bias that takes no gradient, weights from the
@@ -217,25 +217,36 @@ def moe_ffn_ep(params, x, cfg: MoEConfig, mesh: Mesh, axis: str = "data"):
 # softmax, weights optionally normalised over the k), and computes what
 # its own experts add: the tokens' rows are sorted by expert into groups
 # whose sizes are whatever the routing gave (no capacity, nothing
-# dropped), each group padded to whole tiles, and the expert products run
-# as one grouped matrix product over the tiles (`grouped_matmul`, a
-# Pallas kernel; a tile belongs to one expert). The buffer is sized for
-# the most the held experts can be sent, but little follows its size:
-# the groups are packed at its front, `tiles_used` tiles hold them, and
-# the kernels (which prefetch it with the tiles' experts) and the move
-# into the buffer visit those tiles alone. The plan (`dispatch_plan`) is
-# one sort of the choices by expert, one running count of each expert's
-# tokens and a few numbers a tile: a tile's rows are a slice of the
-# sorted order (so `rows_from_tokens` is a loop of `tiles_used` steps,
-# each gathering one tile's token rows), and a choice's row is its
-# group's first row plus the tokens before it that chose the expert (so
-# `tokens_from_rows` gathers k rows a token). Nothing is scattered,
-# forward or backward, and no scalar is gathered for every row or every
-# choice: on this chip a scatter takes 0.3 ms to begin and 45-120 ns an
-# update, a gathered row 5-8 ns (PR 34, PERF.md section 6). Rows of
-# tiles behind `tiles_used` are never written and never added up. What
-# is left over the whole buffer is SwiGLU between the products, and over
-# every choice the gather of the return (ROADMAP R1 d).
+# dropped), each group padded to whole tiles, and the experts' FFN runs
+# as one fused grouped op over the tiles (`grouped_swiglu`, Pallas
+# kernels; a tile belongs to one expert): gate and up in one kernel with
+# SwiGLU in its epilogue, the down product, and backward d hidden with
+# SwiGLU's derivative in its epilogue, dx from both weights in one
+# kernel, the three weight gradients in float32. The kernels take the
+# weights as the parameters store them (float32 masters) and round a
+# block after its DMA; backward they contract over the weights' last
+# axis: no cast, concatenation or transpose of a (G, d, f) array, and no
+# element-wise pass over an (M, f) array, is left outside them (PR 43).
+# The buffer is sized for the most the held experts can be sent, but
+# little follows its size: the groups are packed at its front,
+# `tiles_used` tiles hold them, and the kernels (whose grids are
+# `tiles_used` tiles long and which prefetch the tiles' experts) and the
+# move into the buffer visit those tiles alone. The plan
+# (`dispatch_plan`) is one sort of the choices by expert, one running
+# count of each expert's tokens and a few numbers a tile: a tile's rows
+# are a slice of the sorted order (so `rows_from_tokens` is a loop of
+# `tiles_used` steps, each gathering one tile's token rows), and a
+# choice's row is its group's first row plus the tokens before it that
+# chose the expert (so `tokens_from_rows` gathers k rows a token).
+# Nothing is scattered, forward or backward, and no scalar is gathered
+# for every row or every choice: on this chip a scatter takes 0.3 ms to
+# begin and 45-120 ns an update, a gathered row 5-8 ns (PR 34, PERF.md
+# section 6). Rows of tiles behind `tiles_used` are never written and
+# never added up. What is still done over the whole buffer: its zero
+# fill before the moves (`_unwritten`), and over every choice the gather
+# of the return; a group is padded to whole tiles of 512 rows; and a
+# caller that maps the layer over histories adds the weights' gradients
+# up once a history (ROADMAP S7b).
 # What the absent experts would add is left out: on the chips of a
 # deployment the exchange adds it, and on one chip the layer runs without
 # its exchange.
@@ -356,131 +367,183 @@ def _tile(dim: int, most: int) -> int:
     return dim
 
 
-def _gmm_kernel(te_ref, used_ref, x_ref, w_ref, o_ref, acc_ref):
-    from jax.experimental import pallas as pl
-
-    kk, n_k = pl.program_id(2), pl.num_programs(2)
-    used = pl.program_id(0) < used_ref[0]
-
-    @pl.when(used & (kk == 0))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(used)
-    def _add():
-        acc_ref[...] += jnp.dot(x_ref[...], w_ref[0],
-                                preferred_element_type=jnp.float32)
-
-    @pl.when(used & (kk == n_k - 1))
-    def _emit():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _tgmm_kernel(te_ref, used_ref, x_ref, dy_ref, o_ref):
-    from jax.experimental import pallas as pl
-
-    m = pl.program_id(2)
-    used = m < used_ref[0]
-
-    @pl.when(used & ((m == 0) | (te_ref[jnp.maximum(m - 1, 0)] != te_ref[m])))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(used)
-    def _add():
-        o_ref[0] += jax.lax.dot_general(
-            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+# Weight blocks of one grid step, in bytes as the parameters store them:
+# with the kernels' double buffering, the blocks' copies in the products'
+# type and the accumulators a step stays under `_VMEM` of the chip's 128
+# MiB. A grid step costs ~0.35 us and a block that spans the depth is
+# fetched once an expert, not once a tile, so blocks are as wide and as
+# deep as that allows: at the two cells' widths a product is one or two
+# column blocks of full depth.
+_WEIGHT_BLOCKS = 17 << 20
+_VMEM = 100 << 20
 
 
 def _interpret() -> bool:
     return jax.devices()[0].platform == "cpu"
 
 
-# A tile behind the last group keeps the block indices of the last step
-# of the last tile that holds one: nothing is fetched for it, nothing
-# computed, and its rows of the result are never written.
+def _swiglu(gate, up):
+    """hidden, and what the backward pass reads of the two products."""
+    return jax.nn.silu(gate) * up, gate, up
 
-def _gmm(x, w, tile_expert, tiles_used, tm: int):
+
+def _swiglu_back(d_hidden, gate, up):
+    """d hidden -> (d gate, d up) of hidden = silu(gate) * up."""
+    s = jax.nn.sigmoid(gate)
+    return d_hidden * up * s * (1.0 + gate * (1.0 - s)), d_hidden * gate * s
+
+
+def _tiles_kernel(into, n_rows: int, n_seen: int, n_acc: int, epilogue,
+                  dims):
+    """The body of `_over_tiles`: weight p times rows operand `into[p][0]`
+    is added into accumulator `into[p][1]` a depth block at a time; at
+    the last, `epilogue(*accumulators, *seen blocks)` in float32 gives
+    the output blocks, as many of its values as there are outputs."""
+    from jax.experimental import pallas as pl
+
+    def kernel(te_ref, *refs):
+        rows, refs = refs[:n_rows], refs[n_rows:]
+        weights, refs = refs[:len(into)], refs[len(into):]
+        seen, refs = refs[:n_seen], refs[n_seen:]
+        outs, accs = refs[:-n_acc], refs[-n_acc:]
+        kk, n_k = pl.program_id(2), pl.num_programs(2)
+
+        @pl.when(kk == 0)
+        def _init():
+            for acc in accs:
+                acc[...] = jnp.zeros_like(acc)
+
+        sums = [None] * n_acc
+        for w_ref, (r, a) in zip(weights, into):
+            x = rows[r][...]
+            # the stored block (a float32 master) is rounded here, after
+            # its DMA: no copy of the weights in HBM
+            part = jax.lax.dot_general(
+                x, w_ref[0].astype(x.dtype), dims,
+                preferred_element_type=jnp.float32)
+            sums[a] = part if sums[a] is None else sums[a] + part
+        for acc, total in zip(accs, sums):
+            acc[...] += total
+
+        @pl.when(kk == n_k - 1)
+        def _emit():
+            values = epilogue(*(acc[...] for acc in accs),
+                              *(s[...].astype(jnp.float32) for s in seen))
+            for out, value in zip(outs, values):
+                out[...] = value.astype(out.dtype)
+
+    return kernel
+
+
+def _over_tiles(name: str, rows, weights, into, tile_expert, tiles_used,
+                tm: int, *, transposed=False, seen=(), epilogue=None,
+                n_out: int = 1):
+    """Grouped products over the tiles that hold a group, with what
+    follows them in the kernel's epilogue. rows: (M, K) arrays, every
+    `tm` rows of one expert; weights: (G, K, N) arrays as the parameters
+    store them, (G, N, K) contracted over their last axis with
+    `transposed`; `into[p]` = (the rows operand weight p multiplies, the
+    float32 accumulator its product is added into); seen: (M, N) arrays
+    the epilogue reads beside the accumulators. -> the first `n_out`
+    of the epilogue's values, (M, N) arrays in the rows' dtype. Column
+    blocks are the grid's outer axis and the tiles its middle one, so
+    an expert's consecutive tiles reuse a weight block that spans the
+    depth without another DMA. The tiles' axis is `tiles_used` long (a
+    grid bound read at run time): a tile behind the last group costs no
+    step, and its rows of the results are never written."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    m, k = x.shape
-    n = w.shape[2]
-    tk, tn = _tile(k, 1152), _tile(n, 1024)
-    n_j, n_k = n // tn, k // tk
+    m, k = rows[0].shape
+    w = weights[0]
+    n = w.shape[1] if transposed else w.shape[2]
+    tk = _tile(k, 2304)
+    tn = _tile(n, _WEIGHT_BLOCKS // (len(weights) * tk * w.dtype.itemsize))
+    n_acc = 1 + max(a for _, a in into)
 
-    def at(i, j, kk, used):
-        """(tile, column block, depth block) the grid step works on."""
-        skip = i >= used[0]
-        return (jnp.where(skip, used[0] - 1, i), jnp.where(skip, n_j - 1, j),
-                jnp.where(skip, n_k - 1, kk))
+    def w_map(j, i, kk, te):
+        return (te[i], j, kk) if transposed else (te[i], kk, j)
 
-    def x_map(i, j, kk, te, used):
-        i, _, kk = at(i, j, kk, used)
-        return i, kk
-
-    def w_map(i, j, kk, te, used):
-        i, j, kk = at(i, j, kk, used)
-        return te[i], kk, j
-
-    def o_map(i, j, kk, te, used):
-        i, j, _ = at(i, j, kk, used)
-        return i, j
-
+    outs = tuple(jax.ShapeDtypeStruct((m, n), rows[0].dtype)
+                 for _ in range(n_out))
     return pl.pallas_call(
-        _gmm_kernel, name="moe_gmm",
+        _tiles_kernel(
+            into, len(rows), len(seen), n_acc,
+            epilogue or (lambda acc: (acc,)),
+            (((1,), (1 if transposed else 0,)), ((), ()))),
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(m // tm, n_j, n_k),
-            in_specs=[pl.BlockSpec((tm, tk), x_map),
-                      pl.BlockSpec((1, tk, tn), w_map)],
-            out_specs=pl.BlockSpec((tm, tn), o_map),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+            num_scalar_prefetch=1, grid=(n // tn, tiles_used[0], k // tk),
+            in_specs=[pl.BlockSpec((tm, tk), lambda j, i, kk, te: (i, kk))
+                      for _ in rows]
+            + [pl.BlockSpec((1, tn, tk) if transposed else (1, tk, tn),
+                            w_map) for _ in weights]
+            + [pl.BlockSpec((tm, tn), lambda j, i, kk, te: (i, j))
+               for _ in seen],
+            out_specs=[pl.BlockSpec((tm, tn), lambda j, i, kk, te: (i, j))
+                       for _ in outs],
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)] * n_acc),
+        out_shape=outs,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=48 << 20),
+            vmem_limit_bytes=_VMEM),
         interpret=_interpret(),
-    )(tile_expert, tiles_used, x, w)
+    )(tile_expert, *rows, *weights, *seen)
+
+
+def _gmm(x, w, tile_expert, tiles_used, tm: int, transposed=False):
+    """x (M, K) times each tile's w[e] (K, N), or with `transposed` its
+    w[e] (N, K) over the last axis -> (M, N)."""
+    return _over_tiles("moe_gmm", [x], [w], [(0, 0)], tile_expert,
+                       tiles_used, tm, transposed=transposed)[0]
+
+
+def _tgmm_kernel(te_ref, x_ref, dy_ref, o_ref):
+    from jax.experimental import pallas as pl
+
+    m = pl.program_id(2)
+
+    @pl.when((m == 0) | (te_ref[jnp.maximum(m - 1, 0)] != te_ref[m]))
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    o_ref[0] += jax.lax.dot_general(
+        x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def _tgmm(x, dy, tile_expert, tiles_used, n_groups: int, tm: int):
+    """The weights' gradient: each group's x^T dy, (G, K, N) float32,
+    over the tiles that hold a group."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    m, k = x.shape
-    n = dy.shape[1]
-    tk, tn = _tile(k, 768), _tile(n, 1024)
-
-    def tile(mm, used):
-        return jnp.minimum(mm, used[0] - 1)
-
+    k, n = x.shape[1], dy.shape[1]
+    tk = _tile(k, 2304)
+    # the float32 block is written as well as read: half the budget
+    tn = _tile(n, _WEIGHT_BLOCKS // 2 // (tk * 4))
     return pl.pallas_call(
         _tgmm_kernel, name="moe_tgmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(k // tk, n // tn, m // tm),
-            in_specs=[
-                pl.BlockSpec((tm, tk),
-                             lambda kk, j, mm, te, used: (tile(mm, used), kk)),
-                pl.BlockSpec((tm, tn),
-                             lambda kk, j, mm, te, used: (tile(mm, used), j)),
-            ],
+            num_scalar_prefetch=1, grid=(k // tk, n // tn, tiles_used[0]),
+            in_specs=[pl.BlockSpec((tm, tk), lambda kk, j, mm, te: (mm, kk)),
+                      pl.BlockSpec((tm, tn), lambda kk, j, mm, te: (mm, j))],
             out_specs=pl.BlockSpec(
-                (1, tk, tn),
-                lambda kk, j, mm, te, used: (te[tile(mm, used)], kk, j))),
+                (1, tk, tn), lambda kk, j, mm, te: (te[mm], kk, j))),
         out_shape=jax.ShapeDtypeStruct((n_groups, k, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=48 << 20),
+            vmem_limit_bytes=_VMEM),
         interpret=_interpret(),
-    )(tile_expert, tiles_used, x, dy)
+    )(tile_expert, x, dy)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(4,))
 def grouped_matmul(x, w, tile_expert, tiles_used, tile_rows: int):
     """Rows sorted by expert, times their expert's matrix. x: (M, K),
     every `tile_rows` rows of one expert, padding rows zero; w: (G, K,
-    N); tile_expert: (M / tile_rows,) int32, not decreasing; tiles_used:
+    N), as stored: a block is rounded to x's dtype in the kernel;
+    tile_expert: (M / tile_rows,) int32, not decreasing; tiles_used:
     (1,) int32, the tiles that hold a group. The tiles behind them are
     passed over, so the product's time follows the groups and not the
     buffer, and **their rows of the result are undefined**: nothing may
@@ -498,12 +561,65 @@ def _gmm_fwd(x, w, tile_expert, tiles_used, tile_rows):
 
 def _gmm_bwd(tile_rows, res, dy):
     x, w, tile_expert, tiles_used = res
-    dx = _gmm(dy, jnp.swapaxes(w, 1, 2), tile_expert, tiles_used, tile_rows)
+    dx = _gmm(dy, w, tile_expert, tiles_used, tile_rows, transposed=True)
     dw = _tgmm(x, dy, tile_expert, tiles_used, w.shape[0], tile_rows)
     return dx, dw.astype(w.dtype), None, None
 
 
 grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _gate_up(rows, w_gate, w_up, tile_expert, tiles_used, tm, kept: bool):
+    """SwiGLU of the rows' two products in their kernel's epilogue ->
+    [hidden], and with `kept` gate and up beside it."""
+    return _over_tiles(
+        "moe_gmm_swiglu", [rows], [w_gate, w_up], [(0, 0), (0, 1)],
+        tile_expert, tiles_used, tm, epilogue=_swiglu,
+        n_out=3 if kept else 1)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def grouped_swiglu(rows, w_gate, w_up, w_down, tile_expert, tiles_used,
+                   tile_rows: int):
+    """The held experts' FFN on rows sorted by expert: `(silu(rows
+    W_gate,e) * (rows W_up,e)) W_down,e` a tile, as `grouped_matmul`
+    takes rows, tiles and weights (w_gate, w_up: (G, d, f), w_down: (G,
+    f, d), as stored) and leaves the rows behind `tiles_used`. Three
+    products forward and six backward, SwiGLU and its derivative in
+    their kernels' epilogues: nothing outside the kernels passes over
+    the buffer or the weights. hidden, gate and up are the rows' dtype;
+    the weights' gradients leave `moe_tgmm` in float32 and are returned
+    in the weights' dtype. -> (M, d) in the rows' dtype."""
+    tiles = tile_expert, tiles_used, tile_rows
+    hidden, = _gate_up(rows, w_gate, w_up, *tiles, kept=False)
+    return grouped_matmul(hidden, w_down, *tiles)
+
+
+def _swiglu_fwd(rows, w_gate, w_up, w_down, tile_expert, tiles_used,
+                tile_rows):
+    tiles = tile_expert, tiles_used, tile_rows
+    hidden, gate, up = _gate_up(rows, w_gate, w_up, *tiles, kept=True)
+    return (_gmm(hidden, w_down, *tiles),
+            (rows, w_gate, w_up, w_down, hidden, gate, up, tile_expert,
+             tiles_used))
+
+
+def _swiglu_bwd(tile_rows, res, dy):
+    rows, w_gate, w_up, w_down, hidden, gate, up, *tiles = res
+    n_groups = w_gate.shape[0]
+    d_gate, d_up = _over_tiles(
+        "moe_gmm_dswiglu", [dy], [w_down], [(0, 0)], *tiles, tile_rows,
+        transposed=True, seen=(gate, up), epilogue=_swiglu_back, n_out=2)
+    d_rows, = _over_tiles(
+        "moe_gmm", [d_gate, d_up], [w_gate, w_up], [(0, 0), (1, 0)],
+        *tiles, tile_rows, transposed=True)
+    dw = [_tgmm(x, g, *tiles, n_groups, tile_rows).astype(w.dtype)
+          for x, g, w in ((rows, d_gate, w_gate), (rows, d_up, w_up),
+                          (hidden, dy, w_down))]
+    return d_rows, *dw, None, None
+
+
+grouped_swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
 def _unwritten(shape, dtype):
@@ -664,16 +780,9 @@ def held_moe_ffn(params, x, cfg: HeldExperts, compute_dtype=jnp.bfloat16):
         plan = dispatch_plan(ids, cfg)
         rows = rows_from_tokens(xc, plan)
     with jax.named_scope("seq.moe.gmm"):
-        f = params["w_gate"].shape[2]
-        w_in = jnp.concatenate([params["w_gate"].astype(compute_dtype),
-                                params["w_up"].astype(compute_dtype)], axis=2)
-        tiles = plan["tile_expert"], plan["tiles_used"]
-        gate_up = grouped_matmul(rows, w_in, *tiles, tm)
-        gate = gate_up[:, :f].astype(jnp.float32)
-        hidden = (jax.nn.silu(gate) * gate_up[:, f:].astype(jnp.float32)
-                  ).astype(compute_dtype)
-        out_rows = grouped_matmul(
-            hidden, params["w_down"].astype(compute_dtype), *tiles, tm)
+        out_rows = grouped_swiglu(
+            rows, params["w_gate"], params["w_up"], params["w_down"],
+            plan["tile_expert"], plan["tiles_used"], tm)
     with jax.named_scope("seq.moe.combine"):
         y = tokens_from_rows(out_rows, weights, plan)
     aux = {"counts": plan["counts"], "dropped": plan["dropped"]}

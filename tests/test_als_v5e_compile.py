@@ -1,7 +1,7 @@
-"""The packed-A kernels of the ALS solve and the banded attention's two
-kernels, compiled by the TPU's compiler for a described v5e at the
-benchmark's widths: what Mosaic refuses (a
-slice off the tiling, a stack over the 16 MB of scoped VMEM) shows here,
+"""The packed-A kernels of the ALS solve, the banded attention's two
+kernels and the held experts' grouped kernels, compiled by the TPU's
+compiler for a described v5e at the benchmark's widths: what Mosaic
+refuses (a slice off the tiling, a stack over the scoped VMEM) shows here,
 on a CPU, at no chip time. Nothing runs: these are compiles, not
 measurements. The topology is described inside a fixture, in this one
 file, so that only the worker that is given the file loads the TPU's
@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from pio_tpu.ops import als_pallas, attention
+from pio_tpu.ops import als_pallas, attention, moe
 
 ML20M_USERS, ML20M_ITEMS, MSD_ITEM_BLOCK = 138_493, 26_744, 96_137
 
@@ -104,3 +104,40 @@ def test_banded_attention_gradient_compiles_for_v5e(one_chip, case):
     assert text.count("tpu_custom_call") >= 2
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
     assert "flash_attention_dq" not in text
+
+
+# (rows of the sorted buffer a history, hidden, expert width, held
+# experts): the expert layers of the two sequence cells that route
+EXPERTS = {"mellum2": (73_728, 2304, 896, 16), "glm": (36_864, 2048, 1536, 8)}
+
+
+@pytest.mark.parametrize("case", sorted(EXPERTS))
+def test_grouped_swiglu_gradient_compiles_for_v5e(one_chip, case, monkeypatch):
+    """The fused expert op's seven kernels at the cells' widths, rows in
+    bfloat16 and the weights the float32 masters: blocks that span the
+    depth, their rounded copies and the accumulators pass Mosaic inside
+    the VMEM the kernels ask for, the products that contract over the
+    weights' last axis among them, and no copy of a weight is made
+    outside the kernels."""
+    m, d, f, g = EXPERTS[case]
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+
+    def shape(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def gradient(rows, w_gate, w_up, w_down, te, used, ct):
+        out, back = jax.vjp(
+            lambda *a: moe.grouped_swiglu(*a, te, used, 512),
+            rows, w_gate, w_up, w_down)
+        return out, back(ct)
+
+    rows = shape((m, d), jnp.bfloat16)
+    text = jax.jit(gradient).lower(
+        rows, shape((g, d, f), jnp.float32), shape((g, d, f), jnp.float32),
+        shape((g, f, d), jnp.float32), shape((m // 512,), jnp.int32),
+        shape((1,), jnp.int32), rows).compile().as_text()
+    assert text.count("tpu_custom_call") == 7
+    for name in ("moe_gmm_swiglu", "moe_gmm_dswiglu", "moe_gmm", "moe_tgmm"):
+        assert name in text
+    for copied in (f"bf16[{g},{d},{f}]", f"bf16[{g},{f},{d}]"):
+        assert copied not in text

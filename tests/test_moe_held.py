@@ -12,6 +12,7 @@ from pio_tpu.ops.moe import (
     HeldExperts,
     dispatch_plan,
     grouped_matmul,
+    grouped_swiglu,
     held_moe_ffn,
     route_top_k,
 )
@@ -237,12 +238,29 @@ def test_the_return_reads_the_front_or_the_whole_buffer(routing, monkeypatch):
     _equals_the_reference(routing, 8)
 
 
-def _moves(jaxpr, inside_loop=False):
-    """(primitive, entries moved, their width, inside a loop) of every
-    gather and scatter of a jaxpr, through every jaxpr its equations
-    hold."""
-    found = []
+def _held(eqn):
+    """The jaxprs an equation holds (a call's, a loop's, a kernel's)."""
+    subs = [getattr(sub, "jaxpr", sub) for val in eqn.params.values()
+            for sub in (val if isinstance(val, (list, tuple)) else [val])]
+    return [sub for sub in subs if hasattr(sub, "eqns")]
+
+
+def _equations(jaxpr, inside_loop=False):
+    """(equation, inside a loop) of a jaxpr and of every jaxpr its
+    equations hold, but a Pallas kernel's body."""
     for eqn in jaxpr.eqns:
+        yield eqn, inside_loop
+        if eqn.primitive.name != "pallas_call":
+            for sub in _held(eqn):
+                yield from _equations(
+                    sub, inside_loop or eqn.primitive.name == "while")
+
+
+def _moves(jaxpr):
+    """(primitive, entries moved, their width, inside a loop) of every
+    gather and scatter."""
+    found = []
+    for eqn, inside_loop in _equations(jaxpr):
         name = eqn.primitive.name
         if name == "gather" or name.startswith("scatter"):
             indices = eqn.invars[1].aval.shape
@@ -250,11 +268,6 @@ def _moves(jaxpr, inside_loop=False):
             entries = int(np.prod(indices[:-1]))
             found.append((name, entries, moved.aval.size // entries,
                           inside_loop))
-        for val in eqn.params.values():
-            for sub in val if isinstance(val, (list, tuple)) else [val]:
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    found += _moves(sub, inside_loop or name == "while")
     return found
 
 
@@ -288,9 +301,51 @@ def test_nothing_is_scattered_and_no_scalar_gathered_a_row_or_choice(score):
     assert long == [("gather", T * K, D, False)] * 4, long
 
 
+def test_nothing_outside_the_kernels_passes_over_the_buffer_or_the_weights():
+    """What PR 43 took into the kernels, at the timed precision
+    (bfloat16 products, float32 masters), forward and backward: outside
+    the Pallas calls no equation reads or writes an (M, f) or (M, 2f)
+    array (SwiGLU, its derivative, a slice or a pad of gate | up), and
+    none converts, concatenates or transposes a (G, d, f)-shaped weight
+    or weight gradient: the kernels take the weights as they are stored
+    and their gradients leave `moe_tgmm` in float32."""
+    full, x, w = _layer(seed=6)
+    cfg = HeldExperts(E, K, (2, 5), True, 8)
+    m, g, f = cfg.row_capacity(T), cfg.n_held, 24   # f, 2 f: not d
+    params = {"router": full["router"], "w_gate": jnp.ones((g, D, f)),
+              "w_up": jnp.ones((g, D, f)), "w_down": jnp.ones((g, f, D))}
+
+    def loss(p, x):
+        return jnp.sum(held_moe_ffn(p, x, cfg, jnp.bfloat16)[0] * w)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, x).jaxpr
+    hidden = {(m, f), (m, 2 * f)}
+    weights = {(g, D, f), (g, f, D), (g, D, 2 * f), (g, 2 * f, D)}
+    kernels, over_buffer, over_weights = 0, [], []
+    for eqn, _ in _equations(jaxpr):
+        name = eqn.primitive.name
+        kernels += name == "pallas_call"
+        shapes = {v.aval.shape for v in (*eqn.invars, *eqn.outvars)
+                  if hasattr(v.aval, "shape")}
+        if not _held(eqn) and shapes & hidden:     # a call passes it on
+            over_buffer.append(name)
+        if (name in ("convert_element_type", "concatenate", "transpose")
+                and shapes & weights):
+            over_weights.append(name)
+    assert kernels == 7         # gate | up and down; d hidden, dx, 3 dw
+    assert not over_buffer, over_buffer
+    assert not over_weights, over_weights
+    grads = jax.eval_shape(jax.grad(loss), params, x)
+    assert all(grads[n].dtype == jnp.float32
+               for n in ("w_gate", "w_up", "w_down"))
+
+
 def test_rows_behind_tiles_used_are_never_read(monkeypatch):
-    """The buffers start as NaN instead of zeros: whatever the tiles
-    behind the last group hold reaches neither output nor gradients."""
+    """The buffers start as NaN instead of zeros, and every array a
+    grouped kernel writes (hidden, gate, up and the rows of the down
+    product; d gate, d up and dx backward) is NaN behind the last
+    group: whatever the tiles there hold reaches neither output nor
+    gradients."""
     from pio_tpu.ops import moe
 
     full, x, w = _layer(seed=7)
@@ -300,29 +355,40 @@ def test_rows_behind_tiles_used_are_never_read(monkeypatch):
             lambda f, x: jnp.sum(_part(f, x, 2, 5)[0] * w), (0, 1))(full, x)
 
     want = run()
-    made = []
+    made, written = [], []
 
     def nans(shape, dtype):
         made.append(shape)
         return jnp.full(shape, jnp.nan, dtype)
 
+    over_tiles = moe._over_tiles
+
+    def nans_behind(name, rows, weights, into, tile_expert, tiles_used, tm,
+                    **how):
+        outs = over_tiles(name, rows, weights, into, tile_expert, tiles_used,
+                          tm, **how)
+        written.extend((name, o.shape) for o in outs)
+        behind = jnp.arange(outs[0].shape[0])[:, None] >= tiles_used[0] * tm
+        return [jnp.where(behind, jnp.nan, o) for o in outs]
+
     monkeypatch.setattr(moe, "_unwritten", nans)
+    monkeypatch.setattr(moe, "_over_tiles", nans_behind)
     got = run()
     cfg = HeldExperts(E, K, (2, 5), True, 8)
-    assert made and all(s[0] == cfg.row_capacity(T) for s in made)
+    m = cfg.row_capacity(T)
+    assert made and all(s[0] == m for s in made)
+    assert sorted(written) == sorted(
+        [("moe_gmm_swiglu", (m, F))] * 3 + [("moe_gmm", (m, D))] * 2
+        + [("moe_gmm_dswiglu", (m, F))] * 2)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.parametrize("sizes", [[8, 8, 8], [3, 0, 13], [0, 0, 24],
-                                   [1, 1, 1]])
-def test_grouped_matmul_ragged_groups(sizes):
-    """Rows of group g times matrix g, groups of any size (an empty one
-    still owns a tile of zeros), forward and both gradients. Two tiles
-    follow the last group: the kernels pass over them, whatever they
-    hold, and their rows of the results are undefined."""
-    tm, k, n = 8, 12, 10
+def _ragged(sizes, tm, k, dtype=jnp.float32):
+    """Rows of `sizes` groups padded to tiles of `tm`, two tiles behind
+    the last group that hold NaN -> (x (M, k), tile_expert, tiles_used,
+    the live rows, their experts)."""
     rng = np.random.default_rng(sum(sizes))
     padded = [max(tm, -(-s // tm) * tm) for s in sizes]
     tiles = sum(padded) // tm + 2              # two tiles past the last row
@@ -335,21 +401,33 @@ def test_grouped_matmul_ragged_groups(sizes):
         at += p
     n_active = len(expert)
     expert += [expert[-1]] * 2
-    w = jnp.asarray(rng.normal(size=(len(sizes), k, n)), jnp.float32)
     x[n_active * tm:] = np.nan                 # never read
-    x = jnp.asarray(x)
-    te = jnp.asarray(expert, jnp.int32)
-    used = jnp.asarray([n_active], jnp.int32)
-    live = slice(0, n_active * tm)
+    return (jnp.asarray(x, dtype), jnp.asarray(expert, jnp.int32),
+            jnp.asarray([n_active], jnp.int32), slice(0, n_active * tm),
+            np.repeat(expert[:n_active], tm))
+
+
+RAGGED = [[8, 8, 8], [3, 0, 13], [0, 0, 24], [1, 1, 1]]
+
+
+@pytest.mark.parametrize("sizes", RAGGED)
+def test_grouped_matmul_ragged_groups(sizes):
+    """Rows of group g times matrix g, groups of any size (an empty one
+    still owns a tile of zeros), forward and both gradients. Two tiles
+    follow the last group: the kernels pass over them, whatever they
+    hold, and their rows of the results are undefined."""
+    tm, k, n = 8, 12, 10
+    x, te, used, live, of_row = _ragged(sizes, tm, k)
+    rng = np.random.default_rng(sum(sizes) + 1)
+    w = jnp.asarray(rng.normal(size=(len(sizes), k, n)), jnp.float32)
 
     def plain(x, w):
-        return jnp.einsum("mk,mkn->mn", x[live],
-                          w[np.repeat(expert[:n_active], tm)])
+        return jnp.einsum("mk,mkn->mn", x[live], w[of_row])
 
     got = grouped_matmul(x, w, te, used, tm)
     np.testing.assert_allclose(got[live], plain(x, w), atol=1e-5, rtol=1e-5)
     cot = rng.normal(size=got.shape)
-    cot[n_active * tm:] = np.nan               # never read either
+    cot[live.stop:] = np.nan                   # never read either
     cot = jnp.asarray(cot, jnp.float32)
     gx, gw = jax.vjp(lambda x, w: grouped_matmul(x, w, te, used, tm),
                      x, w)[1](cot)
@@ -357,3 +435,73 @@ def test_grouped_matmul_ragged_groups(sizes):
                       (0, 1))(x, w)
     np.testing.assert_allclose(gx[live], rx[live], atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(gw, rw, atol=1e-5, rtol=1e-5)
+
+
+def _plain_swiglu(x, w_gate, w_up, w_down, of_row):
+    """The fused op's mathematics a row at a time, in the operands'
+    own precision."""
+    gate = jnp.einsum("mk,mkn->mn", x, w_gate[of_row])
+    up = jnp.einsum("mk,mkn->mn", x, w_up[of_row])
+    return jnp.einsum("mk,mkn->mn", jax.nn.silu(gate) * up, w_down[of_row])
+
+
+@pytest.mark.parametrize("tm", [8, 16])
+@pytest.mark.parametrize("sizes", RAGGED)
+def test_grouped_swiglu_ragged_groups(sizes, tm):
+    """The fused op against the float32 mathematics: the output and all
+    four gradients, groups of any size, NaN in the rows and in the
+    cotangent behind the last group."""
+    d, f = 12, 10
+    x, te, used, live, of_row = _ragged(sizes, tm, d)
+    rng = np.random.default_rng(sum(sizes) + 2)
+    ws = [jnp.asarray(rng.normal(size=shape) * 0.3, jnp.float32)
+          for shape in ((len(sizes), d, f), (len(sizes), d, f),
+                        (len(sizes), f, d))]
+    got, back = jax.vjp(
+        lambda x, *ws: grouped_swiglu(x, *ws, te, used, tm), x, *ws)
+    np.testing.assert_allclose(
+        got[live], _plain_swiglu(x[live], *ws, of_row), atol=1e-5, rtol=1e-5)
+    cot = rng.normal(size=got.shape)
+    cot[live.stop:] = np.nan
+    cot = jnp.asarray(cot, jnp.float32)
+    want = jax.grad(lambda x, *ws: jnp.sum(
+        _plain_swiglu(x[live], *ws, of_row) * cot[live]), (0, 1, 2, 3))(
+            x, *ws)
+    for name, a, b in zip(("x", "w_gate", "w_up", "w_down"), back(cot),
+                          want):
+        if name == "x":
+            a, b = a[live], b[live]
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5, err_msg=name)
+
+
+def test_grouped_swiglu_rounds_the_stored_weights_in_the_kernel():
+    """bfloat16 rows and float32 masters, the timed precision: the
+    kernels round a weight block after its DMA, so the results are
+    those of weights rounded beforehand (products of bfloat16 operands,
+    float32 sums, hidden rounded once), and the weights' gradients come
+    back in float32 as `moe_tgmm` summed them, never rounded to
+    bfloat16 on the way."""
+    tm, d, f = 16, 12, 10
+    x, te, used, live, of_row = _ragged([20, 5, 0], tm, d, jnp.bfloat16)
+    rng = np.random.default_rng(3)
+    ws = [jnp.asarray(rng.normal(size=shape) * 0.3, jnp.float32)
+          for shape in ((3, d, f), (3, d, f), (3, f, d))]
+    rounded = [w.astype(jnp.bfloat16) for w in ws]
+    got, back = jax.vjp(
+        lambda x, *ws: grouped_swiglu(x, *ws, te, used, tm), x, *ws)
+    want, back_rounded = jax.vjp(
+        lambda x, *ws: grouped_swiglu(x, *ws, te, used, tm), x, *rounded)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(got[live], want[live])
+    np.testing.assert_allclose(
+        got[live].astype(jnp.float32),
+        _plain_swiglu(x[live].astype(jnp.float32),
+                      *(w.astype(jnp.float32) for w in rounded), of_row),
+        atol=0.03, rtol=0.03)
+    cot = jnp.asarray(rng.normal(size=got.shape), jnp.bfloat16)
+    grads, grads_rounded = back(cot), back_rounded(cot)
+    np.testing.assert_array_equal(grads[0][live], grads_rounded[0][live])
+    for g, r in zip(grads[1:], grads_rounded[1:]):
+        assert g.dtype == jnp.float32 and r.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(g.astype(jnp.bfloat16), r)
+        assert float(jnp.max(jnp.abs(g - r.astype(jnp.float32)))) > 0.0

@@ -173,6 +173,19 @@ def test_nested_operations_count_once_and_unknown_ones_are_unscoped():
     assert per["by_rule"]["neighbour"] == pytest.approx(0.5)
 
 
+def test_the_operations_behind_a_scopes_seconds():
+    """`--ops`: what `reduce` sums under one scope, an instruction a
+    row, with how it came by the scope; a scope is matched as a whole
+    element of the path."""
+    rows = profile.operations(_two_job_profile(), "als.flush")
+    assert [(r["op"].split(" = ")[0], r["rule"], r["calls"]) for r in rows] \
+        == [("%custom-call.2", "own", 2), ("%copy.9", "neighbour", 2)]
+    assert [r["s"] for r in rows] == pytest.approx([2 * 0.499, 2 * 0.25])
+    assert sum(r["s"] for r in profile.operations(
+        _two_job_profile(), "als.user")) == pytest.approx(2 * 2.999)
+    assert profile.operations(_two_job_profile(), "als.flu") == []
+
+
 def test_a_gap_is_split_between_persist_and_the_next_jobs_partition():
     """The gap from job one's last operation (6 s) to job two's first
     (13 s) holds persist AND the next job's setup and partition. Its

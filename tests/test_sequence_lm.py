@@ -305,8 +305,9 @@ def test_profile_joins_the_stacks_scopes(op_name, scope):
 # -- what PR 33 added to the stack leaves the older configuration alone ------
 # (the two hashes below were commit 2ef95e1's until PR 34 changed the expert
 # layer's moves on purpose, PR 34's until PR 40 let the attention
-# forward's o and lse cross the checkpoint, and PR 40's until PR 42 made the
-# attention's backward pass one kernel; they pin PR 42's text the same way)
+# forward's o and lse cross the checkpoint, PR 40's until PR 42 made the
+# attention's backward pass one kernel, and PR 42's until PR 43 made the
+# held experts' FFN one fused grouped op; they pin PR 43's text the same way)
 
 def _step_lowered(cfg, learning_rate, batch):
     spec = seq_blocks.BlockSpec.parse(cfg)
@@ -328,19 +329,19 @@ def _sha(text):
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-def test_the_window_and_full_stacks_step_program_is_pr_42s():
+def test_the_window_and_full_stacks_step_program_is_pr_43s():
     """Latent attention, the dense layer, the shared expert, the sigmoid
     router and the prediction module are chosen by the specification: a
     specification without them lowers to one program text whatever a
-    later specification's keys add (the hash is of PR 42's text, at this
+    later specification's keys add (the hash is of PR 43's text, at this
     file's small blocks)."""
     assert _sha(_step_text(CFG, 0.0625, (2, 41))) == (
-        "b588116ee9acf9bbf6bf8207991c949919173e02f2d1b5882c33da6bb67a528e")
+        "86acbd4439f8a3aed69b748da0e1e6ea6f67f4655ae505ecf0d5e5b96dc8c2cc")
 
 
-def test_mellum2_12b_ep4s_step_program_is_pr_42s(monkeypatch):
+def test_mellum2_12b_ep4s_step_program_is_pr_43s(monkeypatch):
     """The benchmark's configuration at its timed shapes and the
-    program's own blocks: PR 42's text, by hash."""
+    program's own blocks: PR 43's text, by hash."""
     import json
     import os
 
@@ -352,7 +353,7 @@ def test_mellum2_12b_ep4s_step_program_is_pr_42s(monkeypatch):
     with open(path) as f:
         cfg = es.block_spec_of(json.load(f))
     assert _sha(_step_text(cfg, 1e-4, (2, 8193))) == (
-        "902c3a2e64ba8ac49a3cfc30d7f659931234faec95095864cd3b4aad5a77b79a")
+        "614a3cb1794f1941a29900433a5ff155ef640d17060cb5fa25d75d485304ae2a")
 
 
 def test_the_repeated_scope_rule_moves_no_path_of_the_older_stack():
