@@ -3,11 +3,11 @@
 
 PY ?= python
 
-.PHONY: lint lint-deep test check bench-smoke
+.PHONY: lint lint-deep test check
 
 lint:
-	$(PY) -m pio_tpu.tools.cli lint pio_tpu/ tests/ bench.py eval/ examples/
-	$(PY) -m compileall -q pio_tpu tests eval examples bench.py
+	$(PY) -m pio_tpu.tools.cli lint pio_tpu/ tests/ eval/ examples/
+	$(PY) -m compileall -q pio_tpu tests eval examples
 
 # whole-program tier (docs/lint.md "Deep analysis"): lock-order cycles,
 # blocking-under-lock, context-loss, route-contract drift. Fails on any
@@ -21,10 +21,4 @@ test:
 	env JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow' \
 		--continue-on-collection-errors -p no:cacheprovider
 
-# CPU-stable perf gate: ingest events/s + serving p50 vs BASELINE.json
-# published.smoke, +-20% (PIO_SMOKE_TOL). Regressions exit 1.
-# Refresh the baseline with: python bench.py --smoke --update-baseline
-bench-smoke:
-	$(PY) bench.py --smoke
-
-check: lint lint-deep test bench-smoke
+check: lint lint-deep test

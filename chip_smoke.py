@@ -56,7 +56,8 @@ import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# MovieLens-20M catalog and the bench.py ALS parameters (bench_params)
+# MovieLens-20M catalog; ALS_PARAMS below are the parameters of the cell
+# ml20m-r64.train-coo: rank 64, lambda 0.05, alpha 10, implicit
 N_USERS, N_ITEMS, RANK = 138_493, 26_744, 64
 FULL_EVENTS = 20_000_000
 DRY_USERS, DRY_ITEMS, DRY_EVENTS = 2_000, 500, 20_000   # --cpu-dry-run

@@ -1,17 +1,15 @@
 """A/B bench for the ALS normal-equation accumulation strategies.
 
-The round-2 profile put the per-sweep cost far above the kernel's own
-roofline (~0.35% MFU); the suspect is the (n,k,k) accumulator carried
-through the chunk scan (ops/als.py accum="carry"), which re-streams
-~2.3 GB per chunk at the ML-20M shape if the backend materializes the
-carry. This script times each {accum mode x chunk_slots} cell on the
-CURRENT backend and prints one JSON line per cell plus a "best" line,
-so the winner can be pinned as the ALSParams default with a committed
-artifact (eval/ALS_ACCUM_BENCH.json).
+Times `als_train` under each {accum mode x chunk_slots} cell on the
+CURRENT backend, in one process, and prints one JSON line per cell plus
+a "best" line. Kept for ROADMAP S1d: it is the one tool that runs
+`accum="stream"` (the overlapped segment flush, reached by tests only)
+beside `accum="hybrid"` (what `auto` runs on the chip) on the same data.
+A winner here is a candidate for a benchmark run, not a result.
 
 Usage:
-  python eval/als_accum_bench.py [--small] [--out PATH]
-  PIO_BENCH_PLATFORM=cpu python eval/als_accum_bench.py --small
+  chiprun -- python eval/als_accum_bench.py [--out PATH]
+  JAX_PLATFORMS=cpu python eval/als_accum_bench.py --small
 """
 
 from __future__ import annotations
@@ -21,15 +19,9 @@ import os
 import sys
 import time
 
-if os.environ.get("PIO_BENCH_PLATFORM") == "cpu":
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_num_cpu_devices", 1)
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -37,7 +29,7 @@ from pio_tpu.ops.als import ALSParams, als_train  # noqa: E402
 
 SMALL = "--small" in sys.argv
 
-# ML-20M shape (BASELINE.md) unless --small
+# ML-20M shape unless --small
 N_USERS = 5_000 if SMALL else 138_493
 N_ITEMS = 1_000 if SMALL else 26_744
 NNZ = 200_000 if SMALL else 20_000_000
@@ -45,7 +37,7 @@ RANK = 16 if SMALL else 64
 SWEEPS = 2 if SMALL else 6
 
 CELLS = [
-    {"accum": "carry", "chunk_slots": 8192},     # round-2 configuration
+    {"accum": "carry", "chunk_slots": 8192},
     {"accum": "carry", "chunk_slots": 32768},    # fewer carries
     {"accum": "stacked", "chunk_slots": 8192},
     {"accum": "stacked", "chunk_slots": 32768},
@@ -121,10 +113,7 @@ def main() -> None:
 
     ok = [r for r in results if "error" not in r]
     best = min(ok, key=lambda r: r["wall_sec"]) if ok else None
-    from pio_tpu.utils.tpu_health import telemetry
-
     summary = {
-        "transport": telemetry(),
         "device_kind": dev.device_kind,
         "platform": dev.platform,
         "shape": {"n_users": N_USERS, "n_items": N_ITEMS, "nnz": NNZ,

@@ -1,9 +1,10 @@
 """Kernel lab: on-TPU A/B of ALS normal-equation + CG matvec variants.
 
-The round-3 phase profile (eval/ALS_PHASE_PROFILE.json) put the sweep at
-~0.50 s: ne build 0.33 s (gather 0.08 + MXU blocks 0.13 per users half)
-and CG16 0.17 s.  This script measures candidate kernels in isolation at
-the full ML-20M shape so the production knobs are set by data:
+PERF.md section 5 has where a sweep's time goes inside the train program
+(`sweep_*_device_s` of a traced benchmark run). This script measures
+candidate kernels in isolation at the full ML-20M shape; an isolated XLA
+pass times 3-6 x off what it takes inside the program (PERF.md section
+6), so a winner here is a candidate for a benchmark run, not a result:
 
   blocks.high       current: f32 upcast + Precision.HIGH (3-pass bf16)
   blocks.sqrtw      ys = y * sqrt(w) in bf16, A = ys^T ys, 1 MXU pass,
@@ -34,14 +35,9 @@ import sys
 import time
 from functools import partial
 
-if os.environ.get("PIO_BENCH_PLATFORM") == "cpu":
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

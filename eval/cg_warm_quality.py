@@ -1,7 +1,7 @@
 """Quality evidence for the warm-sweep CG schedule (ALSParams.cg_warm_iters).
 
 The schedule cuts the sweep's dominant at-peak traffic term (CG matvecs)
-by running full-strength CG only while cold (eval/ALS_ROOFLINE.md). This
+by running full-strength CG only while cold. This
 script commits the quality side of that trade as an artifact:
 
   explicit:  heldout RMSE on structured synthetic ratings (mean + user/
@@ -89,9 +89,6 @@ def main() -> None:
         out["implicit"].append(row)
         print(json.dumps(row), flush=True)
 
-    from pio_tpu.utils.tpu_health import telemetry
-
-    out["transport"] = telemetry()
     if "--out" in sys.argv:
         with open(sys.argv[sys.argv.index("--out") + 1], "w") as f:
             json.dump(out, f, indent=1)

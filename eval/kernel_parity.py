@@ -65,8 +65,11 @@ def main() -> int:
                             rng.zipf(1.2, NNZ - N_USERS) % N_ITEMS])
     vals = rng.integers(1, 6, NNZ).astype(np.float32)
     p = als.ALSParams(rank=RANK, chunk=8192)
-    lay = als.als_build_layouts(users, items, vals, N_USERS, N_ITEMS, p)
-    by_user, by_item, cs = lay.by_user, lay.by_item, lay.cs
+    u, i, v = als._prep_coo(users, items, vals, N_USERS, N_ITEMS, p)
+    by_user, by_item, cs = jax.jit(
+        als._build_layouts, static_argnames=("n_users", "n_items", "params")
+    )(u, i, v, n_users=N_USERS, n_items=N_ITEMS, params=p)
+    cs = int(cs)    # the chunk size is static in `_solve_factors`
     ku, ki = jax.random.split(jax.random.PRNGKey(0))
     fac_u = als.init_factors(N_USERS, RANK, ku)
     fac_i = als.init_factors(N_ITEMS, RANK, ki)

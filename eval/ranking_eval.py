@@ -26,7 +26,6 @@ import gzip
 import json
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -101,13 +100,11 @@ def main() -> int:
           flush=True)
 
     # -- the real evaluation (pio eval code path) ---------------------------
-    t0 = time.monotonic()
     best_path = os.path.join(here, "best.json")
     instance_id, result = run_evaluation_class(
         QuickstartEval, QuickstartParams, storage,
         output_path=best_path, ctx=ctx,
     )
-    eval_sec = time.monotonic() - t0
 
     rows = [
         {
@@ -121,10 +118,7 @@ def main() -> int:
     import jax
 
     device = jax.devices()[0]
-    from pio_tpu.utils.tpu_health import telemetry
-
     out = {
-        "transport": telemetry(),
         "dataset": "examples/quickstart/events.jsonl.gz",
         "events": ok,
         "folds": FOLDS,
@@ -134,7 +128,6 @@ def main() -> int:
         "popularity_baseline": round(pop_baseline, 5),
         "beats_popularity": best_score > pop_baseline,
         "evaluation_instance": instance_id,
-        "eval_sec": round(eval_sec, 1),
         "platform": device.platform,
         "device_kind": device.device_kind,
     }

@@ -1,7 +1,7 @@
 """Model-quality evidence: ALS vs trivial baselines + CG/Cholesky parity.
 
-Supports the project north star ("≥10x vs Spark-CPU **at equal RMSE**",
-BASELINE.md) with two claims the bench's speed numbers rest on:
+Supports the project north star ("≥10x vs Spark-CPU **at equal RMSE**")
+with two claims every measured speed rests on:
 
  1. ABSOLUTE quality: the shipped ALS clearly beats the global-mean
     predictor (and the stronger per-user/per-item bias baseline) on
@@ -39,7 +39,6 @@ import argparse
 import json
 import os
 import sys
-import time
 
 import numpy as np
 
@@ -98,48 +97,26 @@ def bias_baseline_rmse(tr_u, tr_i, tr_v, te_u, te_i, te_v,
 def train_eval(users, items, vals, te_users, te_items, te_vals,
                n_users, n_items, reg, cg_iters, chunk, sweeps,
                trajectory=False):
-    """-> (heldout RMSE list if trajectory else final-only list,
-    train seconds)."""
-    import jax.numpy as jnp
+    """-> heldout RMSE after every sweep if trajectory, else after the
+    last one alone."""
+    from pio_tpu.ops.als import ALSParams, als_train, rmse
 
-    from pio_tpu.ops.als import ALSParams, als_build_layouts, als_train, rmse
-
-    out = []
-    train_sec = 0.0
     # cg_warm_iters=-1 in BOTH modes: trajectory mode re-enters
     # als_train with iterations=1, which would otherwise never leave the
     # full-strength phase of the warm-CG schedule (the schedule keys on
     # the per-call sweep index) while one-shot mode would — the parity
     # comparison must run one solver
-    if trajectory:
-        p = ALSParams(rank=RANK, iterations=1, reg=reg, chunk=chunk,
-                      cg_iters=cg_iters, cg_warm_iters=-1)
-        # build the slot layouts ON DEVICE once; per-sweep calls reuse
-        # them (ops/als.py ALSLayouts) instead of rebuilding per call —
-        # the round-3 trajectory runs paid the build every sweep
-        t0 = time.monotonic()
-        lay = als_build_layouts(users, items, vals, n_users, n_items, p)
-        float(jnp.sum(lay.by_user[3]))
-        train_sec += time.monotonic() - t0
-        model = None
-        for _ in range(sweeps):
-            t0 = time.monotonic()
-            model = als_train(users, items, vals, n_users, n_items, p,
-                              init=model, layouts=lay)
-            # a scalar readback ends the timed region
-            float(jnp.sum(model.user_factors))
-            train_sec += time.monotonic() - t0
-            out.append(round(float(
-                rmse(model, te_users, te_items, te_vals)), 5))
-    else:
-        p = ALSParams(rank=RANK, iterations=sweeps, reg=reg, chunk=chunk,
-                      cg_iters=cg_iters, cg_warm_iters=-1)
-        t0 = time.monotonic()
-        model = als_train(users, items, vals, n_users, n_items, p)
-        float(jnp.sum(model.user_factors))
-        train_sec = time.monotonic() - t0
-        out.append(round(float(rmse(model, te_users, te_items, te_vals)), 5))
-    return out, train_sec
+    calls, per_call = (sweeps, 1) if trajectory else (1, sweeps)
+    p = ALSParams(rank=RANK, iterations=per_call, reg=reg, chunk=chunk,
+                  cg_iters=cg_iters, cg_warm_iters=-1)
+    out = []
+    model = None
+    for _ in range(calls):
+        model = als_train(users, items, vals, n_users, n_items, p,
+                          init=model)
+        out.append(round(float(
+            rmse(model, te_users, te_items, te_vals)), 5))
+    return out
 
 
 def main() -> int:
@@ -184,11 +161,10 @@ def main() -> int:
     print(f"reg sweep ({solver_label}, {TUNE_SWEEPS} sweeps):", flush=True)
     sweep_rows = []
     for reg in REGS:
-        (v_rmse,), sec = train_eval(
+        (v_rmse,) = train_eval(
             tr_u, tr_i, tr_v, va_u, va_i, va_v, n_users, n_items,
             reg, -1, chunk, TUNE_SWEEPS)
-        sweep_rows.append({"reg": reg, "val_rmse": v_rmse,
-                           "train_sec": round(sec, 2)})
+        sweep_rows.append({"reg": reg, "val_rmse": v_rmse})
         print(f"  reg={reg}: val RMSE {v_rmse:.5f}", flush=True)
     best = min(sweep_rows, key=lambda r: r["val_rmse"])
     reg = best["reg"]
@@ -196,13 +172,13 @@ def main() -> int:
 
     # -- trajectories at the tuned reg --------------------------------------
     print("auto-solver trajectory:", flush=True)
-    cg_traj, cg_sec = train_eval(
+    cg_traj = train_eval(
         tr_u, tr_i, tr_v, te_u, te_i, te_v, n_users, n_items,
         reg, -1, chunk, SWEEPS, trajectory=True)
     for s, r in enumerate(cg_traj):
         print(f"  sweep {s + 1:2d}: heldout RMSE {r:.5f}", flush=True)
     print("direct-Cholesky trajectory:", flush=True)
-    ch_traj, ch_sec = train_eval(
+    ch_traj = train_eval(
         tr_u, tr_i, tr_v, te_u, te_i, te_v, n_users, n_items,
         reg, 0, chunk, SWEEPS, trajectory=True)
     for s, r in enumerate(ch_traj):
@@ -219,10 +195,8 @@ def main() -> int:
 
     p_sel = ALSParams(rank=RANK, iterations=SWEEPS, reg=reg, chunk=chunk,
                       cg_iters=-1)
-    t0 = time.monotonic()
     model_sel, valinfo = als_train_validated(
         tr_u, tr_i, tr_v, n_users, n_items, p_sel, va_u, va_i, va_v)
-    sel_sec = time.monotonic() - t0
     sel_test = round(float(als_rmse(model_sel, te_u, te_i, te_v)), 5)
     print(f"  val curve: {valinfo.curve}", flush=True)
     print(f"  best sweep {valinfo.best_sweep}/{SWEEPS} "
@@ -260,21 +234,21 @@ def main() -> int:
             "final_val_rmse": valinfo.final_rmse,
             "selected_test_rmse": sel_test,
             "last_sweep_test_rmse": cg_traj[-1],
-            "train_sec": round(sel_sec, 2),
             "note": "selection on the validation slice inside the "
                     "compiled scan (ops/als.py ALSValidation); test slice "
                     "untouched until the single final score",
         },
         "config_ties": {
-            "note": ("this artifact's tuned config (rank, reg, solver, "
-                     "warm-CG schedule) IS the perf-benchmark config: "
-                     "bench.py runs rank 64, auto solver, warm schedule "
-                     "at the same ML-20M shape; eval/RANKING_EVAL.md's "
-                     "rank-16 grid winner is the small quickstart "
-                     "dataset's tuning, not this shape's")
+            "note": ("this artifact's tuned config (rank, solver, "
+                     "warm-CG schedule) is the benchmark's: the cell "
+                     "ml20m-r64.train-coo (benchmark/configs/"
+                     "als-ml20m-r64.json) trains rank 64 with the auto "
+                     "solver and the warm schedule at this ML-20M shape; "
+                     "eval/RANKING_EVAL.md's rank-16 grid winner is the "
+                     "small quickstart dataset's tuning, not this shape's")
             if args.scale == "full" else
             ("scaled-down run (--scale %s): shape and solver mirror the "
-             "bench's structure but NOT its size — config-tie claims "
+             "benchmark's structure but NOT its size — config-tie claims "
              "apply only to the full-scale artifact" % args.scale),
             "bench_rank": 64, "this_rank": RANK,
             "is_bench_shape": args.scale == "full",
@@ -284,14 +258,9 @@ def main() -> int:
         "bias_baseline_rmse": round(bias_base, 5),
         "als_vs_mean_improvement": round(1 - als_final / mean_base, 4),
         "als_vs_bias_improvement": round(1 - als_final / bias_base, 4),
-        "train_sec_cg": round(cg_sec, 2),
-        "train_sec_cholesky": round(ch_sec, 2),
         "parity": final_gap < 0.01,   # one-sided: auto must not be worse
         "beats_baselines": quality,
     }
-    from pio_tpu.utils.tpu_health import telemetry
-
-    result["transport"] = telemetry()
     here = os.path.dirname(os.path.abspath(__file__))
     # non-full scales get their own files: a CPU fallback run must not
     # clobber committed full-shape evidence
@@ -343,8 +312,6 @@ def main() -> int:
         f"- Auto-vs-Cholesky final signed gap: {final_gap * 100:+.3f}% "
         f"(negative = auto better) — "
         f"{'PARITY' if result['parity'] else 'NO PARITY'} at the 1% bar",
-        f"- Train wall-clock: auto {cg_sec:.1f}s vs Cholesky {ch_sec:.1f}s "
-        f"for {SWEEPS} sweeps",
     ]
     with open(os.path.join(here, f"RMSE_PARITY{suffix}.md"), "w") as f:
         f.write("\n".join(lines) + "\n")

@@ -8,8 +8,7 @@ for n_devices in {1,2,4,8} it holds PER-DEVICE load constant (weak
 scaling) and records
 
   * sharded ALS (ops/als.py als_train_sharded — the MLlib-shuffle
-    replacement): steady per-sweep seconds (t(N)-t(1) split, same
-    protocol as bench.py) and an isolated timing of the two half-sweep
+    replacement): steady per-sweep seconds (t(N)-t(1) split) and an isolated timing of the two half-sweep
     all_gathers at the exact shapes the sweep issues;
   * ring attention (ops/attention.py): per-ring-step seconds (per-device
     q attends the whole sequence, so total forward grows ~linearly with

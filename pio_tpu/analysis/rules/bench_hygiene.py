@@ -9,16 +9,15 @@ per-event allocation in data-plane hot loops.
     forces completion (`jax.block_until_ready`, a scalar readback via
     `float()` / `.item()`, or `np.asarray`). jax dispatch is async: the
     stopwatch stops when the work is *enqueued*, not when it finishes,
-    so the "measurement" is the dispatch overhead — exactly the bug this
-    repo's own BENCH history records (bench.py round-1/2 postmortem:
-    timings that were silently dispatch times).
+    so the "measurement" is the dispatch overhead, which reads as a
+    plausible, much too small time.
   * `hot-loop-alloc` — per-event `json.loads`/`Event(...)`/
     `Event.from_api_dict`/`DataMap.from_json` construction inside a
     `for`/`while` loop in the data plane (`pio_tpu/data/`,
     `pio_tpu/server/`): the row-at-a-time deserialization the columnar
-    path (data/columnar.py) exists to eliminate — BENCH_r05 measured it
-    at 2.7x the ingest cost of the native path. Use the columnar
-    batch/decode APIs, or justify the row fallback with
+    path (data/columnar.py) exists to eliminate: one Python object and
+    one JSON parse an event where a batch decode makes arrays once. Use
+    the columnar batch/decode APIs, or justify the row fallback with
     `# pio: lint-ok[hot-loop-alloc] <why>`.
 
 Timed regions are matched structurally: `t = <clock>()` ... any later

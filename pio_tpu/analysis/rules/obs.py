@@ -14,9 +14,10 @@ request budget, and is invisible to chaos drills.
     a justification (the one place the urllib call may live), as does
     genuinely non-RPC byte fetching (template gallery downloads).
 
-Scope: ``pio_tpu/`` only. Tests, bench.py, and eval/ scripts drive
-servers from OUTSIDE the traced topology, where raw clients are the
-point (e.g. measuring without client-side instrumentation).
+Scope: ``pio_tpu/`` only. Tests, the benchmark's drivers and eval/
+scripts drive servers from OUTSIDE the traced topology, where raw
+clients are the point (e.g. measuring without client-side
+instrumentation).
 """
 
 from __future__ import annotations

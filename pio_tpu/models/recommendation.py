@@ -111,11 +111,11 @@ class ALSAlgorithmParams(Params):
     chunk: int = 65536
     # inner-solver knobs (ops/als.py): cg_iters -1 = auto per side;
     # warm-sweep schedule drops to cg_warm_iters after cg_warm_sweeps
-    # full-strength sweeps (eval/ALS_ROOFLINE.md) — -1 disables
+    # full-strength sweeps — -1 disables
     cg_iters: int = -1
     # 6 = the ops-layer ALSParams default, so the engine path runs the
     # exact schedule the tuning grid (eval/CG_WARM_QUALITY.json) and the
-    # bench measured; override per-engine in engine.json if needed
+    # benchmark's ALS cells run; override per-engine in engine.json
     cg_warm_iters: int = 6
     cg_warm_sweeps: int = 2
     # > 0: hold out this fraction of interactions, score heldout RMSE

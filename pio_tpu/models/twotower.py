@@ -1,7 +1,7 @@
 """Two-tower neural retrieval template — the flagship pjit model.
 
-The new-capability template from BASELINE.json ("Two-tower neural recommender
-template (new PAlgorithm, pjit data-parallel)"): user and item towers
+The new-capability template of the project's brief ("Two-tower neural
+recommender template (new PAlgorithm, pjit data-parallel)"): user and item towers
 (embedding + MLP) trained with in-batch sampled softmax over (user, item)
 interaction pairs. This is where the mesh design shows its axes:
 

@@ -72,9 +72,8 @@ class ALSParams:
     alpha: float = 1.0        # implicit confidence scale
     implicit: bool = False
     seed: int = 3
-    chunk: int = 65536        # nnz bucketing quantum: ratings are padded to a
-                              # multiple of this so retrains with slightly
-                              # different data sizes reuse the compiled program
+    chunk: int = 65536        # the ratings are padded to a whole number of
+                              # these, with sentinel ids on both sides
     width: int = 128          # ratings per slot (= MXU contraction width)
     chunk_slots: int = 8192   # slots per accumulation step (bounds gather temp)
     # gather the opposing factors in bf16 when building the normal
@@ -664,7 +663,7 @@ def _build_layouts(u, i, v, n_users: int, n_items: int, params: ALSParams):
 def _sweep_factory(by_user, by_item, n_users: int, n_items: int, cs: int,
                    params: ALSParams, reg=None, alpha=None):
     """-> sweep_with(cg_u_n, cg_i_n): the scan body shared by the plain,
-    validated, layout-resident, and stacked trainers.
+    validated and stacked trainers.
 
     ``reg``/``alpha`` override the params' values and may be TRACED
     scalars — the stacked sweep vmaps candidates over them (they only
@@ -778,108 +777,6 @@ def _train_val_jit(u, i, v, vu, vi, vv, n_users: int, n_items: int,
     return bu, bi, jnp.concatenate(curves)
 
 
-# ---------------------------------------------------------------------------
-# device-resident layout reuse (retrain / trajectory fast path)
-# ---------------------------------------------------------------------------
-
-@dataclass(frozen=True)
-class ALSLayouts:
-    """Slot layouts resident in HBM, reusable across train calls.
-
-    At the ML-20M shape the one-time on-device layout build + host->HBM
-    transfer is ~6 s against 4.7 s of actual sweeps
-    (eval/TPU_BENCH_r03.json train decomposition); every als_train call
-    was paying the build again because the layout lived inside the jit.
-    Building once and passing the result back in makes retrain loops,
-    per-sweep trajectory evals, and warm-started continuation calls pay
-    it exactly once. ~2x the COO bytes in HBM (idx+val padded to slot
-    width), freed when the object is dropped."""
-
-    by_user: tuple     # (rows, idx, val, lens) device arrays
-    by_item: tuple
-    cs: int
-    n_users: int
-    n_items: int
-    width: int         # layouts are rank-blind: any rank trains on them
-
-
-@partial(jax.jit, static_argnames=("n_users", "n_items", "params"))
-def _layouts_jit(u, i, v, n_users: int, n_items: int, params: ALSParams):
-    by_user, by_item, _cs = _build_layouts(
-        u, i, v, n_users, n_items, params)
-    return by_user, by_item
-
-
-def als_build_layouts(
-    user_idx, item_idx, values, n_users: int, n_items: int,
-    params: ALSParams,
-) -> ALSLayouts:
-    """Build both slot layouts on device and return them for reuse via
-    ``als_train(..., layouts=...)``. Inputs may be host numpy or
-    device-resident jax arrays (same contract as als_train)."""
-    u, i, v = _prep_coo(user_idx, item_idx, values, n_users, n_items, params)
-    cs, _, _ = _slot_counts(u.shape[0], u.shape[0], n_users, n_items, params)
-    by_user, by_item = _layouts_jit(u, i, v, n_users, n_items, params)
-    return ALSLayouts(by_user, by_item, cs, n_users, n_items, params.width)
-
-
-@partial(jax.jit, static_argnames=("n_users", "n_items", "cs", "params"))
-def _train_from_layouts_jit(bu_rows, bu_idx, bu_val, bu_lens,
-                            bi_rows, bi_idx, bi_val, bi_lens,
-                            n_users: int, n_items: int, cs: int,
-                            params: ALSParams, user0, item0):
-    by_user = (bu_rows, bu_idx, bu_val, bu_lens)
-    by_item = (bi_rows, bi_idx, bi_val, bi_lens)
-    cg_u = params.resolved_cg_iters(n_users)
-    cg_i = params.resolved_cg_iters(n_items)
-    sweep_with = _sweep_factory(by_user, by_item, n_users, n_items, cs,
-                                params)
-    return _run_schedule(sweep_with, params, cg_u, cg_i, (user0, item0))
-
-
-def als_warm_compile(
-    nnz: int, n_users: int, n_items: int, params: ALSParams,
-    sweep_lengths: tuple[int, ...] = (),
-) -> int:
-    """AOT-compile the layout-build and layouts-train programs for this
-    COO shape WITHOUT executing anything: abstract ShapeDtypeStruct
-    inputs through ``.lower().compile()``. With the persistent compile
-    cache (utils/compilecache.py) each ``.compile()`` on a warm restart
-    is a deserialize, so a train process front-loads — or entirely skips
-    — its XLA work while e.g. the host->HBM transfer is in flight,
-    instead of the old warm-up idiom of EXECUTING the programs on
-    zero-filled arrays (whose pointless math burned device time and
-    polluted measurements). Shape/static derivation mirrors
-    ``_prep_coo``/``als_build_layouts`` exactly, so the later real
-    dispatch compiles byte-identical HLO and hits the cache.
-    Returns the number of programs compiled."""
-    nnz_pad = nnz + (-nnz % max(1, params.chunk))
-    u = jax.ShapeDtypeStruct((nnz_pad,), jnp.int32)
-    v = jax.ShapeDtypeStruct((nnz_pad,), jnp.float32)
-    _layouts_jit.lower(
-        u, u, v, n_users=n_users, n_items=n_items, params=params
-    ).compile()
-    n = 1
-    if not sweep_lengths:
-        return n
-    by_user, by_item = jax.eval_shape(
-        lambda a, b, c: _layouts_jit(
-            a, b, c, n_users=n_users, n_items=n_items, params=params),
-        u, u, v,
-    )
-    cs, _, _ = _slot_counts(nnz_pad, nnz_pad, n_users, n_items, params)
-    user0, item0 = jax.eval_shape(
-        lambda: _init_or(None, n_users, n_items, params))
-    for length in sweep_lengths:
-        p = dataclasses.replace(params, iterations=length)
-        _train_from_layouts_jit.lower(
-            *by_user, *by_item, n_users=n_users, n_items=n_items,
-            cs=cs, params=p, user0=user0, item0=item0,
-        ).compile()
-        n += 1
-    return n
-
-
 def als_train(
     user_idx: np.ndarray,
     item_idx: np.ndarray,
@@ -888,7 +785,6 @@ def als_train(
     n_items: int,
     params: ALSParams,
     init: ALSModel | None = None,
-    layouts: "ALSLayouts | None" = None,
 ) -> ALSModel:
     """Train on one device (or one logical device under jit).
 
@@ -899,26 +795,9 @@ def als_train(
     Inputs may be host numpy OR device-resident jax arrays: device inputs
     skip the host conversion/padding copies entirely (pad concatenation
     happens on device), so retrain loops that keep the COO arrays in HBM
-    pay the host->device transfer once, not per call.
-
-    `layouts` (from als_build_layouts, same data/params) skips the
-    per-call slot-layout rebuild entirely — the retrain/trajectory fast
-    path; the COO args are ignored then (pass the same arrays for
-    clarity)."""
+    pay the host->device transfer once, not per call."""
     with tracing.span("als.init"):
         user0, item0 = _init_or(init, n_users, n_items, params)
-    if layouts is not None:
-        if (layouts.n_users, layouts.n_items, layouts.width) != \
-                (n_users, n_items, params.width):
-            raise ValueError(
-                f"layouts built for shape ({layouts.n_users}, "
-                f"{layouts.n_items}, width {layouts.width}), train called "
-                f"with ({n_users}, {n_items}, width {params.width})")
-        users, items = _train_from_layouts_jit(
-            *layouts.by_user, *layouts.by_item,
-            n_users, n_items, layouts.cs, params, user0, item0,
-        )
-        return ALSModel(users, items)
     with tracing.span("als.prep") as sp:
         u, i, v = _prep_coo(
             user_idx, item_idx, values, n_users, n_items, params)
@@ -954,10 +833,9 @@ def _prep_coo(user_idx, item_idx, values, n_users, n_items,
         u = np.ascontiguousarray(user_idx, dtype=np.int32)
         i = np.ascontiguousarray(item_idx, dtype=np.int32)
         v = np.ascontiguousarray(values, dtype=np.float32)
-    # bucket nnz to a params.chunk multiple so retrains with slightly
-    # different data sizes reuse the compiled program; padding entries
-    # carry the sentinel id on BOTH sides (u = n_users, i = n_items) so
-    # whichever side keys the layout drops them via its valid mask
+    # pad to a whole number of params.chunk; padding entries carry the
+    # sentinel id on BOTH sides (u = n_users, i = n_items) so whichever
+    # side keys the layout drops them via its valid mask
     pad = -u.shape[0] % max(1, params.chunk)
     if pad:
         xp = jnp if on_device else np

@@ -158,8 +158,8 @@ def flash_attention(
     bf16): 128x128 ran at 0.0262 s — 2.2x SLOWER than XLA's naive
     attention — while 256x512 runs 0.0057 s, 2.1x faster than naive;
     512x1024 ties it and 1024x1024 fails to compile. The inner k-loop's
-    per-iteration overhead dominates at small blocks
-    (eval/NEURAL_THROUGHPUT.json).
+    per-iteration overhead dominates at small blocks (a round-3
+    reading under an older JAX; no cell runs this kernel: ROADMAP D17).
     """
     from jax.experimental import pallas as pl
 

@@ -66,6 +66,10 @@ def enable_compile_cache() -> str | None:
     sum, not max, is the warm-up."""
     global _enabled
     if cache_disabled():
+        import jax
+
+        # jax reads JAX_COMPILATION_CACHE_DIR by itself: off is off
+        jax.config.update("jax_enable_compilation_cache", False)
         return None
     d = default_cache_dir()
     with _lock:
@@ -128,32 +132,6 @@ def clear_cache(cache_dir: str | None = None) -> int:
             except OSError:
                 pass
     return removed
-
-
-class CacheProbe:
-    """Before/after watermark answering "did this session's compiles hit
-    the persistent cache?" — ``status`` is ``hit`` when the session added
-    nothing to a non-empty cache, ``miss`` when it wrote new entries,
-    ``cold`` when the cache started empty, ``disabled`` when off."""
-
-    def __init__(self):
-        self.dir = enable_compile_cache()
-        self.before = cache_stats(self.dir)["entries"] if self.dir else 0
-
-    def report(self) -> dict:
-        if self.dir is None:
-            return {"enabled": False, "status": "disabled"}
-        after = cache_stats(self.dir)["entries"]
-        if self.before == 0:
-            status = "cold"
-        elif after > self.before:
-            status = "miss"
-        else:
-            status = "hit"
-        return {
-            "enabled": True, "dir": self.dir, "status": status,
-            "entries_before": self.before, "entries_after": after,
-        }
 
 
 class _Phase:

@@ -4,8 +4,8 @@ PredictionIO grew `pio batchpredict` in 0.13 (after the incubator
 version this framework re-implements) because deploy-server round trips
 are the wrong shape for backfills; users migrating from the reference
 expect it, and it is the MOST TPU-congenial serving mode — large
-batched predicts amortize the device dispatch that dominates
-single-query latency (eval/serving_decomposition.py measures it).
+batched predicts amortize the device dispatch a single query pays
+whole (not measured on the chip yet: PERF.md section 7).
 
 Runs each input line through the engine's full serving composition
 (supplement -> [algo.batch_predict ...] -> serve) via

@@ -1595,8 +1595,8 @@ def build_serving_app(server: QueryServer) -> HttpApp:
         checks["freshness"] = {"ok": True, **server.foldin_status()}
         # bucket-warm gate: NOT ready while a micro-batch warm sweep is
         # owed or in flight — a balancer that routes on /readyz never
-        # lands traffic in a bucket-miss XLA compile (BENCH_r05's 187 ms
-        # async_batched cold-start p99). Always-true when batching is off
+        # lands traffic in a bucket-miss XLA compile (a cold start's
+        # p99 is that compile). Always-true when batching is off
         # or no warm query is configured (the sweep then rides the first
         # real request, which readiness must not deadlock on).
         if server.batcher is not None:

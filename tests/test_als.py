@@ -149,7 +149,7 @@ def test_sharded_implicit_nondivisible_matches():
 
 
 def test_high_rank_cg_matches_cholesky():
-    """Rank 64 (the BASELINE.md bench rank, and the MLlib-template range
+    """Rank 64 (the benchmark's ALS rank, and the MLlib-template range
     50-100): the default short warm-started CG solve must reach
     direct-Cholesky quality. The cap is deliberately far below the rank-k
     Krylov bound — CG convergence is set by conditioning, not k, and the
@@ -451,41 +451,52 @@ def test_model_layer_validation_fraction():
     assert algo0.train(Ctx(), data).validation is None
 
 
-def test_layout_reuse_matches_fused_train():
-    """als_train(layouts=...) must produce exactly what the fused path
-    produces (same ops, same schedule — only the build location moves),
-    and continuation calls through the same layouts must keep working."""
-    from pio_tpu.ops.als import als_build_layouts
-
+def test_two_and_two_sweeps_match_four():
+    """Continuation through `init=`: four sweeps in one call equal two
+    and two, the second call warm-started from the first's model (how a
+    per-sweep trajectory is recorded)."""
     users, items, vals, nu, ni = synthetic(seed=13)
-    p = ALSParams(rank=6, iterations=4, reg=0.05, chunk=0, seed=5)
-    fused = als_train(users, items, vals, nu, ni, p)
-    lay = als_build_layouts(users, items, vals, nu, ni, p)
-    reused = als_train(users, items, vals, nu, ni, p, layouts=lay)
-    np.testing.assert_allclose(
-        np.asarray(fused.user_factors), np.asarray(reused.user_factors),
-        rtol=1e-6, atol=1e-7)
-    # trajectory-style continuation: 4 sweeps == 2+2 via init warm start
-    p1 = ALSParams(rank=6, iterations=2, reg=0.05, chunk=0, seed=5,
-                   cg_warm_iters=-1)
-    m = als_train(users, items, vals, nu, ni, p1, layouts=lay)
-    m = als_train(users, items, vals, nu, ni, p1, init=m, layouts=lay)
-    p4 = ALSParams(rank=6, iterations=4, reg=0.05, chunk=0, seed=5,
-                   cg_warm_iters=-1)
-    whole = als_train(users, items, vals, nu, ni, p4, layouts=lay)
-    np.testing.assert_allclose(
-        np.asarray(m.user_factors), np.asarray(whole.user_factors),
-        rtol=1e-5, atol=1e-6)
+    kw = dict(rank=6, reg=0.05, chunk=0, seed=5, cg_warm_iters=-1)
+    p2 = ALSParams(iterations=2, **kw)
+    m = als_train(users, items, vals, nu, ni, p2)
+    m = als_train(users, items, vals, nu, ni, p2, init=m)
+    whole = als_train(users, items, vals, nu, ni,
+                      ALSParams(iterations=4, **kw))
+    for part, full in ((m.user_factors, whole.user_factors),
+                       (m.item_factors, whole.item_factors)):
+        np.testing.assert_allclose(
+            np.asarray(part), np.asarray(full), rtol=1e-5, atol=1e-6)
 
 
-def test_layout_reuse_shape_guard():
-    from pio_tpu.ops.als import als_build_layouts
+def test_device_resident_continuation_matches_host():
+    """A retrain loop that keeps its COO arrays on the device and goes on
+    from the last model: every call pads on the device (3,000 ratings to
+    3 chunks of 1,024) and starts from `init`, and each model is the
+    host-array path's bit for bit."""
+    import jax
+    import jax.numpy as jnp
 
-    users, items, vals, nu, ni = synthetic(seed=2)
-    p = ALSParams(rank=4, iterations=1, chunk=0)
-    lay = als_build_layouts(users, items, vals, nu, ni, p)
-    with pytest.raises(ValueError, match="layouts built for shape"):
-        als_train(users, items, vals, nu + 1, ni, p, layouts=lay)
+    rng = np.random.default_rng(17)
+    nu, ni = 90, 70
+    users = rng.integers(0, nu, 3000)
+    items = rng.integers(0, ni, 3000)
+    vals = rng.integers(1, 6, 3000).astype(np.float32)
+    p = ALSParams(rank=8, iterations=1, reg=0.1, chunk=1024)
+    on_device = (jnp.asarray(users, jnp.int32), jnp.asarray(items, jnp.int32),
+                 jnp.asarray(vals))
+    m_host = m_dev = None
+    for _ in range(3):
+        m_host = als_train(users, items, vals, nu, ni, p, init=m_host)
+        m_dev = als_train(*on_device, nu, ni, p, init=m_dev)
+        assert isinstance(m_dev.user_factors, jax.Array)
+        np.testing.assert_array_equal(
+            np.asarray(m_host.user_factors), np.asarray(m_dev.user_factors))
+        np.testing.assert_array_equal(
+            np.asarray(m_host.item_factors), np.asarray(m_dev.item_factors))
+    # the loop moved: three sweeps are not one
+    first = als_train(users, items, vals, nu, ni, p)
+    assert not np.allclose(np.asarray(first.user_factors),
+                           np.asarray(m_dev.user_factors))
 
 
 def test_accum_mode_validated_at_construction():

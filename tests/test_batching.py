@@ -7,9 +7,10 @@ fleet router coalescer):
   * batched binary wire frames: round-trip, solo interop, every
     truncation + random bit-flips rejected, forged counts die before
     allocation (the CI batching-parity job runs this file unfiltered);
-  * single-host e2e: coalesced answers BIT-identical to the
-    un-batched oracle (mixed users, black/whiteList, unknown user),
-    rollout arms bit-identical with per-arm stats counted ONCE per
+  * single-host e2e: coalesced answers equal to the un-batched
+    oracle's, items and order exactly and scores to 4 ULP of float32
+    (mixed users, black/whiteList, unknown user), rollout arms
+    likewise with per-arm stats counted ONCE per
     query (the hedged/batch double-count regression), /batcher.json +
     key-guarded /batcher/window;
   * 2-shard fleet e2e: coalesced fan-outs bit-identical on exact AND
@@ -116,6 +117,21 @@ MIXED_QUERIES = [
     {"user": "u11", "num": 5},
     {"user": "u2", "num": 3, "blackList": ["i0"]},
 ]
+
+
+def assert_same_answer(got: dict, want: dict, q) -> None:
+    """A coalesced answer against the batch-of-one oracle's: the same
+    items in the same order, and every score within 4 ULP of float32
+    (XLA's CPU matmul sums in another order at another batch shape)."""
+    assert set(got) == set(want), q
+    assert ([s["item"] for s in got["itemScores"]]
+            == [s["item"] for s in want["itemScores"]]), q
+    np.testing.assert_array_max_ulp(
+        np.array([s["score"] for s in got["itemScores"]], np.float32),
+        np.array([s["score"] for s in want["itemScores"]], np.float32),
+        maxulp=4)
+    for key in set(got) - {"itemScores"}:
+        assert got[key] == want[key], (q, key)
 
 
 def concurrent_http(port, queries, path="/queries.json"):
@@ -392,9 +408,9 @@ def serve_coalescing(storage, engine, ep, ctx, window_ms=60.0,
 
 def test_single_host_coalesced_bit_parity(trained):
     """Concurrent queries through the coalescing admission stage answer
-    BIT-identically to the un-batched predict path — blackList,
-    whiteList, unknown user, over-fetch included — and actually share
-    device dispatches."""
+    as the un-batched predict path does (`assert_same_answer`: items and
+    order exactly, scores to 4 ULP) — blackList, whiteList, unknown
+    user, over-fetch included — and actually share device dispatches."""
     storage, engine, ep, ctx, iid = trained
     http, qs = serve_coalescing(storage, engine, ep, ctx)
     oracle = QueryServer(
@@ -406,7 +422,7 @@ def test_single_host_coalesced_bit_parity(trained):
             out = concurrent_http(http.port, MIXED_QUERIES)
             for q, (status, body) in zip(MIXED_QUERIES, out):
                 assert status == 200, (q, body)
-                assert body == oracle.query(dict(q)), q
+                assert_same_answer(body, oracle.query(dict(q)), q)
         _, st = call(http.port, "GET", "/batcher.json")
         assert st["enabled"] and st["mode"] == "continuous"
         assert st["coalescedQueries"] + st["bypassSolo"] >= 16
@@ -425,9 +441,9 @@ def test_single_host_coalesced_bit_parity(trained):
 
 
 def test_single_host_rollout_arms_parity_and_single_count(trained):
-    """Both rollout arms stay bit-identical through the coalescer (the
-    per-arm sub-batching contract) and every query counts ONCE in its
-    arm's stats — the batch-path/hedged double-count regression."""
+    """Both rollout arms answer as their own oracle through the
+    coalescer (`assert_same_answer`; the per-arm sub-batching contract)
+    and every query counts ONCE in its arm's stats — the batch-path/hedged double-count regression."""
     from pio_tpu.rollout import in_canary
 
     storage, engine, ep, ctx, iid_a = trained
@@ -457,7 +473,7 @@ def test_single_host_rollout_arms_parity_and_single_count(trained):
             canary = in_canary(q["user"], pct)
             n_canary += canary
             want = (oracle_b if canary else oracle_a).query(dict(q))
-            assert body == want, q
+            assert_same_answer(body, want, q)
         assert 0 < n_canary < N_USERS   # both arms actually exercised
         _, st = call(http.port, "GET", "/rollout/status")
         # exactly one observation per query per arm — a double-counted

@@ -4,8 +4,6 @@ path may hide the device.
  * `_accelerator_backend` lets a backend-init failure out and answers
    True for "tpu" only;
  * the quantized candidate scan resolves `interpret` from the backend;
- * `bench.py` has no fallback: a failed phase, a CPU-only host or an
-   unknown device kind is a non-zero exit;
  * `chip_smoke.py` refuses a CPU backend without the dry-run flag, keeps
    jax out of its own process, and fails on a child's failure or a
    swallowed warm-up error.
@@ -112,86 +110,6 @@ def test_quantized_scan_interpret_follows_backend(monkeypatch, platform,
             jnp.zeros((32, 64), jnp.int8), jnp.ones((32,)),
             jnp.ones((64,)))
     assert seen["interpret"] is interpret
-
-
-# ---------------------------------------------------------------------------
-# bench.py: a measurement path fails, it does not fall back
-# ---------------------------------------------------------------------------
-
-@pytest.fixture()
-def bench(monkeypatch):
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--no-cpu",
-                                      "--no-serving", "--no-ingest"])
-    monkeypatch.syspath_prepend(REPO)
-    import bench as mod
-
-    return mod
-
-
-_PROBE_TPU = {"ok": True, "platform": "tpu", "device_kind": "TPU v5 lite",
-              "n_devices": 1, "init_sec": 1.0}
-
-
-def _phases(results: dict):
-    calls = []
-
-    def run_phase(name, timeout, env_extra=None, diagnose=False):
-        calls.append(name)
-        return results[name]
-
-    return run_phase, calls
-
-
-def test_bench_main_nonzero_when_a_phase_errors(bench, monkeypatch, capsys):
-    run_phase, calls = _phases({
-        "probe": (_PROBE_TPU, None),
-        "train": (None, "train: rc=1: Mosaic failed to compile"),
-    })
-    monkeypatch.setattr(bench, "run_phase", run_phase)
-    assert bench.main() == 1
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["value"] is None
-    assert "Mosaic" in out["extra"]["errors"]["train"]
-    assert calls == ["probe", "train"]          # no retry
-
-
-def test_bench_main_refuses_a_cpu_only_host(bench, monkeypatch, capsys):
-    run_phase, calls = _phases({
-        "probe": (dict(_PROBE_TPU, platform="cpu", device_kind="cpu"),
-                  None)})
-    monkeypatch.setattr(bench, "run_phase", run_phase)
-    assert bench.main() == 1
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "no accelerator" in out["extra"]["errors"]["probe"]
-    assert calls == ["probe"]                   # nothing ran on the CPU
-
-
-def test_bench_main_zero_when_every_phase_lands(bench, monkeypatch, capsys):
-    run_phase, _ = _phases({
-        "probe": (_PROBE_TPU, None),
-        "train": ({"rate": 123.0, "accum": "hybrid"}, None)})
-    monkeypatch.setattr(bench, "run_phase", run_phase)
-    assert bench.main() == 0
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["value"] == 123.0
-    assert out["extra"]["platform"] == "tpu"
-    assert "errors" not in out["extra"]
-
-
-def test_bench_unknown_device_kind_is_an_error(bench):
-    assert bench.peak_for("TPU v5 lite") == 197e12
-    with pytest.raises(ValueError, match="PEAK_TABLE"):
-        bench.hbm_peak_for("TPU v9 imaginary")
-    with pytest.raises(ValueError, match="PEAK_TABLE"):
-        bench.peak_for("cpu")
-
-
-def test_bench_has_no_fallback_left():
-    with open(os.path.join(REPO, "bench.py")) as f:
-        src = f.read()
-    for gone in ("probe_with_retry", "snapshot_main", "--force-cpu",
-                 "cpu-fallback", "PIO_BENCH_PROBE_", "relay"):
-        assert gone not in src, gone
 
 
 # ---------------------------------------------------------------------------

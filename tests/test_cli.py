@@ -349,8 +349,8 @@ def test_export_import(cli, memory_storage, tmp_path):
 def test_export_import_parquet(cli, memory_storage, tmp_path):
     """Columnar round-trip (reference EventsToFile.scala:39 parquet format):
     full field fidelity incl. properties/tags/times/prId, format inferred
-    from the .parquet extension, and a bulk round-trip for throughput
-    (the 1M-event measurement lives in eval/PARQUET_THROUGHPUT.json)."""
+    from the .parquet extension, and a bulk round-trip of many
+    events."""
     from datetime import datetime, timezone
 
     from pio_tpu.data import DataMap, Event

@@ -118,7 +118,7 @@ from pio_tpu.obs import make_recorder
 from pio_tpu.utils.compilecache import CompileMeter, enable_compile_cache
 from pio_tpu.utils.tracing import Tracer
 
-assert enable_compile_cache() == sys.argv[1]
+assert enable_compile_cache() == (sys.argv[1] or None)
 tracer = Tracer(recorder=make_recorder("test"))
 with tracer.trace("job") as (trace_id, _), CompileMeter() as meter:
     jax.jit(lambda x: jnp.tanh(x) @ x.T)(jnp.ones((37, 5))).block_until_ready()
@@ -151,6 +151,29 @@ def test_a_first_process_misses_and_a_second_hits(tmp_path):
                for b in backend[0])
     assert all(b["cache"] == "hit" and float(b["retrieval_s"]) > 0.0
                and "saved_s" in b for b in backend[1])
+
+
+def test_under_the_kill_switch_no_process_reads_or_writes_the_cache(tmp_path):
+    """`PIO_TPU_COMPILE_CACHE=off` with `JAX_COMPILATION_CACHE_DIR` set:
+    jax would use that directory by itself (a second process reading
+    `hit`, a first one `miss`); the switch turns it off, every row says
+    `off` and the directory is never made."""
+    cache = tmp_path / "cc"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+               JAX_COMPILATION_CACHE_DIR=str(cache),
+               PIO_TPU_COMPILE_CACHE="off")
+    for _ in range(2):
+        out = subprocess.run(
+            [sys.executable, "-c", _TWO_PROCESSES, ""], env=env,
+            capture_output=True, text=True, check=True).stdout
+        said = json.loads(out.strip().splitlines()[-1])
+        backend = [r["labels"] for r in said["rows"]
+                   if r["name"] == "compile.backend"]
+        assert said["programs"] == len(backend) >= 1
+        assert said["hits"] == 0
+        assert all(set(b) == {"program", "cache"} and b["cache"] == "off"
+                   for b in backend), backend
+    assert not cache.exists()
 
 
 def test_with_the_cache_off_a_row_says_so():

@@ -136,26 +136,17 @@ def test_without_env_the_cache_sits_at_one_fixed_path_in_the_checkout(
 
 
 def test_kill_switch(monkeypatch):
+    import jax
+
     monkeypatch.setenv("PIO_TPU_COMPILE_CACHE", "off")
     assert cc.cache_disabled()
-    assert cc.enable_compile_cache() is None
-    probe = cc.CacheProbe()
-    assert probe.report() == {"enabled": False, "status": "disabled"}
-
-
-def test_cache_probe_cold_then_hit(cache_dir):
-    probe = cc.CacheProbe()
-    import jax
-    import jax.numpy as jnp
-
-    f = jax.jit(lambda x: jnp.sin(x) + 41)
-    float(f(jnp.ones(())))
-    rep = probe.report()
-    assert rep["status"] == "cold"          # cache started empty
-    assert rep["entries_after"] > 0
-    probe2 = cc.CacheProbe()
-    float(f(jnp.ones(())))                  # already jitted: no compile
-    assert probe2.report()["status"] == "hit"
+    prev = jax.config.jax_enable_compilation_cache
+    try:
+        assert cc.enable_compile_cache() is None
+        # jax is told too: it finds JAX_COMPILATION_CACHE_DIR by itself
+        assert jax.config.jax_enable_compilation_cache is False
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
 
 
 def test_bucket_registry_round_trip(cache_dir):

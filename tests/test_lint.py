@@ -8,6 +8,8 @@ contract (ISSUE 1 acceptance criteria).
 
 import textwrap
 
+import pytest
+
 from pio_tpu.analysis import ProjectInfo, Severity, lint_text, run_lint
 
 
@@ -997,18 +999,36 @@ def test_cli_lint_verb(tmp_path, capsys):
     assert main(["lint", str(tmp_path)]) == 0
 
 
-def test_repo_lints_clean():
+LINTED_TREES = ("pio_tpu", "tests", "eval", "examples")
+
+
+@pytest.mark.parametrize("tree", LINTED_TREES)
+def test_repo_lints_clean(tree):
     """The analyzer's own acceptance bar: zero unsuppressed findings on
-    the tree it ships in (ISSUE 1)."""
+    the tree it ships in (ISSUE 1), one case for each tree `make lint`
+    names."""
     import os
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    paths = [os.path.join(root, "pio_tpu"),
-             os.path.join(root, "tests"),
-             os.path.join(root, "bench.py")]
-    report = run_lint(paths)
+    report = run_lint([os.path.join(root, tree)])
     assert report.failing == [], "\n".join(
         f.format() for f in report.failing)
+
+
+def test_make_lint_names_the_trees_the_test_lints():
+    """`make lint` (what CI's lint job runs) and the test above name the
+    same trees: a tree added to one and not the other is linted in one
+    place only."""
+    import os
+    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "Makefile")) as f:
+        [line] = re.findall(r"^\t.*tools\.cli lint (?!--deep)(.*)$",
+                            f.read(), re.M)
+    assert tuple(t.rstrip("/") for t in line.split()) == LINTED_TREES
+    with open(os.path.join(root, ".github", "workflows", "ci.yml")) as f:
+        assert "run: make lint\n" in f.read()
 
 
 def test_eval_determinism_fires_in_tuning_scope():

@@ -597,6 +597,75 @@ def test_event_server_spills_on_quorum_loss(tmp_path):
         s.close()
 
 
+def test_binary_batches_from_concurrent_clients_reach_every_replica(
+        tmp_path):
+    """The bulk wire into the replicated store (R=3, W=2), four
+    keep-alive clients at once: every slot of every batch is acked 201
+    with an id of its own, and each of the three replicas holds exactly
+    the acked ids, with no hint left to deliver."""
+    import http.client
+
+    from pio_tpu.data.columnar import (
+        COLUMNAR_CONTENT_TYPE, encode_api_batch,
+    )
+    from pio_tpu.data.dao import AccessKey, App
+    from pio_tpu.server.eventserver import (
+        EventServerConfig, create_event_server,
+    )
+
+    s = Storage(env=replicated_env(tmp_path))
+    app_id = s.get_metadata_apps().insert(App(0, "testapp"))
+    s.get_metadata_access_keys().insert(AccessKey("KEY", app_id, ()))
+    dao = s.get_events()
+    dao.init(app_id)
+    srv = create_event_server(
+        s, EventServerConfig(ip="127.0.0.1", port=0)).start()
+    clients, batches, per_batch = 4, 3, 40
+    acked, errors = [], []
+
+    def client(c):
+        conn = http.client.HTTPConnection("127.0.0.1", srv.port, timeout=30)
+        try:
+            for b in range(batches):
+                body = encode_api_batch([
+                    {"event": "rate", "entityType": "user",
+                     "entityId": f"u{c}-{b}-{j}",
+                     "targetEntityType": "item", "targetEntityId": f"i{j}",
+                     "properties": {"rating": j % 5 + 1}}
+                    for j in range(per_batch)])
+                conn.request(
+                    "POST", "/batch/events.json?accessKey=KEY", body=body,
+                    headers={"Content-Type": COLUMNAR_CONTENT_TYPE})
+                resp = conn.getresponse()
+                slots = json.loads(resp.read())
+                assert resp.status == 200, slots
+                assert [x["status"] for x in slots] == [201] * per_batch
+                acked.extend(x["eventId"] for x in slots)
+        except Exception as e:  # noqa: BLE001 - raised below
+            errors.append(e)
+        finally:
+            conn.close()
+
+    try:
+        threads = [threading.Thread(target=client, args=(c,))
+                   for c in range(clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not errors, errors
+        assert len(set(acked)) == clients * batches * per_batch
+        for replica in dao.replicas:
+            held = [e.event_id for e in replica.find(app_id, limit=-1)]
+            assert sorted(held) == sorted(acked)
+        status = dao.replication_status()
+        assert [r["hintDepth"] for r in status["replicas"]] == [0, 0, 0]
+        assert status["counters"]["hinted"] == 0
+    finally:
+        srv.stop()
+        s.close()
+
+
 def test_event_server_metrics_export_replication_gauges(tmp_path):
     import urllib.request
 
