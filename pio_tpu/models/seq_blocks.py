@@ -26,6 +26,23 @@ learned gate gives each token a distribution over the T exits:
   p_T = prod_{j<T}(1 - lam_j)                  (the rest of the mass)
   loss = mean_i [ sum_t p_t[i] CE_t[i] - EXIT_ENTROPY_WEIGHT * H(p[i]) ]
 
+With `hybrid_override_pattern` the stack is one of single-mixer blocks:
+block l is the mixer its letter of the pattern names, with a pre-norm and
+a residual add, and there are no two halves:
+
+  x = E[ids];  x <- x + Mixer_l(RMSNorm_l(x));  logits = RMSNorm(x_L) W_head^T
+  "M", Mamba-2, u the normed input, H heads of P, G groups of N:
+      [z | xBC | dt] = u W_in            (H P | H P + 2 G N | H columns)
+      xBC_t <- silu(sum_k w_k xBC_{t-K+1+k} + b)    (K taps, a channel)
+      [x | B | C] = xBC;  dt = softplus(dt + dt_bias);  A = -exp(A_log)
+      h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T;  y_t = h_t C_t + D x_t
+      out = RMSNorm_groups(y * silu(z)) W_out       (G groups, one gain)
+    the scan is ops/ssd.py `ssd_scan`, computed in chunks of `chunk_size`;
+  "E": the expert layer below (sigmoid scores, selection on score + bias,
+    weights from the unbiased scores, normalised, times the factor), an
+    expert and the shared expert `W_down relu(W_up v)^2`: no gate;
+  "*": grouped-query attention, full and causal, no rotary positions.
+
 What a specification chooses, by its keys (`BlockSpec.parse` refuses by
 name what the stack does not compute):
 
@@ -68,8 +85,22 @@ name what the stack does not compute):
    the entropy term. Only the (T, B, S) losses and gate logits leave the
    loop. Serving runs the T passes and reads exit T
    (`early_exit_threshold` 1: no early exit; a lower one is refused);
+ * single-mixer blocks (`hybrid_override_pattern`, the equations above;
+   `mamba_num_heads`, `mamba_head_dim`, `ssm_state_size`, `n_groups`,
+   `conv_kernel`, `chunk_size`, `use_conv_bias`; `mlp_hidden_act: relu2`
+   and `moe_shared_expert_intermediate_size` for the experts;
+   `attention_rope: false`, which such a specification has to state). A
+   block is one `jax.checkpoint`, an "E" or "M" block a history at a
+   time; what crosses it beside the block's input: attention's o and
+   log-sum-exp, and the scan's chunk states (B, S / chunk, H, P, N)
+   float32 (ops/ssd.py KEPT_STATES), so the backward pass does not run
+   the carry over the chunks again. A mixer's own parameters are drawn as
+   its family draws them (`init_params`). Serving runs the whole scan a
+   query;
  * precision: float32 master weights and Adam state, bfloat16 operands,
-   float32 accumulation, float32 residual stream, norms and loss;
+   float32 accumulation, float32 residual stream, norms and loss; of a
+   scan also softplus, the decays' running sums, the carried states and
+   their recurrence;
  * memory: every layer's two halves (the module's too; a looped stack's
    layer as one) are recomputed in the backward pass (`jax.checkpoint`
    at their boundaries), but for the attention forward kernel: its output o and the rows' log-sum-exp,
@@ -81,7 +112,9 @@ One chip. Histories are whole (no PAD inside a row): packing and padding
 of short histories, the experts' exchange across chips, a cache for
 serving (for latent attention: the compressed key-value cache), early
 exit at serve time and the exit gate's second training stage are not
-here (ROADMAP R1, R4, R15).
+here (ROADMAP R1, R4, R15); nor, for a scan, recurrent state kept a user
+at serve time, a state reset and a convolution cut at a packed
+history's boundaries, a plain-MLP ("-") block, or a tied head (R3, R4 e).
 """
 
 from __future__ import annotations
@@ -105,6 +138,7 @@ from pio_tpu.ops.attention import (
     banded_flash_attention,
 )
 from pio_tpu.ops.moe import HeldExperts, held_moe_ffn
+from pio_tpu.ops.ssd import KEPT_STATES, ssd_scan
 from pio_tpu.utils import tracing
 
 PAD = 0
@@ -121,10 +155,17 @@ MTP_LOSS_WEIGHT = 0.3      # the prediction module's loss beside the main one
 EXIT_ENTROPY_WEIGHT = 0.1  # beta: the exit distribution's entropy in the loss
 
 
-# keys of architectures this stack has no code for: refused, not ignored
+# keys of architectures this stack has no code for: refused, not ignored.
+# The state-space layers computed are the ones `hybrid_override_pattern`
+# places, with `mamba_num_heads`, `mamba_head_dim`, `ssm_state_size`,
+# `n_groups`, `conv_kernel` and `chunk_size`; other families' keys for
+# such layers stay here
 _NOT_COMPUTED = ("index_topk", "index_n_heads", "index_head_dim",
                  "layers_block_type", "mamba_n_heads", "mamba_d_state",
                  "mamba_expand", "linear_conv_kernel_dim")
+# the kinds of block a `hybrid_override_pattern` may place: a Mamba-2
+# mixer, routed experts beside a shared one, grouped-query attention
+BLOCK_KINDS = "ME*"
 
 
 @dataclass(frozen=True)
@@ -165,23 +206,66 @@ class BlockSpec:
     # A looped layer has the two post-norms, and the loss is the one
     # over the exits, with its gate
     loop_steps: int = 0
+    # a stack of single-mixer blocks (`hybrid_override_pattern`): one kind
+    # a block, "M", "E" or "*" (BLOCK_KINDS); () where a layer is the two
+    # halves above. Its attention has no rotary positions
+    block_kinds: tuple[str, ...] = ()
+    # the Mamba-2 mixer: heads of head_dim, a state of `ssm_state_size`
+    # a head and head dimension, `n_groups` groups that share B and C
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 0
+    conv_kernel: int = 0
+    chunk_size: int = 0
+    use_conv_bias: bool = True
+    time_step: tuple[float, float, float] = (0.001, 0.1, 0.0001)  # min, max, floor
+    # the experts' and the shared expert's form: "swiglu" (three
+    # matrices) or "relu2" (`W_down relu(W_up x)^2`, two)
+    expert_act: str = "swiglu"
+    shared_intermediate_size: int = 0   # the shared expert's width
 
     @classmethod
     def parse(cls, spec: str | dict) -> "BlockSpec":
         c = json.loads(spec) if isinstance(spec, str) else dict(spec)
         n_layers = c["num_hidden_layers"]
+        # a pattern of single-mixer blocks, of which the first n_layers
+        pattern = c.get("hybrid_override_pattern")
+        hybrid = pattern is not None
+        blocks = tuple(pattern[:n_layers]) if hybrid else ()
         latent = bool(c.get("kv_lora_rank"))
         nope, rope_dim, v_dim = (c.get(k, 0) for k in (
             "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
-        scoring = ("sigmoid" if c.get("topk_method") == "noaux_tc"
+        # the pattern's family routes as `noaux_tc` does and has no key
+        scoring = ("sigmoid" if hybrid or c.get("topk_method") == "noaux_tc"
                    else "softmax")
         # no expert keys at all: every layer a dense SwiGLU
         experts_key = next((k for k in ("num_experts", "n_routed_experts")
                             if k in c), None)
         looped = "total_ut_steps" in c
+        n_dense = 0 if hybrid else (
+            c.get("first_k_dense_replace", 0) if experts_key else n_layers)
+        # the feed-forward activation, under either of its keys: relu2
+        # (two matrices, no gate) in experts and shared experts alone
+        acts = {k: c[k] for k in ("hidden_act", "mlp_hidden_act") if k in c}
+        act = c.get("mlp_hidden_act", c.get("hidden_act", "silu"))
+        computed = ("silu",) if n_dense else ("silu", "relu2")
         wrong = {
-            "hidden_act": c.get("hidden_act", "silu") != "silu",
+            **{k: v != act or v not in computed for k, v in acts.items()},
+            "mamba_hidden_act": c.get("mamba_hidden_act", "silu") != "silu",
             "attention_bias": bool(c.get("attention_bias", False)),
+            "mamba_proj_bias": bool(c.get("mamba_proj_bias", False)),
+            "use_bias": bool(c.get("use_bias", False)),
+            "mlp_bias": bool(c.get("mlp_bias", False)),
+            # single-mixer blocks as computed here: the three kinds, the
+            # attention among them without rotary positions and stated so,
+            # a scan's heads in whole groups
+            "hybrid_override_pattern": hybrid and (
+                len(blocks) != n_layers or bool(set(blocks) - set(BLOCK_KINDS))),
+            "attention_rope": hybrid == bool(c.get("attention_rope", True)),
+            "layer_types": hybrid and "layer_types" in c,
+            "n_groups": "M" in blocks and bool(
+                c["mamba_num_heads"] % c["n_groups"]),
             "tie_word_embeddings": bool(c.get("tie_word_embeddings", False)),
             "mlp_layer_types": any(
                 t != "sparse" for t in
@@ -204,33 +288,41 @@ class BlockSpec:
             ) != c["num_attention_heads"],
             "v_head_dim": latent and nope + rope_dim != v_dim,
             "rope_scaling": latent and c.get("rope_scaling") is not None,
-            "first_k_dense_replace":
-                c.get("first_k_dense_replace", 0) > n_layers,
+            "first_k_dense_replace": c.get("first_k_dense_replace", 0) > (
+                0 if hybrid else n_layers),
             "intermediate_size": experts_key is None
                 and not c.get("intermediate_size"),
             # the looped stack as computed here: dense layers of
             # grouped-query heads, every pass to its end, no module
-            "total_ut_steps": looped and c["total_ut_steps"] < 1,
+            "total_ut_steps": looped and (c["total_ut_steps"] < 1 or hybrid),
             "early_exit_threshold": c.get("early_exit_threshold", 1) < 1,
             "exit_entropy_weight": c.get(
                 "exit_entropy_weight", EXIT_ENTROPY_WEIGHT
             ) != EXIT_ENTROPY_WEIGHT,
             experts_key or "num_experts": looped and experts_key is not None,
-            "kv_lora_rank": looped and latent,
+            "kv_lora_rank": (looped or hybrid) and latent,
             # a prediction module's layer is an expert layer
             "num_nextn_predict_layers": c.get(
                 "num_nextn_predict_layers", 0) > (
-                    0 if looped or experts_key is None else 1),
+                    0 if looped or hybrid or experts_key is None else 1),
             **{k: True for k in _NOT_COMPUTED if c.get(k)},
         }
         if any(wrong.values()):
             raise ValueError(
                 "block specification asks for what this stack does not "
-                f"compute: {sorted(k for k, v in wrong.items() if v)}")
-        kinds = tuple(c["layer_types"][:n_layers]) if "layer_types" in c \
-            or not latent else ("full_attention",) * n_layers
-        if len(kinds) != n_layers or set(kinds) - {
-                "sliding_attention", "full_attention"}:
+                f"compute: {sorted(k for k, v in wrong.items() if v)} "
+                "(state-space layers are computed for the family of "
+                "`hybrid_override_pattern` alone: Mamba-2 mixers by "
+                "`mamba_num_heads`, `mamba_head_dim`, `ssm_state_size`, "
+                "`n_groups`, `conv_kernel`, `chunk_size`)")
+        if hybrid:
+            kinds = ()
+        elif "layer_types" in c or not latent:
+            kinds = tuple(c["layer_types"][:n_layers])
+        else:
+            kinds = ("full_attention",) * n_layers
+        if not hybrid and (len(kinds) != n_layers or set(kinds) - {
+                "sliding_attention", "full_attention"}):
             raise ValueError(f"layer_types {kinds} for {n_layers} layers")
         if latent and "sliding_attention" in kinds:
             raise ValueError("layer_types: latent attention has no window")
@@ -240,9 +332,13 @@ class BlockSpec:
             raise ValueError(
                 f"experts_held {held} is not num_experts "
                 f"{n_held} experts")
-        ropes = c.get("rope_parameters") or {
+        ropes = {} if hybrid else c.get("rope_parameters") or {
             "full_attention": {"rope_type": "default",
                                "rope_theta": c["rope_theta"]}}
+        n_shared = (c.get("n_shared_experts") or 0) if n_held else 0
+        mamba = {k: c[k] for k in (
+            "mamba_num_heads", "mamba_head_dim", "ssm_state_size", "n_groups",
+            "conv_kernel", "chunk_size")} if "M" in blocks else {}
         return cls(
             hidden_size=c["hidden_size"], num_hidden_layers=n_layers,
             layer_types=kinds,
@@ -253,8 +349,9 @@ class BlockSpec:
             sliding_window=c.get("sliding_window") or 0,
             rope=tuple(sorted(
                 (kind, tuple(sorted(ropes[kind].items())))
-                for kind in set(kinds))),
-            rms_norm_eps=c["rms_norm_eps"],
+                for kind in set(kinds))),   # none where kinds is ()
+            rms_norm_eps=c["rms_norm_eps"] if "rms_norm_eps" in c
+            else c["layer_norm_epsilon"],
             moe_intermediate_size=c["moe_intermediate_size"] if n_held else 0,
             num_experts_routed=c.get("num_experts_routed", n_held),
             experts_held=held,
@@ -270,20 +367,29 @@ class BlockSpec:
             qk_nope_head_dim=nope if latent else 0,
             qk_rope_head_dim=rope_dim if latent else 0,
             v_head_dim=v_dim if latent else 0,
-            dense_layers=(c.get("first_k_dense_replace", 0) if n_held
-                          else n_layers),
+            dense_layers=n_dense,
             intermediate_size=c.get("intermediate_size", 0),
             n_shared_experts=c.get("n_shared_experts") or 0,
             scoring=scoring,
             routed_scaling_factor=float(c.get("routed_scaling_factor", 1.0)),
             mtp_layers=c.get("num_nextn_predict_layers", 0),
-            loop_steps=c.get("total_ut_steps", 0))
+            loop_steps=c.get("total_ut_steps", 0),
+            block_kinds=blocks, **mamba,
+            use_conv_bias=bool(c.get("use_conv_bias", True)),
+            time_step=tuple(float(c.get(k, v)) for k, v in (
+                ("time_step_min", 0.001), ("time_step_max", 0.1),
+                ("time_step_floor", 0.0001))),
+            expert_act="relu2" if act == "relu2" else "swiglu",
+            shared_intermediate_size=c.get(
+                "moe_shared_expert_intermediate_size",
+                n_shared * c["moe_intermediate_size"]) if n_shared else 0)
 
     @property
     def experts(self) -> HeldExperts:
         return HeldExperts(self.num_experts_routed, self.num_experts_per_tok,
                            self.experts_held, self.norm_topk_prob, MOE_TILE,
-                           self.scoring, self.routed_scaling_factor)
+                           self.scoring, self.routed_scaling_factor,
+                           self.expert_act)
 
     @property
     def router_bias(self) -> bool:
@@ -297,6 +403,21 @@ class BlockSpec:
 
     def window(self, kind: str) -> int | None:
         return self.sliding_window if kind == "sliding_attention" else None
+
+    @property
+    def router_blocks(self) -> tuple[int, ...]:
+        """The stack's layers that hold a router, by index."""
+        if self.block_kinds:
+            return tuple(n for n, k in enumerate(self.block_kinds)
+                         if k == "E")
+        if not self.experts.n_held:
+            return ()
+        return tuple(range(self.dense_layers, self.num_hidden_layers))
+
+    @property
+    def mamba_inner(self) -> int:
+        """Channels of a Mamba-2 mixer's x, z and y."""
+        return self.mamba_num_heads * self.mamba_head_dim
 
 
 # ---------------------------------------------------------------------------
@@ -354,9 +475,50 @@ def apply_rope(x, cos, sin):
 # parameters
 # ---------------------------------------------------------------------------
 
+def _expert_shapes(spec: BlockSpec) -> dict:
+    """A router, the held experts and the shared expert: three matrices
+    an expert, or two with `expert_act` "relu2" (no gate)."""
+    d, f = spec.hidden_size, spec.moe_intermediate_size
+    n_held = spec.experts.n_held
+    gated = spec.expert_act == "swiglu"
+    shapes = {"router": (d, spec.num_experts_routed),
+              "w_up": (n_held, d, f), "w_down": (n_held, f, d)}
+    if gated:
+        shapes["w_gate"] = (n_held, d, f)
+    if spec.router_bias:
+        shapes["router_bias"] = (spec.num_experts_routed,)
+    if spec.n_shared_experts:
+        fs = spec.shared_intermediate_size
+        shapes.update({"shared_up": (d, fs), "shared_down": (fs, d)})
+        if gated:
+            shapes["shared_gate"] = (d, fs)
+    return shapes
+
+
+def _block_shapes(spec: BlockSpec, kind: str) -> dict:
+    """One single-mixer block of a pattern stack: its norm and its
+    mixer's parameters."""
+    d = spec.hidden_size
+    if kind == "E":
+        return {"norm": (d,), **_expert_shapes(spec)}
+    if kind == "*":
+        hq = spec.num_attention_heads * spec.head_dim
+        hkv = spec.num_key_value_heads * spec.head_dim
+        return {"norm": (d,), "wq": (d, hq), "wk": (d, hkv), "wv": (d, hkv),
+                "wo": (hq, d)}
+    di, h = spec.mamba_inner, spec.mamba_num_heads
+    conv = di + 2 * spec.n_groups * spec.ssm_state_size   # x, B and C
+    shapes = {"norm": (d,), "in_proj": (d, di + conv + h),   # [z | xBC | dt]
+              "conv_w": (spec.conv_kernel, conv), "dt_bias": (h,),
+              "A_log": (h,), "D": (h,), "ssm_norm": (di,),
+              "out_proj": (di, d)}
+    if spec.use_conv_bias:
+        shapes["conv_b"] = (conv,)
+    return shapes
+
+
 def _layer_shapes(spec: BlockSpec, dense: bool) -> dict:
-    d, f, h = (spec.hidden_size, spec.moe_intermediate_size,
-               spec.num_attention_heads)
+    d, h = spec.hidden_size, spec.num_attention_heads
     if spec.kv_lora_rank:
         rq, rkv = spec.q_lora_rank, spec.kv_lora_rank
         dn, dr, dv = (spec.qk_nope_head_dim, spec.qk_rope_head_dim,
@@ -376,16 +538,7 @@ def _layer_shapes(spec: BlockSpec, dense: bool) -> dict:
         layer.update({"mlp_gate": (d, i), "mlp_up": (d, i),
                       "mlp_down": (i, d)})
         return layer
-    n_held = spec.experts.n_held
-    layer.update({"router": (d, spec.num_experts_routed),
-                  "w_gate": (n_held, d, f), "w_up": (n_held, d, f),
-                  "w_down": (n_held, f, d)})
-    if spec.router_bias:
-        layer["router_bias"] = (spec.num_experts_routed,)
-    if spec.n_shared_experts:
-        fs = spec.n_shared_experts * f
-        layer.update({"shared_gate": (d, fs), "shared_up": (d, fs),
-                      "shared_down": (fs, d)})
+    layer.update(_expert_shapes(spec))
     return layer
 
 
@@ -393,8 +546,10 @@ def param_shapes(spec: BlockSpec) -> dict:
     d = spec.hidden_size
     shapes = {"embed": (spec.vocab_size, d), "head": (spec.vocab_size, d),
               "final_norm": (d,),
-              "layers": [_layer_shapes(spec, n < spec.dense_layers)
-                         for n in range(spec.num_hidden_layers)]}
+              "layers": [_block_shapes(spec, kind) for kind in
+                         spec.block_kinds] if spec.block_kinds else [
+                  _layer_shapes(spec, n < spec.dense_layers)
+                  for n in range(spec.num_hidden_layers)]}
     if spec.mtp_layers:
         shapes["mtp"] = {"enorm": (d,), "hnorm": (d,), "eh_proj": (2 * d, d),
                          "final_norm": (d,),
@@ -407,7 +562,7 @@ def param_shapes(spec: BlockSpec) -> dict:
 def expert_layers(params: dict, spec: BlockSpec) -> list[dict]:
     """The layers that hold a router, in the order of the step's
     counters: the stack's, then the prediction module's."""
-    layers = list(params["layers"][spec.dense_layers:])
+    layers = [params["layers"][n] for n in spec.router_blocks]
     return layers + ([params["mtp"]["layer"]] if spec.mtp_layers else [])
 
 
@@ -422,24 +577,56 @@ def _init_program(spec: BlockSpec):
         return (spec.embedding_initializer_range
                 if path[0].key == "embed" else spec.initializer_range)
 
+    def uniform(k, s, lo: float, hi: float):
+        return jax.random.uniform(k, s, jnp.float32, lo, hi)
+
+    dt_min, dt_max, dt_floor = spec.time_step
+    taps = (spec.conv_kernel or 1) ** -0.5
+    # a Mamba-2 mixer's own, as its family's code draws them: with
+    # normal(0, initializer_range) every head would forget in a few
+    # positions
+    mamba = {
+        # decay rates A = -exp(A_log) uniform in [-16, -1]
+        "A_log": lambda k, s: jnp.log(uniform(k, s, 1.0, 16.0)),
+        # softplus(dt_bias) log-uniform in [dt_min, dt_max], floored
+        "dt_bias": lambda k, s: _softplus_inverse(jnp.maximum(jnp.exp(
+            uniform(k, s, math.log(dt_min), math.log(dt_max))), dt_floor)),
+        # a depthwise convolution's default: uniform in +- 1 / sqrt(taps)
+        "conv_w": lambda k, s: uniform(k, s, -taps, taps),
+        "conv_b": lambda k, s: uniform(k, s, -taps, taps),
+    }
+
+    def leaf(path, s, k):
+        name = path[-1].key
+        if name in mamba:
+            return mamba[name](k, s)
+        if name in ("router_bias", "exit_bias"):
+            return jnp.zeros(s, jnp.float32)
+        if len(s) == 1:                   # norm gains, and a mixer's D
+            return jnp.ones(s, jnp.float32)
+        return scale(path) * jax.random.normal(k, s, jnp.float32)
+
     @jax.jit
     def make(key):
         keys = jax.random.split(key, len(leaves))
         return jax.tree_util.tree_unflatten(tree, [
-            jnp.zeros(s, jnp.float32)
-            if path[-1].key in ("router_bias", "exit_bias") else
-            jnp.ones(s, jnp.float32) if len(s) == 1 else
-            scale(path) * jax.random.normal(k, s, jnp.float32)
-            for (path, s), k in zip(leaves, keys)])
+            leaf(path, s, k) for (path, s), k in zip(leaves, keys)])
 
     return make
+
+
+def _softplus_inverse(y):
+    """x with softplus(x) = y, for y > 0."""
+    return y + jnp.log(-jnp.expm1(-y))
 
 
 def init_params(spec: BlockSpec, seed: int) -> dict:
     """normal(0, initializer_range) matrices (the embedding's rows
     normal(0, embedding_initializer_range)), unit norm gains, zero
-    router biases and a zero exit bias, float32, a pure function of
-    (spec, seed)."""
+    router biases and a zero exit bias; of a Mamba-2 mixer A uniform in
+    [-16, -1], softplus(dt_bias) log-uniform in `time_step`, D 1 and the
+    convolution uniform in +- 1 / sqrt(taps). float32, a pure function
+    of (spec, seed)."""
     return _init_program(spec)(jax.random.PRNGKey(seed))
 
 
@@ -467,8 +654,11 @@ def _attention_half(lp, x, cos, sin, *, spec: BlockSpec, kind: str):
                               w.astype(COMPUTE).reshape(d, n, dh),
                               preferred_element_type=jnp.float32)
 
-        q = apply_rope(heads(lp["wq"], hq), cos, sin).astype(COMPUTE)
-        k = apply_rope(heads(lp["wk"], hkv), cos, sin).astype(COMPUTE)
+        def rotated(t):     # a pattern stack's attention: no rotation
+            return t if cos is None else apply_rope(t, cos, sin)
+
+        q = rotated(heads(lp["wq"], hq)).astype(COMPUTE)
+        k = rotated(heads(lp["wk"], hkv)).astype(COMPUTE)
         v = heads(lp["wv"], hkv).astype(COMPUTE)
     window = spec.window(kind)
     with jax.named_scope("seq.attn.window" if window else "seq.attn.full"):
@@ -530,13 +720,20 @@ def _latent_attention_half(lp, x, cos, sin, *, spec: BlockSpec):
     return x + out
 
 
+def _dot(a, w):
+    """a in COMPUTE times a stored weight, accumulated in float32."""
+    return jnp.dot(a, w.astype(COMPUTE), preferred_element_type=jnp.float32)
+
+
 def _swiglu(y, gate, up, down):
     """(T, d) in COMPUTE -> (T, d) float32: (silu(y Wg) * (y Wu)) Wd."""
-    def dot(a, w):
-        return jnp.dot(a, w.astype(COMPUTE),
-                       preferred_element_type=jnp.float32)
+    return _dot((jax.nn.silu(_dot(y, gate)) * _dot(y, up)).astype(COMPUTE),
+                down)
 
-    return dot((jax.nn.silu(dot(y, gate)) * dot(y, up)).astype(COMPUTE), down)
+
+def _relu2_ffn(y, up, down):
+    """(T, d) in COMPUTE -> (T, d) float32: relu(y Wu)^2 Wd, no gate."""
+    return _dot(jnp.square(jax.nn.relu(_dot(y, up))).astype(COMPUTE), down)
 
 
 def _experts_half(lp, h, *, spec: BlockSpec):
@@ -550,8 +747,13 @@ def _experts_half(lp, h, *, spec: BlockSpec):
         y, spec.experts, COMPUTE)
     if spec.n_shared_experts:
         with jax.named_scope("seq.moe.shared"):
-            out = out + _swiglu(y.astype(COMPUTE), lp["shared_gate"],
-                                lp["shared_up"], lp["shared_down"])
+            yc = y.astype(COMPUTE)
+            if spec.expert_act == "relu2":
+                out = out + _relu2_ffn(yc, lp["shared_up"],
+                                       lp["shared_down"])
+            else:
+                out = out + _swiglu(yc, lp["shared_gate"], lp["shared_up"],
+                                    lp["shared_down"])
     return h + out, aux
 
 
@@ -563,6 +765,81 @@ def _dense_half(lp, h, *, spec: BlockSpec):
         if spec.loop_steps:
             out = rms_norm(out, lp["norm2_post"], spec.rms_norm_eps)
         return h + out
+
+
+def causal_conv(x, w, bias=None):
+    """A causal depthwise convolution over positions: x (B, S, C), w
+    (taps, C), the last tap on the current position ->
+    out[t] = sum_k w[k] x[t - (taps - 1) + k] (+ bias), as shifted adds."""
+    taps, s = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    out = sum(w[k] * padded[:, k:k + s] for k in range(taps))
+    return out if bias is None else out + bias
+
+
+def gated_group_norm(y, z, gain, groups: int, eps: float):
+    """RMSNorm_groups(y * silu(z)) * gain: the gate first, then each of
+    `groups` equal groups of channels normed by its own mean square."""
+    gated = y * jax.nn.silu(z)
+    by_group = gated.reshape(*gated.shape[:-1], groups, -1)
+    normed = by_group * jax.lax.rsqrt(
+        jnp.mean(by_group * by_group, axis=-1, keepdims=True) + eps)
+    return normed.reshape(gated.shape) * gain
+
+
+def _mamba_block(lp, x, *, spec: BlockSpec):
+    """x (B, S, d) -> x + the Mamba-2 mixer of its norm: in_proj to
+    [z | xBC | dt], a causal convolution and SiLU over xBC, the scan
+    (ops/ssd.py) of x by dt, B, C with A = -exp(A_log) and the skip D,
+    the gated group norm, out_proj."""
+    b, s, _ = x.shape
+    h, p, g, n = (spec.mamba_num_heads, spec.mamba_head_dim, spec.n_groups,
+                  spec.ssm_state_size)
+    di = spec.mamba_inner
+    with jax.named_scope("seq.ssm.proj"):
+        y = rms_norm(x, lp["norm"], spec.rms_norm_eps).astype(COMPUTE)
+        zxbcdt = jnp.dot(y, lp["in_proj"].astype(COMPUTE),
+                         preferred_element_type=jnp.float32)
+        z, xbc, dt = (zxbcdt[..., :di], zxbcdt[..., di:-h], zxbcdt[..., -h:])
+    with jax.named_scope("seq.ssm.conv"):
+        xbc = jax.nn.silu(causal_conv(xbc, lp["conv_w"], lp.get("conv_b")))
+    with jax.named_scope("seq.ssm.scan"):
+        xs, b_mat, c_mat = (xbc[..., :di], xbc[..., di:di + g * n],
+                            xbc[..., di + g * n:])
+        out = ssd_scan(
+            xs.astype(COMPUTE).reshape(b, s, h, p),
+            jax.nn.softplus(dt + lp["dt_bias"]), -jnp.exp(lp["A_log"]),
+            b_mat.astype(COMPUTE).reshape(b, s, g, n),
+            c_mat.astype(COMPUTE).reshape(b, s, g, n), lp["D"],
+            spec.chunk_size)
+    with jax.named_scope("seq.ssm.proj"):
+        out = gated_group_norm(out.reshape(b, s, di), z, lp["ssm_norm"], g,
+                               spec.rms_norm_eps)
+        return x + jnp.dot(out.astype(COMPUTE),
+                           lp["out_proj"].astype(COMPUTE),
+                           preferred_element_type=jnp.float32)
+
+
+def _block(lp, x, *, spec: BlockSpec, kind: str):
+    """One single-mixer block of a pattern stack, x <- x + Mixer(RMSNorm(
+    x)), recomputed in the backward pass (an "E" or "M" block a history
+    at a time: what the recomputation holds at once is one history's) but
+    for what only a kernel or the scan can make: attention's o and
+    log-sum-exp, and the scan's chunk states (B, S / chunk, H, P, N)
+    float32. -> (x', the router's counters or None)."""
+    keep = jax.checkpoint_policies.save_only_these_names(
+        *KEPT_RESIDUALS, KEPT_STATES)
+    # a block's one norm, under the key its mixer's code reads
+    if kind == "E":
+        return jax.lax.map(jax.checkpoint(partial(
+            _experts_half, {**lp, "norm2": lp["norm"]}, spec=spec)), x)
+    if kind == "M":
+        one = jax.checkpoint(partial(_mamba_block, lp, spec=spec),
+                             policy=keep)
+        return jax.lax.map(lambda x_b: one(x_b[None])[0], x), None
+    return jax.checkpoint(
+        partial(_attention_half, spec=spec, kind="full_attention"),
+        policy=keep)({**lp, "norm1": lp["norm"]}, x, None, None), None
 
 
 def _layer(lp, x, table, *, spec: BlockSpec, kind: str, dense: bool):
@@ -602,9 +879,13 @@ def _stack(params, x, tables, spec: BlockSpec):
     """x (B, S, d) through the L layers once -> (x', the counters of the
     layers that route, one entry each)."""
     counters = []
-    for n, (lp, kind) in enumerate(zip(params["layers"], spec.layer_types)):
-        x, aux = _layer(lp, x, tables[kind], spec=spec, kind=kind,
-                        dense=n < spec.dense_layers)
+    kinds = spec.block_kinds or spec.layer_types
+    for n, (lp, kind) in enumerate(zip(params["layers"], kinds)):
+        if spec.block_kinds:
+            x, aux = _block(lp, x, spec=spec, kind=kind)
+        else:
+            x, aux = _layer(lp, x, tables[kind], spec=spec, kind=kind,
+                            dense=n < spec.dense_layers)
         if aux is not None:
             counters.append(aux)
     return x, counters
@@ -871,15 +1152,13 @@ def balance_routers(params, counts_all, spec: BlockSpec):
     def moved(lp, move):
         return {**lp, "router_bias": lp["router_bias"] + move}
 
-    n_stack = spec.num_hidden_layers - spec.dense_layers
     layers = list(params["layers"])
-    for i in range(n_stack):
-        n = spec.dense_layers + i
+    for i, n in enumerate(spec.router_blocks):
         layers[n] = moved(layers[n], moves[i])
     out = {**params, "layers": layers}
     if spec.mtp_layers:
         out["mtp"] = {**params["mtp"], "layer": moved(
-            params["mtp"]["layer"], moves[n_stack])}
+            params["mtp"]["layer"], moves[len(spec.router_blocks)])}
     return out
 
 
@@ -929,40 +1208,62 @@ def attention_counters(jaxpr) -> dict:
     and pass); the backward kernels, which are every other kernel of the
     name `flash_attention_*` (one a layer application, two while dq and
     dk / dv had a kernel each); and the bytes of the arrays named KEPT_RESIDUALS
-    that a checkpoint's backward pass takes in, which are the ones kept."""
+    that a checkpoint's backward pass takes in, which are the ones kept.
+    Of a program with state-space scans, likewise: `ssm_state_bytes`,
+    the chunk states the forward pass names KEPT_STATES (a Mamba-2 block
+    runs a history at a time, so its checkpoint lies inside a loop over
+    the histories and the array kept is the loop's: counted where it is
+    named, outside every recomputation, times the loops around it), and
+    the calls of the scan's kernels `ssd_chunk_fwd` / `ssd_chunk_bwd`, a
+    loop's body once (the forward kernel also in a block's
+    recomputation); a program without a scan has none of the three
+    keys."""
     found = {"attn_fwd_kernels": 0, "attn_bwd_kernels": 0,
              "attn_residual_bytes": 0, "layer_applications": 0}
+    scans = {"ssm_fwd_kernels": 0, "ssm_bwd_kernels": 0,
+             "ssm_state_bytes": 0}
+    counts = {**found, **scans}
 
-    def walk(jaxpr, named):
+    def walk(jaxpr, named, times, recomputed):
         def is_named(v):
             return isinstance(v, Var) and v in named
 
         for eqn in jaxpr.eqns:
             if eqn.primitive is name_p:
+                out = eqn.outvars[0]
                 if eqn.params["name"] in KEPT_RESIDUALS:
-                    named.add(eqn.outvars[0])
+                    named.add(out)
+                elif eqn.params["name"] == KEPT_STATES and not recomputed:
+                    counts["ssm_state_bytes"] += (
+                        times * out.aval.size * out.aval.dtype.itemsize)
             elif eqn.primitive is jax.lax.reduce_precision_p:
                 # jax passes a kept array that the forward pass reads too
                 # through one that changes nothing
                 if is_named(eqn.invars[0]):
                     named.add(eqn.outvars[0])
             elif eqn.primitive is remat_p:
-                found["attn_residual_bytes"] += sum(
+                counts["attn_residual_bytes"] += sum(
                     v.aval.size * v.aval.dtype.itemsize
                     for v in eqn.invars if is_named(v))
             elif eqn.primitive.name == "pallas_call":
                 name = eqn.params["name"]
                 if name == "flash_attention_fwd":
-                    found["attn_fwd_kernels"] += 1
+                    counts["attn_fwd_kernels"] += 1
                 elif name.startswith("flash_attention_"):
-                    found["attn_bwd_kernels"] += 1
-                    found["layer_applications"] += (
+                    counts["attn_bwd_kernels"] += 1
+                    counts["layer_applications"] += (
                         name == "flash_attention_bwd")
+                elif name in ("ssd_chunk_fwd", "ssd_chunk_bwd"):
+                    counts[f"ssm_{name[-3:]}_kernels"] += 1
+            inside = times * eqn.params.get("length", 1) if (
+                eqn.primitive.name == "scan") else times
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub, set())
+                walk(sub, set(), inside,
+                     recomputed or eqn.primitive is remat_p)
 
-    walk(jaxpr, set())
-    return found
+    walk(jaxpr, set(), 1, False)
+    has_scan = counts["ssm_state_bytes"] or counts["ssm_fwd_kernels"]
+    return {k: v for k, v in counts.items() if k in found or has_scan}
 
 
 @lru_cache(maxsize=None)
@@ -1059,6 +1360,11 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
     positions = seqs.shape[1] - 1 - spec.mtp_layers
     if positions < 1:
         raise ValueError(f"histories of {seqs.shape[1]} ids train nothing")
+    n_scans = spec.block_kinds.count("M")
+    if n_scans and positions % spec.chunk_size:
+        raise ValueError(
+            f"chunk_size {spec.chunk_size} does not divide the {positions} "
+            "positions of a history: the scan runs over whole chunks")
     steps, batch = p.steps, p.batch_size
     with tracing.span("seq.batch", steps=steps, histories=batch):
         order = epoch_order(len(seqs), steps, batch, p.seed)
@@ -1092,6 +1398,13 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
             sp.update(**_expert_counters(counters, spec, positions))
         if "sliding_attention" in spec.layer_types:
             sp.update(**band_counters(spec, positions))
+        if n_scans:
+            # ssm_state_bytes and the scan's kernels came with `program`
+            sp.update(ssm_blocks=n_scans,
+                      ssm_chunks=positions // spec.chunk_size,
+                      block_kinds=" ".join(
+                          f"{k}{spec.block_kinds.count(k)}"
+                          for k in BLOCK_KINDS))
         if spec.loop_steps:
             sp.update(**_exit_counters(counters))
         if spec.mtp_layers:

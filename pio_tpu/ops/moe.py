@@ -22,14 +22,15 @@ tests pin them together and pin top-1 routing against a per-token loop.
 (`held_moe_ffn`; the block stack of models/seq_blocks.py). The layer is
 told which experts it holds (`HeldExperts.held` of `n_routed`), routes
 every token over ALL of them and computes what its own add, by one fused
-grouped op (`grouped_swiglu`, Pallas) over rows sorted by expert: no
+grouped op (`grouped_swiglu`, or for experts of two matrices with relu^2
+between them `grouped_relu2`; Pallas) over rows sorted by expert: no
 capacity, nothing dropped, no exchange on one chip. The router scores by
 softmax (top-k of the probabilities) or by sigmoid (`score="sigmoid"`:
 selection on score + a bias that takes no gradient, weights from the
 unbiased score, normalised and scaled: the aux-loss-free balancing of
 `topk_method: noaux_tc`); with a bias it also counts the tokens of every
 routed expert, which the bias rule of the train step needs. A shared
-expert is the caller's (it is a dense SwiGLU beside this layer).
+expert is the caller's (it is a dense FFN beside this layer).
 """
 
 from __future__ import annotations
@@ -261,6 +262,7 @@ class HeldExperts:
     tile_rows: int = 512           # rows of one tile of the grouped product
     score: str = "softmax"         # or "sigmoid": each expert scored alone
     scale: float = 1.0             # on the weights (`routed_scaling_factor`)
+    activation: str = "swiglu"     # or "relu2": W_down relu(W_up x)^2, no gate
 
     @property
     def n_held(self) -> int:
@@ -622,6 +624,59 @@ def _swiglu_bwd(tile_rows, res, dy):
 grouped_swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
+def _relu2(up):
+    """hidden = relu(up)^2, and what the backward pass reads: up."""
+    return jnp.square(jax.nn.relu(up)), up
+
+
+def _relu2_back(d_hidden, up):
+    return (2.0 * d_hidden * jax.nn.relu(up),)
+
+
+def _up(rows, w_up, tile_expert, tiles_used, tm, kept: bool):
+    """relu^2 of the rows' product in its kernel's epilogue -> [hidden],
+    and with `kept` up beside it."""
+    return _over_tiles(
+        "moe_gmm_relu2", [rows], [w_up], [(0, 0)], tile_expert, tiles_used,
+        tm, epilogue=_relu2, n_out=2 if kept else 1)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def grouped_relu2(rows, w_up, w_down, tile_expert, tiles_used,
+                  tile_rows: int):
+    """The held experts' FFN without a gate, on rows sorted by expert:
+    `relu(rows W_up,e)^2 W_down,e` a tile, as `grouped_swiglu` takes
+    rows, tiles and weights (w_up (G, d, f), w_down (G, f, d), as
+    stored). Two products forward and four backward, relu^2 and its
+    derivative in their kernels' epilogues: nothing outside the kernels
+    passes over the buffer or the weights. -> (M, d) in the rows' dtype."""
+    tiles = tile_expert, tiles_used, tile_rows
+    hidden, = _up(rows, w_up, *tiles, kept=False)
+    return grouped_matmul(hidden, w_down, *tiles)
+
+
+def _relu2_fwd(rows, w_up, w_down, tile_expert, tiles_used, tile_rows):
+    tiles = tile_expert, tiles_used, tile_rows
+    hidden, up = _up(rows, w_up, *tiles, kept=True)
+    return (_gmm(hidden, w_down, *tiles),
+            (rows, w_up, w_down, hidden, up, tile_expert, tiles_used))
+
+
+def _relu2_bwd(tile_rows, res, dy):
+    rows, w_up, w_down, hidden, up, *tiles = res
+    n_groups = w_up.shape[0]
+    d_up, = _over_tiles(
+        "moe_gmm_drelu2", [dy], [w_down], [(0, 0)], *tiles, tile_rows,
+        transposed=True, seen=(up,), epilogue=_relu2_back)
+    d_rows = _gmm(d_up, w_up, *tiles, tile_rows, transposed=True)
+    dw = [_tgmm(x, g, *tiles, n_groups, tile_rows).astype(w.dtype)
+          for x, g, w in ((rows, d_up, w_up), (hidden, dy, w_down))]
+    return d_rows, *dw, None, None
+
+
+grouped_relu2.defvjp(_relu2_fwd, _relu2_bwd)
+
+
 def _unwritten(shape, dtype):
     """The sorted buffer before its tiles are written; what a row behind
     `tiles_used` holds is never read (a test fills it with NaN). Zeros: a
@@ -762,7 +817,9 @@ tokens_from_rows.defvjp(_tfr_fwd, _tfr_bwd)
 
 def held_moe_ffn(params, x, cfg: HeldExperts, compute_dtype=jnp.bfloat16):
     """This rank's part of the layer for (T, d) tokens: `sum over the
-    held e in top-k(x) of w_e * W_down,e(silu(x W_gate,e) * (x W_up,e))`.
+    held e in top-k(x) of w_e * W_down,e(silu(x W_gate,e) * (x W_up,e))`,
+    or with `cfg.activation == "relu2"` of `w_e * W_down,e relu(x
+    W_up,e)^2` (no w_gate).
     params: router (d, n_routed), w_gate / w_up (n_held, d, f), w_down
     (n_held, f, d), and optionally router_bias (n_routed,), which moves
     the selection and takes no gradient. Products take `compute_dtype`
@@ -780,9 +837,14 @@ def held_moe_ffn(params, x, cfg: HeldExperts, compute_dtype=jnp.bfloat16):
         plan = dispatch_plan(ids, cfg)
         rows = rows_from_tokens(xc, plan)
     with jax.named_scope("seq.moe.gmm"):
-        out_rows = grouped_swiglu(
-            rows, params["w_gate"], params["w_up"], params["w_down"],
-            plan["tile_expert"], plan["tiles_used"], tm)
+        tiles = plan["tile_expert"], plan["tiles_used"], tm
+        if cfg.activation == "relu2":
+            out_rows = grouped_relu2(rows, params["w_up"], params["w_down"],
+                                     *tiles)
+        else:
+            out_rows = grouped_swiglu(
+                rows, params["w_gate"], params["w_up"], params["w_down"],
+                *tiles)
     with jax.named_scope("seq.moe.combine"):
         y = tokens_from_rows(out_rows, weights, plan)
     aux = {"counts": plan["counts"], "dropped": plan["dropped"]}

@@ -1,5 +1,6 @@
 """The packed-A kernels of the ALS solve, the banded attention's two
-kernels and the held experts' grouped kernels, compiled by the TPU's
+kernels, the held experts' grouped kernels and the state-space scan's
+two, compiled by the TPU's
 compiler for a described v5e at the benchmark's widths: what Mosaic
 refuses (a slice off the tiling, a stack over the scoped VMEM) shows here,
 on a CPU, at no chip time. Nothing runs: these are compiles, not
@@ -12,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from pio_tpu.ops import als_pallas, attention, moe
+from pio_tpu.ops import als_pallas, attention, moe, ssd
 
 ML20M_USERS, ML20M_ITEMS, MSD_ITEM_BLOCK = 138_493, 26_744, 96_137
 
@@ -141,3 +142,56 @@ def test_grouped_swiglu_gradient_compiles_for_v5e(one_chip, case, monkeypatch):
         assert name in text
     for copied in (f"bf16[{g},{d},{f}]", f"bf16[{g},{f},{d}]"):
         assert copied not in text
+
+
+def test_grouped_relu2_gradient_compiles_for_v5e(one_chip, monkeypatch):
+    """The gateless expert op's six kernels at the state-space cell's
+    widths (8 held experts of 2,688 x 1,856, a history's buffer of
+    8,192 x 6 rows + a tile an expert), relu^2 and its derivative in
+    their epilogues."""
+    m, d, f, g = 49_152 + 8 * 512, 2688, 1856, 8
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+
+    def shape(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def gradient(rows, w_up, w_down, te, used, ct):
+        out, back = jax.vjp(
+            lambda *a: moe.grouped_relu2(*a, te, used, 512),
+            rows, w_up, w_down)
+        return out, back(ct)
+
+    rows = shape((m, d), jnp.bfloat16)
+    text = jax.jit(gradient).lower(
+        rows, shape((g, d, f), jnp.float32), shape((g, f, d), jnp.float32),
+        shape((m // 512,), jnp.int32), shape((1,), jnp.int32),
+        rows).compile().as_text()
+    assert text.count("tpu_custom_call") == 6
+    for name in ("moe_gmm_relu2", "moe_gmm_drelu2", "moe_gmm", "moe_tgmm"):
+        assert name in text
+    for copied in (f"bf16[{g},{d},{f}]", f"bf16[{g},{f},{d}]"):
+        assert copied not in text
+
+
+def test_ssd_scan_gradient_compiles_for_v5e(one_chip, monkeypatch):
+    """The scan op forward and backward at the cell's shapes, one
+    history (the block stack maps a Mamba-2 block over histories): 64
+    heads of 64, 8 groups of 128, 64 chunks of 128: two kernels (lane
+    slices of 64, a state of (8, 64, 128) float32 carried in VMEM)."""
+    monkeypatch.setattr(ssd, "_interpret", lambda: False)
+    s, h, p, g, n = 8192, 64, 64, 8, 128
+
+    def shape(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def gradient(*args):
+        y, back = jax.vjp(lambda *a: ssd.ssd_scan(*a, 128), *args)
+        return back(y)
+
+    text = jax.jit(gradient).lower(
+        shape((1, s, h, p), jnp.bfloat16), shape((1, s, h), jnp.float32),
+        shape((h,), jnp.float32), shape((1, s, g, n), jnp.bfloat16),
+        shape((1, s, g, n), jnp.bfloat16), shape((h,), jnp.float32),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "ssd_chunk_fwd" in text and "ssd_chunk_bwd" in text

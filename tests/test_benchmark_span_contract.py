@@ -24,8 +24,10 @@ READERS = ("span-self", "idle-span", "warm-span", "seq-counter")
 # (benchmark/readers/span_self_loop.py says why)
 ALIASES = {"span-self-loop": "span-self", "idle-span-loop": "idle-span"}
 # a traffic kind a later PR added brings its rehearsal's overlay here
-# (benchmark/tests/test_contract_loop.py holds the looped cell's scopes)
-OVERLAYS = {**OVERLAYS, "train_sequence_loop": "loop-tiny.json"}
+# (benchmark/tests/test_contract_loop.py and test_contract_ssm.py hold the
+# looped and the state-space cells' scopes)
+OVERLAYS = {**OVERLAYS, "train_sequence_loop": "loop-tiny.json",
+            "train_sequence_ssm": "ssm-tiny.json"}
 LISTED = {m["name"]: m for m in BENCH["per_layer"]}
 
 

@@ -505,3 +505,117 @@ def test_grouped_swiglu_rounds_the_stored_weights_in_the_kernel():
         assert g.dtype == jnp.float32 and r.dtype == jnp.bfloat16
         np.testing.assert_array_equal(g.astype(jnp.bfloat16), r)
         assert float(jnp.max(jnp.abs(g - r.astype(jnp.float32)))) > 0.0
+
+
+# -- experts of two matrices with relu^2 between them -------------------------
+
+def _relu2_layer(seed=2, e=16, scale=0.2):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    full = {"router": jax.random.normal(ks[0], (D, e)) * 0.5,
+            "router_bias": jax.random.normal(ks[1], (e,)) * 0.05,
+            "w_up": jax.random.normal(ks[2], (e, D, F)) * scale,
+            "w_down": jax.random.normal(ks[3], (e, F, D)) * scale,
+            "shared_up": jax.random.normal(ks[4], (D, 2 * F)) * scale,
+            "shared_down": jax.random.normal(ks[5], (2 * F, D)) * scale}
+    return (full, jax.random.normal(ks[6], (T, D)),
+            jax.random.normal(ks[7], (T, D)))
+
+
+def _relu2_part(full, x, lo, hi, tile=8, e=16):
+    params = {"router": full["router"], "router_bias": full["router_bias"],
+              "w_up": full["w_up"][lo:hi], "w_down": full["w_down"][lo:hi]}
+    cfg = HeldExperts(e, K, (lo, hi), True, tile, "sigmoid", 2.5, "relu2")
+    return held_moe_ffn(params, x, cfg, jnp.float32)
+
+
+def _relu2_dense(full, x, lo, hi, e=16):
+    """A loop over the held experts, every token through each, times the
+    routing weight (0 where the token did not choose it)."""
+    ids, w = route_top_k(x @ full["router"], K, True, "sigmoid",
+                         full["router_bias"], 2.5)
+    out = jnp.zeros_like(x)
+    for n in range(lo, hi):
+        weight = jnp.sum(jnp.where(ids == n, w, 0.0), axis=1)
+        hidden = jnp.square(jax.nn.relu(x @ full["w_up"][n]))
+        out = out + weight[:, None] * (hidden @ full["w_down"][n])
+    return out
+
+
+@pytest.mark.parametrize("tile", [8, 16, 64])
+@pytest.mark.parametrize("held", [(0, 4), (4, 12), (0, 16)])
+def test_relu2_held_experts_equal_a_dense_loop(held, tile):
+    full, x, ct = _relu2_layer()
+    y, aux = _relu2_part(full, x, *held, tile=tile)
+    np.testing.assert_allclose(y, _relu2_dense(full, x, *held),
+                               atol=2e-5, rtol=2e-5)
+    assert int(aux["dropped"]) == 0 and aux["counts_all"].sum() == T * K
+
+    def mine(full, x):
+        return jnp.sum(_relu2_part(full, x, *held, tile=tile)[0] * ct)
+
+    def theirs(full, x):
+        return jnp.sum(_relu2_dense(full, x, *held) * ct)
+
+    got, want = (jax.grad(f, argnums=(0, 1))(full, x) for f in (mine, theirs))
+    for name in ("router", "w_up", "w_down"):
+        np.testing.assert_allclose(got[0][name], want[0][name], atol=3e-5,
+                                   rtol=3e-4, err_msg=name)
+    np.testing.assert_allclose(got[1], want[1], atol=3e-5, rtol=3e-4)
+    assert not np.any(got[0]["router_bias"])     # the bias takes no gradient
+
+
+def test_the_shares_of_an_expert_block_add_up_to_the_uncut_references():
+    """The share test of the state-space configuration (guide section 4):
+    4 shares of 4 of 16 experts. Every share routes over all 16, selects
+    on score + bias, and gives its own experts' part; the parts added up,
+    with the shared expert (which every chip computes alike) counted
+    once, are the uncut reference's block."""
+    from benchmark.reference import ssm_moe_lm
+
+    full, x, _ = _relu2_layer(seed=3)
+    cfg = {"num_experts_per_tok": K, "norm_topk_prob": True,
+           "routed_scaling_factor": 2.5, "n_routed_experts": 16}
+    flags = ssm_moe_lm.with_faults(cfg)
+    whole = ssm_moe_lm.expert_mixer(full, x, cfg, flags, share=(0, 16))
+    shared = ssm_moe_lm.relu2_ffn(x, full["shared_up"], full["shared_down"],
+                                  flags)
+    parts = [_relu2_part(full, x, lo, lo + 4) for lo in range(0, 16, 4)]
+    np.testing.assert_allclose(sum(y for y, _ in parts) + shared, whole,
+                               atol=3e-5, rtol=3e-5)
+    counts = np.concatenate([np.asarray(a["counts"]) for _, a in parts])
+    assert counts.sum() == T * K              # every choice computed once
+    for (y, _), lo in zip(parts, range(0, 16, 4)):
+        lp = dict(full, w_up=full["w_up"][lo:lo + 4],
+                  w_down=full["w_down"][lo:lo + 4])
+        np.testing.assert_allclose(
+            y, ssm_moe_lm.expert_mixer(lp, x, cfg, flags,
+                                       share=(lo, lo + 4), shared=False),
+            atol=3e-5, rtol=3e-5)
+
+
+def test_grouped_relu2_leaves_no_pass_over_the_buffer_outside_its_kernels():
+    """What PR 43 took out for SwiGLU stays out for relu^2: between the
+    rows and the result, forward and backward, the program is the
+    kernels' calls alone (no square, maximum, multiply or convert of an
+    (M, f) or (G, d, f) array)."""
+    from pio_tpu.ops.moe import grouped_relu2
+
+    m, g = 64, 4
+    rows = jnp.ones((m, D), jnp.bfloat16)
+    w_up, w_down = jnp.ones((g, D, F)), jnp.ones((g, F, D))
+    te = jnp.repeat(jnp.arange(g, dtype=jnp.int32), m // 8 // g)
+
+    def both(rows, w_up, w_down):
+        out, back = jax.vjp(
+            lambda *a: grouped_relu2(*a, te, jnp.array([m // 8]), 8),
+            rows, w_up, w_down)
+        return out, back(out)
+
+    jaxpr = jax.make_jaxpr(both)(rows, w_up, w_down)
+    names = [e.params["name"] for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert sorted(names) == ["moe_gmm", "moe_gmm", "moe_gmm_drelu2",
+                             "moe_gmm_relu2", "moe_tgmm", "moe_tgmm"]
+    # what else there is reads the one number of `tiles_used`
+    others = [e for e in jaxpr.jaxpr.eqns if e.primitive.name != "pallas_call"]
+    assert all(v.aval.size <= 1 for e in others for v in e.outvars), others
