@@ -496,9 +496,33 @@ def ulysses_attention(
 # key blocks go by); the backward kernel walks it by key block, dk and dv
 # of the block in VMEM and dq of the whole head beside them, so a block
 # pair's scores and probabilities are made once for all three.
+#
+# Both kernels make a pair's scores transposed, (keys, queries) = k.q^T:
+# a query's statistics are then (1, bq) rows, lane-dense, and the max and
+# the sum over keys go down the sublanes. The forward kernel (PR 46) folds
+# a pair in tiles of 128 keys, each an online-softmax step, and asks for
+# every tile's scores before it folds the first: the compiler then runs a
+# tile's softmax beside its neighbours' products, where one (bk, bq) tile
+# ran product, softmax, product one after another. The mask is a path of
+# its own: scores that pass through a `cond` are copied through VMEM (a
+# third of the old kernel's instructions; the backward kernel still pays
+# it). The accumulator is o^T, (D, bq) += v^T.prob, scaled by the alpha
+# row and transposed once a query block; as (bq, D) += prob^T.v with alpha
+# turned to a column it read 14 % / 15 % / 5 % longer a call at (2, 32 / 4,
+# 8192, 128) full / window 1,024 / (2, 20 / 20, 8192, 256): 19.57 / 8.19 /
+# 15.76 ms against 17.21 / 7.11 / 14.97 (one whole tile, 512 x 512 blocks,
+# a v5e, `eval/attention_fwd_bench.py`), so it left the tree. lse leaves
+# the kernel as a row, (B, Hq, 1, S). Blocks, ms a call at those three
+# shapes (the tree before: 19.52 / 8.28 / 17.93): 512 x 512 10.51 / 4.50 /
+# 10.48, 1,024 x 512 9.87 / 4.94 / 10.35, 512 x 1,024 9.39 / 5.04 / 10.06,
+# 1,024 x 1,024 9.17 / - / 9.91 (a window layer 4 % under its 512 x 512
+# in an earlier form of the kernel), 2,048 x 1,024 9.45 / 5.76 / 10.57,
+# 2,048 x 2,048 refused (VMEM): a full layer's forward call takes 1,024
+# square at both widths, a window layer's the caller's (`forward_blocks`).
 
 _FIRST, _LAST, _EDGE = 1, 2, 4
-_LANES = 128
+_KEY_TILE = 128         # keys of a block pair the forward kernel folds at once
+FWD_FULL_BLOCK = 1024   # the forward kernel's block in a full layer
 # `checkpoint_name`s of the forward's two residuals that only the kernel
 # can make: o (B, Hq, S, D) and the rows' log-sum-exp (B, Hq, S) float32
 KEPT_RESIDUALS = ("banded_attention_o", "banded_attention_lse")
@@ -549,7 +573,9 @@ def band_blocks(seq_len: int, block_q: int, block_k: int,
 
 def _band_scores(q, k, qi, kj, edge, *, scale, window, by_key=False):
     """(bq, bk) float32 scores of one block pair, -inf where masked;
-    with `by_key` their transpose, (bk, bq), as the product k.q^T."""
+    with `by_key` their transpose, (bk, bq), as the product k.q^T. `edge`
+    says whether the pair holds a masked score: a traced flag (the mask
+    under a `cond` the scores pass through) or a Python bool."""
     lhs, rhs = (k, q) if by_key else (q, k)
     s = jax.lax.dot_general(lhs, rhs, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -565,14 +591,26 @@ def _band_scores(q, k, qi, kj, edge, *, scale, window, by_key=False):
             keep = keep & (rows - cols < window)
         return jnp.where(keep, s, -jnp.inf)
 
+    if isinstance(edge, bool):      # a path of its own either way
+        return masked(s) if edge else s
     return jax.lax.cond(edge, masked, lambda s: s, s)
 
 
 def _band_fwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref,
                      o_ref, lse_ref, m_scr, l_scr, acc_scr,
                      *, scale, window):
+    """One block pair of one (batch, query head): the scores transposed,
+    (keys, queries), in tiles of `_KEY_TILE` keys; a query's running max,
+    sum and log-sum-exp are (1, bq) rows, and the accumulator is o^T, (D,
+    bq), transposed once a query block. Every tile's scores are asked
+    for before the first is folded in, so a tile's softmax runs beside
+    the products of its neighbours; the masked and the whole pair are two
+    paths, so no tile crosses a `cond`."""
     p = pl.program_id(2)
     fl = fl_ref[p]
+    block_k = k_ref.shape[2]
+    tile = _KEY_TILE if block_k % _KEY_TILE == 0 else block_k
+    n_tiles = block_k // tile
 
     @pl.when((fl & _FIRST) != 0)
     def _init():
@@ -580,24 +618,34 @@ def _band_fwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    s = _band_scores(q_ref[0, 0], k_ref[0, 0], qi_ref[p], kj_ref[p],
-                     (fl & _EDGE) != 0, scale=scale, window=window)
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    prob = jnp.exp(s - m_new)            # masked: exp(-inf) = 0
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(prob, axis=-1, keepdims=True)
-    acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
-        prob.astype(v_ref.dtype), v_ref[0, 0],
-        preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    def pair(masked):
+        q = q_ref[0, 0]
+        v_t = v_ref[0, 0].T                          # (D, bk)
+        scores = [
+            _band_scores(q, k_ref[0, 0, pl.ds(j * tile, tile), :],
+                         qi_ref[p], kj_ref[p] * n_tiles + j, masked,
+                         scale=scale, window=window, by_key=True)
+            for j in range(n_tiles)]
+        for j, s in enumerate(scores):
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            prob = jnp.exp(s - m_new)                # masked: exp(-inf) = 0
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[...] = alpha * l_scr[...] + jnp.sum(prob, axis=0,
+                                                      keepdims=True)
+            m_scr[...] = m_new
+            acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+                v_t[:, j * tile:(j + 1) * tile], prob.astype(v_t.dtype),
+                preferred_element_type=jnp.float32)
+
+    jax.lax.cond((fl & _EDGE) != 0, partial(pair, True),
+                 partial(pair, False))
 
     @pl.when((fl & _LAST) != 0)
     def _emit():
         l = l_scr[...]
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.broadcast_to(m_scr[...] + jnp.log(l),
-                                         lse_ref.shape[2:])
+        o_ref[0, 0] = (acc_scr[...] / l).T.astype(o_ref.dtype)
+        lse_ref[0, 0] = m_scr[...] + jnp.log(l)
 
 
 def _band_bwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
@@ -672,14 +720,15 @@ def _band_call(kernel, name, table, args, kinds, outs, scratch, *,
                vmem_limit_bytes=None):
     """One pallas_call over (batch, query head, table entry). `kinds`
     says, per tensor argument, which block it is: "q" (query head, block
-    qi[p]), "k" (key-value head h // group, block kj[p]), "lse" (as "q",
-    128 lanes), "row" (two row statistics of the query block, (2,
-    block_q)); `outs` likewise, with the out dtype: "kq" (a key block a
+    qi[p]), "k" (key-value head h // group, block kj[p]), "lse" and "row"
+    (one and two row statistics of the query block, (1 | 2, block_q) of
+    (B, Hq, 1 | 2, S)); `outs` likewise, with the out dtype: "kq" (a key block a
     QUERY head) and "head" (the query head's whole sequence, written back
     when the head is done)."""
     from jax.experimental.pallas import tpu as pltpu
 
     seq = args[0].shape[2]
+    rows = {"lse": 1, "row": 2}
 
     def spec(kind):
         if kind == "k":
@@ -693,16 +742,17 @@ def _band_call(kernel, name, table, args, kinds, outs, scratch, *,
         if kind == "head":
             return pl.BlockSpec((1, 1, seq, head_dim),
                                 lambda b, h, p, qi, kj, fl: (b, h, 0, 0))
-        if kind == "row":
-            return pl.BlockSpec((1, 1, 2, block_q),
+        if kind in rows:
+            return pl.BlockSpec((1, 1, rows[kind], block_q),
                                 lambda b, h, p, qi, kj, fl: (b, h, 0, qi[p]))
-        width = _LANES if kind == "lse" else head_dim
-        return pl.BlockSpec((1, 1, block_q, width),
+        return pl.BlockSpec((1, 1, block_q, head_dim),
                             lambda b, h, p, qi, kj, fl: (b, h, qi[p], 0))
 
     def shape(kind, dtype):
-        width = _LANES if kind == "lse" else head_dim
-        return jax.ShapeDtypeStruct((batch, heads, seq, width), dtype)
+        if kind in rows:
+            return jax.ShapeDtypeStruct((batch, heads, rows[kind], seq),
+                                        dtype)
+        return jax.ShapeDtypeStruct((batch, heads, seq, head_dim), dtype)
 
     return pl.pallas_call(
         kernel, name=name,
@@ -768,30 +818,57 @@ def banded_flash_attention(q, k, v, window: int | None = None,
                        interpret)[0]
 
 
-def _banded_fwd(q, k, v, window, scale, block_q, block_k, interpret):
+def forward_blocks(padded: int, block_q: int, block_k: int,
+                   window: int | None):
+    """The forward kernel's blocks. A full layer's are `FWD_FULL_BLOCK`
+    square where the padded sequence is a multiple of it and the
+    caller's are no larger (a step of the grid costs the kernel ~0.4 us
+    beside its pair: four times fewer of them read 20 % shorter at 128
+    wide and 14 % at 256, PERF.md PR 46); a window layer's, and the
+    backward kernel's always, are the caller's."""
+    if (window is None and padded % FWD_FULL_BLOCK == 0
+            and max(block_q, block_k) <= FWD_FULL_BLOCK):
+        return FWD_FULL_BLOCK, FWD_FULL_BLOCK
+    return block_q, block_k
+
+
+@partial(jax.jit, static_argnums=(3, 4, 5, 6, 7), inline=True)
+def _forward_call(qp, kp, vp, window, scale, block_q, block_k, interpret):
+    """`flash_attention_fwd` over padded operands: o, and lse as (B, Hq,
+    1, S). Jitted and inlined into its caller: a step's layers of one
+    shape then share one traced kernel and one lowering of it (its body
+    is 2 x block_k / 128 tile bodies long: a looped stack would trace and
+    lower it sixteen times, seconds of every process's set-up)."""
     from jax.experimental.pallas import tpu as pltpu
 
+    b, hq, sp, d = qp.shape
+    return _band_call(
+        partial(_band_fwd_kernel, scale=scale, window=window),
+        "flash_attention_fwd", band_pairs(sp, block_q, block_k, window),
+        (qp, kp, vp), ("q", "k", "k"),
+        (("q", qp.dtype), ("lse", jnp.float32)),
+        [pltpu.VMEM((1, block_q), jnp.float32),
+         pltpu.VMEM((1, block_q), jnp.float32),
+         pltpu.VMEM((d, block_q), jnp.float32)],
+        batch=b, heads=hq, group=hq // kp.shape[1], block_q=block_q,
+        block_k=block_k, head_dim=d, interpret=interpret)
+
+
+def _banded_fwd(q, k, v, window, scale, block_q, block_k, interpret):
     s, d = q.shape[2], q.shape[3]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     block_q, block_k = min(block_q, s), min(block_k, s)
     dims, pad, _ = _band_setup(q.shape, k.shape[1], s, block_q, block_k,
                                interpret, q.dtype.itemsize)
     qp, kp, vp = _pad_seq(q, pad), _pad_seq(k, pad), _pad_seq(v, pad)
-    table = band_pairs(s + pad, block_q, block_k, window)
-    o, lse = _band_call(
-        partial(_band_fwd_kernel, scale=scale, window=window),
-        "flash_attention_fwd", table, (qp, kp, vp), ("q", "k", "k"),
-        (("q", q.dtype), ("lse", jnp.float32)),
-        [pltpu.VMEM((block_q, 1), jnp.float32),
-         pltpu.VMEM((block_q, 1), jnp.float32),
-         pltpu.VMEM((block_q, d), jnp.float32)], **dims)
+    o, lse = _forward_call(
+        qp, kp, vp, window, scale,
+        *forward_blocks(s + pad, block_q, block_k, window),
+        dims["interpret"])
     # what only the kernel can make of a row, under the names a
     # `jax.checkpoint` policy keeps them by: o as the kernel wrote it, and
-    # column 0 of lse's 128 equal lanes. The barrier ties the slice to o,
-    # which the forward pass reads next: alone, XLA schedules the slice
-    # where the backward pass wants it and keeps the 128-lane array alive
-    # until then in the column's place (PERF.md, PR 40)
-    o, lse = jax.lax.optimization_barrier((o, lse[..., 0]))
+    # the rows' log-sum-exp, (B, Hq, S) float32 as the kernel's one row
+    lse = lse[:, :, 0]
     o = checkpoint_name(o, KEPT_RESIDUALS[0])
     lse = checkpoint_name(lse, KEPT_RESIDUALS[1])
     return o[:, :, :s], (qp, kp, vp, o, lse)

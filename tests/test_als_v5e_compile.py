@@ -79,6 +79,7 @@ ATTENTION = {
     "mellum2_full": (2, 32, 4, 8192, 128, None),
     "mellum2_window": (2, 32, 4, 8192, 128, 1024),
     "ouro": (2, 16, 16, 8192, 128, None),
+    "nemotron": (2, 32, 2, 8192, 128, None),
     "64k_positions": (1, 2, 1, 65536, 128, 1024),
 }
 

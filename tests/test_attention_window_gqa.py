@@ -47,6 +47,137 @@ def test_forward_equals_masked_reference(s, window, bq, bk, hq, hkv):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
+# the forward kernel's two outputs, o and the rows' log-sum-exp as the
+# kernel leaves it, a row: (sequence, window, block, query heads, key-value
+# heads, head width, operands' dtype)
+ROW_LSE = {
+    "128_wide_full_grouped": (96, None, 32, 4, 2, 128, "float32"),
+    "128_wide_window_ungrouped": (96, 40, 32, 2, 2, 128, "float32"),
+    "128_wide_window_padded_grouped": (150, 70, 64, 4, 1, 128, "float32"),
+    "128_wide_full_bfloat16": (128, None, 32, 4, 2, 128, "bfloat16"),
+    "256_wide_full_ungrouped": (96, None, 32, 2, 2, 256, "float32"),
+    "256_wide_full_padded": (100, None, 32, 2, 2, 256, "float32"),
+    "256_wide_window_grouped": (96, 33, 32, 4, 2, 256, "float32"),
+    "256_wide_window_padded_bfloat16": (150, 70, 64, 2, 2, 256, "bfloat16"),
+    "one_pair_a_query_block": (130, 1, 32, 2, 1, 16, "float32"),
+    "blocks_larger_than_the_sequence": (48, 33, 64, 4, 2, 16, "float32"),
+    # blocks over one key tile (128) that are no multiple of it
+    "a_block_of_200_keys": (200, None, 512, 2, 1, 16, "float32"),
+    "window_blocks_of_192_keys": (384, 100, 192, 2, 1, 16, "float32"),
+}
+
+
+def lse_reference(q, k, window):
+    """log-sum-exp of a row's kept scores, (B, Hq, S)."""
+    k = jnp.repeat(k, q.shape[1] // k.shape[1], axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    pos = jnp.arange(q.shape[2])
+    keep = pos[None, :] <= pos[:, None]
+    if window is not None:
+        keep = keep & (pos[:, None] - pos[None, :] < window)
+    return jax.nn.logsumexp(jnp.where(keep, s, -jnp.inf), axis=-1)
+
+
+@pytest.mark.parametrize("case", sorted(ROW_LSE))
+def test_forward_o_and_row_lse_equal_masked_reference(case):
+    from pio_tpu.ops.attention import _banded_fwd
+
+    s, window, block, hq, hkv, d, dtype = ROW_LSE[case]
+    q, k, v, _ = (x.astype(dtype) for x in _inputs(s, hq, hkv, d, seed=8))
+    o, res = _banded_fwd(q, k, v, window, None, block, block, None)
+    lse = res[-1]
+    padded = -(-s // min(block, s)) * min(block, s)
+    assert o.shape == q.shape and o.dtype == q.dtype
+    assert lse.shape == (2, hq, padded) and lse.dtype == jnp.float32
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    limit = 2e-5 if dtype == "float32" else 0.06
+    want = banded_attention_reference(q, k, v, window)
+    assert float(jnp.max(jnp.abs(o.astype(jnp.float32) - want))) < limit
+    # the statistics are float32 whatever the operands: lse is held to the
+    # float32 limit on rounded operands too
+    np.testing.assert_allclose(lse[:, :, :s], lse_reference(q, k, window),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_lse_leaves_the_forward_kernel_as_a_row(window):
+    """The kernel's second output is (B, Hq, 1, S) float32, a block a
+    query block: nothing 128 lanes wide a row is written (the heads are
+    64 wide here, so no output has a minor dimension of 128), and what
+    the backward pass is handed is B x Hq x S x 4 bytes."""
+    from pio_tpu.ops.attention import _banded_fwd
+
+    q, k, v, _ = _inputs(96, 4, 2, 64)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: _banded_fwd(
+        q, k, v, window, None, 32, 32, None))(q, k, v)
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    outs = [(var.aval.shape, var.aval.dtype) for var in calls[0].outvars]
+    assert outs == [((2, 4, 96, 64), jnp.float32),
+                    ((2, 4, 1, 96), jnp.float32)]
+    assert not [e for e in jaxpr.jaxpr.eqns for var in e.outvars
+                if var.aval.shape[-1:] == (128,)]
+    kept = jaxpr.out_avals[-1]
+    assert kept.shape == (2, 4, 96) and kept.dtype == jnp.float32
+
+
+def test_layers_of_one_shape_share_one_traced_forward_kernel():
+    """Two layers of one shape and window in one program: their
+    `flash_attention_fwd` calls hold the same kernel jaxpr (traced once,
+    lowered once); another window is another kernel."""
+    from pio_tpu.ops.attention import _banded_fwd
+
+    q, k, v, _ = _inputs(96, 4, 2, 64)
+
+    def three(q, k, v):
+        o = _banded_fwd(q, k, v, None, None, 32, 32, None)[0]
+        o = _banded_fwd(o, k, v, None, None, 32, 32, None)[0]
+        return _banded_fwd(o, k, v, 40, None, 32, 32, None)[0]
+
+    kernels = [e.params["jaxpr"] for e in jax.make_jaxpr(three)(q, k, v).eqns
+               if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 3
+    assert kernels[0] is kernels[1] and kernels[2] is not kernels[0]
+
+
+@pytest.mark.parametrize("padded,bq,bk,window,want", [
+    (8192, 512, 512, None, (1024, 1024)),     # the cells' full layers
+    (8192, 512, 512, 1024, (512, 512)),       # a window layer: the caller's
+    (8704, 512, 512, None, (512, 512)),       # no multiple of 1,024
+    (2048, 2048, 1024, None, (2048, 1024)),   # a caller's larger block
+    (48, 16, 16, None, (16, 16)),
+])
+def test_the_forward_kernel_chooses_its_blocks_by_what_it_sees(
+        padded, bq, bk, window, want):
+    from pio_tpu.ops.attention import forward_blocks
+
+    assert forward_blocks(padded, bq, bk, window) == want
+
+
+def test_a_full_layers_forward_blocks_of_1024_equal_the_reference():
+    """2,048 positions, the caller's blocks 512: the forward kernel walks
+    three pairs of 1,024 x 1,024 (eight key tiles each), the backward
+    kernel ten of 512 x 512 on the forward's residuals."""
+    q, k, v, w = _inputs(2048, 2, 1, 16, seed=9, b=1)
+
+    def loss(q, k, v):
+        return jnp.sum(banded_flash_attention(q, k, v, None, None, 512, 512)
+                       * w)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v)
+    grids = {e.params["name"]: e.params["grid_mapping"].grid
+             for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"}
+    assert grids == {"flash_attention_fwd": (1, 2, 3),
+                     "flash_attention_bwd": (1, 2, 10)}
+    np.testing.assert_allclose(
+        banded_flash_attention(q, k, v, None, None, 512, 512),
+        banded_attention_reference(q, k, v), atol=2e-5, rtol=2e-5)
+    want = jax.grad(lambda q, k, v: jnp.sum(
+        banded_attention_reference(q, k, v) * w), (0, 1, 2))(q, k, v)
+    for g, r in zip(jax.grad(loss, (0, 1, 2))(q, k, v), want):
+        np.testing.assert_allclose(g, r, atol=1e-4, rtol=1e-4)
+
+
 @pytest.mark.parametrize("s,window,bq,bk,hq,hkv", SHAPES)
 def test_backward_equals_masked_reference(s, window, bq, bk, hq, hkv):
     q, k, v, w = _inputs(s, hq, hkv, seed=1)
@@ -305,6 +436,18 @@ def half_keeps_x_o_and_a_compact_lse(cfg, kind="full_attention"):
 def test_the_gradient_holds_one_forward_kernel_a_layer(monkeypatch):
     small_blocks(monkeypatch)   # float32 operands, blocks of 16
     holds_one_forward_kernel_a_layer(GQA_CFG, 2)
+
+
+def test_the_small_stacks_kept_residuals_are_the_parents_bytes(monkeypatch):
+    """27,648 bytes before the forward kernel wrote lse as a row (PR 45's
+    tree: two layers, o (2, 4, 48, 8) and lse (2, 4, 48) float32 each),
+    and after."""
+    small_blocks(monkeypatch)
+    _, params, _, grad = stack_case(GQA_CFG)
+    counted = seq_blocks.attention_counters(
+        jax.make_jaxpr(grad)(params).jaxpr)
+    assert counted["attn_residual_bytes"] == 27_648
+    assert counted["attn_fwd_kernels"] == counted["layer_applications"] == 2
 
 
 @pytest.mark.parametrize("other", sorted(OTHER_CHECKPOINTS))

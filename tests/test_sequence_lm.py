@@ -306,8 +306,10 @@ def test_profile_joins_the_stacks_scopes(op_name, scope):
 # (the two hashes below were commit 2ef95e1's until PR 34 changed the expert
 # layer's moves on purpose, PR 34's until PR 40 let the attention
 # forward's o and lse cross the checkpoint, PR 40's until PR 42 made the
-# attention's backward pass one kernel, and PR 42's until PR 43 made the
-# held experts' FFN one fused grouped op; they pin PR 43's text the same way)
+# attention's backward pass one kernel, PR 42's until PR 43 made the
+# held experts' FFN one fused grouped op, and PR 43's until PR 46 gave the
+# attention's forward kernel transposed scores in key tiles, row statistics
+# and a row lse; they pin PR 46's text the same way)
 
 def _step_lowered(cfg, learning_rate, batch):
     spec = seq_blocks.BlockSpec.parse(cfg)
@@ -329,19 +331,19 @@ def _sha(text):
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-def test_the_window_and_full_stacks_step_program_is_pr_43s():
+def test_the_window_and_full_stacks_step_program_is_pr_46s():
     """Latent attention, the dense layer, the shared expert, the sigmoid
     router and the prediction module are chosen by the specification: a
     specification without them lowers to one program text whatever a
-    later specification's keys add (the hash is of PR 43's text, at this
+    later specification's keys add (the hash is of PR 46's text, at this
     file's small blocks)."""
     assert _sha(_step_text(CFG, 0.0625, (2, 41))) == (
-        "86acbd4439f8a3aed69b748da0e1e6ea6f67f4655ae505ecf0d5e5b96dc8c2cc")
+        "be061931219deae003fc92ba552a225cbf0cd70db88ae19bb90dd39bdc0491a9")
 
 
-def test_mellum2_12b_ep4s_step_program_is_pr_43s(monkeypatch):
+def test_mellum2_12b_ep4s_step_program_is_pr_46s(monkeypatch):
     """The benchmark's configuration at its timed shapes and the
-    program's own blocks: PR 43's text, by hash."""
+    program's own blocks: PR 46's text, by hash."""
     import json
     import os
 
@@ -353,7 +355,7 @@ def test_mellum2_12b_ep4s_step_program_is_pr_43s(monkeypatch):
     with open(path) as f:
         cfg = es.block_spec_of(json.load(f))
     assert _sha(_step_text(cfg, 1e-4, (2, 8193))) == (
-        "614a3cb1794f1941a29900433a5ff155ef640d17060cb5fa25d75d485304ae2a")
+        "9cc31a47c7bf8340dd2bf5956dbd8ff9f1046a6bc9e351fda4b4440d3d8378a0")
 
 
 def test_the_repeated_scope_rule_moves_no_path_of_the_older_stack():
