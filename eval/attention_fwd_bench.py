@@ -39,6 +39,9 @@ SHAPES = {
     "glm_latent": (2, 20, 20, 8192, 256, None),
     "ouro": (2, 16, 16, 8192, 128, None),
     "nemotron": (2, 32, 2, 8192, 128, None),
+    # groups of 6 and of 8 query heads, a window of one block (PR 49)
+    "laguna_full": (2, 48, 8, 8192, 128, None),
+    "laguna_window": (2, 64, 8, 8192, 128, 512),
 }
 
 

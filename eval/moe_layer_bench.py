@@ -1,5 +1,7 @@
-"""`held_moe_ffn` alone on the chip, forward + backward, at the two
-sequence cells' shapes and three seeded routings: the cell's (a held
+"""`held_moe_ffn` alone on the chip, forward + backward, at the
+sequence cells' shapes (`laguna`: 16 of 256 experts of width 512, 256
+rows a held expert at balance, half a tile of 512; PR 49) and three
+seeded routings: the cell's (a held
 share near 0.15: what one rank's router sends over a job), balanced
 (1.0) and every choice held (the sorted buffer full, the regime in
 which moves that follow `tiles_used` can only lose).
@@ -10,8 +12,11 @@ which moves that follow `tiles_used` can only lose).
 `pio_tpu/ops/moe.py` beside this one (the parent's, from `git archive`,
 in a git-ignored directory), in the same process on the same inputs. Prints one JSON line a
 (shape, routing): milliseconds a forward + backward, held share, tiles
-used of the buffer's; `--out` writes them all. Nothing here is a cell's
-number: a layer alone times otherwise than inside the step."""
+used of the buffer's; `--out` writes them all. `--tiles 512,256,128`
+times this checkout's layer at other rows a tile too (`HeldExperts.
+tile_rows`, which the block stack sets to `MOE_TILE`): `this_tile<n>_ms`
+and that tile's fill. Nothing here is a cell's number: a layer alone
+times otherwise than inside the step."""
 
 import argparse
 import importlib.util
@@ -32,6 +37,8 @@ SHAPES = {
                     score="softmax", scale=1.0),
     "glm": dict(t=8192, d=2048, f=1536, routed=64, held=8, k=4,
                 score="sigmoid", scale=1.8),
+    "laguna": dict(t=8192, d=2048, f=512, routed=256, held=16, k=8,
+                   score="sigmoid", scale=2.5),
 }
 # what is added to the held experts' router logits
 ROUTINGS = {"cell": None, "balanced": 0.0, "full": 30.0}
@@ -110,7 +117,10 @@ def main(argv=None) -> int:
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--shapes", default="mellum2,glm")
     ap.add_argument("--routings", default="cell,balanced,full")
+    ap.add_argument("--tiles", default="512",
+                    help="rows a tile, this checkout's layer at each")
     args = ap.parse_args(argv)
+    tiles_asked = [int(t) for t in args.tiles.split(",")]
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sides = {"this": load_moe(here, "moe_this")}
     for root in args.other:
@@ -126,29 +136,35 @@ def main(argv=None) -> int:
                     "device": device.device_kind,
                     "platform": device.platform}
             for side, moe in sides.items():
-                cfg = moe.HeldExperts(
-                    shape["routed"], shape["k"], (0, shape["held"]), True,
-                    512, shape["score"], shape["scale"])
-                shift = ROUTINGS[routing]
-                if shift is None:
-                    shift = cell_shift(moe, params, x, cfg)
-                p = routed(params, shift, cfg.n_held)
+                for tile in (tiles_asked if side == "this" else [512]):
+                    cfg = moe.HeldExperts(
+                        shape["routed"], shape["k"], (0, shape["held"]),
+                        True, tile, shape["score"], shape["scale"])
+                    shift = ROUTINGS[routing]
+                    if shift is None:
+                        shift = cell_shift(moe, params, x, cfg)
+                    p = routed(params, shift, cfg.n_held)
 
-                def loss(p, x, cot, moe=moe, cfg=cfg):
-                    y, aux = moe.held_moe_ffn(p, x, cfg)
-                    return jnp.sum(y * cot), aux["counts"]
+                    def loss(p, x, cot, moe=moe, cfg=cfg):
+                        y, aux = moe.held_moe_ffn(p, x, cfg)
+                        return jnp.sum(y * cot), aux["counts"]
 
-                step = jax.jit(jax.value_and_grad(loss, (0, 1),
-                                                  has_aux=True))
-                ms = timed(step, (p, x, cot), args.reps)
-                (_, counts), _ = step(p, x, cot)
-                counts = np.asarray(counts)
-                tiles = int(np.maximum(-(-counts // 512), 1).sum())
-                line.update({
-                    "held_share": held_share(moe, p, x, cfg),
-                    "tiles_used": tiles,
-                    "tiles": cfg.row_capacity(shape["t"]) // 512,
-                    side + "_ms": min(ms), side + "_ms_all": ms})
+                    step = jax.jit(jax.value_and_grad(loss, (0, 1),
+                                                      has_aux=True))
+                    ms = timed(step, (p, x, cot), args.reps)
+                    (_, counts), _ = step(p, x, cot)
+                    counts = np.asarray(counts)
+                    used = int(np.maximum(-(-counts // tile), 1).sum())
+                    key = side if tile == 512 else f"{side}_tile{tile}"
+                    line.update({key + "_ms": min(ms),
+                                 key + "_ms_all": ms,
+                                 key + "_tile_fill": float(
+                                     counts.sum() / (used * tile))})
+                    if tile == 512:
+                        line.update({
+                            "held_share": held_share(moe, p, x, cfg),
+                            "tiles_used": used,
+                            "tiles": cfg.row_capacity(shape["t"]) // 512})
             for side in list(sides)[1:]:
                 line["this_over_" + side] = (line["this_ms"]
                                              / line[side + "_ms"])

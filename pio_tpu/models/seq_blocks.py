@@ -43,12 +43,37 @@ a residual add, and there are no two halves:
     expert and the shared expert `W_down relu(W_up v)^2`: no gate;
   "*": grouped-query attention, full and causal, no rotary positions.
 
+With `num_attention_heads_per_layer`, `rope_parameters[kind].
+partial_rotary_factor`, `use_qk_norm` and `gating` a layer's attention
+half is the gated grouped-query one, kind k of layer l from
+`layer_types`, H_k query heads over G key-value heads of D, y =
+RMSNorm(x):
+
+  q = y W_q -> (S, H_k, D);  k = y W_k, v = y W_v -> (S, G, D)
+  q <- RMSNorm_D(q) g_q;  k <- RMSNorm_D(k) g_k    (a head's own D)
+  q, k <- the first R_k = factor_k D dimensions rotate as halves (pairs
+          (i, i + R_k / 2)), frequencies over R_k; R_k .. D pass
+  o_h = softmax(q_h k_g^T / sqrt(D) + mask_k) v_g,   g = h // (H_k / G)
+  gamma = softplus(y W_g) -> (S, H_k);  o_h <- gamma_h o_h
+  h = x + concat_h(o_h) W_o
+
+and `mlp_layer_types` says which layers' second half is dense (a leading
+run of "dense") and which routes ("sparse").
+
 What a specification chooses, by its keys (`BlockSpec.parse` refuses by
 name what the stack does not compute):
 
  * attention: grouped-query heads (`head_dim`, `num_key_value_heads`,
    `layer_types` of window and full layers, rotary positions by layer
-   kind, RoPE or YaRN), or latent attention (`q_lora_rank`,
+   kind, RoPE or YaRN; by layer kind too the number of query heads,
+   `num_attention_heads_per_layer`: one count a kind, whole groups of
+   the key-value heads, and the width that rotates,
+   `rope_parameters[kind].partial_rotary_factor`; `use_qk_norm`: an
+   RMSNorm over a head's own dimensions on every query and key head
+   before it rotates, leaves `q_head_norm` / `k_head_norm`; `gating`
+   true or "per-head": a gate a head on the kernel's output, leaf
+   `w_gate_heads` (d, H_k), the product made in float32 and rounded once
+   as W_o's operand), or latent attention (`q_lora_rank`,
    `kv_lora_rank`, `qk_nope_head_dim`, `qk_rope_head_dim`, `v_head_dim`:
    low-rank query and key-value paths with an RMSNorm each, rotary
    positions on the `qk_rope_head_dim` dimensions only, one rope key
@@ -57,14 +82,17 @@ name what the stack does not compute):
  * the second half of a layer: a dense SwiGLU of `intermediate_size`, in
    every layer of a specification without expert keys (no `num_experts`
    / `n_routed_experts`: no router, no held experts) and in the first
-   `first_k_dense_replace` layers of one with them; else ops/moe.py
+   `first_k_dense_replace` layers of one with them (or the leading run
+   of "dense" in its `mlp_layer_types`); else ops/moe.py
    `held_moe_ffn` (dropless top-k, the held experts' part by a grouped
    matrix product), one history at a time, with `n_shared_experts`
-   shared experts (a dense SwiGLU every token takes) beside it;
+   shared experts (a dense SwiGLU every token takes; or one of
+   `shared_expert_intermediate_size`) beside it;
  * the router: softmax top-k, or with `topk_method: noaux_tc` sigmoid
    scores, selection on score + bias, weights from the unbiased score
-   times `routed_scaling_factor`. The bias is a leaf of the parameter
-   tree (`router_bias`, so it is persisted with the model) that takes no
+   times `routed_scaling_factor` (or `moe_routed_scaling_factor`). The
+   bias is a leaf of the parameter tree (`router_bias`, so it is
+   persisted with the model) that takes no
    gradient: the train step moves it after the optimizer, `b +=
    ROUTER_BIAS_RATE * sign(mean(c) - c)` over the step's token counts c
    of every routed expert;
@@ -224,6 +252,15 @@ class BlockSpec:
     # matrices) or "relu2" (`W_down relu(W_up x)^2`, two)
     expert_act: str = "swiglu"
     shared_intermediate_size: int = 0   # the shared expert's width
+    # grouped-query heads that differ by layer kind
+    # (`num_attention_heads_per_layer`): kind -> query heads, and
+    # (`rope_parameters[kind].partial_rotary_factor`) kind -> the leading
+    # dimensions of a head that rotate; () where every kind has
+    # `num_attention_heads` heads that rotate whole
+    heads_by_kind: tuple[tuple[str, int], ...] = ()
+    rotary_by_kind: tuple[tuple[str, int], ...] = ()
+    attn_gate: bool = False             # `gating`: a gate a head on o
+    head_norms: bool = False            # `use_qk_norm`: RMSNorm of q, k heads
 
     @classmethod
     def parse(cls, spec: str | dict) -> "BlockSpec":
@@ -243,8 +280,32 @@ class BlockSpec:
         experts_key = next((k for k in ("num_experts", "n_routed_experts")
                             if k in c), None)
         looped = "total_ut_steps" in c
+        # `mlp_layer_types`: a leading run of "dense", then "sparse"
+        mlp_kinds = list(c.get("mlp_layer_types", ()))[:n_layers]
+        lead = next((n for n, t in enumerate(mlp_kinds) if t != "dense"),
+                    len(mlp_kinds))
         n_dense = 0 if hybrid else (
-            c.get("first_k_dense_replace", 0) if experts_key else n_layers)
+            c.get("first_k_dense_replace", lead) if experts_key
+            else n_layers)
+        # grouped-query heads by layer kind: query heads, rotating width
+        grouped = not (hybrid or latent)
+        per_layer = c.get("num_attention_heads_per_layer")
+        layer_kinds = list(c.get("layer_types", ()))[:n_layers]
+        heads: dict = {}
+        for kind, count in zip(layer_kinds, per_layer or ()):
+            heads.setdefault(kind, set()).add(count)
+        ropes_in = c.get("rope_parameters") or {}
+        factors = {
+            kind: ropes_in[kind]["partial_rotary_factor"]
+            for kind in set(layer_kinds)
+            if "partial_rotary_factor" in ropes_in.get(kind, {})}
+        top_factor = c.get("partial_rotary_factor", 1)
+        gating = c.get("gating", False)
+        shared_key = next((k for k in (
+            "moe_shared_expert_intermediate_size",
+            "shared_expert_intermediate_size") if c.get(k)), None)
+        scale_keys = [k for k in ("routed_scaling_factor",
+                                  "moe_routed_scaling_factor") if k in c]
         # the feed-forward activation, under either of its keys: relu2
         # (two matrices, no gate) in experts and shared experts alone
         acts = {k: c[k] for k in ("hidden_act", "mlp_hidden_act") if k in c}
@@ -267,9 +328,31 @@ class BlockSpec:
             "n_groups": "M" in blocks and bool(
                 c["mamba_num_heads"] % c["n_groups"]),
             "tie_word_embeddings": bool(c.get("tie_word_embeddings", False)),
-            "mlp_layer_types": any(
-                t != "sparse" for t in
-                c.get("mlp_layer_types", [])[:n_layers]),
+            # a dense layer after a sparse one, a count of leading dense
+            # layers that `first_k_dense_replace` gives otherwise, or
+            # expert keys and no sparse layer for them
+            "mlp_layer_types": bool(set(mlp_kinds) - {"dense", "sparse"})
+                or "dense" in mlp_kinds[lead:]
+                or bool(lead) and (hybrid or looped or n_dense != lead or (
+                    experts_key is not None and lead == n_layers)),
+            # one count a kind, of whole groups of key-value heads
+            "num_attention_heads_per_layer": per_layer is not None and (
+                not grouped or looped or len(per_layer) < n_layers
+                or any(len(v) > 1 or next(iter(v)) % c.get(
+                    "num_key_value_heads", 1) for v in heads.values())),
+            # `gating`: a gate a head (true or "per-head"), on grouped-
+            # query heads
+            "gating": gating not in (False, True, "per-head")
+                or bool(gating) and not grouped,
+            "gating_types": bool(set(c.get("gating_types", ())) - {
+                "per_head"}) or "gating_types" in c and not gating,
+            "use_qk_norm": bool(c.get("use_qk_norm")) and not grouped,
+            "moe_apply_router_weight_on_input": bool(c.get(
+                "moe_apply_router_weight_on_input", False)),
+            "moe_router_logit_softcapping": bool(c.get(
+                "moe_router_logit_softcapping", 0)),
+            "moe_routed_scaling_factor": len(
+                {float(c[k]) for k in scale_keys}) > 1,
             "n_group": c.get("n_group", 1) > 1 or c.get("topk_group", 1) > 1,
             "topk_method": c.get("topk_method", "greedy") not in (
                 "greedy", "noaux_tc"),
@@ -279,7 +362,17 @@ class BlockSpec:
             ) != ROUTER_BIAS_RATE,
             "mtp_loss_weight": c.get(
                 "mtp_loss_weight", MTP_LOSS_WEIGHT) != MTP_LOSS_WEIGHT,
-            "partial_rotary_factor": c.get("partial_rotary_factor", 1) != 1,
+            # a rotating width by layer kind: every kind states its
+            # own (an even number of a head's dimensions) and the
+            # top-level key, the model's default, is one of them;
+            # without them the top-level key is 1
+            "partial_rotary_factor": top_factor != 1 and not (
+                grouped and factors and set(factors) == set(layer_kinds)
+                and top_factor in factors.values()),
+            "rope_parameters": bool(factors) and (
+                not grouped or looped or any(
+                    f <= 0 or f > 1 or (f * c["head_dim"]) % 2
+                    for f in factors.values())),
             # latent attention as computed here: a low-rank query path,
             # every head its own keys and values, q.k as wide as p.v
             "q_lora_rank": latent and not c.get("q_lora_rank"),
@@ -335,7 +428,10 @@ class BlockSpec:
         ropes = {} if hybrid else c.get("rope_parameters") or {
             "full_attention": {"rope_type": "default",
                                "rope_theta": c["rope_theta"]}}
-        n_shared = (c.get("n_shared_experts") or 0) if n_held else 0
+        # a shared expert by its width alone is one shared expert
+        n_shared = (c.get("n_shared_experts")
+                    or int(shared_key is not None)) if n_held else 0
+        head_dim = v_dim if latent else c["head_dim"]
         mamba = {k: c[k] for k in (
             "mamba_num_heads", "mamba_head_dim", "ssm_state_size", "n_groups",
             "conv_kernel", "chunk_size")} if "M" in blocks else {}
@@ -345,7 +441,7 @@ class BlockSpec:
             num_attention_heads=c["num_attention_heads"],
             num_key_value_heads=c.get("num_key_value_heads",
                                       c["num_attention_heads"]),
-            head_dim=v_dim if latent else c["head_dim"],
+            head_dim=head_dim,
             sliding_window=c.get("sliding_window") or 0,
             rope=tuple(sorted(
                 (kind, tuple(sorted(ropes[kind].items())))
@@ -369,9 +465,10 @@ class BlockSpec:
             v_head_dim=v_dim if latent else 0,
             dense_layers=n_dense,
             intermediate_size=c.get("intermediate_size", 0),
-            n_shared_experts=c.get("n_shared_experts") or 0,
+            n_shared_experts=c.get("n_shared_experts") or n_shared,
             scoring=scoring,
-            routed_scaling_factor=float(c.get("routed_scaling_factor", 1.0)),
+            routed_scaling_factor=float(
+                c[scale_keys[0]] if scale_keys else 1.0),
             mtp_layers=c.get("num_nextn_predict_layers", 0),
             loop_steps=c.get("total_ut_steps", 0),
             block_kinds=blocks, **mamba,
@@ -380,9 +477,15 @@ class BlockSpec:
                 ("time_step_min", 0.001), ("time_step_max", 0.1),
                 ("time_step_floor", 0.0001))),
             expert_act="relu2" if act == "relu2" else "swiglu",
-            shared_intermediate_size=c.get(
-                "moe_shared_expert_intermediate_size",
-                n_shared * c["moe_intermediate_size"]) if n_shared else 0)
+            shared_intermediate_size=(
+                c[shared_key] if shared_key
+                else n_shared * c["moe_intermediate_size"]
+            ) if n_shared else 0,
+            heads_by_kind=tuple(sorted(
+                (kind, next(iter(v))) for kind, v in heads.items())),
+            rotary_by_kind=tuple(sorted(
+                (kind, int(f * head_dim)) for kind, f in factors.items())),
+            attn_gate=bool(gating), head_norms=bool(c.get("use_qk_norm")))
 
     @property
     def experts(self) -> HeldExperts:
@@ -398,8 +501,18 @@ class BlockSpec:
 
     @property
     def rope_dim(self) -> int:
-        """The dimensions of a head that rotate."""
+        """The dimensions of a head that rotate, where every layer kind
+        has the same (`rotary_dims` where not)."""
         return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+
+    def rotary_dims(self, kind: str) -> int:
+        """The leading dimensions of a head that rotate in a layer of
+        that kind; the rest pass."""
+        return dict(self.rotary_by_kind).get(kind, self.rope_dim)
+
+    def q_heads(self, kind: str) -> int:
+        """Query heads of a layer of that kind."""
+        return dict(self.heads_by_kind).get(kind, self.num_attention_heads)
 
     def window(self, kind: str) -> int | None:
         return self.sliding_window if kind == "sliding_attention" else None
@@ -454,10 +567,12 @@ def rope_inv_freq(rope: dict, head_dim: int) -> tuple[np.ndarray, float]:
 
 
 def rope_tables(spec: BlockSpec, seq_len: int) -> dict:
-    """kind -> (cos, sin), each (seq_len, rope_dim / 2) float32."""
+    """kind -> (cos, sin), each (seq_len, rotary_dims(kind) / 2)
+    float32: the inverse frequencies, and YaRN's correction range, are
+    over the dimensions that rotate."""
     out = {}
     for kind, items in spec.rope:
-        inv, scale = rope_inv_freq(dict(items), spec.rope_dim)
+        inv, scale = rope_inv_freq(dict(items), spec.rotary_dims(kind))
         angle = np.arange(seq_len, dtype=np.float64)[:, None] * inv[None]
         out[kind] = (np.float32(np.cos(angle) * scale),
                      np.float32(np.sin(angle) * scale))
@@ -465,10 +580,15 @@ def rope_tables(spec: BlockSpec, seq_len: int) -> dict:
 
 
 def apply_rope(x, cos, sin):
-    """x: (B, H, S, D) float32; the two halves of D rotate as pairs."""
-    half = x.shape[-1] // 2
-    a, b = x[..., :half], x[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    """x: (B, H, S, D) float32; cos and sin (S, R / 2): the first R
+    dimensions of D rotate, their two halves as pairs (i, i + R / 2),
+    and the dimensions R .. D pass."""
+    half = cos.shape[-1]
+    a, b = x[..., :half], x[..., half:2 * half]
+    turned = [a * cos - b * sin, b * cos + a * sin]
+    if 2 * half < x.shape[-1]:
+        turned.append(x[..., 2 * half:])
+    return jnp.concatenate(turned, axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -517,8 +637,10 @@ def _block_shapes(spec: BlockSpec, kind: str) -> dict:
     return shapes
 
 
-def _layer_shapes(spec: BlockSpec, dense: bool) -> dict:
-    d, h = spec.hidden_size, spec.num_attention_heads
+def _layer_shapes(spec: BlockSpec, dense: bool, kind: str) -> dict:
+    """One layer of the two halves; its attention is of `kind`, which
+    says how many query heads it has."""
+    d, h = spec.hidden_size, spec.q_heads(kind)
     if spec.kv_lora_rank:
         rq, rkv = spec.q_lora_rank, spec.kv_lora_rank
         dn, dr, dv = (spec.qk_nope_head_dim, spec.qk_rope_head_dim,
@@ -530,6 +652,12 @@ def _layer_shapes(spec: BlockSpec, dense: bool) -> dict:
         hq = h * spec.head_dim
         hkv = spec.num_key_value_heads * spec.head_dim
         layer = {"wq": (d, hq), "wk": (d, hkv), "wv": (d, hkv), "wo": (hq, d)}
+        # `w_gate` is the experts' and `q_norm` the latent path's
+        if spec.attn_gate:
+            layer["w_gate_heads"] = (d, h)
+        if spec.head_norms:
+            layer.update({"q_head_norm": (spec.head_dim,),
+                          "k_head_norm": (spec.head_dim,)})
     layer.update({"norm1": (d,), "norm2": (d,)})
     if spec.loop_steps:
         layer.update({"norm1_post": (d,), "norm2_post": (d,)})
@@ -548,12 +676,13 @@ def param_shapes(spec: BlockSpec) -> dict:
               "final_norm": (d,),
               "layers": [_block_shapes(spec, kind) for kind in
                          spec.block_kinds] if spec.block_kinds else [
-                  _layer_shapes(spec, n < spec.dense_layers)
-                  for n in range(spec.num_hidden_layers)]}
+                  _layer_shapes(spec, n < spec.dense_layers, kind)
+                  for n, kind in enumerate(spec.layer_types)]}
     if spec.mtp_layers:
         shapes["mtp"] = {"enorm": (d,), "hnorm": (d,), "eh_proj": (2 * d, d),
                          "final_norm": (d,),
-                         "layer": _layer_shapes(spec, False)}
+                         "layer": _layer_shapes(spec, False,
+                                                spec.layer_types[-1])}
     if spec.loop_steps:
         shapes.update({"exit_gate": (d, 1), "exit_bias": (1,)})
     return shapes
@@ -644,26 +773,41 @@ def _attention_half(lp, x, cos, sin, *, spec: BlockSpec, kind: str):
     if spec.kv_lora_rank:
         return _latent_attention_half(lp, x, cos, sin, spec=spec)
     b, s, d = x.shape
-    hq, hkv, dh = (spec.num_attention_heads, spec.num_key_value_heads,
+    hq, hkv, dh = (spec.q_heads(kind), spec.num_key_value_heads,
                    spec.head_dim)
     with jax.named_scope("seq.attn.proj"):
         y = rms_norm(x, lp["norm1"], spec.rms_norm_eps).astype(COMPUTE)
 
-        def heads(w, n):
-            return jnp.einsum("bsd,dhk->bhsk", y,
-                              w.astype(COMPUTE).reshape(d, n, dh),
-                              preferred_element_type=jnp.float32)
+        def heads(w, n, gain=None):
+            t = jnp.einsum("bsd,dhk->bhsk", y,
+                           w.astype(COMPUTE).reshape(d, n, dh),
+                           preferred_element_type=jnp.float32)
+            # `use_qk_norm`: a head normed over its own dimensions,
+            # before it rotates
+            return t if gain is None else rms_norm(
+                t, lp[gain], spec.rms_norm_eps)
 
         def rotated(t):     # a pattern stack's attention: no rotation
             return t if cos is None else apply_rope(t, cos, sin)
 
-        q = rotated(heads(lp["wq"], hq)).astype(COMPUTE)
-        k = rotated(heads(lp["wk"], hkv)).astype(COMPUTE)
+        norms = ("q_head_norm", "k_head_norm") if spec.head_norms else (
+            None, None)
+        q = rotated(heads(lp["wq"], hq, norms[0])).astype(COMPUTE)
+        k = rotated(heads(lp["wk"], hkv, norms[1])).astype(COMPUTE)
         v = heads(lp["wv"], hkv).astype(COMPUTE)
     window = spec.window(kind)
     with jax.named_scope("seq.attn.window" if window else "seq.attn.full"):
         o = banded_flash_attention(q, k, v, window, None,
                                    ATTN_BLOCK, ATTN_BLOCK)
+    if spec.attn_gate:
+        # `gating`: o_h <- softplus(y W_g)_h * o_h, the product in
+        # float32 on the kernel's o, rounded once as W_o's operand
+        with jax.named_scope("seq.attn.gate"):
+            gate = jax.nn.softplus(jnp.dot(
+                y, lp["w_gate_heads"].astype(COMPUTE),
+                preferred_element_type=jnp.float32))        # (B, S, H)
+            o = (o.astype(jnp.float32)
+                 * gate.transpose(0, 2, 1)[..., None]).astype(COMPUTE)
     with jax.named_scope("seq.attn.proj"):
         out = jnp.einsum("bhsk,hkd->bsd", o,
                          lp["wo"].astype(COMPUTE).reshape(hq, dh, d),
@@ -1176,6 +1320,12 @@ def epoch_order(n: int, steps: int, batch: int, seed: int) -> np.ndarray:
     return order[np.arange(steps * batch) % n].reshape(steps, batch)
 
 
+def group_tiles(counts: np.ndarray, tile_rows: int) -> np.ndarray:
+    """Tiles of the sorted buffer each group owns: its rows rounded up
+    to whole tiles, an empty group one tile."""
+    return np.maximum(-(-counts // tile_rows), 1)
+
+
 def tiles_used_share(counts: np.ndarray, held: HeldExperts,
                      positions: int) -> float:
     """Tiles of the sorted buffer that hold a group, which are the tiles
@@ -1184,8 +1334,34 @@ def tiles_used_share(counts: np.ndarray, held: HeldExperts,
     held expert; the mean over everything before. An empty group owns
     one tile."""
     tm = held.tile_rows
-    used = np.maximum(-(-counts // tm), 1).sum(axis=-1)
+    used = group_tiles(counts, tm).sum(axis=-1)
     return float(used.mean() / (held.row_capacity(positions) // tm))
+
+
+def tile_fill(counts: np.ndarray, held: HeldExperts) -> float:
+    """Rows that hold a (token, held expert) choice over the rows of the
+    tiles the grouped products visit (`tiles_used_share`'s tiles): what
+    is left is the padding of each group to whole tiles. counts (...,
+    held experts): a history's tokens per held expert; over everything
+    before, as a ratio of sums."""
+    tm = held.tile_rows
+    return float(counts.sum() / (group_tiles(counts, tm).sum() * tm))
+
+
+def head_counters(spec: BlockSpec) -> dict:
+    """Query heads and rotating dimensions of a head, by the kind of
+    attention layer the stack has: what the attention kernels' shapes
+    and the rotation's width are a layer kind at a time."""
+    kinds = set(spec.layer_types) or (
+        {"full_attention"} if "*" in spec.block_kinds else set())
+    out = {}
+    for kind, label in (("full_attention", "full"),
+                        ("sliding_attention", "window")):
+        if kind in kinds:
+            out[f"attn_q_heads_{label}"] = spec.q_heads(kind)
+            out[f"attn_rotary_dims_{label}"] = (
+                0 if spec.block_kinds else spec.rotary_dims(kind))
+    return out
 
 
 def band_counters(spec: BlockSpec, seq_len: int) -> dict:
@@ -1313,6 +1489,8 @@ def _expert_counters(counters: list, spec: BlockSpec,
         # only every choice of every token held fills it
         expert_tiles_used_share=repr(tiles_used_share(
             counts, held, positions)),
+        # rows that hold a choice over the rows of those tiles
+        expert_tile_fill=repr(tile_fill(counts, held)),
         dropped_tokens=dropped)
 
 
@@ -1393,7 +1571,8 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
         sp.update(tokens_per_step=batch * positions,
                   loss_first=repr(float(losses[0])),
                   loss_last=repr(float(losses[-1])),
-                  loop_steps=spec.loop_steps or 1, **program)
+                  loop_steps=spec.loop_steps or 1, **program,
+                  **head_counters(spec))
         if "counts" in counters[0]:
             sp.update(**_expert_counters(counters, spec, positions))
         if "sliding_attention" in spec.layer_types:

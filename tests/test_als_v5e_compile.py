@@ -80,6 +80,9 @@ ATTENTION = {
     "mellum2_window": (2, 32, 4, 8192, 128, 1024),
     "ouro": (2, 16, 16, 8192, 128, None),
     "nemotron": (2, 32, 2, 8192, 128, None),
+    # groups of 6 and of 8 query heads, a window of one block (PR 49)
+    "laguna_full": (2, 48, 8, 8192, 128, None),
+    "laguna_window": (2, 64, 8, 8192, 128, 512),
     "64k_positions": (1, 2, 1, 65536, 128, 1024),
 }
 
@@ -109,8 +112,9 @@ def test_banded_attention_gradient_compiles_for_v5e(one_chip, case):
 
 
 # (rows of the sorted buffer a history, hidden, expert width, held
-# experts): the expert layers of the two sequence cells that route
-EXPERTS = {"mellum2": (73_728, 2304, 896, 16), "glm": (36_864, 2048, 1536, 8)}
+# experts): the expert layers of the sequence cells that route by SwiGLU
+EXPERTS = {"mellum2": (73_728, 2304, 896, 16), "glm": (36_864, 2048, 1536, 8),
+           "laguna": (73_728, 2048, 512, 16)}
 
 
 @pytest.mark.parametrize("case", sorted(EXPERTS))

@@ -27,6 +27,7 @@ ALIASES = {"span-self-loop": "span-self", "idle-span-loop": "idle-span"}
 # (benchmark/tests/test_contract_loop.py and test_contract_ssm.py hold the
 # looped and the state-space cells' scopes)
 OVERLAYS = {**OVERLAYS, "train_sequence_loop": "loop-tiny.json",
+            "train_sequence_gated": "gated-tiny.json",
             "train_sequence_ssm": "ssm-tiny.json"}
 LISTED = {m["name"]: m for m in BENCH["per_layer"]}
 
