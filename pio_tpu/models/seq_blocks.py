@@ -38,6 +38,8 @@ a residual add, and there are no two halves:
       h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T;  y_t = h_t C_t + D x_t
       out = RMSNorm_groups(y * silu(z)) W_out       (G groups, one gain)
     the scan is ops/ssd.py `ssd_scan`, computed in chunks of `chunk_size`;
+    the convolution and the norm are ops/ssm_rows.py's passes over u W_in
+    where it lies, if the widths are whole lane tiles;
   "E": the expert layer below (sigmoid scores, selection on score + bias,
     weights from the unbiased scores, normalised, times the factor), an
     expert and the shared expert `W_down relu(W_up v)^2`: no gate;
@@ -159,6 +161,7 @@ import optax
 from jax.extend.core import Var
 from jax.extend.core.primitives import name_p, remat_p
 
+from pio_tpu.ops import ssm_rows
 from pio_tpu.ops.attention import (
     KEPT_RESIDUALS,
     band_blocks,
@@ -935,32 +938,44 @@ def _mamba_block(lp, x, *, spec: BlockSpec):
     """x (B, S, d) -> x + the Mamba-2 mixer of its norm: in_proj to
     [z | xBC | dt], a causal convolution and SiLU over xBC, the scan
     (ops/ssd.py) of x by dt, B, C with A = -exp(A_log) and the skip D,
-    the gated group norm, out_proj."""
+    the gated group norm, out_proj. Where the shapes are whole lane tiles
+    the convolution and the norm are ops/ssm_rows.py's passes over
+    in_proj's output where it lies; else the functions above, on slices."""
     b, s, _ = x.shape
     h, p, g, n = (spec.mamba_num_heads, spec.mamba_head_dim, spec.n_groups,
                   spec.ssm_state_size)
-    di = spec.mamba_inner
+    di, taps = spec.mamba_inner, lp["conv_w"].shape[0]
+    widths = (di, g * n, g * n)                           # x, B, C
+    rows = ssm_rows.takes(s, taps, *widths, di // g)
     with jax.named_scope("seq.ssm.proj"):
         y = rms_norm(x, lp["norm"], spec.rms_norm_eps).astype(COMPUTE)
         zxbcdt = jnp.dot(y, lp["in_proj"].astype(COMPUTE),
                          preferred_element_type=jnp.float32)
-        z, xbc, dt = (zxbcdt[..., :di], zxbcdt[..., di:-h], zxbcdt[..., -h:])
+        dt = zxbcdt[..., -h:]
     with jax.named_scope("seq.ssm.conv"):
-        xbc = jax.nn.silu(causal_conv(xbc, lp["conv_w"], lp.get("conv_b")))
+        if rows:
+            xs, b_mat, c_mat = ssm_rows.conv_silu(
+                zxbcdt, lp["conv_w"], lp.get("conv_b", jnp.zeros(sum(widths))),
+                di, widths, COMPUTE)
+        else:
+            xbc = jax.nn.silu(causal_conv(
+                zxbcdt[..., di:-h], lp["conv_w"], lp.get("conv_b")))
+            xs, b_mat, c_mat = (v.astype(COMPUTE) for v in jnp.split(
+                xbc, (di, di + g * n), axis=-1))
     with jax.named_scope("seq.ssm.scan"):
-        xs, b_mat, c_mat = (xbc[..., :di], xbc[..., di:di + g * n],
-                            xbc[..., di + g * n:])
         out = ssd_scan(
-            xs.astype(COMPUTE).reshape(b, s, h, p),
-            jax.nn.softplus(dt + lp["dt_bias"]), -jnp.exp(lp["A_log"]),
-            b_mat.astype(COMPUTE).reshape(b, s, g, n),
-            c_mat.astype(COMPUTE).reshape(b, s, g, n), lp["D"],
-            spec.chunk_size)
+            xs.reshape(b, s, h, p), jax.nn.softplus(dt + lp["dt_bias"]),
+            -jnp.exp(lp["A_log"]), b_mat.reshape(b, s, g, n),
+            c_mat.reshape(b, s, g, n), lp["D"], spec.chunk_size)
     with jax.named_scope("seq.ssm.proj"):
-        out = gated_group_norm(out.reshape(b, s, di), z, lp["ssm_norm"], g,
-                               spec.rms_norm_eps)
-        return x + jnp.dot(out.astype(COMPUTE),
-                           lp["out_proj"].astype(COMPUTE),
+        out = out.reshape(b, s, di)
+        if rows:
+            out = ssm_rows.gated_norm(out, zxbcdt, lp["ssm_norm"], g,
+                                      spec.rms_norm_eps, COMPUTE)
+        else:
+            out = gated_group_norm(out, zxbcdt[..., :di], lp["ssm_norm"], g,
+                                   spec.rms_norm_eps).astype(COMPUTE)
+        return x + jnp.dot(out, lp["out_proj"].astype(COMPUTE),
                            preferred_element_type=jnp.float32)
 
 
@@ -1392,11 +1407,14 @@ def attention_counters(jaxpr) -> dict:
     named, outside every recomputation, times the loops around it), and
     the calls of the scan's kernels `ssd_chunk_fwd` / `ssd_chunk_bwd`, a
     loop's body once (the forward kernel also in a block's
-    recomputation); a program without a scan has none of the three
-    keys."""
+    recomputation), and `ssm_conv_kernels` / `ssm_norm_kernels`, the
+    calls either way of ops/ssm_rows.py's passes (`ssm_conv_silu_*`,
+    `ssm_gated_norm_*`: 0 where a block's shapes sent it to the plain
+    functions); a program without a scan has none of the five keys."""
     found = {"attn_fwd_kernels": 0, "attn_bwd_kernels": 0,
              "attn_residual_bytes": 0, "layer_applications": 0}
     scans = {"ssm_fwd_kernels": 0, "ssm_bwd_kernels": 0,
+             "ssm_conv_kernels": 0, "ssm_norm_kernels": 0,
              "ssm_state_bytes": 0}
     counts = {**found, **scans}
 
@@ -1431,6 +1449,10 @@ def attention_counters(jaxpr) -> dict:
                         name == "flash_attention_bwd")
                 elif name in ("ssd_chunk_fwd", "ssd_chunk_bwd"):
                     counts[f"ssm_{name[-3:]}_kernels"] += 1
+                elif name.startswith("ssm_conv_silu_"):
+                    counts["ssm_conv_kernels"] += 1
+                elif name.startswith("ssm_gated_norm_"):
+                    counts["ssm_norm_kernels"] += 1
             inside = times * eqn.params.get("length", 1) if (
                 eqn.primitive.name == "scan") else times
             for sub in jax.core.jaxprs_in_params(eqn.params):
@@ -1578,7 +1600,7 @@ def train_lm(seqs: np.ndarray, p, lifecycle=None):
         if "sliding_attention" in spec.layer_types:
             sp.update(**band_counters(spec, positions))
         if n_scans:
-            # ssm_state_bytes and the scan's kernels came with `program`
+            # ssm_state_bytes and the kernels' counts came with `program`
             sp.update(ssm_blocks=n_scans,
                       ssm_chunks=positions // spec.chunk_size,
                       block_kinds=" ".join(

@@ -1,6 +1,6 @@
 """The packed-A kernels of the ALS solve, the banded attention's two
-kernels, the held experts' grouped kernels and the state-space scan's
-two, compiled by the TPU's
+kernels, the held experts' grouped kernels, the state-space scan's two
+and the four row-tiled passes of a Mamba-2 block, compiled by the TPU's
 compiler for a described v5e at the benchmark's widths: what Mosaic
 refuses (a slice off the tiling, a stack over the scoped VMEM) shows here,
 on a CPU, at no chip time. Nothing runs: these are compiles, not
@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from pio_tpu.ops import als_pallas, attention, moe, ssd
+from pio_tpu.ops import als_pallas, attention, moe, ssd, ssm_rows
 
 ML20M_USERS, ML20M_ITEMS, MSD_ITEM_BLOCK = 138_493, 26_744, 96_137
 
@@ -200,3 +200,56 @@ def test_ssd_scan_gradient_compiles_for_v5e(one_chip, monkeypatch):
     ).compile().as_text()
     assert text.count("tpu_custom_call") == 2
     assert "ssd_chunk_fwd" in text and "ssd_chunk_bwd" in text
+
+
+# in_proj's output in the state-space cell: z 4,096 | x 4,096, B 1,024,
+# C 1,024 | dt 64
+ROWS_S, ROWS_DI, ROWS_GN, ROWS_H = 8192, 4096, 1024, 64
+
+
+def _rows_shape(one_chip, *shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct((1, ROWS_S) + shape, dtype, sharding=one_chip)
+
+
+def test_conv_silu_gradient_compiles_for_v5e(one_chip, monkeypatch):
+    """The convolution's pass over the columns [4096, 10240) of (1, 8192,
+    10304) float32, forward and backward: x, B and C as three bfloat16
+    arrays from one call, their cotangents into one."""
+    monkeypatch.setattr(ssm_rows, "_interpret", lambda: False)
+    widths = (ROWS_DI, ROWS_GN, ROWS_GN)
+
+    def gradient(u, w, bias, *cots):
+        out, back = jax.vjp(lambda *a: ssm_rows.conv_silu(
+            *a, ROWS_DI, widths, jnp.bfloat16), u, w, bias)
+        return out, back(cots)
+
+    text = jax.jit(gradient).lower(
+        _rows_shape(one_chip, 2 * ROWS_DI + 2 * ROWS_GN + ROWS_H),
+        jax.ShapeDtypeStruct((4, sum(widths)), jnp.float32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((sum(widths),), jnp.float32, sharding=one_chip),
+        *(_rows_shape(one_chip, w, dtype=jnp.bfloat16) for w in widths),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "ssm_conv_silu_fwd" in text and "ssm_conv_silu_bwd" in text
+
+
+def test_gated_norm_gradient_compiles_for_v5e(one_chip, monkeypatch):
+    """The gated group norm's pass, 8 groups of 512 lanes, z read in the
+    wide array: no (8192, 8, 512) view of anything."""
+    monkeypatch.setattr(ssm_rows, "_interpret", lambda: False)
+
+    def gradient(y, u, gain, cot):
+        out, back = jax.vjp(lambda *a: ssm_rows.gated_norm(
+            *a, 8, 1e-5, jnp.bfloat16), y, u, gain)
+        return out, back(cot)
+
+    text = jax.jit(gradient).lower(
+        _rows_shape(one_chip, ROWS_DI),
+        _rows_shape(one_chip, 2 * ROWS_DI + 2 * ROWS_GN + ROWS_H),
+        jax.ShapeDtypeStruct((ROWS_DI,), jnp.float32, sharding=one_chip),
+        _rows_shape(one_chip, ROWS_DI, dtype=jnp.bfloat16),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "ssm_gated_norm_fwd" in text and "ssm_gated_norm_bwd" in text
+    assert "8,512]" not in text

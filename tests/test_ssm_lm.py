@@ -276,6 +276,8 @@ def test_run_train_persists_loads_and_predicts_the_stack(monkeypatch):
     assert labels["block_kinds"] == "M4 E4 *1"
     assert labels["ssm_state_bytes"] == 4 * 2 * 4 * 8 * 8 * 16 * 4
     assert labels["ssm_fwd_kernels"] == 8 and labels["ssm_bwd_kernels"] == 4
+    # 64 channels in groups of 32: the plain convolution and norm
+    assert labels["ssm_conv_kernels"] == labels["ssm_norm_kernels"] == 0
     assert labels["tokens_per_step"] == 2 * POSITIONS
     assert labels["dropped_tokens"] == 0 and labels["loop_steps"] == 1
     assert 0.0 < float(labels["router_bias_abs_max"]) <= 4 * 0.001 + 1e-9
